@@ -1316,6 +1316,9 @@ def main(argv: list[str] | None = None) -> int:
         # anomalies => nonzero, so a soak harness can gate on the exit
         return 1 if out["anomalies"] else 0
     cfg = load_config(args.app_file)
+    from parameter_server_tpu.utils.hostenv import init_compile_cache
+
+    init_compile_cache()
     if getattr(args, "trace_dir", ""):
         # flag wins over both the config and the ambient env; run_node /
         # PodTrainer re-arm with a role-specific process name from cfg

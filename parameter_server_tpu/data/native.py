@@ -2,8 +2,9 @@
 
 Reference analog: src/data/text_parser.cc — the reference's parsing is
 C++; this keeps the rebuild's ingest hot path native too. The extension is
-built on demand with ``make`` (g++); if unavailable, callers fall back to
-the Python parsers in data/libsvm.py, which produce identical rows.
+built on demand with ``make`` (g++) from ``native/parser.cpp``; if the
+build fails, its stderr is printed and callers fall back to the Python
+parsers in data/libsvm.py, which produce identical rows.
 
 Chunked protocol: files are read in ~2 MiB chunks cut at line boundaries
 (measured-best: chunk + its parsed outputs stay LLC-resident — 2 MiB runs
@@ -20,6 +21,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import sys
 from collections.abc import Iterator
 from pathlib import Path
 
@@ -45,6 +47,10 @@ _lib_tried = False
 
 
 def _build() -> Path | None:
+    """Build ``libpsdata.so`` from ``parser.cpp`` with ``make`` unless an
+    up-to-date one is already there. A failed build returns None — callers
+    with ``backend="auto"`` then use the Python parsers — but never
+    quietly: the compiler's stderr goes to this process's stderr."""
     so = _NATIVE_DIR / "libpsdata.so"
     src = _NATIVE_DIR / "parser.cpp"
     if not src.exists():  # deployed artifact without sources: use as-is
@@ -56,11 +62,19 @@ def _build() -> Path | None:
             ["make", "-C", str(_NATIVE_DIR)],
             check=True,
             capture_output=True,
+            text=True,
             timeout=120,
         )
-        return so if so.exists() else None
-    except (subprocess.SubprocessError, OSError):
+    except (subprocess.SubprocessError, OSError) as e:
+        detail = getattr(e, "stderr", None) or ""
+        print(
+            f"[native] building {so} failed ({e}); falling back to the "
+            f"Python parsers\n{detail}",
+            file=sys.stderr,
+            flush=True,
+        )
         return None
+    return so if so.exists() else None
 
 
 def _tune_malloc() -> None:

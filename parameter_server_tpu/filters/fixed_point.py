@@ -67,20 +67,18 @@ class FixedPointCodec:
         )
 
     def encode_fast(self, seed: int, x: jax.Array) -> Encoded:
-        """Device-path encode: Pallas kernel with the TPU hardware PRNG
-        (~50x the threefry jnp path at 64 MB on v5e). Falls back to
-        ``encode`` off-TPU."""
+        """Device-path encode: Pallas kernel with the TPU hardware PRNG.
+        Raises off-TPU — use ``encode`` there."""
         from parameter_server_tpu.ops.pallas_kernels import (
             quantize_stochastic_pallas,
-            tpu_available,
+            require_tpu,
         )
 
-        if tpu_available():
-            q, lo, scale = quantize_stochastic_pallas(
-                seed, x, num_bytes=self.num_bytes
-            )
-            return Encoded(q, lo, scale)
-        return self.encode(jax.random.key(seed), x)
+        require_tpu("FixedPointCodec.encode_fast")
+        q, lo, scale = quantize_stochastic_pallas(
+            seed, x, num_bytes=self.num_bytes
+        )
+        return Encoded(q, lo, scale)
 
     def decode(self, e: Encoded) -> jax.Array:
         zero = self._levels // 2

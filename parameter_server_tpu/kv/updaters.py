@@ -113,7 +113,7 @@ class Ftrl:
     beta: float = 1.0
     lambda_l1: float = 1.0
     lambda_l2: float = 0.0
-    use_pallas: bool = False  # fuse the delta in one Pallas VMEM pass on TPU
+    use_pallas: bool = False  # fuse the delta in one Pallas VMEM pass (TPU only: raises elsewhere)
     name: str = "ftrl"
 
     def init(self, num_keys: int, vdim: int = 1, dtype: Any = jnp.float32) -> Rows:
@@ -126,17 +126,16 @@ class Ftrl:
         if self.use_pallas:
             from parameter_server_tpu.ops.pallas_kernels import (
                 ftrl_delta_pallas,
-                tpu_available,
+                require_tpu,
             )
 
-            if tpu_available():
-
-                dz, dn = ftrl_delta_pallas(
-                    rows["z"], rows["n"], grad,
-                    alpha=self.alpha, beta=self.beta,
-                    l1=self.lambda_l1, l2=self.lambda_l2,
-                )
-                return {"z": dz, "n": dn}
+            require_tpu("Ftrl(use_pallas=True)")
+            dz, dn = ftrl_delta_pallas(
+                rows["z"], rows["n"], grad,
+                alpha=self.alpha, beta=self.beta,
+                l1=self.lambda_l1, l2=self.lambda_l2,
+            )
+            return {"z": dz, "n": dn}
         n = rows["n"]
         w = self.weights(rows)
         n_new = n + grad * grad

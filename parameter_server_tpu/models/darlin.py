@@ -342,10 +342,8 @@ def make_darlin_spmd_fns(
     every block wholly inside one kv range (n_blocks % kv_shards == 0 with
     contiguous equal blocks).
     """
-    from jax import lax
+    from jax import lax, shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from parameter_server_tpu.utils.jaxcompat import shard_map
 
     kv = mesh.shape["kv"]
     if num_keys % kv:

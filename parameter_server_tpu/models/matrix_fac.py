@@ -175,9 +175,7 @@ def _make_mf_spmd(
 ):
     """Shared builder for the K=1 and scanned-K MF mesh programs (one home
     for validation, specs, and the jit contract)."""
-    from jax import lax
-
-    from parameter_server_tpu.utils.jaxcompat import shard_map
+    from jax import lax, shard_map
     from jax.sharding import PartitionSpec as P
 
     from parameter_server_tpu.parallel.spmd import (

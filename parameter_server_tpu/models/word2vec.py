@@ -159,9 +159,7 @@ def _make_w2v_spmd(
     parallel.spmd)."""
     import functools
 
-    from jax import lax
-
-    from parameter_server_tpu.utils.jaxcompat import shard_map
+    from jax import lax, shard_map
     from jax.sharding import PartitionSpec as P
 
     from parameter_server_tpu.parallel.spmd import _shard_size, state_spec

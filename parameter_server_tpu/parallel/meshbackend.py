@@ -136,11 +136,10 @@ class MeshBackend(PSBackend):
     def _programs(self):
         import jax
         import jax.numpy as jnp
-        from jax import lax
+        from jax import lax, shard_map
         from jax.sharding import PartitionSpec as P
 
         from parameter_server_tpu.filters.quant import dequantize_flat
-        from parameter_server_tpu.utils.jaxcompat import shard_map
 
         updater, shard, vdim = self.updater, self._shard, self.vdim
         # the non-kv mesh axes carry no state; specs stay kv-only and the

@@ -2662,7 +2662,12 @@ def launch_local(
     the harness simulates a multi-host cluster on one machine, and N
     processes must not fight over this host's accelerator (real multi-host
     runs get one process per host from the cluster manager, not from here).
-    ``devices="inherit"`` leaves the environment alone.
+    ``devices="inherit"`` leaves the environment alone — and is refused
+    with a ValueError unless that environment already says
+    ``JAX_PLATFORMS=cpu``: every node imports JAX and initialises a
+    backend, an accelerator belongs to one process at a time, and the
+    ``1 + num_servers + num_workers`` nodes started here would hang
+    waiting for it rather than fail.
 
     ``fault_kill="worker:1@2.0"`` is the fault-injection hook (SURVEY §5.3:
     "fault injection = kill a host process in the simulated integration
@@ -2699,6 +2704,15 @@ def launch_local(
         from parameter_server_tpu.utils.hostenv import force_cpu
 
         force_cpu(child_env)
+    elif devices != "inherit":
+        raise ValueError(f"devices must be 'cpu' or 'inherit', got {devices!r}")
+    elif child_env.get("JAX_PLATFORMS", "").strip().lower() != "cpu":
+        raise ValueError(
+            f"launch_local(devices='inherit') would start "
+            f"{1 + num_servers + num_workers} processes that each "
+            "initialise a JAX backend, and an accelerator belongs to one "
+            "process at a time; use devices='cpu', or set JAX_PLATFORMS=cpu"
+        )
     if fault_plan:
         FaultPlan.parse(fault_plan, seed=fault_seed)  # fail fast on a typo
         child_env[PLAN_ENV] = fault_plan
@@ -2862,6 +2876,8 @@ def run_node(
     """Role dispatch for one spawned process (ref: App::Create + main.cc)."""
     import os
 
+    from parameter_server_tpu.utils.hostenv import init_compile_cache
+
     # the ONE unknown-role gate, before ANY arming side effects (an
     # armed tracer/recorder/profiler named after a typo'd role, or a
     # KeyError out of the metrics-port table, are worse diagnostics);
@@ -2873,6 +2889,8 @@ def run_node(
     }.get(role)
     if metrics_offset is None:
         raise ValueError(f"unknown role {role!r}")
+
+    init_compile_cache()
 
     # arm tracing for this node: config [trace] trace_dir wins, then the
     # inherited PS_TRACE_DIR env (launch_local's arming path); the process

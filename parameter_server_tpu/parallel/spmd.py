@@ -31,10 +31,8 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from parameter_server_tpu.utils.jaxcompat import shard_map
 
 from parameter_server_tpu.data.batch import CSRBatch
 from parameter_server_tpu.kv.updaters import Updater
@@ -89,9 +87,8 @@ CSR_FULL_FIELDS = (
     "unique_keys", "local_ids", "row_ids", "values", "labels", "example_mask",
 )
 # Compact wire format: row structure rides as (B+1,) row_splits instead of
-# (NNZ,) row_ids — ~40% fewer host->device bytes at typical densities (the
-# usual bottleneck on PCIe/tunnel feeds); the device rebuilds row ids with
-# one searchsorted (see _row_ids_of).
+# (NNZ,) row_ids — ~40% fewer host->device bytes at typical densities; the
+# device rebuilds row ids with one searchsorted (see _row_ids_of).
 CSR_COMPACT_FIELDS = (
     "unique_keys", "local_ids", "row_splits", "values", "labels", "example_mask",
 )
@@ -403,7 +400,7 @@ def make_spmd_train_multistep(
     """K parameter-server steps per device call: ``lax.scan`` over a
     leading microstep axis inside ONE jitted shard_map program.
 
-    Why: on a tunneled or dispatch-bound host, per-step host->device
+    Why: on a dispatch-bound host, per-step host->device
     round trips (transfer + dispatch + retirement sync) put a hard floor
     under examples/sec no matter how fast the chip is. Scanning K
     microsteps amortizes that floor K-fold: one transfer of K stacked

@@ -637,7 +637,8 @@ class PodTrainer:
                 step_idx += 1
                 if step_idx % report_every == 0:
                     gate.drain()
-                    last = self._flush(window, n_since, t0)
+                    if n_since:  # a drained host's inert calls report nothing
+                        last = self._flush(window, n_since, t0)
                     window, n_since, t0 = [], 0, time.perf_counter()
             gate.wait_all()  # epoch sync point: every dispatched step retired
         finally:

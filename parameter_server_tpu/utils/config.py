@@ -40,9 +40,7 @@ class DataConfig:
     # the next power of two above the real count instead of the
     # max_nnz_per_example worst case — host->device bytes track actual
     # density; jit compiles once per bucket (a handful of shapes).
-    # Default ON: measured 3.5x e2e on TPU (BENCH_r03_local.json ladder,
-    # 4.1k -> 14.2k ex/s) and 5.8x on CPU (BENCH_r04 ladder) with AUC
-    # unchanged (0.854) in both — see BASELINE.md "default promotions"
+    # Default ON; its gain on the chip is not measured (ROADMAP S1)
     bucket_nnz: bool = True
     # compact wire format (on by default): int32 keys + (B+1,) row_splits
     # instead of (NNZ,) row_ids on the host->device transfer — ~40% fewer
@@ -87,8 +85,8 @@ class SolverConfig:
     # bounded-delay pipelining of many small Push/Pull tasks): K > 1 runs K
     # SEQUENTIAL parameter-server steps inside one jitted program — one
     # host->device transfer, one dispatch, one retirement per K steps —
-    # amortizing the per-call round-trip floor that dominates on tunneled
-    # or dispatch-bound hosts. Same trajectory as K single-step calls;
+    # amortizing the per-call round-trip floor that dominates on
+    # dispatch-bound hosts. Same trajectory as K single-step calls;
     # max_delay then counts device CALLS in flight (each K steps deep).
     # Honored by the linear_method path (PodTrainer) and the word2vec and
     # matrix_fac apps (steps_per_call=..., wired from this field by the CLI).

@@ -380,6 +380,30 @@ class TestDurablePushDedup:
             srv.server.stop()
 
 
+class TestLaunchLocalDevices:
+    """launch_local starts 1 + servers + workers processes that each
+    initialise a JAX backend: letting them inherit an accelerator must be
+    a named error before anything is spawned, not a hang."""
+
+    def test_inherit_refuses_an_unpinned_environment(self, monkeypatch):
+        from parameter_server_tpu.parallel.multislice import launch_local
+
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        spawned = []
+        monkeypatch.setattr(
+            "subprocess.Popen", lambda *a, **k: spawned.append(a)
+        )
+        with pytest.raises(ValueError, match="4 processes.*one process at a time"):
+            launch_local("unused.json", 2, 1, devices="inherit")
+        assert spawned == []
+
+    def test_unknown_devices_value(self):
+        from parameter_server_tpu.parallel.multislice import launch_local
+
+        with pytest.raises(ValueError, match="'cpu' or 'inherit'"):
+            launch_local("unused.json", 1, 1, devices="tpu")
+
+
 @pytest.mark.slow
 class TestLaunchLocal:
     """The reference's local.sh run, for real: 1 scheduler + 2 servers +

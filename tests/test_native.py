@@ -530,3 +530,20 @@ class TestCount4:
             ord(":"), 0x0A, 0x00, 0x00, out,
         )
         assert out[0] == 37 and out[1] == 3
+
+
+class TestBuildIsLoud:
+    def test_failed_build_prints_the_compiler_stderr(
+        self, tmp_path, monkeypatch, capfd
+    ):
+        """A parser.cpp that does not compile must not quietly become the
+        Python parser: _build returns None AND shows the compiler's words."""
+        import shutil
+
+        shutil.copy(native._NATIVE_DIR / "Makefile", tmp_path / "Makefile")
+        (tmp_path / "parser.cpp").write_text("this is not C++;\n")
+        monkeypatch.setattr(native, "_NATIVE_DIR", tmp_path)
+        assert native._build() is None
+        err = capfd.readouterr().err
+        assert "building" in err and "failed" in err
+        assert "parser.cpp" in err and "error" in err

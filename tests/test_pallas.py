@@ -1,11 +1,9 @@
 """Pallas kernel numerics tests (interpret mode on the CPU mesh).
 
-The real (non-interpret) kernels only execute on TPU hardware:
-bench.py's pallas_ftrl sub-bench times the fused FTRL delta against the
-jnp composite there and flips the headline step to use_pallas=True when
-the kernel wins; nothing in this CPU test tree runs them for real."""
+Mosaic compiles the kernels only on a TPU: chip_smoke.py checks each
+against its XLA reference there; nothing in this CPU test tree runs
+them compiled."""
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -23,11 +21,6 @@ from parameter_server_tpu.ops.pallas_kernels import (
 def interpret_mode():
     from jax.experimental.pallas import tpu as pltpu
 
-    if not hasattr(pltpu, "force_tpu_interpret_mode"):
-        pytest.skip(
-            "this jax's pallas has no force_tpu_interpret_mode; "
-            "kernel parity is covered on real hardware by bench.py"
-        )
     with pltpu.force_tpu_interpret_mode():
         yield
 
@@ -54,13 +47,12 @@ class TestFtrlKernel:
         np.testing.assert_allclose(np.asarray(dz), np.asarray(ref["z"]), atol=1e-6)
         np.testing.assert_allclose(np.asarray(dn), np.asarray(ref["n"]), atol=1e-6)
 
-    def test_use_pallas_flag_cpu_fallback(self):
-        """On CPU the flag falls back to jnp — same numbers, no crash."""
-        up = Ftrl(use_pallas=True)
+    def test_use_pallas_flag_raises_off_tpu(self):
+        """Asking for the kernel on a backend that cannot run it raises
+        instead of quietly computing the jnp composite."""
         rows = {"z": jnp.ones((4, 1)), "n": jnp.ones((4, 1))}
-        d = up.delta(rows, jnp.ones((4, 1)))
-        ref = Ftrl().delta(rows, jnp.ones((4, 1)))
-        np.testing.assert_allclose(np.asarray(d["z"]), np.asarray(ref["z"]))
+        with pytest.raises(RuntimeError, match="needs a TPU backend"):
+            Ftrl(use_pallas=True).delta(rows, jnp.ones((4, 1)))
 
 
 class TestQuantizeKernel:
@@ -77,89 +69,3 @@ class TestQuantizeKernel:
         assert q.dtype == jnp.int16
         dec = (q.astype(jnp.float32) + 32767) * scale + lo
         assert float(jnp.max(jnp.abs(dec - x))) <= float(scale) + 1e-6
-
-
-class TestFusedPushKernel:
-    """Fused gather -> FTRL -> scatter (HOT LOOP #2 as one VMEM pass):
-    interpret-mode parity against kv.store.push. ULP tolerance, not
-    bitwise: XLA may contract n + g*g into one FMA; the kernel's op
-    order is otherwise identical."""
-
-    @pytest.mark.parametrize("vdim,u", [(1, 300), (1, 256), (8, 77), (16, 5)])
-    def test_matches_store_push(self, interpret_mode, rng, vdim, u):
-        from parameter_server_tpu.kv import store
-        from parameter_server_tpu.ops.pallas_kernels import ftrl_push_pallas
-
-        K = 2048
-        z = rng.normal(size=(K, vdim)).astype(np.float32)
-        n = np.abs(rng.normal(size=(K, vdim))).astype(np.float32)
-        uniq = np.unique(rng.integers(1, K, u))
-        idx = np.concatenate([uniq, [0, 0]])  # duplicate PAD rows, zero grad
-        g = rng.normal(size=(len(idx), vdim)).astype(np.float32)
-        g[len(uniq):] = 0.0
-        up = Ftrl(alpha=0.1, beta=1.0, lambda_l1=1.0, lambda_l2=0.0)
-        ref = store.push(
-            up, {"z": jnp.asarray(z), "n": jnp.asarray(n)},
-            jnp.asarray(idx), jnp.asarray(g),
-        )
-        got = ftrl_push_pallas(
-            {"z": jnp.asarray(z), "n": jnp.asarray(n)},
-            jnp.asarray(idx), jnp.asarray(g),
-            alpha=0.1, beta=1.0, l1=1.0, l2=0.0,
-        )
-        for k in ("z", "n"):
-            np.testing.assert_allclose(
-                np.asarray(got[k]), np.asarray(ref[k]), rtol=1e-6, atol=1e-6
-            )
-        # untouched rows are EXACTLY the originals (in-place aliasing)
-        untouched = np.setdiff1d(np.arange(1, K), uniq)[:50]
-        np.testing.assert_array_equal(
-            np.asarray(got["z"])[untouched], z[untouched]
-        )
-
-    @pytest.mark.parametrize(
-        "vdim,u,l2",
-        [
-            # (16, 300) forces kernel-internal tile padding (u_pad > u);
-            # every case carries DUPLICATE pad slots. With l2 > 0 the
-            # pad row's inertness relies on the framework invariant that
-            # row 0's state is zero (init + dump exclusion maintain it);
-            # the l2=0 case keeps a random nonzero row 0 to show zero
-            # grad is inert for ANY state there.
-            (16, 300, 0.01),
-            (64, 40, 0.01),
-            (16, 120, 0.0),
-        ],
-    )
-    def test_adagrad_matches_store_push(self, interpret_mode, rng, vdim, u, l2):
-        """Same scaffold, AdaGrad math (the embedding-table updater):
-        parity against kv.store.push at embedding-shaped vdims,
-        including duplicate pad slots and tile-padded shapes."""
-        from parameter_server_tpu.kv import store
-        from parameter_server_tpu.kv.updaters import Adagrad
-        from parameter_server_tpu.ops.pallas_kernels import adagrad_push_pallas
-
-        K = 1024
-        w = rng.normal(size=(K, vdim)).astype(np.float32)
-        n = np.abs(rng.normal(size=(K, vdim))).astype(np.float32)
-        if l2 > 0.0:
-            w[0] = 0.0  # the PAD-row invariant the framework maintains
-            n[0] = 0.0
-        uniq = np.unique(rng.integers(1, K, u))
-        idx = np.concatenate([uniq, [0, 0]])
-        g = rng.normal(size=(len(idx), vdim)).astype(np.float32)
-        g[len(uniq):] = 0.0
-        up = Adagrad(eta=0.1, eps=1e-8, lambda_l2=l2)
-        ref = store.push(
-            up, {"w": jnp.asarray(w), "n": jnp.asarray(n)},
-            jnp.asarray(idx), jnp.asarray(g),
-        )
-        got = adagrad_push_pallas(
-            {"w": jnp.asarray(w), "n": jnp.asarray(n)},
-            jnp.asarray(idx), jnp.asarray(g),
-            eta=0.1, eps=1e-8, l2=l2,
-        )
-        for k in ("w", "n"):
-            np.testing.assert_allclose(
-                np.asarray(got[k]), np.asarray(ref[k]), rtol=1e-6, atol=1e-6
-            )

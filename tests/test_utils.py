@@ -126,3 +126,44 @@ class TestMetrics:
         with t:
             pass
         assert t.count == 1 and t.total >= 0
+
+
+class TestCompileCachePlacement:
+    """utils.hostenv.init_compile_cache: the one setter of the persistent
+    compile cache's directory (jax.config.update is intercepted, so the
+    process-wide config stays as conftest left it)."""
+
+    def _updates(self, monkeypatch) -> dict:
+        import jax
+
+        seen: dict = {}
+        monkeypatch.setattr(
+            jax.config, "update", lambda k, v: seen.__setitem__(k, v)
+        )
+        return seen
+
+    def test_env_placed_cache_is_left_to_jax(self, monkeypatch, tmp_path):
+        from parameter_server_tpu.utils.hostenv import init_compile_cache
+
+        seen = self._updates(monkeypatch)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert init_compile_cache() == str(tmp_path)
+        assert seen == {}
+
+    def test_unset_env_points_at_the_checkout(self, monkeypatch):
+        from pathlib import Path
+
+        from parameter_server_tpu.utils.hostenv import init_compile_cache
+
+        seen = self._updates(monkeypatch)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(Path(__file__).resolve().parent.parent / ".jax_cache")
+        assert init_compile_cache() == want
+        assert seen["jax_compilation_cache_dir"] == want
+
+    def test_force_cpu_pins_only_the_platform(self):
+        from parameter_server_tpu.utils.hostenv import force_cpu
+
+        env = {"JAX_PLATFORMS": "tpu", "OTHER": "kept"}
+        assert force_cpu(env) is env
+        assert env == {"JAX_PLATFORMS": "cpu", "OTHER": "kept"}
