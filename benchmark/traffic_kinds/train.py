@@ -1,0 +1,118 @@
+"""Traffic kind ``train``: one long epoch of whole device calls.
+
+Set-up trains the correctness prefix from the fresh table (which also
+compiles the cell's one bucket shape), then starts ONE epoch over a file
+list long enough for any window. The window opens at the retire of warm
+call ``warm_calls - 1`` of that epoch - the pipeline is full and
+``max_delay + 1`` calls are in flight - and closes at the first retire at
+or after ``--seconds``; the hook then stops the epoch. Examples retired
+between the two stamps over the time between them is ``ex_rate``: no
+partial call, no pipeline fill, no drain, no report inside.
+
+Parameters (the mix's JSON): ``train_files``/``heldout_files`` (files of
+``steps_per_call x minibatch`` examples, cycled), ``prefix_calls``,
+``warm_calls``, ``min_call_s`` (the shortest device call the file list is
+sized for), ``limits``.
+"""
+
+from __future__ import annotations
+
+import math
+import time
+
+import numpy as np
+
+from benchmark.harness import window
+from benchmark.harness.checks import Check
+
+
+def run(ctx, app) -> dict:
+    import jax
+
+    from parameter_server_tpu.utils.metrics import timers
+
+    t = ctx.traffic
+    sess = app.Session(ctx)
+    build_rate = sess.measure_build_rate()
+    sess.prefix()
+
+    warm = int(t["warm_calls"])
+    cap_calls = warm + math.ceil(ctx.seconds / float(t["min_call_s"])) + 1
+    files = sess.file_list(cap_calls * sess.data_shards, start=sess.prefix_files)
+    open_at = warm - 1
+    stamps: list = []
+    snap: dict = {}
+
+    def on_retire(stamp: float, i: int) -> None:
+        stamps.append(stamp)
+        ctx.mark("bench.retire")
+        if i == open_at:
+            ctx.mark("bench.window_open")
+            snap["open"] = timers.snapshot()
+            snap["setup_s"] = stamp - ctx.t0 - ctx.excluded_s
+        elif i > open_at and stamp - stamps[open_at] >= ctx.seconds:
+            ctx.mark("bench.window_close")
+            snap["close"] = timers.snapshot()
+            raise app.StopWindow
+
+    sess.on_retire = on_retire
+    if ctx.trace:
+        jax.profiler.start_trace(ctx.trace_dir)
+    try:
+        ran_out = sess.train(files)
+    finally:
+        sess.on_retire = None
+        jax.block_until_ready(sess.trainer.state)
+        if ctx.trace:
+            jax.profiler.stop_trace()
+    if ran_out:
+        raise RuntimeError(
+            f"the epoch of {cap_calls} calls ended before the window closed: "
+            f"lower min_call_s ({t['min_call_s']}) in the traffic file"
+        )
+
+    ctx.stage("window closed")
+    work = sess.call_work()
+    win = window.summarize(stamps, work[: len(stamps)], open_at, ctx.seconds)
+    losses, dev_examples = sess.call_outputs()  # every dispatched call, in flight ones too
+    inside = range(open_at + 1, len(work))  # dispatched after the window opened
+    attempted = int(sum(work[i] for i in inside))
+    done = int(sum(dev_examples[i].sum() for i in inside if np.isfinite(losses[i]).all()))
+    nonfinite = int(sum((~np.isfinite(l)).sum() for l in losses))
+
+    # after the window: held-out quality of the trained table, then the reference
+    ev = sess.evaluate(sess.heldout_paths)
+    ref, ref_losses, held = sess.reference("float32")
+    ref_auc, _, _ = app.heldout_scores(ref, held)
+    lim = t["limits"]
+    checks = sess.prefix_checks(ref, ref_losses) + [
+        Check("window.nonfinite_losses", nonfinite, 0),
+        Check("window.unretired_examples", attempted - done, 0),
+        Check(
+            "heldout.auc_below_reference", ref_auc - float(ev["auc"]), lim["heldout.auc_below_reference"],
+            note=f"program after the window {ev['auc']:.4f}, reference after the prefix {ref_auc:.4f}",
+        ),
+    ]
+    ctx.stage("reference compared")
+    sess.close()
+    return {
+        "end_to_end": {"ex_rate": win["rate"], "setup_s": snap["setup_s"]},
+        "attempted": attempted,
+        "failed": attempted - done,
+        "checks": checks,
+        "window": win,
+        "stamps": window.stamp_lines(stamps, work[: len(stamps)], open_at, win["close_at"]),
+        "timers_open": snap["open"],
+        "timers_close": snap["close"],
+        "facts": {
+            "build_rate": build_rate,
+            "inflight_peak": sess.trainer.max_inflight,
+            "microsteps": win["units"] * sess.steps_per_call,
+            "data_shards": sess.data_shards,
+            "kv_shards": sess.kv_shards,
+            "bucket_rows": sess.bucket_rows,
+            "pushes_per_step": sess.data_shards,
+            "mode": "train",
+        },
+    }
+
