@@ -15,6 +15,13 @@ queue. The dispatch loop then only pops + dispatches the device step,
 overlapping host parse/build with device compute instead of serializing
 D batch builds inline before every step.
 
+Named phases (``trace.phase``: a named timer each, and a span in the
+profiler's and the tracer's timelines when those run): ``feed.build`` one
+``next_batch()`` in a producer thread (count: batches), ``feed.put_wait``
+a producer blocked on its full queue (the feed's slack), ``feed.stack``
+``prepare`` + ``assemble`` in the stacker thread (count: emitted items;
+one thread serves every stream, so its busy share has a wall at 100%).
+
 Draining contract: ``get()`` returns ``None`` once every stream is
 exhausted (and forever after). Callers that must keep issuing collectives
 (multi-host SPMD: every process runs the same program) substitute their
@@ -27,6 +34,8 @@ import queue
 import threading
 from collections.abc import Callable, Sequence
 from typing import Any
+
+from parameter_server_tpu.utils import trace
 
 _END = object()
 
@@ -101,12 +110,20 @@ class PrefetchPipeline:
     # -- threads -----------------------------------------------------------
     def _produce(self, i: int) -> None:
         try:
+            q = self._qs[i]
             while not self._stop.is_set():
-                b = self.streams[i].next_batch()
-                if b is None:
-                    break
-                if not self._put(self._qs[i], b):
-                    return
+                with trace.phase("feed.build") as build:
+                    b = self.streams[i].next_batch()
+                    if b is None:
+                        build.count = 0  # the probe that finds it drained
+                        break
+                try:
+                    q.put_nowait(b)
+                except queue.Full:
+                    # the feed's slack: this stream is ahead of the stacker
+                    with trace.phase("feed.put_wait"):
+                        if not self._put(q, b):
+                            return
         except BaseException as e:  # re-raised on the consumer side
             self._errs.append(e)
         finally:
@@ -130,21 +147,26 @@ class PrefetchPipeline:
                         batches.append(item)
                 if all(done):
                     break
-                prepared = self.prepare(batches)
-                if self.group_size == 1:
-                    if not self._put(self._out, prepared):
-                        return
-                    continue
-                pending.append(prepared)
-                if len(pending) == self.group_size:
-                    if not self._put(self._out, self.assemble(pending)):
-                        return
-                    pending = []
+                # feed.stack counts emitted items: a group's K prepares and
+                # its one assemble add up to one
+                with trace.phase("feed.stack") as stack:
+                    item = self.prepare(batches)
+                    if self.group_size > 1:
+                        pending.append(item)
+                        if len(pending) < self.group_size:
+                            stack.count = 0
+                            continue
+                        item = self.assemble(pending)
+                        pending = []
+                if not self._put(self._out, item):
+                    return
             if pending and not self._stop.is_set():
                 # pad the final partial group with inert prepared items
-                empty = self.prepare([s._empty() for s in self.streams])
-                pending += [empty] * (self.group_size - len(pending))
-                self._put(self._out, self.assemble(pending))
+                with trace.phase("feed.stack"):
+                    empty = self.prepare([s._empty() for s in self.streams])
+                    pending += [empty] * (self.group_size - len(pending))
+                    item = self.assemble(pending)
+                self._put(self._out, item)
         except BaseException as e:
             self._errs.append(e)
         finally:

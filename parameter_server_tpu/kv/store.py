@@ -58,8 +58,10 @@ def pad_state_rows(state: State, num_rows: int) -> State:
 @functools.partial(jax.jit, static_argnums=0)
 def pull(updater: Updater, state: State, idx: jax.Array) -> jax.Array:
     """Gather weights for (unique, padded) key indices: (U,) -> (U, vdim)."""
-    rows = {k: jnp.take(v, idx, axis=0) for k, v in state.items()}
-    return updater.weights(rows)
+    # phase names shared with the SPMD step (parallel.spmd.PHASE_SCOPES)
+    with jax.named_scope("ps.pull"):
+        rows = {k: jnp.take(v, idx, axis=0) for k, v in state.items()}
+        return updater.weights(rows)
 
 
 @functools.partial(jax.jit, static_argnums=0)
@@ -68,9 +70,13 @@ def push(updater: Updater, state: State, idx: jax.Array, grad: jax.Array) -> Sta
 
     grad: (U, vdim) pre-aggregated gradient aligned with ``idx``.
     """
-    rows = {k: jnp.take(v, idx, axis=0) for k, v in state.items()}
-    deltas = updater.delta(rows, grad)
-    return {k: state[k].at[idx].add(deltas[k]) for k in state}
+    with jax.named_scope("ps.push"):
+        with jax.named_scope("gather"):
+            rows = {k: jnp.take(v, idx, axis=0) for k, v in state.items()}
+        with jax.named_scope("update"):
+            deltas = updater.delta(rows, grad)
+        with jax.named_scope("scatter"):
+            return {k: state[k].at[idx].add(deltas[k]) for k in state}
 
 
 @functools.partial(jax.jit, static_argnums=0)

@@ -26,7 +26,9 @@ per-data-shard CSRBatches stacked on a leading axis and sharded over
 
 from __future__ import annotations
 
+import dataclasses
 import functools
+import re
 from typing import Any
 
 import jax
@@ -40,6 +42,104 @@ from parameter_server_tpu.ops.sparse import csr_grad, csr_logits, logistic_loss
 
 State = dict[str, jax.Array]
 Batch = dict[str, jax.Array]
+
+# Phase names of one parameter-server step, as ``jax.named_scope``s in the
+# step and predict programs and in ``kv.store``. A contract: the benchmark's
+# ``step.*_ms`` readers and PERF.md find device time by these names (through
+# ``op_scopes``), so whatever replaces the code underneath keeps them.
+# Inside "ps.push" three nested scopes: "gather" (rows read for the
+# updater), "update" (``updater.delta``), "scatter" (the ``.at[].add``).
+PHASE_SCOPES = ("ps.row_ids", "ps.pull", "ps.grad", "ps.push")
+_PUSH_STAGES = ("gather", "update", "scatter")
+
+@dataclasses.dataclass
+class _RanProgram:
+    """A step or predict program and the abstract arguments of its first
+    call with one set of shapes: what ``op_scopes`` compiles again, after
+    the run, to read the names (kept in ``scopes`` once read)."""
+
+    jitted: Any
+    args: tuple
+    scopes: tuple[str, dict[str, str]] | None = None
+
+
+_ran: list[_RanProgram] = []
+
+
+def _note_program(jitted, seen: set, *args) -> None:
+    """Remember the shapes, dtypes and shardings a step or predict program
+    is called with, once a distinct set: a dict lookup on the dispatch
+    path, nothing compiled or read here."""
+    key = tuple(
+        (getattr(x, "shape", None), getattr(x, "dtype", None))
+        for x in jax.tree.leaves(args)
+    )
+    if key in seen:
+        return
+    seen.add(key)
+
+    def abstract(x):
+        if not hasattr(x, "shape"):
+            return x  # a Python scalar keeps its weak type
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=getattr(x, "sharding", None)
+        )
+
+    _ran.append(_RanProgram(jitted, jax.tree.map(abstract, args)))
+
+
+def hlo_scopes(hlo_text: str) -> tuple[str, dict[str, str]]:
+    """(module name, {instruction name: scope path}) of one optimised HLO
+    module's text. The scope path is the ``ps.*`` phase in the
+    instruction's ``op_name`` metadata (a fusion carries its root's), with
+    the push's nested stage after a slash (``ps.push/scatter``); ``""`` for
+    an instruction that carries none (input copies, some custom calls)."""
+    module = re.search(r"^HloModule\s+([^\s,]+)", hlo_text, re.M)
+    out: dict[str, str] = {}
+    for m in re.finditer(
+        r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = (.*)$", hlo_text, re.M
+    ):
+        op_name = re.search(r'op_name="([^"]*)"', m.group(2))
+        out[m.group(1)] = _scope_of(op_name.group(1)) if op_name else ""
+    return (module.group(1) if module else ""), out
+
+
+def _scope_of(op_name: str) -> str:
+    parts = op_name.split("/")
+    for i, part in enumerate(parts):
+        if part in PHASE_SCOPES:
+            if part == "ps.push":
+                # the last part is the primitive's own name ("gather")
+                for stage in parts[i + 1 : -1]:
+                    if stage in _PUSH_STAGES:
+                        return f"{part}/{stage}"
+            return part
+    return ""
+
+
+def op_scopes() -> dict[str, dict[str, str]]:
+    """{HLO module name: {instruction name: scope path}} of every step and
+    predict program this process has run, read from the optimised HLO of
+    the executable (``hlo_scopes``). A profile names device ops by
+    instruction; this says which phase of the parameter-server step each
+    belongs to, whatever XLA numbered its fusions this time.
+
+    Derived here, on request, from the shapes the steppers were first
+    called with: compiling them again is a fetch from the persistent
+    compile cache where one is set, a compile otherwise, so call it after
+    the timed part of a run. Programs that share a module name (one step at
+    two bucket shapes) share a map; an instruction they scope differently
+    reads ``""``, as an unscoped one does."""
+    out: dict[str, dict[str, str]] = {}
+    for ran in _ran:
+        if ran.scopes is None:
+            compiled = ran.jitted.lower(*ran.args).compile()
+            ran.scopes = hlo_scopes(compiled.as_text())
+        module, scopes = ran.scopes
+        have = out.setdefault(module, {})
+        for name, scope in scopes.items():
+            have[name] = scope if have.get(name, scope) == scope else ""
+    return out
 
 
 def state_spec() -> P:
@@ -174,10 +274,15 @@ def _local_push(
         local = idx - begin
         in_range = (local >= 0) & (local < shard_size)
         safe = jnp.where(in_range, local, 0)
-        rows = {k: jnp.take(v, safe, axis=0) for k, v in state_l.items()}
-        deltas = updater.delta(rows, g)
-        mask = in_range[:, None].astype(g.dtype)
-        new = {k: state_l[k].at[safe].add(mask * deltas[k]) for k in state_l}
+        with jax.named_scope("gather"):
+            rows = {k: jnp.take(v, safe, axis=0) for k, v in state_l.items()}
+        with jax.named_scope("update"):
+            deltas = updater.delta(rows, g)
+        with jax.named_scope("scatter"):
+            mask = in_range[:, None].astype(g.dtype)
+            new = {
+                k: state_l[k].at[safe].add(mask * deltas[k]) for k in state_l
+            }
         return new, None
 
     new_state, _ = lax.scan(body, state_l, (all_idx, all_grad))
@@ -213,14 +318,19 @@ def _local_push_aggregate(
     safe = jnp.where(in_range, local, 0)
     mask = in_range[:, None].astype(grad.dtype)
     vdim = grad.shape[-1]
-    g_slice = jnp.zeros((shard_size, vdim), grad.dtype).at[safe].add(mask * grad)
-    touched = jnp.zeros((shard_size, 1), grad.dtype).at[safe].add(mask)
+    with jax.named_scope("scatter"):
+        g_slice = jnp.zeros((shard_size, vdim), grad.dtype).at[safe].add(
+            mask * grad
+        )
+        touched = jnp.zeros((shard_size, 1), grad.dtype).at[safe].add(mask)
     # one collective pre-sums every worker's contribution to this range
     g_slice = lax.psum(g_slice, "data")
     touched = lax.psum(touched, "data")
-    deltas = updater.delta(state_l, g_slice)
-    hit = (touched > 0).astype(grad.dtype)
-    return {k: state_l[k] + hit * deltas[k] for k in state_l}
+    # no "gather" here: the updater reads the whole range slice
+    with jax.named_scope("update"):
+        deltas = updater.delta(state_l, g_slice)
+        hit = (touched > 0).astype(grad.dtype)
+        return {k: state_l[k] + hit * deltas[k] for k in state_l}
 
 
 def _local_push_quantized(
@@ -290,6 +400,8 @@ def _wrap_stepper(step, push_mode: str):
         new_state, loss, ex, probs = step(state, batch, jnp.int32(push_seed))
         return new_state, {"loss_sum": loss, "examples": ex, "probs": probs}
 
+    seen: set = set()
+
     def stepper(state: State, batch: Batch, push_seed=None):
         if push_seed is None:
             if push_mode == "quantized":
@@ -301,6 +413,7 @@ def _wrap_stepper(step, push_mode: str):
                     "call step(state, batch, step_index)"
                 )
             push_seed = 0
+        _note_program(_jitted, seen, state, batch, push_seed)
         return _jitted(state, batch, push_seed)
 
     return stepper
@@ -318,37 +431,45 @@ def _microstep(
     Shared verbatim by the single-step and scanned multi-step programs so
     the wire semantics cannot diverge between them."""
     idx = b["unique_keys"]
-    row_ids = _row_ids_of(b)
-    values = _values_of(b)
-    w_u = lax.psum(
-        _local_pull(updater, state_l, idx, shard_size), "kv"
-    )  # Pull: slice + merge (ref kv_vector match)
-    logits = csr_logits(
-        w_u, values, b["local_ids"], row_ids,
-        num_rows=b["labels"].shape[0],
-    )
-    loss, err = logistic_loss(logits, b["labels"], b["example_mask"])
-    g = csr_grad(
-        err, values, b["local_ids"], row_ids, num_unique=idx.shape[0]
-    )
-    if push_mode == "aggregate":
-        new_state = _local_push_aggregate(updater, state_l, idx, g, shard_size)
-    elif push_mode == "quantized":
-        new_state = _local_push_quantized(
-            updater, state_l, idx, g, shard_size, push_seed
+    with jax.named_scope("ps.row_ids"):
+        row_ids = _row_ids_of(b)
+    with jax.named_scope("ps.pull"):
+        w_u = lax.psum(
+            _local_pull(updater, state_l, idx, shard_size), "kv"
+        )  # Pull: slice + merge (ref kv_vector match)
+    with jax.named_scope("ps.grad"):
+        values = _values_of(b)
+        logits = csr_logits(
+            w_u, values, b["local_ids"], row_ids,
+            num_rows=b["labels"].shape[0],
         )
-    else:
-        # Push: every data shard's (keys, grads) reach every kv shard.
-        all_idx = lax.all_gather(idx, "data")  # (D, U)
-        all_grad = lax.all_gather(g, "data")  # (D, U, vdim)
-        new_state = _local_push(updater, state_l, all_idx, all_grad, shard_size)
+        loss, err = logistic_loss(logits, b["labels"], b["example_mask"])
+        g = csr_grad(
+            err, values, b["local_ids"], row_ids, num_unique=idx.shape[0]
+        )
+        probs = jax.nn.sigmoid(logits)
+    with jax.named_scope("ps.push"):
+        if push_mode == "aggregate":
+            new_state = _local_push_aggregate(
+                updater, state_l, idx, g, shard_size
+            )
+        elif push_mode == "quantized":
+            new_state = _local_push_quantized(
+                updater, state_l, idx, g, shard_size, push_seed
+            )
+        else:
+            # Push: every data shard's (keys, grads) reach every kv shard.
+            all_idx = lax.all_gather(idx, "data")  # (D, U)
+            all_grad = lax.all_gather(g, "data")  # (D, U, vdim)
+            new_state = _local_push(
+                updater, state_l, all_idx, all_grad, shard_size
+            )
     loss_sum = lax.psum(loss, "data")
     # pod-wide real-example count: the host-side termination signal
     # (a drained host keeps feeding empty batches; every host stops
     # deterministically after retiring a step with examples == 0 —
     # this rides async dispatch instead of a blocking host barrier)
     examples = lax.psum(jnp.sum(b["example_mask"]), "data")
-    probs = jax.nn.sigmoid(logits)
     return new_state, loss_sum, examples, probs
 
 
@@ -479,14 +600,19 @@ def make_spmd_predict_step(updater: Updater, mesh: Mesh, num_keys: int):
 
     def local_predict(state_l: State, batch: Batch):
         b = {k: v[0] for k, v in batch.items()}
-        w_u = lax.psum(
-            _local_pull(updater, state_l, b["unique_keys"], shard_size), "kv"
-        )
-        logits = csr_logits(
-            w_u, _values_of(b), b["local_ids"], _row_ids_of(b),
-            num_rows=b["labels"].shape[0],
-        )
-        return jax.nn.sigmoid(logits)[None, :]
+        with jax.named_scope("ps.row_ids"):
+            row_ids = _row_ids_of(b)
+        with jax.named_scope("ps.pull"):
+            w_u = lax.psum(
+                _local_pull(updater, state_l, b["unique_keys"], shard_size),
+                "kv",
+            )
+        with jax.named_scope("ps.grad"):
+            logits = csr_logits(
+                w_u, _values_of(b), b["local_ids"], row_ids,
+                num_rows=b["labels"].shape[0],
+            )
+            return jax.nn.sigmoid(logits)[None, :]
 
     step = shard_map(
         local_predict,
@@ -495,4 +621,11 @@ def make_spmd_predict_step(updater: Updater, mesh: Mesh, num_keys: int):
         out_specs=batch_spec(),
         check_vma=False,
     )
-    return jax.jit(step)
+    jitted = jax.jit(step)
+    seen: set = set()
+
+    def predict(state: State, batch: Batch) -> jax.Array:
+        _note_program(jitted, seen, state, batch)
+        return jitted(state, batch)
+
+    return predict

@@ -18,6 +18,7 @@ host batch-prep with device compute."""
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import time
 from collections.abc import Iterator
@@ -45,7 +46,7 @@ from parameter_server_tpu.parallel.ssp import DispatchWindow, SSPClock
 from parameter_server_tpu.parallel.workload import WorkloadPool
 from parameter_server_tpu.utils import flightrec, trace
 from parameter_server_tpu.utils.config import PSConfig
-from parameter_server_tpu.utils.metrics import ProgressReporter, timers
+from parameter_server_tpu.utils.metrics import ProgressReporter
 
 
 # process-wide trainer sequence for control-plane KV namespacing (see
@@ -289,25 +290,14 @@ class PodTrainer:
         # observability: peak dispatch run-ahead (the SSP/async-overlap
         # depth actually reached; == max_delay + 1 when the gate binds)
         self.max_inflight = 0
-        # observability (SURVEY §5.1): jax.profiler traces on demand + the
-        # static per-step collective-byte estimate in every report (the
-        # reference's Postoffice byte counters; reconcile the estimate
-        # against profiler-measured collective sizes on real hardware)
+        # observability (SURVEY §5.1): a jax.profiler trace of every
+        # train_files / evaluate_files call on demand; the host phases
+        # below (trace.phase) land on its timeline by name
         self.profile_dir = profile_dir
-        from parameter_server_tpu.parallel.traffic import linear_step_traffic
-
-        cap = min(
-            cfg.solver.minibatch * cfg.data.max_nnz_per_example + 1,
-            cfg.data.num_keys,
-        )
-        self.est_step_traffic = linear_step_traffic(
-            unique_capacity=cap,
-            vdim=1,
-            data_shards=self.data_shards,
-            kv_shards=self.mesh.shape["kv"],
-            push_mode=cfg.parallel.push_mode,
-            num_keys=cfg.data.num_keys,
-        )
+        # (program, nnz, unique) bucket shapes this trainer has dispatched:
+        # with bucket_nnz a new one is a new device program (the phases
+        # trainer.new_shapes / eval.new_shapes)
+        self._dispatched_shapes: set[tuple[str, int, int]] = set()
 
     def _builder(self, key_mode: str) -> BatchBuilder:
         from parameter_server_tpu.data.batch import training_builder
@@ -325,13 +315,26 @@ class PodTrainer:
             return self._run_epochs(files, key_mode, report_every)
 
     def _trace_cm(self):
-        import contextlib
-
         return (
             jax.profiler.trace(self.profile_dir)
             if self.profile_dir
             else contextlib.nullcontext()
         )
+
+    def _new_shape_phase(self, name: str, stacked: dict, **args):
+        """The timed phase ``name`` (``trainer.new_shapes`` for the step,
+        ``eval.new_shapes`` for predict) around the first device call of
+        each bucket shape — the call that compiles, or fetches, a program:
+        its count says how many, its span which bucket (and step). A null
+        context for a shape this trainer has dispatched before."""
+        bucket = (
+            name, stacked["values"].shape[-1],
+            stacked["unique_keys"].shape[-1],
+        )
+        if bucket in self._dispatched_shapes:
+            return contextlib.nullcontext()
+        self._dispatched_shapes.add(bucket)
+        return trace.phase(name, nnz=bucket[1], unique=bucket[2], **args)
 
     def train_files_dynamic(
         self,
@@ -504,8 +507,7 @@ class PodTrainer:
             # np.asarray blocks until the device call is done (the SSP
             # bound taking effect); single-step outputs are scalars,
             # multistep outputs carry a (K,) microstep axis
-            with trace.span("step.retire", cat="step", step=step), \
-                    timers.timer("trainer.retire"):
+            with trace.phase("trainer.retire", step=step):
                 losses = np.atleast_1d(np.asarray(loss_arr))
                 exs = np.atleast_1d(np.asarray(examples_arr))
             # flight recorder: the trainer's dispatch/retire cadence —
@@ -600,17 +602,16 @@ class PodTrainer:
                 if drained:
                     break
                 # step anatomy: fetch (host pipeline pop) vs dispatch
-                # (bucket agreement + H2D + device-call issue) — named
-                # timers feed the telemetry snapshot, spans the timeline
-                with trace.span("step.fetch", cat="step", step=step_idx), \
-                        timers.timer("trainer.fetch"):
+                # (bucket agreement + H2D + device-call issue) vs retire
+                # (blocked on the chip): one name each in the named
+                # timers, the tracer's timeline and the profiler's
+                with trace.phase("trainer.fetch", step=step_idx):
                     if K == 1:
                         stacked_np, n, labels, mask_counts = _next_item()
                         metas = [(labels, mask_counts)]
                     else:
                         stacked_np, n, metas = _next_item()
-                with trace.span("step.dispatch", cat="step", step=step_idx), \
-                        timers.timer("trainer.dispatch"):
+                with trace.phase("trainer.dispatch", step=step_idx):
                     if self._bucket_sync:
                         stacked_np = self._agree_bucket(
                             stacked_np, f"{bkt_gen}/{step_idx}"
@@ -620,9 +621,12 @@ class PodTrainer:
                     # stochastic rounding never reuses a key (traced
                     # scalar: no recompile); step_idx * K is this call's
                     # first microstep index
-                    self.state, out = self.step_fn(
-                        self.state, stacked, step_idx * K
-                    )
+                    with self._new_shape_phase(
+                        "trainer.new_shapes", stacked_np, step=step_idx
+                    ):
+                        self.state, out = self.step_fn(
+                            self.state, stacked, step_idx * K
+                        )
                 flightrec.record("step.dispatch", step=step_idx, examples=int(n))
                 self.examples_seen += n
                 n_since += n
@@ -664,10 +668,6 @@ class PodTrainer:
             auc=M.auc(y, p) if len(y) else float("nan"),
             ex_per_sec=n_since / max(time.perf_counter() - t0, 1e-9),
             ssp=self.clock.progress(),
-            # static per-device collective estimate for this window (ref:
-            # Postoffice byte counters; see traffic.py)
-            est_collective_bytes=self.est_step_traffic.total_bytes
-            * len(window),
         )
 
     def full_weights(self) -> np.ndarray:
@@ -723,7 +723,15 @@ class PodTrainer:
 
     def evaluate_files(self, files: list[str], key_mode: str = "hash") -> dict:
         """Pod-wide batch evaluation using the predict step on shard 0's
-        stream layout (eval is read-only; one worker suffices)."""
+        stream layout (eval is read-only; one worker suffices).
+
+        Host phases (``trace.phase``): ``eval.open`` from entry to the
+        return of the first predict call (builder, reader, first parse +
+        build, stack, H2D, enqueue), ``eval.dispatch`` / ``eval.retire``
+        for each later call, ``eval.score`` for the AUC and logloss over
+        the pass; the device idles in the first and the last.
+        ``eval.new_shapes`` times the first predict call of a bucket shape
+        (the compile), which lies inside ``eval.open`` or ``eval.dispatch``."""
         if self.runtime.process_count > 1:
             # multi-host: evaluate host-locally against the full weight
             # vector (every host holds a complete replica) — no cross-host
@@ -739,10 +747,12 @@ class PodTrainer:
                 max_nnz_per_example=self.cfg.data.max_nnz_per_example,
                 key_mode=key_mode,
             )
-        from parameter_server_tpu.data.batch import eval_builder
+        with self._trace_cm():
+            return self._evaluate_pass(files, key_mode)
 
-        builder = eval_builder(self.cfg, key_mode)
-        reader = MinibatchReader(files, self.cfg.data.format, builder)
+    def _evaluate_pass(self, files: list[str], key_mode: str) -> dict:
+        from parameter_server_tpu.data.batch import eval_builder, pad_group
+
         # bounded async dispatch (the train loop's DispatchWindow pattern):
         # up to EVAL_INFLIGHT predicts ride JAX async dispatch — no
         # host<->device sync per D-group — while retirement of the oldest
@@ -760,8 +770,6 @@ class PodTrainer:
                 ys.append(labels)
 
         def _dispatch(group: list[CSRBatch]) -> None:
-            from parameter_server_tpu.data.batch import pad_group
-
             # fill every data shard with real batches (D at a time); only
             # the tail group pads with inert batches
             batches = pad_group(
@@ -771,33 +779,44 @@ class PodTrainer:
                     for _ in range(self.data_shards - len(group))
                 ]
             )
-            probs_dev = self.predict_fn(
-                self.state,
-                stack_batches(
-                    batches, self.mesh,
-                    compact=self.cfg.data.compact_wire,
-                    values_f16=self.cfg.data.wire_values == "f16",
-                ),
+            stacked = stack_batches(
+                batches, self.mesh,
+                compact=self.cfg.data.compact_wire,
+                values_f16=self.cfg.data.wire_values == "f16",
             )
+            with self._new_shape_phase("eval.new_shapes", stacked):
+                probs_dev = self.predict_fn(self.state, stacked)
             pending.append(
                 (probs_dev, [b.labels[: b.num_examples] for b in group])
             )
-            if len(pending) >= _EVAL_INFLIGHT:
-                _retire_oldest()
 
-        group: list[CSRBatch] = []
-        for b in reader:
-            group.append(b)
-            if len(group) == self.data_shards:
+        with trace.phase("eval.open"):
+            builder = eval_builder(self.cfg, key_mode)
+            reader = iter(
+                MinibatchReader(files, self.cfg.data.format, builder)
+            )
+            groups = iter(
+                lambda: list(itertools.islice(reader, self.data_shards)), []
+            )
+            first = next(groups, None)
+            if first is not None:
+                _dispatch(first)
+        for group in groups:
+            with trace.phase("eval.dispatch"):
                 _dispatch(group)
-                group = []
-        if group:
-            _dispatch(group)
+            if len(pending) >= _EVAL_INFLIGHT:
+                with trace.phase("eval.retire"):
+                    _retire_oldest()
         while pending:
-            _retire_oldest()
-        y = np.concatenate(ys)
-        p = np.concatenate(ps)
-        return {"auc": M.auc(y, p), "logloss": M.logloss(y, p), "examples": len(y)}
+            with trace.phase("eval.retire"):
+                _retire_oldest()
+        with trace.phase("eval.score"):
+            y = np.concatenate(ys)
+            p = np.concatenate(ps)
+            return {
+                "auc": M.auc(y, p), "logloss": M.logloss(y, p),
+                "examples": len(y),
+            }
 
 
 def _pad_like(builder: BatchBuilder) -> CSRBatch:

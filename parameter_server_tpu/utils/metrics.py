@@ -493,14 +493,16 @@ class Timer:
     def tic(self) -> None:
         self._local.t0 = time.perf_counter()
 
-    def toc(self) -> float:
+    def toc(self, count: int = 1) -> float:
+        """Add the time since ``tic`` and ``count`` finished units (0 for
+        time that belongs here but completes no unit of its own)."""
         t0 = getattr(self._local, "t0", None)
         assert t0 is not None, "toc without tic"
         dt = time.perf_counter() - t0
         self._local.t0 = None
         with self._lock:
             self.total += dt
-            self.count += 1
+            self.count += count
         return dt
 
     def snapshot(self) -> dict[str, float]:
@@ -972,7 +974,6 @@ def merge_progress(reports: list[dict[str, Any]]) -> dict[str, Any]:
         "wire_bytes_in",
         "wire_bytes_saved",
         "wire_comp_skipped",
-        "est_collective_bytes",
         # self-healing control plane (each worker reports its cumulative
         # wire_counters; the merge is the cluster total)
         "rpc_retries",

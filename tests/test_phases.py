@@ -1,0 +1,261 @@
+"""Phase names: ``ps.*`` scopes in the step and predict programs and what
+``op_scopes()`` reads back from them; ``trace.phase`` in its three planes;
+the timers the feed, the trainer and the evaluator leave behind."""
+
+import glob
+import os
+import time
+
+import jax
+import numpy as np
+import pytest
+
+from parameter_server_tpu.data.pipeline import PrefetchPipeline
+from parameter_server_tpu.data.synthetic import make_sparse_logistic, write_libsvm
+from parameter_server_tpu.kv import store
+from parameter_server_tpu.models.linear import updater_from_config
+from parameter_server_tpu.parallel import spmd
+from parameter_server_tpu.parallel.mesh import make_mesh
+from parameter_server_tpu.parallel.trainer import PodTrainer
+from parameter_server_tpu.utils import trace
+from parameter_server_tpu.utils.config import PSConfig
+from parameter_server_tpu.utils.metrics import ProgressReporter, timers
+
+PHASES = ("ps.row_ids", "ps.pull", "ps.grad", "ps.push/scatter")
+NUM_KEYS, B, NNZ, U = 1 << 10, 16, 64, 65
+
+
+def _count(name: str) -> int:
+    return timers.snapshot().get(name, {"count": 0})["count"]
+
+
+def _batch(data: int, lead: tuple = ()) -> dict:
+    """Compact-wire batch fields, (data, *lead, ...): every example has
+    NNZ // B entries, keys below NUM_KEYS."""
+    rng = np.random.default_rng(3)
+    one = {
+        "unique_keys": rng.permutation(NUM_KEYS)[:U].astype(np.int32),
+        "local_ids": rng.integers(0, U, NNZ).astype(np.int32),
+        "row_splits": (np.arange(B + 1) * (NNZ // B)).astype(np.int32),
+        "values": rng.normal(size=NNZ).astype(np.float32),
+        "labels": rng.integers(0, 2, B).astype(np.float32),
+        "example_mask": np.ones(B, bool),
+    }
+    return {k: np.broadcast_to(v, (data, *lead, *v.shape)).copy() for k, v in one.items()}
+
+
+@pytest.fixture
+def no_programs(monkeypatch):
+    """``op_scopes`` as a fresh process sees it."""
+    monkeypatch.setattr(spmd, "_ran", [])
+
+
+class TestDeviceScopes:
+    @pytest.mark.parametrize("program", ["step", "multistep", "predict"])
+    def test_program_text_names_the_phases(self, program, no_programs):
+        mesh = make_mesh(2, 2)
+        updater = updater_from_config(PSConfig())
+        state = spmd.shard_state(updater.init(NUM_KEYS, 1), mesh)
+        assert spmd.op_scopes() == {}  # nothing has run
+        if program == "predict":
+            fn = spmd.make_spmd_predict_step(updater, mesh, NUM_KEYS)
+            call = lambda st: fn(st, _batch(2))  # noqa: E731
+        elif program == "step":
+            fn = spmd.make_spmd_train_step(updater, mesh, NUM_KEYS)
+            call = lambda st: fn(st, _batch(2), 0)  # noqa: E731
+        else:
+            fn = spmd.make_spmd_train_multistep(updater, mesh, NUM_KEYS)
+            call = lambda st: fn(st, _batch(2, (3,)), 0)  # noqa: E731
+        call(state)
+        want = PHASES[:3] if program == "predict" else PHASES
+        (ran,) = spmd._ran
+        lowered = ran.jitted.lower(*ran.args)
+        text = lowered.as_text(debug_info=True)
+        for phase in want:
+            # the push's scan body is lowered as a function of its own, so
+            # its stages stand there without the "ps.push/" before them
+            head, _, stage = phase.partition("/")
+            assert f'"{head}/' in text and f'"{stage}/' in text, phase
+        module, scopes = spmd.hlo_scopes(lowered.compile().as_text())
+        assert set(want) <= set(scopes.values())
+        assert spmd.op_scopes() == {module: scopes}
+        call(spmd.shard_state(updater.init(NUM_KEYS, 1), mesh))  # the train steps donate their state
+        assert len(spmd._ran) == 1  # the same shapes again: nothing new to read
+
+    def test_store_pull_and_push_carry_the_same_names(self):
+        updater = updater_from_config(PSConfig())
+        state = updater.init(NUM_KEYS, 1)
+        idx = jax.numpy.arange(8, dtype=jax.numpy.int32)
+        grad = jax.numpy.ones((8, 1), jax.numpy.float32)
+        _, pull = spmd.hlo_scopes(store.pull.lower(updater, state, idx).compile().as_text())
+        _, push = spmd.hlo_scopes(store.push.lower(updater, state, idx, grad).compile().as_text())
+        assert "ps.pull" in pull.values()
+        assert {"ps.push/gather", "ps.push/update", "ps.push/scatter"} <= set(push.values())
+
+    def test_scope_of_an_op_name(self):
+        f = spmd._scope_of
+        assert f("jit(_jitted)/while/body/closed_call/ps.push/while/body/closed_call/scatter/scatter-add") == "ps.push/scatter"
+        assert f("jit(_jitted)/ps.push/all_gather") == "ps.push"
+        assert f("jit(step)/ps.pull/jit(_take)/gather") == "ps.pull"  # the primitive, not the stage
+        assert f("jit(step)/ps.push/gather") == "ps.push"  # a primitive named like a stage
+        assert f("jit(step)/ps.push/gather/jit(_take)/gather") == "ps.push/gather"
+        assert f("jit(_jitted)/while") == "" and f("") == ""
+
+    def test_programs_that_share_a_module_name_merge(self, monkeypatch):
+        monkeypatch.setattr(spmd, "_ran", [
+            spmd._RanProgram(None, (), ("jit__jitted", {"fusion.1": "ps.pull", "fusion.2": "ps.grad", "copy.1": ""})),
+            spmd._RanProgram(None, (), ("jit__jitted", {"fusion.1": "ps.row_ids", "fusion.2": "ps.grad", "fusion.3": "ps.push"})),
+        ])
+        assert spmd.op_scopes() == {"jit__jitted": {
+            "fusion.1": "", "fusion.2": "ps.grad", "copy.1": "", "fusion.3": "ps.push",
+        }}
+
+
+class TestPhase:
+    def test_off_is_a_timer_and_nothing_else(self):
+        assert not trace.enabled()
+        before = _count("test.phase_off")
+        ph = trace.phase("test.phase_off", step=3)
+        assert ph._span is trace._NOOP  # no Span allocated
+        with ph:
+            time.sleep(0.002)
+        snap = timers.snapshot()["test.phase_off"]
+        assert snap["count"] == before + 1 and snap["total_s"] >= 0.002
+
+    def test_count_of_a_phase_that_finishes_no_unit(self):
+        before = _count("test.phase_partial")
+        with trace.phase("test.phase_partial") as ph:
+            ph.count = 0
+        assert _count("test.phase_partial") == before
+        assert timers.snapshot()["test.phase_partial"]["total_s"] > 0
+
+    def test_armed_tracer_records_one_span_of_that_name(self, tmp_path):
+        t = trace.configure(str(tmp_path), process_name="phase-test")
+        try:
+            with trace.phase("test.phase_armed", step=7):
+                pass
+            evs = [e for e in t.events() if e["name"] == "test.phase_armed"]
+        finally:
+            trace.configure(None)
+        assert len(evs) == 1 and evs[0]["ph"] == "X" and evs[0]["cat"] == "test"
+        assert evs[0]["args"]["step"] == 7
+
+    def test_name_is_a_host_event_of_the_profilers_trace(self, tmp_path):
+        with jax.profiler.trace(str(tmp_path)):
+            with trace.phase("test.phase_profiled"):
+                jax.block_until_ready(jax.numpy.ones(8) + 1)
+        (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+        prof = jax.profiler.ProfileData.from_file(path)
+        hosts = [p for p in prof.planes if not p.name.startswith("/device:")]
+        names = {ev.name for p in hosts for ln in p.lines for ev in ln.events}
+        assert "test.phase_profiled" in names
+
+
+class _Stream:
+    def __init__(self, n: int):
+        self.left = n
+
+    def next_batch(self):
+        if not self.left:
+            return None
+        self.left -= 1
+        return self.left
+
+    def _empty(self):
+        return -1
+
+
+class TestFeedTimers:
+    def test_one_build_a_batch_one_stack_an_item(self):
+        b0, s0 = _count("feed.build"), _count("feed.stack")
+        with PrefetchPipeline([_Stream(6), _Stream(4)], prepare=list, depth=8) as p:
+            items = []
+            while (it := p.get()) is not None:
+                items.append(it)
+        assert len(items) == 6
+        assert _count("feed.build") - b0 == 10  # not the probes that found the streams drained
+        assert _count("feed.stack") - s0 == 6
+
+    def test_a_group_is_one_stacked_item(self):
+        s0 = _count("feed.stack")
+        with PrefetchPipeline(
+            [_Stream(7)], prepare=list, depth=8, group_size=3, assemble=list
+        ) as p:
+            n = 0
+            while p.get() is not None:
+                n += 1
+        assert n == 3  # 3 + 3 + (1 padded to 3)
+        assert _count("feed.stack") - s0 == 3
+
+    def test_a_full_queue_adds_to_put_wait(self):
+        w0 = timers.snapshot().get("feed.put_wait", {"count": 0, "total_s": 0.0})
+        with PrefetchPipeline([_Stream(5)], prepare=list, depth=1) as p:
+            time.sleep(0.3)  # the producer runs ahead of a consumer that is not there
+            while p.get() is not None:
+                pass
+        w1 = timers.snapshot()["feed.put_wait"]
+        assert w1["count"] > w0["count"] and w1["total_s"] - w0["total_s"] > 0.1
+
+
+def _files(tmp_path, nnz_per_example: int, tag: str) -> list:
+    labels, keys, vals, _ = make_sparse_logistic(
+        512, 300, nnz_per_example=nnz_per_example, noise=0.3, seed=5
+    )
+    path = tmp_path / f"{tag}.svm"
+    write_libsvm(path, labels, keys, vals)
+    return [str(path)]
+
+
+def _trainer(**data) -> PodTrainer:
+    cfg = PSConfig()
+    cfg.data.num_keys = 1 << 12
+    cfg.solver.minibatch = 128
+    cfg.solver.epochs = 1
+    cfg.parallel.data_shards = 2
+    cfg.parallel.kv_shards = 2
+    for k, v in data.items():
+        setattr(cfg.data, k, v)
+    return PodTrainer(cfg, reporter=ProgressReporter(print_fn=lambda *_: None))
+
+
+class TestTrainerAndEvaluatorTimers:
+    def test_one_pass_is_one_open_and_one_score(self, tmp_path):
+        t = _trainer()
+        files = _files(tmp_path, 8, "a")
+        t.train_files(files, report_every=100)
+        names = ("eval.open", "eval.score", "eval.dispatch", "eval.retire", "eval.new_shapes")
+        before = {n: _count(n) for n in names}
+        ev = t.evaluate_files(files)
+        after = {n: _count(n) - before[n] for n in before}
+        calls = -(-ev["examples"] // (128 * 2))  # predict calls: two data shards a call
+        assert ev["examples"] == 512 and calls == 2
+        assert after == {
+            "eval.open": 1, "eval.score": 1, "eval.dispatch": calls - 1, "eval.retire": calls,
+            "eval.new_shapes": 1,  # the pass's one shape, first dispatched here
+        }
+        t.evaluate_files(files)
+        assert _count("eval.new_shapes") - before["eval.new_shapes"] == 1  # and not again
+
+    def test_the_three_trainer_phases_keep_their_timers(self, tmp_path):
+        before = {n: _count(n) for n in ("trainer.fetch", "trainer.dispatch", "trainer.retire")}
+        _trainer().train_files(_files(tmp_path, 8, "b"), report_every=100)
+        grew = {n: _count(n) - before[n] for n in before}
+        assert grew["trainer.fetch"] == grew["trainer.dispatch"] == grew["trainer.retire"] >= 2
+
+    def test_a_second_bucket_is_one_new_shape(self, tmp_path):
+        t = _trainer(bucket_nnz=True)
+        small, large = _files(tmp_path, 4, "small"), _files(tmp_path, 40, "large")
+        n0 = _count("trainer.new_shapes")
+        t.train_files(small, report_every=100)
+        n1 = _count("trainer.new_shapes")
+        t.train_files(small, report_every=100)
+        n2 = _count("trainer.new_shapes")
+        t.train_files(large, report_every=100)
+        n3 = _count("trainer.new_shapes")
+        assert len(t._dispatched_shapes) == n3 - n0
+        assert {name for name, _, _ in t._dispatched_shapes} == {"trainer.new_shapes"}
+        assert n2 == n1  # the shapes of the first epoch again: nothing new
+        assert n3 - n2 == len(t._dispatched_shapes) - (n1 - n0) >= 1
+        # the progress report no longer carries the static traffic estimate
+        assert not hasattr(t, "est_step_traffic")
+        assert all("est_collective_bytes" not in r for r in t.reporter.history)

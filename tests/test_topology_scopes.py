@@ -1,0 +1,170 @@
+"""The step and predict programs compiled for a described ``v5e:2x2`` at the
+benchmark cells' real sizes (2^30 rows on one chip; 2^31 over ``kv`` 2,
+``data`` 2), with no chip: every executed instruction that reads or writes
+the table, and the loop that rebuilds the row ids, sits under a ``ps.*``
+scope - the names the ``step.*_ms`` metrics find device time by.
+
+After the ``on-chip-measurement`` guide, section 2: the topology is
+described inside a module-scoped fixture that skips where it cannot be,
+nothing here touches the TPU's library at import time, and this is the one
+test file that does (a second file would go to another worker, whose
+fixture would skip). A compile that passes is not a chip run."""
+
+import math
+import os
+import re
+
+import numpy as np
+import pytest
+
+MINIBATCH, K, NNZ = 8192, 8, 1 << 19  # the cells' one bucket: 39 x 8192 entries
+UNIQUE = NNZ + 1
+ROWS_PER_CHIP = 1 << 30
+# opcodes that run nothing of their own: a container's time is its body's,
+# the others only name a value
+_NOT_EXECUTED = {
+    "parameter", "tuple", "get-tuple-element", "bitcast", "constant",
+    "while", "conditional", "call",
+}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps the library away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def compiled_text(topo):
+    """(data, kv, program) -> optimised HLO text, compiled once each."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding
+
+    from parameter_server_tpu.models.linear import updater_from_config
+    from parameter_server_tpu.parallel import spmd
+    from parameter_server_tpu.utils.config import PSConfig
+
+    texts: dict = {}
+
+    def get(data: int, kv: int, program: str) -> str:
+        key = (data, kv, program)
+        if key in texts:
+            return texts[key]
+        mesh = Mesh(np.array(topo.devices[: data * kv]).reshape(data, kv), ("data", "kv"))
+        rows = ROWS_PER_CHIP * kv
+        updater = updater_from_config(PSConfig())  # FTRL: z and n
+        table = NamedSharding(mesh, spmd.state_spec())
+        feed = NamedSharding(mesh, spmd.batch_spec())
+        state = {k: jax.ShapeDtypeStruct((rows, 1), jnp.float32, sharding=table) for k in ("z", "n")}
+        lead = (K,) if program == "multistep" else ()
+        fields = {
+            "unique_keys": ((UNIQUE,), jnp.int32), "local_ids": ((NNZ,), jnp.int32),
+            "row_splits": ((MINIBATCH + 1,), jnp.int32), "values": ((NNZ,), jnp.float32),
+            "labels": ((MINIBATCH,), jnp.float32), "example_mask": ((MINIBATCH,), jnp.bool_),
+        }
+        batch = {
+            k: jax.ShapeDtypeStruct((data, *lead, *shape), dt, sharding=feed)
+            for k, (shape, dt) in fields.items()
+        }
+        if program == "multistep":
+            fn, args = spmd.make_spmd_train_multistep(updater, mesh, rows), (state, batch, 0)
+        else:
+            fn, args = spmd.make_spmd_predict_step(updater, mesh, rows), (state, batch)
+        # the maker returns a plain function around its jitted program
+        (jitted,) = [
+            c.cell_contents for c in fn.__closure__
+            if callable(c.cell_contents) and hasattr(c.cell_contents, "lower")
+        ]
+        texts[key] = jitted.lower(*args).compile().as_text()
+        return texts[key]
+
+    return get
+
+
+def executed(text: str) -> list:
+    """(name, result shape, opcode, operand shapes) of every instruction a
+    profile would show: those outside fused and applied computations, less
+    the opcodes that run nothing of their own."""
+    inner = set(re.findall(r"(?:calls|to_apply)=%?([\w.\-]+)", text))
+    shape_of, rows, comp = {}, [], None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+) \(.*\) -> .*\{\s*$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        m = re.match(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = (\S+) ([\w\-]+)\((.*)$", line)
+        if m:
+            shape_of[m.group(1)] = m.group(2)
+            rows.append((comp, *m.groups()))
+    out = []
+    for comp, name, shape, opcode, rest in rows:
+        if comp in inner or opcode in _NOT_EXECUTED:
+            continue
+        operands = re.findall(r"%([\w.\-]+)", rest.split("), ")[0])
+        out.append((name, shape, opcode, [shape_of.get(o, "") for o in operands]))
+    return out
+
+
+def elements(shape: str) -> int:
+    return max(
+        (math.prod(int(x) for x in dims.split(",")) for dims in re.findall(r"\[([\d,]+)\]", shape)),
+        default=0,
+    )
+
+
+CASES = [(1, 1, "multistep"), (1, 1, "predict"), (2, 2, "multistep"), (2, 2, "predict")]
+
+
+@pytest.mark.parametrize("data,kv,program", CASES)
+def test_table_ops_and_the_row_id_loop_are_scoped(compiled_text, data, kv, program):
+    from parameter_server_tpu.parallel import spmd
+
+    text = compiled_text(data, kv, program)
+    _, scopes = spmd.hlo_scopes(text)
+    table = re.compile(rf"\[{ROWS_PER_CHIP}(,1)?\]")
+    touching, row_id_loop = [], []
+    for name, shape, opcode, operand_shapes in executed(text):
+        if table.search(shape) or any(table.search(s) for s in operand_shapes):
+            touching.append((name, scopes[name]))
+        if shape.startswith(f"s32[{NNZ}]") and any(s.startswith(f"s32[{MINIBATCH + 1}]") for s in operand_shapes):
+            row_id_loop.append((name, scopes[name]))
+    # two gathers (z, n) at least; a train step adds two scatter-adds a worker's push
+    assert len(touching) >= (4 if program == "multistep" else 2), touching
+    assert all(scope.startswith("ps.") for _, scope in touching), touching
+    found = {scope for _, scope in touching}
+    assert "ps.pull" in found
+    if program == "multistep":
+        assert "ps.push/scatter" in found
+    assert row_id_loop and all(scope == "ps.row_ids" for _, scope in row_id_loop), row_id_loop
+
+
+@pytest.mark.parametrize("data,kv,program", CASES)
+def test_large_unscoped_instructions_are_the_known_kinds(compiled_text, data, kv, program):
+    """What runs at batch size with no scope (PERF.md section 3 lists them
+    with the reason): the compiler's own copies between memory spaces and
+    buffer allocations, the scan's slicing of its stacked inputs, index
+    clamping XLA split from a gather, and the ``all_gather`` of the
+    gradients once XLA has rewritten it as an all-reduce."""
+    from parameter_server_tpu.parallel import spmd
+
+    _, scopes = spmd.hlo_scopes(compiled_text(data, kv, program))
+    known = re.compile(
+        r"^(copy|copy-start|copy-done|custom-call|slice-start|slice-done|async-start|async-done"
+        r"|reduce|broadcast|dynamic-update-slice|all-reduce|fusion)$"
+    )
+    strays = [
+        (name, opcode, shape)
+        for name, shape, opcode, _ in executed(compiled_text(data, kv, program))
+        if not scopes[name] and elements(shape) >= NNZ and not known.match(opcode)
+    ]
+    assert not strays, strays
+    # an unscoped fusion at this size is bookkeeping, never a table op
+    for name, shape, opcode, operand_shapes in executed(compiled_text(data, kv, program)):
+        if opcode == "fusion" and not scopes[name]:
+            assert elements(shape) < 4 * UNIQUE and all(elements(s) < ROWS_PER_CHIP for s in operand_shapes), name
