@@ -29,10 +29,11 @@ class CSRBatch:
     and ``row_splits`` carries the same row structure as ``row_ids`` in
     B+1 ints instead of NNZ — together the compact wire format
     (parallel.spmd CSR_COMPACT_FIELDS) that cuts host->device bytes ~40%
-    at typical densities; the device rebuilds row_ids with one
-    searchsorted. The reference ships raw int64 keys + per-entry row ids
-    over ZeroMQ and leans on its filter pipeline instead (src/filter/);
-    here the transfer layout itself is the filter."""
+    at typical densities; the device rebuilds row_ids by marking the
+    splits and summing along the entries. The reference ships raw int64
+    keys + per-entry row ids over ZeroMQ and leans on its filter pipeline
+    instead (src/filter/); here the transfer layout itself is the
+    filter."""
 
     unique_keys: np.ndarray  # (U,) int32/int64 — hashed global ids, slot 0 = pad
     local_ids: np.ndarray  # (NNZ,) int32 — entry -> unique slot

@@ -188,7 +188,8 @@ CSR_FULL_FIELDS = (
 )
 # Compact wire format: row structure rides as (B+1,) row_splits instead of
 # (NNZ,) row_ids — ~40% fewer host->device bytes at typical densities; the
-# device rebuilds row ids with one searchsorted (see _row_ids_of).
+# device rebuilds row ids by marking the splits and summing along the
+# entries (see _row_ids_of).
 CSR_COMPACT_FIELDS = (
     "unique_keys", "local_ids", "row_splits", "values", "labels", "example_mask",
 )
@@ -225,16 +226,34 @@ def stack_batches(
 
 def _row_ids_of(b: Batch) -> jax.Array:
     """Entry -> example-row ids for one shard's batch: passthrough for the
-    full wire format, one searchsorted over (B+1,) row_splits for the
-    compact one. Padded entries (value 0) clamp to the last row and stay
-    inert under the masked loss/grad ops."""
+    full wire format; for the compact one, rebuilt from the (B+1,)
+    row_splits with no data-dependent loop. An entry's row is the number
+    of interior splits at or before it, so the splits are marked in a
+    zeroed (NNZ,) vector and summed along it. Empty rows repeat a split
+    and their marks add up; a split equal to NNZ (a buffer filled to its
+    last entry) falls off the end and is dropped. Padded entries (value
+    0) land on the last row and stay inert under the masked loss/grad
+    ops."""
     if "row_ids" in b:
         return b["row_ids"]
     nnz = b["values"].shape[0]
     num_rows = b["labels"].shape[0]
-    e = jnp.arange(nnz, dtype=jnp.int32)
-    r = jnp.searchsorted(b["row_splits"], e, side="right").astype(jnp.int32) - 1
-    return jnp.clip(r, 0, num_rows - 1)
+    marks = jnp.zeros((nnz,), jnp.int32)
+    marks = marks.at[b["row_splits"][1:num_rows]].add(1, mode="drop")
+    return _running_sum(marks)
+
+
+def _running_sum(x: jax.Array) -> jax.Array:
+    """Inclusive prefix sums of a vector as log2(n) shifted adds, each one
+    streaming pass. Not jnp.cumsum: the TPU compiler turns that into a
+    tree of reduce-windows that is slow to compile inside the scanned step
+    and whose pieces lose their op_name, so a profile could not file them
+    under ps.row_ids (PERF.md, PR 25)."""
+    k = 1
+    while k < x.shape[0]:
+        x = x + jnp.pad(x[:-k], (k, 0))
+        k *= 2
+    return x
 
 
 def _values_of(b: Batch) -> jax.Array:

@@ -44,8 +44,9 @@ class DataConfig:
     bucket_nnz: bool = True
     # compact wire format (on by default): int32 keys + (B+1,) row_splits
     # instead of (NNZ,) row_ids on the host->device transfer — ~40% fewer
-    # bytes at typical densities; the device rebuilds row ids with one
-    # searchsorted. False ships the full row_ids (debugging / parity runs)
+    # bytes at typical densities; the device rebuilds row ids by marking
+    # the splits and summing along the entries (spmd._row_ids_of). False
+    # ships the full row_ids (debugging / parity runs)
     compact_wire: bool = True
     # feature-value dtype on the host->device wire: "f32" (exact, default)
     # or "f16" — half the value bytes; IEEE round-to-nearest quantization,

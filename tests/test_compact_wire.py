@@ -71,6 +71,59 @@ def test_row_splits_match_row_ids():
     )
 
 
+def _splits(lens, batch_size):
+    """(batch_size + 1,) row_splits as data/batch.py writes them: the
+    cumulative real entries per row, the total repeated over a padded
+    tail."""
+    out = np.zeros(batch_size + 1, dtype=np.int32)
+    np.cumsum(lens, out=out[1 : len(lens) + 1])
+    out[len(lens) + 1 :] = out[len(lens)]
+    return out
+
+
+def _ragged_lens():
+    lens = np.random.default_rng(3).integers(1, 9, 64)
+    lens[[5, 6, 7, 30]] = 0  # empty rows in the middle, three in a run
+    lens[-4:] = 0  # and at the end
+    return lens
+
+
+ROW_ID_CASES = {
+    # the benchmark cells' own shape: every row 39 entries, buffer full
+    "39_a_row_at_8192_by_2p19": (_splits(np.full(8192, 39), 8192), 1 << 19),
+    "empty_rows_in_the_middle_and_at_the_end": (_splits(_ragged_lens(), 64), 512),
+    "padded_tail_batch": (_splits(np.full(40, 5), 64), 512),
+    "buffer_filled_to_its_last_entry": (_splits(np.full(64, 8), 64), 512),
+    "last_row_alone_fills_the_buffer": (_splits([0] * 63 + [512], 64), 512),
+    "empty_batch": (_splits([], 64), 512),
+    "one_row": (_splits([7], 1), 16),
+    "one_row_empty": (_splits([0], 1), 16),
+    "nnz_not_a_power_of_two": (_splits(np.full(100, 3), 100), 390),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ROW_ID_CASES))
+def test_row_ids_rebuilt_from_row_splits(case):
+    """_row_ids_of on a compact batch against np.repeat, equal at every
+    position, the padding included: padded entries sit on the last row."""
+    import jax
+
+    from parameter_server_tpu.parallel.spmd import _row_ids_of
+
+    splits, nnz = ROW_ID_CASES[case]
+    num_rows = len(splits) - 1
+    want = np.full(nnz, num_rows - 1, dtype=np.int32)
+    want[: splits[-1]] = np.repeat(np.arange(num_rows, dtype=np.int32), np.diff(splits))
+    b = {
+        "row_splits": splits,
+        "values": np.zeros(nnz, np.float32),
+        "labels": np.zeros(num_rows, np.float32),
+    }
+    got = np.asarray(jax.jit(_row_ids_of)(b))
+    assert got.dtype == np.int32
+    np.testing.assert_array_equal(got, want)
+
+
 def test_unique_keys_dtype_tracks_key_space():
     small = BatchBuilder(num_keys=1 << 20, batch_size=4)
     big = BatchBuilder(num_keys=(1 << 33), batch_size=4, key_mode="identity")

@@ -1,8 +1,9 @@
 """The step and predict programs compiled for a described ``v5e:2x2`` at the
 benchmark cells' real sizes (2^30 rows on one chip; 2^31 over ``kv`` 2,
 ``data`` 2), with no chip: every executed instruction that reads or writes
-the table, and the loop that rebuilds the row ids, sits under a ``ps.*``
-scope - the names the ``step.*_ms`` metrics find device time by.
+the table, and every one that rebuilds the row ids (with no loop among
+them), sits under a ``ps.*`` scope - the names the ``step.*_ms`` metrics
+find device time by.
 
 After the ``on-chip-measurement`` guide, section 2: the topology is
 described inside a module-scoped fixture that skips where it cannot be,
@@ -128,12 +129,19 @@ def test_table_ops_and_the_row_id_loop_are_scoped(compiled_text, data, kv, progr
     text = compiled_text(data, kv, program)
     _, scopes = spmd.hlo_scopes(text)
     table = re.compile(rf"\[{ROWS_PER_CHIP}(,1)?\]")
-    touching, row_id_loop = [], []
+    touching, marking, summing, strays = [], [], [], []
     for name, shape, opcode, operand_shapes in executed(text):
         if table.search(shape) or any(table.search(s) for s in operand_shapes):
             touching.append((name, scopes[name]))
-        if shape.startswith(f"s32[{NNZ}]") and any(s.startswith(f"s32[{MINIBATCH + 1}]") for s in operand_shapes):
-            row_id_loop.append((name, scopes[name]))
+        if not shape.startswith(f"s32[{NNZ}]"):
+            continue
+        # an entry-sized int32 result: the rebuild's, a consumer's under its own
+        # phase, or the compiler's move of a buffer; never a nameless computation
+        if scopes[name] == "ps.row_ids":
+            splits = any(s.startswith(f"s32[{MINIBATCH - 1}]") for s in operand_shapes)
+            (marking if splits else summing).append(name)
+        elif not scopes[name] and opcode not in ("copy-start", "copy-done", "custom-call"):
+            strays.append((name, opcode))
     # two gathers (z, n) at least; a train step adds two scatter-adds a worker's push
     assert len(touching) >= (4 if program == "multistep" else 2), touching
     assert all(scope.startswith("ps.") for _, scope in touching), touching
@@ -141,7 +149,19 @@ def test_table_ops_and_the_row_id_loop_are_scoped(compiled_text, data, kv, progr
     assert "ps.pull" in found
     if program == "multistep":
         assert "ps.push/scatter" in found
-    assert row_id_loop and all(scope == "ps.row_ids" for _, scope in row_id_loop), row_id_loop
+    # the rebuild: the interior splits marked in an entry-sized vector (one
+    # scatter-add from the MINIBATCH - 1 of them), then shifted adds along it
+    assert len(marking) == 1, marking
+    assert len(summing) >= math.log2(NNZ) - 2, summing  # XLA may fold an add or two into a neighbour
+    assert not strays, strays
+    # and no loop: the only ``while``s are the scan over microsteps and, across
+    # chips, the push's loop over workers
+    whiles = {
+        m.group(1): scopes[m.group(1)]
+        for m in re.finditer(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*? while\(", text, re.M)
+    }
+    assert "ps.row_ids" not in whiles.values(), whiles
+    assert len(whiles) <= {"multistep": 1 + (data > 1), "predict": 0}[program], whiles
 
 
 @pytest.mark.parametrize("data,kv,program", CASES)
