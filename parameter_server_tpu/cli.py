@@ -747,26 +747,30 @@ def _run_train_w2v(cfg: PSConfig, args: argparse.Namespace) -> dict:
 def _run_train_wd(cfg: PSConfig, args: argparse.Namespace) -> dict:
     """wide_deep app dispatch (ref: App::Create on the W&D CTR config;
     BASELINE parity config "Wide-&-Deep CTR ... server-sharded
-    embeddings"): streaming file-driven train over the same text formats
-    as linear_method, optional (data, kv) mesh via [parallel]."""
-    from parameter_server_tpu.data.batch import eval_builder, training_builder
-    from parameter_server_tpu.models.wide_deep import WideDeep
+    embeddings"): the same ``PodTrainer`` the linear app's pod path runs
+    through, over the app's own description — streaming file-driven
+    train over the same text formats, (data, kv) mesh via [parallel],
+    checkpoint of both tables, the tower and its optimizer state."""
+    from parameter_server_tpu.models.wide_deep import dump_model
+    from parameter_server_tpu.parallel.trainer import PodTrainer
 
-    app = WideDeep.from_config(cfg, mesh=_mesh_from_cfg(cfg))
-    last = app.train_files(
-        cfg.data.files, cfg.data.format, training_builder(cfg),
-        epochs=max(1, cfg.solver.epochs),
-        report_every=args.report_interval,
+    trainer = PodTrainer(cfg)
+    if args.resume:
+        if not args.ckpt_dir:
+            raise SystemExit("--resume requires --ckpt_dir")
+        trainer.load(args.ckpt_dir)
+    out = dict(
+        trainer.train_files(cfg.data.files, report_every=args.report_interval)
+        or {}
     )
-    out = dict(last or {})
     out.update({"emb_dim": cfg.wd.emb_dim, "hidden": list(cfg.wd.hidden)})
+    if args.ckpt_dir:
+        trainer.save(args.ckpt_dir)
     if cfg.data.val_files:
-        ev = app.evaluate_files(
-            cfg.data.val_files, cfg.data.format, eval_builder(cfg)
-        )
+        ev = trainer.evaluate_files(cfg.data.val_files)
         out.update({f"val_{k}": v for k, v in ev.items()})
     if args.model_out:
-        out["model_out"] = app.dump_model(args.model_out)
+        out["model_out"] = dump_model(trainer, args.model_out)
     return out
 
 
@@ -819,12 +823,9 @@ def run_evaluate(cfg: PSConfig, args: argparse.Namespace) -> dict:
     if cfg.app == "wide_deep":
         # the W&D dump is an npz (wide + embedding + MLP), not the linear
         # apps' flat text vector
-        from parameter_server_tpu.data.batch import eval_builder
         from parameter_server_tpu.models.wide_deep import evaluate_dump
 
-        return evaluate_dump(
-            args.model, files, cfg.data.format, eval_builder(cfg)
-        )
+        return evaluate_dump(cfg, args.model, files)
     return evaluate_model(
         args.model,
         files,

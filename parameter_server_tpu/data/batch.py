@@ -121,6 +121,24 @@ def zero_extend(a: np.ndarray, n: int, axis: int = 0) -> np.ndarray:
     return np.pad(a, pad)
 
 
+def inert_like(b: CSRBatch) -> CSRBatch:
+    """All-zero batch with b's static shapes (mask False, value 0): the
+    pad for a partial multistep group or a worker whose stream has dried
+    up — zero loss, zero gradient."""
+    return CSRBatch(
+        unique_keys=np.zeros_like(b.unique_keys),
+        local_ids=np.zeros_like(b.local_ids),
+        row_ids=np.zeros_like(b.row_ids),
+        values=np.zeros_like(b.values),
+        labels=np.zeros_like(b.labels),
+        example_mask=np.zeros_like(b.example_mask),
+        row_splits=np.zeros_like(b.row_splits),
+        num_examples=0,
+        num_unique=1,
+        num_entries=0,
+    )
+
+
 def pad_batch(b: CSRBatch, nnz_cap: int, u_cap: int) -> CSRBatch:
     """Re-pad a (possibly bucketed) batch to the given capacities — used
     to bring a group of differently-bucketed batches to one static shape

@@ -55,6 +55,37 @@ def pad_state_rows(state: State, num_rows: int) -> State:
     }
 
 
+def _fmix32(x: jax.Array) -> jax.Array:
+    """murmur3's 32-bit finalizer over uint32 (arithmetic wraps)."""
+    x = x ^ (x >> 16)
+    x = x * jnp.uint32(0x85EBCA6B)
+    x = x ^ (x >> 13)
+    x = x * jnp.uint32(0xC2B2AE35)
+    return x ^ (x >> 16)
+
+
+def hashed_uniform(
+    seed: int, rows: jax.Array, vdim: int, scale: float, live_rows: int
+) -> jax.Array:
+    """Starting values of an embedding table as a function of (seed, row,
+    lane) alone: (len(rows), vdim) float32, uniform with standard deviation
+    ``scale``, exactly zero for the pad row 0 and for rows at or past
+    ``live_rows`` (the kv-axis pad tail). A counter-based draw - two rounds
+    of a 32-bit mix over the row and the lane, the top 24 bits to
+    [-1, 1) - so that the table is made on the device slice by slice, no
+    host array of its size ever exists, and anything that knows the seed
+    can compute any row (the benchmark's reference does, bit for bit:
+    every step is exact in uint32 and float32 but the last product, which
+    IEEE rounds one way)."""
+    r = rows.astype(jnp.uint32)[:, None]
+    lane = jnp.arange(vdim, dtype=jnp.uint32)[None, :]
+    x = _fmix32(r * jnp.uint32(0x9E3779B1) + jnp.uint32(seed & 0xFFFFFFFF))
+    x = _fmix32(x ^ (lane * jnp.uint32(0x85EBCA77) + jnp.uint32(0xC2B2AE3D)))
+    unit = (x >> 8).astype(jnp.float32) * jnp.float32(2.0**-23) - jnp.float32(1.0)
+    live = (rows > 0) & (rows < live_rows)
+    return jnp.where(live[:, None], unit * jnp.float32(scale * 3.0**0.5), 0.0)
+
+
 @functools.partial(jax.jit, static_argnums=0)
 def pull(updater: Updater, state: State, idx: jax.Array) -> jax.Array:
     """Gather weights for (unique, padded) key indices: (U,) -> (U, vdim)."""

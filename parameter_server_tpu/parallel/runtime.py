@@ -164,14 +164,6 @@ class Runtime:
             num_shards=self.process_count,
         )
 
-    def load_checkpoint(self, ckpt_dir) -> tuple[dict, dict]:
-        """Each host reads all shards (contiguous key ranges), assembles its
-        full replica, and re-places it on the mesh."""
-        from parameter_server_tpu.utils.checkpoint import load_checkpoint
-
-        host_state, meta = load_checkpoint(ckpt_dir)
-        return self.state_from_host(host_state), meta
-
     def cp_allmax(
         self, tag: str, values: tuple[int, ...], timeout_ms: int = 600_000
     ) -> tuple[int, ...] | None:

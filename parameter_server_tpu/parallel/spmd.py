@@ -17,7 +17,7 @@ Reference analog, mapped one-to-one:
 
 State layout: every table is (num_keys, vdim) sharded over "kv" on axis 0.
 ``num_keys`` need not divide the kv axis size: tables are zero-padded up
-to the next axis multiple (``padded_num_keys``) and the pad rows stay
+to the next axis multiple, in whole tiles (``padded_num_keys``) and the pad rows stay
 exactly zero under the store's pad-row invariant (batch keys are always
 below the real ``num_keys``, so no push ever touches them). Batches are
 per-data-shard CSRBatches stacked on a leading axis and sharded over
@@ -26,9 +26,11 @@ per-data-shard CSRBatches stacked on a leading axis and sharded over
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import re
+from collections.abc import Callable
 from typing import Any
 
 import jax
@@ -49,8 +51,20 @@ Batch = dict[str, jax.Array]
 # ``op_scopes``), so whatever replaces the code underneath keeps them.
 # Inside "ps.push" three nested scopes: "gather" (rows read for the
 # updater), "update" (``updater.delta``), "scatter" (the ``.at[].add``).
-PHASE_SCOPES = ("ps.row_ids", "ps.pull", "ps.grad", "ps.push")
+# An app of several tables names the table innermost ("ps.pull/emb",
+# "ps.push/scatter/emb"), so a reader of "ps.pull" sums over tables; its
+# dense group's forward and backward lie under "ps.grad/<group>" and the
+# group's ``psum`` and optimizer step under "ps.dense".
+PHASE_SCOPES = ("ps.row_ids", "ps.pull", "ps.grad", "ps.push", "ps.dense")
 _PUSH_STAGES = ("gather", "update", "scatter")
+
+
+def _sub_scope(name: str):
+    """A table's or dense group's ``name`` as a scope under a phase
+    (``ps.pull/emb``); no scope at all for the empty name of a
+    single-table app."""
+    return jax.named_scope(name) if name else contextlib.nullcontext()
+
 
 @dataclasses.dataclass
 class _RanProgram:
@@ -61,19 +75,32 @@ class _RanProgram:
     jitted: Any
     args: tuple
     scopes: tuple[str, dict[str, str]] | None = None
+    names: frozenset = frozenset()  # the app's tables and dense group
 
 
 _ran: list[_RanProgram] = []
+_forgotten = 0  # calls of ``forget_programs``: part of what counts as seen
 
 
-def _note_program(jitted, seen: set, *args) -> None:
+def forget_programs() -> None:
+    """Drop what ``op_scopes`` knows of the programs run so far; one that
+    runs again is remembered again. For a caller that reads a profile of a
+    part of the run: programs of one module name share a map there, and
+    one that ran only before that part (the short inert calls that end an
+    epoch) would blank the names the two scope differently."""
+    global _forgotten
+    _ran.clear()
+    _forgotten += 1
+
+
+def _note_program(jitted, seen: set, names: frozenset, *args) -> None:
     """Remember the shapes, dtypes and shardings a step or predict program
     is called with, once a distinct set: a dict lookup on the dispatch
     path, nothing compiled or read here."""
-    key = tuple(
+    key = (_forgotten, *(
         (getattr(x, "shape", None), getattr(x, "dtype", None))
         for x in jax.tree.leaves(args)
-    )
+    ))
     if key in seen:
         return
     seen.add(key)
@@ -85,35 +112,43 @@ def _note_program(jitted, seen: set, *args) -> None:
             x.shape, x.dtype, sharding=getattr(x, "sharding", None)
         )
 
-    _ran.append(_RanProgram(jitted, jax.tree.map(abstract, args)))
+    _ran.append(_RanProgram(jitted, jax.tree.map(abstract, args), names=names))
 
 
-def hlo_scopes(hlo_text: str) -> tuple[str, dict[str, str]]:
+def hlo_scopes(hlo_text: str, names: frozenset = frozenset()) -> tuple[str, dict[str, str]]:
     """(module name, {instruction name: scope path}) of one optimised HLO
     module's text. The scope path is the ``ps.*`` phase in the
     instruction's ``op_name`` metadata (a fusion carries its root's), with
-    the push's nested stage after a slash (``ps.push/scatter``); ``""`` for
-    an instruction that carries none (input copies, some custom calls)."""
+    what the program nested under it after a slash: the push's stages
+    (``ps.push/scatter``) and, of the app that ran it, the ``names`` of its
+    tables and dense group (``ps.pull/emb``; ``StepApp.scope_names``);
+    ``""`` for an instruction that carries none (input copies, some
+    custom calls)."""
     module = re.search(r"^HloModule\s+([^\s,]+)", hlo_text, re.M)
     out: dict[str, str] = {}
     for m in re.finditer(
         r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = (.*)$", hlo_text, re.M
     ):
         op_name = re.search(r'op_name="([^"]*)"', m.group(2))
-        out[m.group(1)] = _scope_of(op_name.group(1)) if op_name else ""
+        out[m.group(1)] = _scope_of(op_name.group(1), names) if op_name else ""
     return (module.group(1) if module else ""), out
 
 
-def _scope_of(op_name: str) -> str:
+_TRANSFORMED = re.compile(r"^\w+\((.*)\)$")  # "transpose(jvp(mlp))" -> "mlp"
+
+
+def _scope_of(op_name: str, names: frozenset = frozenset()) -> str:
     parts = op_name.split("/")
     for i, part in enumerate(parts):
         if part in PHASE_SCOPES:
-            if part == "ps.push":
-                # the last part is the primitive's own name ("gather")
-                for stage in parts[i + 1 : -1]:
-                    if stage in _PUSH_STAGES:
-                        return f"{part}/{stage}"
-            return part
+            path = [part]
+            # the last part is the primitive's own name ("gather")
+            for sub in parts[i + 1 : -1]:
+                while (m := _TRANSFORMED.match(sub)) is not None:
+                    sub = m.group(1)  # a scope inside jax.grad
+                if sub in _PUSH_STAGES or sub in names:
+                    path.append(sub)
+            return "/".join(path)
     return ""
 
 
@@ -134,12 +169,184 @@ def op_scopes() -> dict[str, dict[str, str]]:
     for ran in _ran:
         if ran.scopes is None:
             compiled = ran.jitted.lower(*ran.args).compile()
-            ran.scopes = hlo_scopes(compiled.as_text())
+            ran.scopes = hlo_scopes(compiled.as_text(), ran.names)
         module, scopes = ran.scopes
         have = out.setdefault(module, {})
         for name, scope in scopes.items():
             have[name] = scope if have.get(name, scope) == scope else ""
     return out
+
+
+@dataclasses.dataclass(frozen=True)
+class Table:
+    """One key-addressed table of an app: its updater and how many values a
+    key holds. ``name`` prefixes its entries in the flat state ("emb.w")
+    and is the innermost scope of its pulls and pushes ("ps.pull/emb"); the
+    one table of a single-table app goes unnamed ("z", "n"; "ps.pull").
+    ``init`` makes the slots of ``rows`` rows where the updater's zeros
+    will not do (an embedding's starting values): on the device, inside
+    ``Runtime.init_state``, never as a host array of table size."""
+
+    name: str
+    updater: Updater
+    vdim: int = 1
+    init: Callable[[int], State] | None = None
+
+    def key(self, slot: str) -> str:
+        return f"{self.name}.{slot}" if self.name else slot
+
+    def slots(self) -> tuple[str, ...]:
+        return tuple(jax.eval_shape(lambda: self.updater.init(1, self.vdim)))
+
+    def init_slots(self, rows: int) -> State:
+        slots = (
+            self.init(rows) if self.init is not None
+            else self.updater.init(rows, self.vdim)
+        )
+        return {self.key(k): v for k, v in slots.items()}
+
+    def of(self, state: State) -> State:
+        """This table's slots out of the flat state, by the updater's names."""
+        return {k: state[self.key(k)] for k in self.slots()}
+
+
+def _named_leaves(prefix: str, tree: Any) -> dict[str, Any]:
+    """A pytree's leaves under flat names: ``prefix`` and the leaf's key
+    path, dot-joined ("mlp.0.W", "mlp_opt.0.mu.0.W")."""
+    def part(k) -> str:
+        for attr in ("key", "idx", "name"):
+            if hasattr(k, attr):
+                return str(getattr(k, attr))
+        return str(k)
+
+    leaves = jax.tree_util.tree_flatten_with_path(tree)[0]
+    return {
+        ".".join([prefix, *(part(k) for k in path)]): leaf
+        for path, leaf in leaves
+    }
+
+
+@dataclasses.dataclass(frozen=True)
+class DenseGroup:
+    """Parameters every device holds whole (a tower over the pulled rows),
+    with their optimizer (an optax transformation) stepped inside the
+    device step: gradients ``psum``'d over "data", the update gated on the
+    microstep having any real example (Adam moves its moments on a zero
+    gradient, a KV updater does not). In the flat state the parameters sit
+    under ``<name>.`` and the optimizer's state under ``<name>_opt.``."""
+
+    name: str
+    init: Callable[[], Any]  # -> the parameters' pytree
+    opt: Any
+
+    @functools.cached_property
+    def _templates(self):
+        """(parameters, optimizer state) as shapes: the flat names' order."""
+        params = jax.eval_shape(self.init)
+        return params, jax.eval_shape(self.opt.init, params)
+
+    def pack(self, params: Any, opt_state: Any) -> State:
+        return {
+            **_named_leaves(self.name, params),
+            **_named_leaves(self.name + "_opt", opt_state),
+        }
+
+    def unpack(self, state: State) -> tuple[Any, Any]:
+        """(parameters, optimizer state) as pytrees out of the flat state."""
+        def fill(prefix: str, like: Any) -> Any:
+            return jax.tree.unflatten(
+                jax.tree.structure(like),
+                [state[n] for n in _named_leaves(prefix, like)],
+            )
+
+        params, opt_state = self._templates
+        return fill(self.name, params), fill(self.name + "_opt", opt_state)
+
+    def keys(self) -> list[str]:
+        return list(self.pack(*self._templates))
+
+    def init_state(self) -> State:
+        params = self.init()
+        return self.pack(params, self.opt.init(params))
+
+
+@dataclasses.dataclass(frozen=True)
+class StepApp:
+    """What one parameter-server step needs to know of an app: its tables,
+    its replicated dense group if it has one, and the two functions of the
+    pulled rows that are the model. ``grad(pulled, dense, b, row_ids)`` ->
+    (summed loss, logits (B,), {table name: (U, vdim) gradient of the
+    pulled rows}, the dense parameters' gradient or None);
+    ``logits(pulled, dense, b, row_ids)`` -> (B,). ``pulled`` maps a table's
+    name to its (U, vdim) weights for ``b["unique_keys"]``; every table is
+    addressed by the batch's one key set (an app hashes one key space).
+
+    The state of an app is one flat ``{name: array}`` dict: each table's
+    slots (range-sharded over "kv") and the dense group's leaves
+    (replicated)."""
+
+    tables: tuple[Table, ...]
+    grad: Callable
+    logits: Callable
+    dense: DenseGroup | None = None
+
+    def table(self, name: str) -> Table:
+        (t,) = [t for t in self.tables if t.name == name]
+        return t
+
+    def scope_names(self) -> frozenset:
+        """The names this app's programs nest under a phase scope: its
+        named tables and its dense group (what ``hlo_scopes`` keeps of an
+        op_name besides the push's stages)."""
+        names = {t.name for t in self.tables if t.name}
+        if self.dense is not None:
+            names.add(self.dense.name)
+        return frozenset(names)
+
+    def init_tables(self, rows: int) -> State:
+        out: State = {}
+        for t in self.tables:
+            out.update(t.init_slots(rows))
+        return out
+
+    def table_keys(self) -> list[str]:
+        return [t.key(k) for t in self.tables for k in t.slots()]
+
+    def specs(self) -> dict[str, P]:
+        """The state's partition specs, entry by entry."""
+        out = {k: state_spec() for k in self.table_keys()}
+        if self.dense is not None:
+            out.update({k: P() for k in self.dense.keys()})
+        return out
+
+
+def _linear_logits(pulled, dense, b: Batch, row_ids: jax.Array) -> jax.Array:
+    return csr_logits(
+        pulled[""], _values_of(b), b["local_ids"], row_ids,
+        num_rows=b["labels"].shape[0],
+    )
+
+
+def _linear_grad(pulled, dense, b: Batch, row_ids: jax.Array):
+    logits = _linear_logits(pulled, dense, b, row_ids)
+    loss, err = logistic_loss(logits, b["labels"], b["example_mask"])
+    g = csr_grad(
+        err, _values_of(b), b["local_ids"], row_ids,
+        num_unique=b["unique_keys"].shape[0],
+    )
+    return loss, logits, {"": g}, None
+
+
+def linear_app(updater: Updater) -> StepApp:
+    """Sparse logistic regression over one unnamed ``vdim`` 1 table, the
+    gradient hand-written (``ops.sparse.csr_grad``): the flagship."""
+    return StepApp((Table("", updater, 1),), _linear_grad, _linear_logits)
+
+
+def _as_app(app: "StepApp | Updater") -> StepApp:
+    """The step makers take an app's description; a bare updater stands
+    for the linear app over it."""
+    return app if isinstance(app, StepApp) else linear_app(app)
 
 
 def state_spec() -> P:
@@ -265,16 +472,20 @@ def _values_of(b: Batch) -> jax.Array:
 
 
 def _local_pull(
-    updater: Updater, state_l: State, idx: jax.Array, shard_size: int
+    updater: Updater, state_l: State, idx: jax.Array, shard_size: int,
+    table: str = "",
 ) -> jax.Array:
-    """This shard's contribution to pulled weights for global ids ``idx``."""
+    """This shard's contribution to pulled weights for global ids ``idx``.
+    ``table`` (here and in the pushes): the scope a named table's ops go
+    under, innermost."""
     begin = lax.axis_index("kv") * shard_size
     local = idx - begin
     in_range = (local >= 0) & (local < shard_size)
     safe = jnp.where(in_range, local, 0)
-    rows = {k: jnp.take(v, safe, axis=0) for k, v in state_l.items()}
-    w = updater.weights(rows)
-    return jnp.where(in_range[:, None], w, 0.0)
+    with _sub_scope(table):
+        rows = {k: jnp.take(v, safe, axis=0) for k, v in state_l.items()}
+        w = updater.weights(rows)
+        return jnp.where(in_range[:, None], w, 0.0)
 
 
 def _local_push(
@@ -283,6 +494,7 @@ def _local_push(
     all_idx: jax.Array,  # (D, U) pushes from every data shard
     all_grad: jax.Array,  # (D, U, vdim)
     shard_size: int,
+    table: str = "",
 ) -> State:
     """Apply every worker's push to this kv shard, sequentially (ref: the
     server processes each worker's Push message as its own updater step)."""
@@ -293,11 +505,11 @@ def _local_push(
         local = idx - begin
         in_range = (local >= 0) & (local < shard_size)
         safe = jnp.where(in_range, local, 0)
-        with jax.named_scope("gather"):
+        with jax.named_scope("gather"), _sub_scope(table):
             rows = {k: jnp.take(v, safe, axis=0) for k, v in state_l.items()}
-        with jax.named_scope("update"):
+        with jax.named_scope("update"), _sub_scope(table):
             deltas = updater.delta(rows, g)
-        with jax.named_scope("scatter"):
+        with jax.named_scope("scatter"), _sub_scope(table):
             mask = in_range[:, None].astype(g.dtype)
             new = {
                 k: state_l[k].at[safe].add(mask * deltas[k]) for k in state_l
@@ -314,6 +526,7 @@ def _local_push_aggregate(
     idx: jax.Array,  # (U,) this data shard's unique keys
     grad: jax.Array,  # (U, vdim) this data shard's per-key grads
     shard_size: int,
+    table: str = "",
 ) -> State:
     """Aggregate-then-update push (the BASELINE north star's
     "push ≡ reduce-scatter"): every data shard scatters its grads into a
@@ -337,7 +550,7 @@ def _local_push_aggregate(
     safe = jnp.where(in_range, local, 0)
     mask = in_range[:, None].astype(grad.dtype)
     vdim = grad.shape[-1]
-    with jax.named_scope("scatter"):
+    with jax.named_scope("scatter"), _sub_scope(table):
         g_slice = jnp.zeros((shard_size, vdim), grad.dtype).at[safe].add(
             mask * grad
         )
@@ -346,7 +559,7 @@ def _local_push_aggregate(
     g_slice = lax.psum(g_slice, "data")
     touched = lax.psum(touched, "data")
     # no "gather" here: the updater reads the whole range slice
-    with jax.named_scope("update"):
+    with jax.named_scope("update"), _sub_scope(table):
         deltas = updater.delta(state_l, g_slice)
         hit = (touched > 0).astype(grad.dtype)
         return {k: state_l[k] + hit * deltas[k] for k in state_l}
@@ -360,6 +573,7 @@ def _local_push_quantized(
     shard_size: int,
     push_seed: jax.Array,  # scalar int32, varies per step
     stream: int = 0,  # static sub-stream tag (multi-table apps: one per table)
+    table: str = "",
 ) -> State:
     """Per-worker push with int8-quantized gradients on the wire (the
     reference's fixing_float filter re-expressed as a quantized
@@ -388,28 +602,39 @@ def _local_push_quantized(
     all_q = lax.all_gather(q, "data")  # (D, U, vdim) int8
     all_scale = lax.all_gather(scale, "data")  # (D,)
     all_grad = all_q.astype(grad.dtype) * all_scale[:, None, None]
-    return _local_push(updater, state_l, all_idx, all_grad, shard_size)
+    return _local_push(updater, state_l, all_idx, all_grad, shard_size, table)
 
 
 PUSH_MODES = ("per_worker", "aggregate", "quantized")
 
 
+# XLA holds a (rows, 1) table in 128-row tiles at a program's edge and in
+# 1024-row tiles under its gathers and scatters: the same bytes, converted
+# by a bitcast, only when the rows are whole 1024-row tiles
+ROW_TILE = 1024
+
+
 def padded_num_keys(num_keys: int, kv_size: int) -> int:
-    """``num_keys`` rounded up to the next multiple of the kv axis size —
-    the table rows the sharded tiers actually allocate. The rows past the
+    """The table rows the sharded tiers actually allocate for ``num_keys``:
+    every kv shard the same number of rows, and a shard of more than one
+    ``ROW_TILE`` whole tiles (10^8 rows on one chip would otherwise cost a
+    copy of the table at every gather and scatter). The rows past the
     real ``num_keys`` are pad rows: exactly zero and never touched (the
     data layer only emits keys below ``num_keys``), so arbitrary table
     sizes run on any mesh shape with no semantic change."""
     if num_keys < 1:
         raise ValueError(f"num_keys must be >= 1, got {num_keys}")
-    return -(-num_keys // kv_size) * kv_size
+    shard = -(-num_keys // kv_size)
+    if shard > ROW_TILE:
+        shard = -(-shard // ROW_TILE) * ROW_TILE
+    return shard * kv_size
 
 
 def _shard_size(num_keys: int, kv_size: int) -> int:
     return padded_num_keys(num_keys, kv_size) // kv_size
 
 
-def _wrap_stepper(step, push_mode: str):
+def _wrap_stepper(step, push_mode: str, names: frozenset = frozenset()):
     """Shared jit + push_seed contract for the single- and multi-step
     makers (one home for the quantized-seed guard): ``step`` is the
     shard_map'd program (state, batch, seed) -> (state, loss, ex, probs)."""
@@ -432,70 +657,101 @@ def _wrap_stepper(step, push_mode: str):
                     "call step(state, batch, step_index)"
                 )
             push_seed = 0
-        _note_program(_jitted, seen, state, batch, push_seed)
+        _note_program(_jitted, seen, names, state, batch, push_seed)
         return _jitted(state, batch, push_seed)
 
     return stepper
 
 
+def _dense_step(group: DenseGroup, params, opt_state, grads, active):
+    """The dense group's optimizer step on the pod-wide gradient, applied
+    only when ``active``: an all-padding microstep (a drained host, the pad
+    of a partial group) leaves parameters and optimizer state as they
+    were."""
+    import optax
+
+    grads = jax.tree.map(lambda g: lax.psum(g, "data"), grads)
+    updates, new_opt = group.opt.update(grads, opt_state, params)
+    new_params = optax.apply_updates(params, updates)
+    keep = lambda new, old: jax.tree.map(  # noqa: E731
+        lambda n, o: jnp.where(active, n, o), new, old
+    )
+    return keep(new_params, params), keep(new_opt, opt_state)
+
+
 def _microstep(
-    updater: Updater,
+    app: StepApp,
     state_l: State,
     b: Batch,  # one data shard's un-stacked batch fields
     shard_size: int,
     push_mode: str,
     push_seed: jax.Array,
 ):
-    """One parameter-server step on this device: pull -> CSR grad -> push.
-    Shared verbatim by the single-step and scanned multi-step programs so
-    the wire semantics cannot diverge between them."""
+    """One parameter-server step on this device: pull every table's rows
+    for the batch's keys -> the app's loss and gradients -> push each
+    table's gradient through its updater, step the dense group. Shared
+    verbatim by the single-step and scanned multi-step programs and by
+    every app, so the wire semantics cannot diverge between them."""
     idx = b["unique_keys"]
+    dense = app.dense.unpack(state_l) if app.dense is not None else (None, None)
     with jax.named_scope("ps.row_ids"):
         row_ids = _row_ids_of(b)
     with jax.named_scope("ps.pull"):
-        w_u = lax.psum(
-            _local_pull(updater, state_l, idx, shard_size), "kv"
-        )  # Pull: slice + merge (ref kv_vector match)
+        pulled = {
+            t.name: lax.psum(
+                _local_pull(t.updater, t.of(state_l), idx, shard_size, t.name),
+                "kv",
+            )  # Pull: slice + merge (ref kv_vector match)
+            for t in app.tables
+        }
     with jax.named_scope("ps.grad"):
-        values = _values_of(b)
-        logits = csr_logits(
-            w_u, values, b["local_ids"], row_ids,
-            num_rows=b["labels"].shape[0],
-        )
-        loss, err = logistic_loss(logits, b["labels"], b["example_mask"])
-        g = csr_grad(
-            err, values, b["local_ids"], row_ids, num_unique=idx.shape[0]
-        )
+        loss, logits, grads, g_dense = app.grad(pulled, dense[0], b, row_ids)
         probs = jax.nn.sigmoid(logits)
+    new_state = dict(state_l)
     with jax.named_scope("ps.push"):
-        if push_mode == "aggregate":
-            new_state = _local_push_aggregate(
-                updater, state_l, idx, g, shard_size
-            )
-        elif push_mode == "quantized":
-            new_state = _local_push_quantized(
-                updater, state_l, idx, g, shard_size, push_seed
-            )
-        else:
-            # Push: every data shard's (keys, grads) reach every kv shard.
-            all_idx = lax.all_gather(idx, "data")  # (D, U)
-            all_grad = lax.all_gather(g, "data")  # (D, U, vdim)
-            new_state = _local_push(
-                updater, state_l, all_idx, all_grad, shard_size
-            )
+        for i, t in enumerate(app.tables):
+            g, tab = grads[t.name], t.of(state_l)
+            if push_mode == "aggregate":
+                new = _local_push_aggregate(
+                    t.updater, tab, idx, g, shard_size, t.name
+                )
+            elif push_mode == "quantized":
+                # tables that share a microstep's seed round on streams of
+                # their own; a single table keeps the original key schedule
+                new = _local_push_quantized(
+                    t.updater, tab, idx, g, shard_size, push_seed,
+                    stream=i + 1 if len(app.tables) > 1 else 0, table=t.name,
+                )
+            else:
+                # Push: every data shard's (keys, grads) reach every kv shard.
+                all_idx = lax.all_gather(idx, "data")  # (D, U)
+                all_grad = lax.all_gather(g, "data")  # (D, U, vdim)
+                new = _local_push(
+                    t.updater, tab, all_idx, all_grad, shard_size, t.name
+                )
+            new_state.update({t.key(k): v for k, v in new.items()})
     loss_sum = lax.psum(loss, "data")
     # pod-wide real-example count: the host-side termination signal
     # (a drained host keeps feeding empty batches; every host stops
     # deterministically after retiring a step with examples == 0 —
     # this rides async dispatch instead of a blocking host barrier)
     examples = lax.psum(jnp.sum(b["example_mask"]), "data")
+    if app.dense is not None:
+        with jax.named_scope("ps.dense"):
+            new_state.update(
+                app.dense.pack(
+                    *_dense_step(app.dense, *dense, g_dense, examples > 0)
+                )
+            )
     return new_state, loss_sum, examples, probs
 
 
 def make_spmd_train_step(
-    updater: Updater, mesh: Mesh, num_keys: int, push_mode: str = "per_worker"
+    app: "StepApp | Updater", mesh: Mesh, num_keys: int,
+    push_mode: str = "per_worker",
 ):
-    """Build the jitted multi-device train step.
+    """Build the jitted multi-device train step of ``app`` (a ``StepApp``;
+    a bare updater stands for the linear app over it).
 
     step(state, batch) -> (state, out) with out keys:
       "loss_sum" — scalar, psum over data
@@ -503,7 +759,7 @@ def make_spmd_train_step(
           termination signal; see PodTrainer's drained contract)
       "probs"    — (D, B) per-shard probabilities
 
-    push_mode:
+    push_mode, for every table of the app:
       "per_worker" — faithful reference semantics: each data shard's push is
           its own server updater step (all_gather + sequential scan).
       "aggregate"  — pre-sum per-key grads across data shards with one psum,
@@ -513,29 +769,12 @@ def make_spmd_train_step(
           (see ``_local_push_quantized``; the fixing_float filter as a
           quantized collective for DCN-limited pods).
     """
-    if push_mode not in PUSH_MODES:
-        raise ValueError(f"unknown push_mode {push_mode!r}; known: {PUSH_MODES}")
-    shard_size = _shard_size(num_keys, mesh.shape["kv"])
-
-    def local_step(state_l: State, batch: Batch, push_seed: jax.Array):
-        b = {k: v[0] for k, v in batch.items()}  # this data shard's batch
-        new_state, loss_sum, examples, probs = _microstep(
-            updater, state_l, b, shard_size, push_mode, push_seed
-        )
-        return new_state, loss_sum, examples, probs[None, :]  # -> (D, B)
-
-    step = shard_map(
-        local_step,
-        mesh=mesh,
-        in_specs=(state_spec(), batch_spec(), P()),
-        out_specs=(state_spec(), P(), P(), batch_spec()),
-        check_vma=False,
-    )
-    return _wrap_stepper(step, push_mode)
+    return _make_train(app, mesh, num_keys, push_mode, multistep=False)
 
 
 def make_spmd_train_multistep(
-    updater: Updater, mesh: Mesh, num_keys: int, push_mode: str = "per_worker"
+    app: "StepApp | Updater", mesh: Mesh, num_keys: int,
+    push_mode: str = "per_worker",
 ):
     """K parameter-server steps per device call: ``lax.scan`` over a
     leading microstep axis inside ONE jitted shard_map program.
@@ -560,20 +799,30 @@ def make_spmd_train_multistep(
           trail real batches within a group)
       "probs"    — (D, K, B) per-shard, per-microstep probabilities
     """
+    return _make_train(app, mesh, num_keys, push_mode, multistep=True)
+
+
+def _make_train(app, mesh: Mesh, num_keys: int, push_mode: str, multistep: bool):
     if push_mode not in PUSH_MODES:
         raise ValueError(f"unknown push_mode {push_mode!r}; known: {PUSH_MODES}")
+    app = _as_app(app)
     shard_size = _shard_size(num_keys, mesh.shape["kv"])
 
     def local_step(state_l: State, batch: Batch, push_seed: jax.Array):
-        b = {k: v[0] for k, v in batch.items()}  # this shard's (K, ...) group
-        n_micro = b["labels"].shape[0]
+        b = {k: v[0] for k, v in batch.items()}  # this data shard's batch
+        if not multistep:
+            new_state, loss_sum, examples, probs = _microstep(
+                app, state_l, b, shard_size, push_mode, push_seed
+            )
+            return new_state, loss_sum, examples, probs[None, :]  # -> (D, B)
+        n_micro = b["labels"].shape[0]  # b holds this shard's (K, ...) group
 
         def body(st: State, micro):
             mb, i = micro
             # quantized mode: a distinct PRNG key per microstep (the
             # same per-step-seed contract as single-step dispatch)
             new_st, loss, ex, probs = _microstep(
-                updater, st, mb, shard_size, push_mode, push_seed + i
+                app, st, mb, shard_size, push_mode, push_seed + i
             )
             return new_st, (loss, ex, probs)
 
@@ -582,14 +831,15 @@ def make_spmd_train_multistep(
         )
         return new_state, losses, exs, probs[None]  # -> (D, K, B)
 
+    specs = app.specs()
     step = shard_map(
         local_step,
         mesh=mesh,
-        in_specs=(state_spec(), batch_spec(), P()),
-        out_specs=(state_spec(), P(), P(), batch_spec()),
+        in_specs=(specs, batch_spec(), P()),
+        out_specs=(specs, P(), P(), batch_spec()),
         check_vma=False,
     )
-    return _wrap_stepper(step, push_mode)
+    return _wrap_stepper(step, push_mode, app.scope_names())
 
 
 def stack_step_groups(stacked_items: list[Batch]) -> Batch:
@@ -614,29 +864,34 @@ def stack_step_groups(stacked_items: list[Batch]) -> Batch:
     }
 
 
-def make_spmd_predict_step(updater: Updater, mesh: Mesh, num_keys: int):
+def make_spmd_predict_step(app: "StepApp | Updater", mesh: Mesh, num_keys: int):
+    app = _as_app(app)
     shard_size = _shard_size(num_keys, mesh.shape["kv"])
 
     def local_predict(state_l: State, batch: Batch):
         b = {k: v[0] for k, v in batch.items()}
+        dense = app.dense.unpack(state_l)[0] if app.dense is not None else None
         with jax.named_scope("ps.row_ids"):
             row_ids = _row_ids_of(b)
         with jax.named_scope("ps.pull"):
-            w_u = lax.psum(
-                _local_pull(updater, state_l, b["unique_keys"], shard_size),
-                "kv",
-            )
+            pulled = {
+                t.name: lax.psum(
+                    _local_pull(
+                        t.updater, t.of(state_l), b["unique_keys"],
+                        shard_size, t.name,
+                    ),
+                    "kv",
+                )
+                for t in app.tables
+            }
         with jax.named_scope("ps.grad"):
-            logits = csr_logits(
-                w_u, _values_of(b), b["local_ids"], row_ids,
-                num_rows=b["labels"].shape[0],
-            )
+            logits = app.logits(pulled, dense, b, row_ids)
             return jax.nn.sigmoid(logits)[None, :]
 
     step = shard_map(
         local_predict,
         mesh=mesh,
-        in_specs=(state_spec(), batch_spec()),
+        in_specs=(app.specs(), batch_spec()),
         out_specs=batch_spec(),
         check_vma=False,
     )
@@ -644,7 +899,7 @@ def make_spmd_predict_step(updater: Updater, mesh: Mesh, num_keys: int):
     seen: set = set()
 
     def predict(state: State, batch: Batch) -> jax.Array:
-        _note_program(jitted, seen, state, batch)
+        _note_program(jitted, seen, app.scope_names(), state, batch)
         return jitted(state, batch)
 
     return predict

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import contextlib
 import itertools
+import os
 import time
 from collections.abc import Iterator
 from typing import Any
@@ -27,7 +28,7 @@ from typing import Any
 import jax
 import numpy as np
 
-from parameter_server_tpu.data.batch import BatchBuilder, CSRBatch
+from parameter_server_tpu.data.batch import BatchBuilder, CSRBatch, inert_like
 from parameter_server_tpu.data.pipeline import PrefetchPipeline
 from parameter_server_tpu.data.reader import MinibatchReader
 from parameter_server_tpu.models import metrics as M
@@ -35,6 +36,8 @@ from parameter_server_tpu.models.linear import updater_from_config
 from parameter_server_tpu.parallel.mesh import make_mesh
 from parameter_server_tpu.parallel.runtime import Runtime
 from parameter_server_tpu.parallel.spmd import (
+    StepApp,
+    linear_app,
     make_spmd_predict_step,
     make_spmd_train_multistep,
     make_spmd_train_step,
@@ -62,6 +65,10 @@ _PROBE_GRACE_FLOOR_S = 120.0
 # enough to overlap host batch-build with device predict, small enough
 # that queued input/result buffers stay a constant HBM footprint
 _EVAL_INFLIGHT = 2
+
+# the dense group's leaves in a checkpoint directory, beside the tables'
+# per-host shards
+_DENSE_FILE = "dense.npz"
 
 
 class _WorkerStream:
@@ -113,6 +120,31 @@ class _WorkerStream:
         return self.builder.build(np.zeros(0, dtype=np.float32), [], [])
 
 
+class _ListStream:
+    """One logical worker's share of an in-memory batch list, offered the
+    way ``_WorkerStream`` offers a file's batches (``train_batches``)."""
+
+    def __init__(self, batches: list[CSRBatch], like: CSRBatch):
+        self._iter = iter(batches)
+        self._like = like
+
+    def next_batch(self) -> CSRBatch | None:
+        return next(self._iter, None)
+
+    def _empty(self) -> CSRBatch:
+        return inert_like(self._like)
+
+
+def app_from_config(cfg: PSConfig) -> StepApp:
+    """The description of ``cfg.app`` for the shared step: its tables, its
+    dense group, its model (``parallel.spmd.StepApp``)."""
+    if cfg.app == "wide_deep":
+        from parameter_server_tpu.models import wide_deep
+
+        return wide_deep.app_from_config(cfg)
+    return linear_app(updater_from_config(cfg))
+
+
 class _RemotePool:
     """WorkloadPool facade over the TCP Coordinator: the wire tier's
     scheduler assigns shards across SPMD hosts (tier composition)."""
@@ -136,7 +168,10 @@ class _EpochStream(_WorkerStream):
 
 
 class PodTrainer:
-    """Train the flagship sparse-LR app across a data x kv device mesh."""
+    """Train ``cfg.app`` (the flagship sparse-LR app, or Wide&Deep) across
+    a data x kv device mesh: state, step, predict and checkpoint all come
+    from the app's description (``app_from_config``; ``app`` overrides
+    it)."""
 
     def __init__(
         self,
@@ -145,6 +180,7 @@ class PodTrainer:
         reporter: ProgressReporter | None = None,
         runtime: Runtime | None = None,
         profile_dir: str = "",
+        app: StepApp | None = None,
     ):
         self.cfg = cfg
         if cfg.trace.trace_dir and not trace.tracer.enabled:
@@ -247,7 +283,8 @@ class PodTrainer:
         self.data_shards = self.mesh.shape["data"]
         # this process feeds only its own data rows (multi-host contract)
         self.local_data_shards = self.runtime.local_data_shards
-        self.updater = updater_from_config(cfg)
+        self.app = app if app is not None else app_from_config(cfg)
+        self.updater = self.app.tables[0].updater
         # K microsteps scanned per device call (see SolverConfig.steps_per
         # _call): amortizes the per-call host->device round-trip floor
         if cfg.solver.steps_per_call < 1:
@@ -267,11 +304,11 @@ class PodTrainer:
             else make_spmd_train_step
         )
         self.step_fn = maker(
-            self.updater, self.mesh, cfg.data.num_keys,
+            self.app, self.mesh, cfg.data.num_keys,
             push_mode=cfg.parallel.push_mode,
         )
         self.predict_fn = make_spmd_predict_step(
-            self.updater, self.mesh, cfg.data.num_keys
+            self.app, self.mesh, cfg.data.num_keys
         )
         # table rows are num_keys rounded up to the kv-axis multiple (pad
         # rows stay exactly zero — no batch key ever reaches them), so
@@ -279,14 +316,22 @@ class PodTrainer:
         self._table_rows = padded_num_keys(
             cfg.data.num_keys, self.mesh.shape["kv"]
         )
+        # one flat {name: array} dict: every table's slots made on the
+        # devices, slice by slice; the dense group's leaves replicated
         self.state = self.runtime.init_state(
-            lambda: self.updater.init(self._table_rows, 1)
+            lambda: self.app.init_tables(self._table_rows)
         )
+        if self.app.dense is not None:
+            self.state.update(self._replicated(self.app.dense.init_state()))
         self.reporter = reporter or ProgressReporter()
         self.clock = SSPClock(
             num_workers=1, max_delay=max(cfg.solver.max_delay, 0)
         )
         self.examples_seen = 0
+        # device calls dispatched with real examples, over the trainer's
+        # life: the base of each call's push_seed, so that quantized
+        # rounding never reuses a key, in a later epoch either
+        self.calls_trained = 0
         # observability: peak dispatch run-ahead (the SSP/async-overlap
         # depth actually reached; == max_delay + 1 when the gate binds)
         self.max_inflight = 0
@@ -313,6 +358,19 @@ class PodTrainer:
         """Run all epochs over ``files`` sharded across workers."""
         with self._trace_cm():
             return self._run_epochs(files, key_mode, report_every)
+
+    def train_batches(self, batches, report_every: int = 20) -> dict:
+        """One pass over an in-memory CSRBatch stream, through the loop
+        ``train_files`` runs: worker d of the D data shards takes batches
+        d, d + D, ... so that a microstep consumes D consecutive batches,
+        ``steps_per_call`` microsteps a device call."""
+        batches = list(batches)
+        if not batches:
+            return {}
+        d = self.local_data_shards
+        streams = [_ListStream(batches[w::d], batches[0]) for w in range(d)]
+        with self._trace_cm():
+            return self._train_epoch(streams, report_every)
 
     def _trace_cm(self):
         return (
@@ -619,15 +677,16 @@ class PodTrainer:
                     stacked = self.runtime.globalize_batch(stacked_np)
                     # push_seed varies per microstep so quantized-push
                     # stochastic rounding never reuses a key (traced
-                    # scalar: no recompile); step_idx * K is this call's
-                    # first microstep index
+                    # scalar: no recompile); calls_trained * K is this
+                    # call's first microstep index
                     with self._new_shape_phase(
                         "trainer.new_shapes", stacked_np, step=step_idx
                     ):
                         self.state, out = self.step_fn(
-                            self.state, stacked, step_idx * K
+                            self.state, stacked, self.calls_trained * K
                         )
                 flightrec.record("step.dispatch", step=step_idx, examples=int(n))
+                self.calls_trained += int(n > 0)
                 self.examples_seen += n
                 n_since += n
                 gate.add(
@@ -670,19 +729,68 @@ class PodTrainer:
             ssp=self.clock.progress(),
         )
 
-    def full_weights(self) -> np.ndarray:
-        """Materialize the (num_keys, vdim) weight vector on this host from
-        its local replica of the kv-sharded state."""
+    def _replicated(self, tree):
+        """Place host values whole on every device of the mesh."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        return jax.device_put(tree, NamedSharding(self.mesh, P()))
+
+    def table_state(self, name: str = "") -> dict:
+        """Table ``name``'s slots ({slot: (rows, vdim) array}) out of the
+        flat state."""
+        return self.app.table(name).of(self.state)
+
+    def _place_tables(self, host: dict) -> dict:
+        """Host arrays of at least ``num_keys`` rows, keyed as the state is,
+        onto the mesh: cut to the real rows, zero-padded to THIS mesh's
+        table rows (another mesh shape carries another pad tail)."""
+        from parameter_server_tpu.data.batch import zero_extend
+
+        return self.runtime.state_from_host(
+            {
+                k: zero_extend(
+                    np.asarray(v)[: self.cfg.data.num_keys], self._table_rows
+                )
+                for k, v in host.items()
+            }
+        )
+
+    def set_table(self, name: str, slots: dict) -> None:
+        """Put host or device arrays of ``num_keys`` rows (or some mesh's
+        padded rows) in place of table ``name``'s slots."""
+        t = self.app.table(name)
+        self.state = {
+            **self.state,
+            **self._place_tables({t.key(k): v for k, v in slots.items()}),
+        }
+
+    def dense(self) -> tuple:
+        """(parameters, optimizer state) of the app's dense group."""
+        return self.app.dense.unpack(self.state)
+
+    def set_dense(self, params, opt_state) -> None:
+        self.state = {
+            **self.state,
+            **self._replicated(self.app.dense.pack(params, opt_state)),
+        }
+
+    def full_weights(self, table: str | None = None) -> np.ndarray:
+        """Materialize a table's (num_keys, vdim) weights on this host from
+        its local replica of the kv-sharded state (the app's first table
+        unless named)."""
         import jax.numpy as jnp
 
-        host = self.runtime.state_to_host(self.state)
+        t = self.app.tables[0] if table is None else self.app.table(table)
+        host = self.runtime.state_to_host(t.of(self.state))
         return np.asarray(
-            self.updater.weights({k: jnp.asarray(v) for k, v in host.items()})
+            t.updater.weights({k: jnp.asarray(v) for k, v in host.items()})
         )[: self.cfg.data.num_keys]
 
     def save(self, ckpt_dir, meta: dict | None = None) -> None:
         """Per-host sharded checkpoint (each host writes its key-range
-        slice; ref: each server dumps its own range).
+        slice of every table; ref: each server dumps its own range); the
+        dense group and its optimizer state, which every host holds whole,
+        go into ``dense.npz`` from host 0.
 
         Multi-host contract: ``save`` ends in a cross-host barrier, so
         EVERY process must call it with the same decision to save — run
@@ -690,34 +798,27 @@ class PodTrainer:
         or a saving host deadlocks waiting on one that skipped it."""
         self.runtime.save_checkpoint(
             ckpt_dir,
-            self.state,
+            {k: self.state[k] for k in self.app.table_keys()},
             meta={"examples_seen": self.examples_seen, **(meta or {})},
         )
+        if self.app.dense is not None and self.runtime.process_index == 0:
+            np.savez(
+                os.path.join(ckpt_dir, _DENSE_FILE),
+                **{k: np.asarray(self.state[k]) for k in self.app.dense.keys()},
+            )
         self.runtime.barrier("ckpt_saved")
 
     def load(self, ckpt_dir) -> dict:
-        self.state, meta = self.runtime.load_checkpoint(ckpt_dir)
-        rows = next(iter(self.state.values())).shape[0]
-        if rows != self._table_rows:
-            # a checkpoint written on a different mesh shape (or before
-            # padding existed) carries a different pad tail: re-pad the
-            # host replica up to THIS mesh's table rows
-            from parameter_server_tpu.kv.store import pad_state_rows
+        from parameter_server_tpu.utils.checkpoint import load_checkpoint
 
-            host = self.runtime.state_to_host(self.state)
-            host = {
-                k: np.asarray(v)[: self.cfg.data.num_keys]
-                for k, v in host.items()
-            }
-            import jax.numpy as jnp
-
-            host = pad_state_rows(
-                {k: jnp.asarray(v) for k, v in host.items()},
-                self._table_rows,
-            )
-            self.state = self.runtime.state_from_host(
-                {k: np.asarray(v) for k, v in host.items()}
-            )
+        host, meta = load_checkpoint(ckpt_dir)
+        tables = self._place_tables({k: host[k] for k in self.app.table_keys()})
+        self.state = dict(tables)
+        if self.app.dense is not None:
+            with np.load(os.path.join(ckpt_dir, _DENSE_FILE)) as d:
+                self.state.update(
+                    self._replicated({k: d[k] for k in self.app.dense.keys()})
+                )
         self.examples_seen = int(meta.get("examples_seen", 0))
         return meta
 
@@ -738,6 +839,13 @@ class PodTrainer:
             # collectives, so hosts may evaluate different file sets
             from parameter_server_tpu.models.evaluation import evaluate_model
 
+            if self.app.dense is not None:
+                raise NotImplementedError(
+                    "multi-host evaluation scores a linear model's weight "
+                    "vector host-locally; an app with a dense group is "
+                    "evaluated on one host"
+                )
+
             return evaluate_model(
                 self.full_weights().ravel(),
                 files,
@@ -747,11 +855,40 @@ class PodTrainer:
                 max_nnz_per_example=self.cfg.data.max_nnz_per_example,
                 key_mode=key_mode,
             )
-        with self._trace_cm():
-            return self._evaluate_pass(files, key_mode)
+        from parameter_server_tpu.data.batch import eval_builder
 
-    def _evaluate_pass(self, files: list[str], key_mode: str) -> dict:
-        from parameter_server_tpu.data.batch import eval_builder, pad_group
+        def open_reader():
+            builder = eval_builder(self.cfg, key_mode)
+            reader = MinibatchReader(files, self.cfg.data.format, builder)
+            return iter(reader), lambda: _pad_like(builder)
+
+        with self._trace_cm():
+            return self._score(*self._predict_pass(open_reader))
+
+    def predict_batches(self, batches) -> tuple[np.ndarray, np.ndarray]:
+        """(labels, probabilities) of an in-memory CSRBatch stream through
+        the predict step, D batches a call."""
+        ys, ps = self._predict_pass(_opener(batches))
+        return np.concatenate(ys), np.concatenate(ps)
+
+    def evaluate_batches(self, batches) -> dict:
+        return self._score(*self._predict_pass(_opener(batches)))
+
+    @staticmethod
+    def _score(ys: list, ps: list) -> dict:
+        with trace.phase("eval.score"):
+            y = np.concatenate(ys)
+            p = np.concatenate(ps)
+            return {
+                "auc": M.auc(y, p), "logloss": M.logloss(y, p),
+                "examples": len(y),
+            }
+
+    def _predict_pass(self, open_batches) -> tuple[list, list]:
+        """One pass of the predict step over the batches ``open_batches()``
+        yields ((iterator, maker of an inert batch of their shape), opened
+        inside ``eval.open``): per-group labels and probabilities."""
+        from parameter_server_tpu.data.batch import pad_group
 
         # bounded async dispatch (the train loop's DispatchWindow pattern):
         # up to EVAL_INFLIGHT predicts ride JAX async dispatch — no
@@ -773,11 +910,7 @@ class PodTrainer:
             # fill every data shard with real batches (D at a time); only
             # the tail group pads with inert batches
             batches = pad_group(
-                group
-                + [
-                    _pad_like(builder)
-                    for _ in range(self.data_shards - len(group))
-                ]
+                group + [pad() for _ in range(self.data_shards - len(group))]
             )
             stacked = stack_batches(
                 batches, self.mesh,
@@ -791,10 +924,7 @@ class PodTrainer:
             )
 
         with trace.phase("eval.open"):
-            builder = eval_builder(self.cfg, key_mode)
-            reader = iter(
-                MinibatchReader(files, self.cfg.data.format, builder)
-            )
+            reader, pad = open_batches()
             groups = iter(
                 lambda: list(itertools.islice(reader, self.data_shards)), []
             )
@@ -810,13 +940,13 @@ class PodTrainer:
         while pending:
             with trace.phase("eval.retire"):
                 _retire_oldest()
-        with trace.phase("eval.score"):
-            y = np.concatenate(ys)
-            p = np.concatenate(ps)
-            return {
-                "auc": M.auc(y, p), "logloss": M.logloss(y, p),
-                "examples": len(y),
-            }
+        return ys, ps
+
+
+def _opener(batches):
+    """``_predict_pass``'s source over an in-memory batch stream."""
+    batches = list(batches)
+    return lambda: (iter(batches), lambda: inert_like(batches[0]))
 
 
 def _pad_like(builder: BatchBuilder) -> CSRBatch:

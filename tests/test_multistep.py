@@ -313,9 +313,9 @@ class TestWideDeepMultistep:
         when the K-th microstep is all-inert (the padded-tail case): the
         pod-wide activity gate must keep Adam's moments AND count frozen
         on the pad, or mlp/opt state silently diverges."""
+        from parameter_server_tpu.data.batch import inert_like as _inert_like
         from parameter_server_tpu.models.wide_deep import (
             WideDeep,
-            _inert_like,
             make_wd_spmd_train_step,
             make_wd_spmd_train_multistep,
         )

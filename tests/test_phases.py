@@ -101,6 +101,35 @@ class TestDeviceScopes:
         assert f("jit(step)/ps.push/gather/jit(_take)/gather") == "ps.push/gather"
         assert f("jit(_jitted)/while") == "" and f("") == ""
 
+    def test_a_named_table_or_group_is_told_by_the_apps_names(self):
+        names = frozenset({"emb", "mlp", "while"})
+        f = lambda op_name: spmd._scope_of(op_name, names)  # noqa: E731
+        assert f("jit(_jitted)/ps.pull/emb/jit(_take)/gather") == "ps.pull/emb"
+        assert f("jit(_jitted)/ps.push/scatter/emb/scatter-add") == "ps.push/scatter/emb"
+        assert f("jit(_jitted)/ps.grad/transpose(jvp(mlp))/dot_general") == "ps.grad/mlp"
+        # names come from the app that ran the program (StepApp.scope_names), not
+        # from whatever was traced earlier in the process: another app's are no scope
+        assert spmd._scope_of("jit(_jitted)/ps.pull/emb/jit(_take)/gather") == "ps.pull"
+        assert spmd._scope_of("jit(_jitted)/ps.pull/while/body/gather") == "ps.pull"
+        from parameter_server_tpu.models import wide_deep
+
+        cfg = PSConfig()
+        cfg.app = "wide_deep"
+        assert wide_deep.app_from_config(cfg).scope_names() == {"wide", "emb", "mlp"}
+        assert spmd.linear_app(updater_from_config(PSConfig())).scope_names() == frozenset()
+
+    def test_forgotten_programs_are_remembered_when_they_run_again(self, no_programs):
+        mesh = make_mesh(1, 1)
+        updater = updater_from_config(PSConfig())
+        fn = spmd.make_spmd_predict_step(updater, mesh, NUM_KEYS)
+        state = spmd.shard_state(updater.init(NUM_KEYS, 1), mesh)
+        fn(state, _batch(1))
+        assert len(spmd._ran) == 1
+        spmd.forget_programs()
+        assert spmd.op_scopes() == {}
+        fn(state, _batch(1))  # shapes this stepper has seen before
+        assert len(spmd._ran) == 1 and len(spmd.op_scopes()) == 1
+
     def test_programs_that_share_a_module_name_merge(self, monkeypatch):
         monkeypatch.setattr(spmd, "_ran", [
             spmd._RanProgram(None, (), ("jit__jitted", {"fusion.1": "ps.pull", "fusion.2": "ps.grad", "copy.1": ""})),

@@ -234,14 +234,15 @@ class TestWideDeepQuantizedPush:
         """Two dispatches must not reuse one PRNG stream: the app's base
         seed advances by K per device call (a silently-frozen seed would
         correlate the rounding noise across steps instead of averaging
-        it out)."""
+        it out): the trainer's count of trained calls, times K, is the
+        seed the shared step is handed."""
         mesh = make_mesh(2, 2)
         app = WideDeep(num_keys=64, emb_dim=8, hidden=[16], reporter=quiet(),
                        mesh=mesh, push_mode="quantized", steps_per_call=2)
         builder = BatchBuilder(num_keys=64, batch_size=256, key_mode="identity")
         batches, _ = TestWideDeepSPMD()._xor_batches(builder, n=1024)
         app.train(batches, report_every=10**6)
-        assert app._push_calls == len(batches) // (2 * 2)
+        assert app.trainer.calls_trained == len(batches) // (2 * 2)
 
 
 class TestWord2VecSPMD:
@@ -298,4 +299,4 @@ class TestWideDeepQuantizedFromConfig:
         batches, _ = TestWideDeepSPMD()._xor_batches(builder, n=1024)
         app.train(batches, report_every=10**6)
         assert app.push_mode == "quantized"
-        assert app._push_calls == len(batches) // (2 * 2)
+        assert app.trainer.calls_trained == len(batches) // (2 * 2)

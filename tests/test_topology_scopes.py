@@ -188,3 +188,119 @@ def test_large_unscoped_instructions_are_the_known_kinds(compiled_text, data, kv
     for name, shape, opcode, operand_shapes in executed(compiled_text(data, kv, program)):
         if opcode == "fusion" and not scopes[name]:
             assert elements(shape) < 4 * UNIQUE and all(elements(s) < ROWS_PER_CHIP for s in operand_shapes), name
+
+
+# -- Wide&Deep: two tables and a dense tower through the same step ------------
+WD_ROWS = 100_000_000  # the cell wd100m.train: 10^8 rows x (vdim 1 + vdim 16)
+WD_CASES = [(1, 1, "multistep"), (1, 1, "predict"), (2, 2, "multistep")]
+WD_NAMES = frozenset({"wide", "emb", "mlp"})  # the app's StepApp.scope_names()
+
+
+@pytest.fixture(scope="module")
+def wd_text(topo):
+    """(data, kv, program) -> optimised HLO text of the Wide&Deep programs at
+    the cell's size (the tower 1024-512-256, ``emb_dim`` 16), compiled once."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding
+
+    from parameter_server_tpu.models import wide_deep
+    from parameter_server_tpu.parallel import spmd
+    from parameter_server_tpu.utils.config import PSConfig
+
+    texts: dict = {}
+
+    def get(data: int, kv: int, program: str) -> str:
+        key = (data, kv, program)
+        if key in texts:
+            return texts[key]
+        cfg = PSConfig()
+        cfg.app, cfg.data.num_keys = "wide_deep", WD_ROWS
+        cfg.wd.emb_dim, cfg.wd.hidden = 16, [1024, 512, 256]
+        app = wide_deep.app_from_config(cfg)
+        mesh = Mesh(np.array(topo.devices[: data * kv]).reshape(data, kv), ("data", "kv"))
+        specs = app.specs()
+        rows = spmd.padded_num_keys(WD_ROWS, kv)  # whole tiles a shard, as PodTrainer allocates
+        shapes = jax.eval_shape(lambda: {**app.init_tables(rows), **app.dense.init_state()})
+        state = {
+            k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=NamedSharding(mesh, specs[k]))
+            for k, v in shapes.items()
+        }
+        feed = NamedSharding(mesh, spmd.batch_spec())
+        lead = (K,) if program == "multistep" else ()
+        fields = {
+            "unique_keys": ((UNIQUE,), jnp.int32), "local_ids": ((NNZ,), jnp.int32),
+            "row_splits": ((MINIBATCH + 1,), jnp.int32), "values": ((NNZ,), jnp.float32),
+            "labels": ((MINIBATCH,), jnp.float32), "example_mask": ((MINIBATCH,), jnp.bool_),
+        }
+        batch = {
+            k: jax.ShapeDtypeStruct((data, *lead, *shape), dt, sharding=feed)
+            for k, (shape, dt) in fields.items()
+        }
+        if program == "multistep":
+            fn, args = spmd.make_spmd_train_multistep(app, mesh, WD_ROWS), (state, batch, 0)
+        else:
+            fn, args = spmd.make_spmd_predict_step(app, mesh, WD_ROWS), (state, batch)
+        (jitted,) = [
+            c.cell_contents for c in fn.__closure__
+            if callable(c.cell_contents) and hasattr(c.cell_contents, "lower")
+        ]
+        compiled = jitted.lower(*args).compile()
+        mem = compiled.memory_analysis()
+        texts[key] = compiled.as_text()
+        texts[key, "bytes"] = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        return texts[key]
+
+    get.texts = texts
+    return get
+
+
+@pytest.mark.parametrize("data,kv,program", WD_CASES)
+def test_wd_table_ops_are_scoped_by_table(wd_text, data, kv, program):
+    """Every executed instruction that reads or writes one of the two tables
+    sits under a ``ps.*`` scope that names its table innermost, the tower's
+    matmuls under ``ps.grad/mlp``, its optimizer under ``ps.dense``; and a
+    10^8 x 16 table is held unpadded, so the step fits one chip."""
+    from parameter_server_tpu.parallel import spmd
+
+    text = wd_text(data, kv, program)
+    _, scopes = spmd.hlo_scopes(text, WD_NAMES)
+    rows = spmd.padded_num_keys(WD_ROWS, kv) // kv
+    table = re.compile(rf"\[(1,1,)?{rows}(,1|,16)?\]")  # however XLA views a table
+    touching = [
+        (name, scopes[name])
+        for name, shape, opcode, operand_shapes in executed(text)
+        if table.search(shape) or any(table.search(s) for s in operand_shapes)
+    ]
+    assert touching
+    assert all(re.match(r"^ps\.(pull|push/\w+)/(wide|emb)$", scope) for _, scope in touching), touching
+    found = set(scopes.values())
+    assert {"ps.row_ids", "ps.pull/wide", "ps.pull/emb", "ps.grad", "ps.grad/mlp"} <= found, found
+    if program == "multistep":
+        assert {"ps.push/scatter/wide", "ps.push/scatter/emb", "ps.dense"} <= found, found
+    # what the program and its state take of one chip: z + n + w + n unpadded
+    # are 12.67 GiB of it at kv 1 (lane-padded to 128 they would be 96), and
+    # no copy of a table is among the temporaries
+    assert wd_text.texts[(data, kv, program), "bytes"] < (13.5 if kv == 1 else 7.5) * 2**30
+
+
+@pytest.mark.parametrize("data,kv,program", WD_CASES)
+def test_wd_large_unscoped_instructions_are_the_known_kinds(wd_text, data, kv, program):
+    from parameter_server_tpu.parallel import spmd
+
+    text = wd_text(data, kv, program)
+    _, scopes = spmd.hlo_scopes(text, WD_NAMES)
+    known = re.compile(
+        r"^(copy|copy-start|copy-done|custom-call|slice-start|slice-done|async-start|async-done"
+        r"|reduce|broadcast|dynamic-update-slice|all-reduce|fusion)$"
+    )
+    strays = [
+        (name, opcode, shape)
+        for name, shape, opcode, _ in executed(text)
+        if not scopes[name] and elements(shape) >= NNZ and not known.match(opcode)
+    ]
+    assert not strays, strays
+    for name, shape, opcode, operand_shapes in executed(text):
+        if opcode == "fusion" and not scopes[name]:
+            # bookkeeping at batch size (a (U, 16) buffer at most), never a table op
+            assert elements(shape) < 17 * UNIQUE and all(elements(s) < WD_ROWS // kv for s in operand_shapes), name
