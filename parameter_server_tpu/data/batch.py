@@ -7,7 +7,13 @@ is what Pull/Push are issued against.
 
 TPU twist: every batch is padded to static (B, NNZ, U) so one compiled
 program serves the whole stream. Padding contract (see kv.store):
-  - ``unique_keys[0] == PAD_KEY (0)`` always; unused unique slots repeat 0.
+  - ``unique_keys[0] == PAD_KEY (0)`` always; ``unique_keys[1:num_unique]``
+    are the real keys, strictly ascending (``np.unique``'s order; a real
+    key is never 0); the tail ``unique_keys[num_unique:]`` repeats
+    PAD_KEY. Every grow path (``zero_extend``) and ``inert_like`` keep
+    this, and the step's push rests on it: it tells XLA that the rows it
+    scatters to ascend (parallel.spmd ``_local_push``), which a key list in
+    any other order would make undefined behaviour on the chip.
   - padded CSR entries have ``value == 0`` and point at unique slot 0, row 0.
   - padded example rows have ``label == 0`` and ``example_mask == False``.
 """
@@ -49,6 +55,19 @@ class CSRBatch:
     @property
     def shape(self) -> tuple[int, int, int]:
         return (len(self.labels), len(self.values), len(self.unique_keys))
+
+    def keys_in_order(self) -> bool:
+        """The key half of the padding contract above (``PAD_KEY``,
+        strictly ascending real keys, ``PAD_KEY`` to the end): what the
+        step's push promises XLA. For an ``assert`` where batches from any
+        builder enter the trainer; about 0.1 ms at 2^19 slots."""
+        keys, n = self.unique_keys, self.num_unique
+        return bool(
+            keys[0] == PAD_KEY
+            and (keys[1:n] != PAD_KEY).all()
+            and (keys[2:n] > keys[1 : n - 1]).all()
+            and not keys[n:].any()
+        )
 
 
 def training_builder(cfg, key_mode: str = "hash") -> "BatchBuilder":

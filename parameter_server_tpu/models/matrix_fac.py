@@ -31,7 +31,10 @@ from parameter_server_tpu.utils.metrics import ProgressReporter
 
 @dataclass
 class MFBatch:
-    """Localized rating minibatch (static shapes)."""
+    """Localized rating minibatch (static shapes). The key lists obey the
+    batch contract of ``data.batch``: slot 0 ``PAD_KEY``, then
+    ``np.unique``'s strictly ascending ids, then ``PAD_KEY`` to the end
+    (what the mesh step promises ``_local_push``)."""
 
     user_keys: np.ndarray  # (Uu,) unique user ids (slot 0 = pad)
     item_keys: np.ndarray  # (Ui,) unique item ids (slot 0 = pad)
@@ -201,13 +204,14 @@ def _make_mf_spmd(
             new_user = _local_push_aggregate(user_up, user_l, uk, g_u, u_shard)
             new_item = _local_push_aggregate(item_up, item_l, ik, g_v, i_shard)
         else:
+            # MFBatch's key lists ascend behind slot 0: the push may say so
             new_user = _local_push(
                 user_up, user_l, lax.all_gather(uk, "data"),
-                lax.all_gather(g_u, "data"), u_shard,
+                lax.all_gather(g_u, "data"), u_shard, ascending=True,
             )
             new_item = _local_push(
                 item_up, item_l, lax.all_gather(ik, "data"),
-                lax.all_gather(g_v, "data"), i_shard,
+                lax.all_gather(g_v, "data"), i_shard, ascending=True,
             )
         return new_user, new_item, loss
 

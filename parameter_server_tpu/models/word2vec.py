@@ -136,6 +136,8 @@ def _make_w2v_local_micro(in_up, out_up, shard: int, push_mode: str):
             new_in = _local_push_aggregate(in_up, in_l, center, g_u, shard)
             new_out = _local_push_aggregate(out_up, out_l, out_ids, g_v, shard)
         else:
+            # word ids as the pairs come, repeats among them: no ascending
+            # promise to the scatter
             new_in = _local_push(
                 in_up, in_l, lax.all_gather(center, "data"),
                 lax.all_gather(g_u, "data"), shard,

@@ -41,6 +41,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from parameter_server_tpu.data.batch import CSRBatch
 from parameter_server_tpu.kv.updaters import Updater
 from parameter_server_tpu.ops.sparse import csr_grad, csr_logits, logistic_loss
+from parameter_server_tpu.utils.hashing import PAD_KEY
 
 State = dict[str, jax.Array]
 Batch = dict[str, jax.Array]
@@ -50,7 +51,7 @@ Batch = dict[str, jax.Array]
 # ``step.*_ms`` readers and PERF.md find device time by these names (through
 # ``op_scopes``), so whatever replaces the code underneath keeps them.
 # Inside "ps.push" three nested scopes: "gather" (rows read for the
-# updater), "update" (``updater.delta``), "scatter" (the ``.at[].add``).
+# updater), "update" (``updater.delta``), "scatter" (the scatter-add).
 # An app of several tables names the table innermost ("ps.pull/emb",
 # "ps.push/scatter/emb"), so a reader of "ps.pull" sums over tables; its
 # dense group's forward and backward lie under "ps.grad/<group>" and the
@@ -488,6 +489,40 @@ def _local_pull(
         return jnp.where(in_range[:, None], w, 0.0)
 
 
+def _ascending_rows(idx: jax.Array, local: jax.Array) -> jax.Array:
+    """The rows a push of ascending keys scatters to, non-decreasing on
+    every kv shard: a key's row in this shard's frame (``local``, monotone
+    in the key, out of ``[0, shard_size)`` for another shard's), and the
+    tail's pads (``PAD_KEY`` behind slot 0) at the dtype's maximum, behind
+    every real row: on the first shard of a 2^31-row table real locals run
+    to 2^31 - 1, so ``shard_size`` would not be far enough."""
+    slot = lax.iota(jnp.int32, idx.shape[0])
+    pad = (slot > 0) & (idx == PAD_KEY)
+    return jnp.where(pad, jnp.iinfo(local.dtype).max, local)
+
+
+def _add_rows(
+    table: jax.Array, rows: jax.Array, deltas: jax.Array, ascending: bool
+) -> jax.Array:
+    """``table[rows] += deltas``, rows outside the table dropped.
+    ``ascending`` tells XLA that ``rows`` is non-decreasing: at ``vdim`` 1
+    it then leaves the 33-tile emitter that serialises on every slot
+    (PERF.md section 6, PR 27). ``lax.scatter_add`` and not
+    ``.at[].add(mode="drop")``, which wraps a negative row (an earlier
+    shard's key) onto a valid one first. A false promise is undefined
+    behaviour on the chip and invisible on the CPU, which ignores the
+    hint."""
+    dnums = lax.ScatterDimensionNumbers(
+        update_window_dims=(1,),
+        inserted_window_dims=(0,),
+        scatter_dims_to_operand_dims=(0,),
+    )
+    return lax.scatter_add(
+        table, rows[:, None], deltas, dnums, indices_are_sorted=ascending,
+        mode=lax.GatherScatterMode.FILL_OR_DROP,
+    )
+
+
 def _local_push(
     updater: Updater,
     state_l: State,
@@ -495,9 +530,19 @@ def _local_push(
     all_grad: jax.Array,  # (D, U, vdim)
     shard_size: int,
     table: str = "",
+    ascending: bool = False,
 ) -> State:
     """Apply every worker's push to this kv shard, sequentially (ref: the
-    server processes each worker's Push message as its own updater step)."""
+    server processes each worker's Push message as its own updater step).
+
+    ``ascending``: the caller's promise, set by code that knows it and
+    never by configuration, that each worker's ids obey the batch contract
+    of ``data.batch`` (slot 0 ``PAD_KEY``, then strictly ascending keys,
+    then ``PAD_KEY`` to the end). The scatter then sends the tail's pads
+    past the table and tells XLA that its rows ascend. Promised or not,
+    a row that is not this shard's is dropped, not added as a zero to
+    row 0. The gathers keep the clamped index vector they share with
+    ``_local_pull``: XLA merges the two on one chip."""
     begin = lax.axis_index("kv") * shard_size
 
     def body(state_l: State, push: tuple[jax.Array, jax.Array]):
@@ -510,9 +555,10 @@ def _local_push(
         with jax.named_scope("update"), _sub_scope(table):
             deltas = updater.delta(rows, g)
         with jax.named_scope("scatter"), _sub_scope(table):
-            mask = in_range[:, None].astype(g.dtype)
+            to = _ascending_rows(idx, local) if ascending else local
             new = {
-                k: state_l[k].at[safe].add(mask * deltas[k]) for k in state_l
+                k: _add_rows(state_l[k], to, deltas[k], ascending)
+                for k in state_l
             }
         return new, None
 
@@ -574,6 +620,7 @@ def _local_push_quantized(
     push_seed: jax.Array,  # scalar int32, varies per step
     stream: int = 0,  # static sub-stream tag (multi-table apps: one per table)
     table: str = "",
+    ascending: bool = False,  # forwarded to ``_local_push``
 ) -> State:
     """Per-worker push with int8-quantized gradients on the wire (the
     reference's fixing_float filter re-expressed as a quantized
@@ -602,7 +649,9 @@ def _local_push_quantized(
     all_q = lax.all_gather(q, "data")  # (D, U, vdim) int8
     all_scale = lax.all_gather(scale, "data")  # (D,)
     all_grad = all_q.astype(grad.dtype) * all_scale[:, None, None]
-    return _local_push(updater, state_l, all_idx, all_grad, shard_size, table)
+    return _local_push(
+        updater, state_l, all_idx, all_grad, shard_size, table, ascending
+    )
 
 
 PUSH_MODES = ("per_worker", "aggregate", "quantized")
@@ -692,6 +741,8 @@ def _microstep(
     table's gradient through its updater, step the dense group. Shared
     verbatim by the single-step and scanned multi-step programs and by
     every app, so the wire semantics cannot diverge between them."""
+    # the batch contract of ``data.batch``: PAD_KEY, ascending keys, PAD_KEY
+    # to the end; the pushes below promise it to ``_local_push``
     idx = b["unique_keys"]
     dense = app.dense.unpack(state_l) if app.dense is not None else (None, None)
     with jax.named_scope("ps.row_ids"):
@@ -721,13 +772,15 @@ def _microstep(
                 new = _local_push_quantized(
                     t.updater, tab, idx, g, shard_size, push_seed,
                     stream=i + 1 if len(app.tables) > 1 else 0, table=t.name,
+                    ascending=True,
                 )
             else:
                 # Push: every data shard's (keys, grads) reach every kv shard.
                 all_idx = lax.all_gather(idx, "data")  # (D, U)
                 all_grad = lax.all_gather(g, "data")  # (D, U, vdim)
                 new = _local_push(
-                    t.updater, tab, all_idx, all_grad, shard_size, t.name
+                    t.updater, tab, all_idx, all_grad, shard_size, t.name,
+                    ascending=True,
                 )
             new_state.update({t.key(k): v for k, v in new.items()})
     loss_sum = lax.psum(loss, "data")
@@ -758,6 +811,15 @@ def make_spmd_train_step(
       "examples" — scalar pod-wide real-example count (the host-side
           termination signal; see PodTrainer's drained contract)
       "probs"    — (D, B) per-shard probabilities
+
+    ``batch["unique_keys"]`` obeys the padding contract of ``data.batch``
+    on every shard: slot 0 ``PAD_KEY``, then strictly ascending keys, then
+    ``PAD_KEY`` to the end. The push tells XLA that its rows ascend
+    (``_local_push``), so a key list in any other order is undefined
+    behaviour on the chip, and the CPU does not show it. ``BatchBuilder``
+    and every path that grows or stacks its batches keep the contract
+    (tests/test_push_rows.py); ``CSRBatch.keys_in_order`` checks a batch
+    built some other way.
 
     push_mode, for every table of the app:
       "per_worker" — faithful reference semantics: each data shard's push is
@@ -791,7 +853,8 @@ def make_spmd_train_multistep(
     not a K-times-larger batch.
 
     batch fields are stacked (D, K, ...): data shard leading (sharded),
-    microstep second (scanned). step(state, batch, push_seed) ->
+    microstep second (scanned); every ``unique_keys[d, k]`` under the key
+    order ``make_spmd_train_step`` states. step(state, batch, push_seed) ->
     (state, out) with out keys:
       "loss_sum" — (K,) per-microstep pod-wide loss sums
       "examples" — (K,) per-microstep pod-wide real-example counts (the

@@ -497,6 +497,9 @@ class PodTrainer:
         powers of two, so group shapes stay a small compiled set)."""
         from parameter_server_tpu.data.batch import pad_group
 
+        # the step's push tells XLA its rows ascend (spmd._local_push): on
+        # the chip a batch out of order is undefined behaviour, not an error
+        assert all(b.keys_in_order() for b in batches), "unique_keys out of order"
         stacked = stack_batches(
             pad_group(batches), None,
             compact=self.cfg.data.compact_wire,

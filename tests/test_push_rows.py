@@ -1,0 +1,154 @@
+"""The promise ``_microstep`` makes to ``_local_push`` (``ascending=True``,
+so the table scatter carries ``indices_are_sorted``): whatever the feed
+hands the step, the rows the scatter receives are non-decreasing on every
+kv shard, a shard's own keys land on their rows, and every pad and every
+other shard's key lies outside ``[0, shard_size)`` and is dropped. On the
+CPU the hint is ignored, so a false promise would not show in any number
+here; on the chip it is undefined behaviour. Hence this test of the batch
+contract itself (``data/batch.py``: slot 0 ``PAD_KEY``, then strictly
+ascending keys, then ``PAD_KEY`` to the end)."""
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from parameter_server_tpu.data.batch import (
+    BatchBuilder,
+    inert_like,
+    pad_batch,
+    pad_group,
+)
+from parameter_server_tpu.parallel import spmd
+from parameter_server_tpu.utils.hashing import PAD_KEY
+
+BATCH, F = 16, 6
+# (key_mode, num_keys): hashed and identity keys; int32 on the host and, at
+# 2^31 rows (the 2x2 cell's table: only key vectors are made here), int64
+KEY_SPACES = [("hash", 4096), ("identity", 4096), ("hash", 100_000), ("hash", 1 << 31)]
+
+
+def build(key_mode, num_keys, bucket_nnz, n_examples=BATCH, seed=0):
+    rng = np.random.default_rng(seed)
+    top = num_keys - 2 if key_mode == "identity" else 1 << 40
+    keys = [rng.integers(0, top, F).astype(np.uint64) for _ in range(n_examples)]
+    if key_mode == "identity" and n_examples:
+        keys[0][:2] = (0, top - 1)  # the first and the last real row of the table
+    vals = [np.ones(F, np.float32)] * n_examples
+    builder = BatchBuilder(
+        num_keys=num_keys, batch_size=BATCH, max_nnz_per_example=256,
+        key_mode=key_mode, bucket_nnz=bucket_nnz,
+    )
+    return builder.build(rng.integers(0, 2, n_examples).astype(np.float32), keys, vals)
+
+
+def key_vectors(source, key_mode, num_keys):
+    """(U,) ``unique_keys`` vectors as ``source`` hands them to the step."""
+    b = build(key_mode, num_keys, bucket_nnz=True)
+    big = build(key_mode, num_keys, bucket_nnz=False, seed=1)  # the static worst-case shape
+    if source == "builder":
+        out = [big.unique_keys]
+    elif source == "bucketed":
+        out = [b.unique_keys, build(key_mode, num_keys, True, n_examples=0).unique_keys]
+    elif source == "inert_like":
+        out = [inert_like(b).unique_keys, inert_like(big).unique_keys]
+    elif source == "pad_batch":
+        out = [pad_batch(b, len(b.values) * 2, len(b.unique_keys) * 2 + 3).unique_keys]
+    elif source == "pad_group":
+        out = [g.unique_keys for g in pad_group([b, big, inert_like(b)])]
+    elif source == "stack_batches":
+        stacked = spmd.stack_batches(pad_group([b, big]), compact=True)["unique_keys"]
+        out = list(stacked)
+    elif source == "stack_step_groups":
+        small = spmd.stack_batches([b, inert_like(b)], compact=True)
+        large = spmd.stack_batches([big, big], compact=True)
+        grown = spmd.stack_step_groups([small, large, small])["unique_keys"]  # (D, K, U)
+        out = list(grown.reshape(-1, grown.shape[-1]))
+    else:
+        raise AssertionError(source)
+    want_dtype = np.int64 if num_keys > np.iinfo(np.int32).max else np.int32
+    assert all(v.dtype == want_dtype for v in out), [v.dtype for v in out]
+    return out
+
+
+SOURCES = [
+    "builder", "bucketed", "inert_like", "pad_batch", "pad_group", "stack_batches",
+    "stack_step_groups",
+]
+
+
+# a 2^31-row shard is past what int32 keys address (the step does not trace
+# there: ``local < shard_size`` overflows), so that table starts at kv 2
+SPACES_AND_SHARDS = [
+    (mode, n, kv) for mode, n in KEY_SPACES for kv in (1, 2, 4) if n // kv < 1 << 31
+]
+
+
+@pytest.mark.parametrize("key_mode,num_keys,kv", SPACES_AND_SHARDS)
+@pytest.mark.parametrize("source", SOURCES)
+def test_scatter_rows_ascend_and_foreign_keys_fall_outside(source, key_mode, num_keys, kv):
+    shard = spmd._shard_size(num_keys, kv)
+    for keys in key_vectors(source, key_mode, num_keys):
+        assert keys[0] == PAD_KEY
+        real = keys != PAD_KEY
+        n_real = int(real.sum())
+        # the contract of data/batch.py, of which the rest follows
+        assert real[1 : 1 + n_real].all() and not real[1 + n_real :].any()
+        assert (np.diff(keys[1 : 1 + n_real].astype(np.int64)) > 0).all()
+        idx = jnp.asarray(keys.astype(np.int32))  # what the device holds of either host dtype
+        for shard_index in {0, kv - 1}:
+            begin = shard_index * shard
+            rows = np.asarray(spmd._ascending_rows(idx, idx - begin)).astype(np.int64)
+            assert (np.diff(rows) >= 0).all(), (source, shard_index)
+            local = keys.astype(np.int64) - begin
+            mine = real & (local >= 0) & (local < shard)
+            np.testing.assert_array_equal(rows[mine], local[mine])
+            dropped = ~mine
+            dropped[0] = False  # slot 0 is row 0 of the first shard, before every other
+            assert ((rows[dropped] < 0) | (rows[dropped] >= shard)).all()
+            assert rows[0] == -begin
+
+
+@pytest.mark.parametrize("key_mode,num_keys", KEY_SPACES)
+def test_keys_in_order_accepts_the_feed_and_refuses_any_other_order(key_mode, num_keys):
+    """``CSRBatch.keys_in_order``: the check for a batch built some other
+    way, which ``PodTrainer._prepare`` asserts before it stacks."""
+    import dataclasses
+
+    b = build(key_mode, num_keys, bucket_nnz=True)
+    grown = pad_batch(b, len(b.values) * 2, len(b.unique_keys) * 2 + 3)
+    for ok in (b, grown, inert_like(b), build(key_mode, num_keys, False, n_examples=0)):
+        assert ok.keys_in_order()
+    keys, n = b.unique_keys, b.num_unique
+    assert n > 3
+
+    def with_keys(edit):
+        out = keys.copy()
+        edit(out)
+        return dataclasses.replace(b, unique_keys=out)
+
+    def swap(k):
+        k[1], k[2] = k[2], k[1]
+
+    def repeat(k):
+        k[2] = k[1]
+
+    def pad_inside(k):
+        k[1] = PAD_KEY
+
+    def key_in_tail(k):
+        k[-1] = k[n - 1]
+
+    def no_pad_slot(k):
+        k[0] = 1
+
+    for edit in (swap, repeat, pad_inside, key_in_tail, no_pad_slot):
+        assert not with_keys(edit).keys_in_order(), edit.__name__
+
+
+def test_trainer_refuses_a_batch_out_of_order():
+    from parameter_server_tpu.parallel.trainer import PodTrainer
+
+    b = build("hash", 4096, bucket_nnz=True)
+    b.unique_keys[[1, 2]] = b.unique_keys[[2, 1]]
+    with pytest.raises(AssertionError, match="out of order"):
+        PodTrainer._prepare(None, [b])

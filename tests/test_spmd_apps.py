@@ -300,3 +300,76 @@ class TestWideDeepQuantizedFromConfig:
         app.train(batches, report_every=10**6)
         assert app.push_mode == "quantized"
         assert app.trainer.calls_trained == len(batches) // (2 * 2)
+
+
+class TestOneWorkerMeshStepAgainstSingleDevice:
+    """The embedding apps call ``_local_push`` with id lists of their own.
+    With one worker the mesh step is the single-device step (which pushes
+    through plain ``.at[].add``), whatever the kv sharding: MF's key lists
+    are ``np.unique`` output behind the pad slot, so its push promises
+    ascending rows; SGNS pushes word ids as the pairs come, repeats and
+    all, and promises nothing: the same scatter, told nothing of the order
+    (another shard's rows dropped there too, not added as zeros to row 0)."""
+
+    @pytest.mark.parametrize("mesh_shape", [(1, 1), (1, 2), (1, 4)])
+    def test_mf(self, mesh_shape):
+        from parameter_server_tpu.models.matrix_fac import batch_to_device, mf_train_step
+
+        mesh = make_mesh(*mesh_shape)
+        n_u, n_i = 96, 64  # rows: a kv shard's edge falls inside the ids used
+        rng = np.random.default_rng(3)
+        us, it = rng.integers(0, n_u - 1, 400), rng.integers(0, n_i - 1, 400)
+        us[:2], it[:2] = (0, n_u - 2), (0, n_i - 2)  # the first and the last real row
+        r = rng.normal(size=400).astype(np.float32)
+        app = MatrixFactorization(n_u - 1, n_i - 1, rank=8, eta=0.1, l2=0.002, reporter=quiet())
+        step = make_mf_spmd_train_step(app.user_up, app.item_up, mesh, n_u, n_i, l2=0.002)
+        b = MFBatchBuilder(batch_size=512).build(us, it, r)  # 112 pad slots behind the keys
+        start = [{k: np.asarray(v) for k, v in s.items()} for s in (app.user_state, app.item_state)]
+        user, item, loss = step(
+            shard_state(app.user_state, mesh), shard_state(app.item_state, mesh),
+            stack_mf_batches([b], mesh),
+        )
+        want_user, want_item, want_loss = mf_train_step(
+            app.user_up, app.item_up, *[{k: jax.numpy.asarray(v) for k, v in s.items()} for s in start],
+            batch_to_device(b), 0.002,
+        )
+        np.testing.assert_allclose(float(loss), float(want_loss), rtol=1e-6)
+        for got, want in ((user, want_user), (item, want_item)):
+            for k in want:
+                # row 0 is the pad row: the tail's pads no longer add their L2 term to it
+                np.testing.assert_allclose(
+                    np.asarray(got[k])[1:], np.asarray(want[k])[1:], rtol=1e-6, atol=1e-7, err_msg=k
+                )
+
+    @pytest.mark.parametrize("mesh_shape", [(1, 1), (1, 2), (1, 4)])
+    def test_sgns(self, mesh_shape):
+        from parameter_server_tpu.kv.updaters import Adagrad
+        from parameter_server_tpu.models.word2vec import make_w2v_spmd_train_step, sgns_train_step
+
+        mesh = make_mesh(*mesh_shape)
+        vocab, dim, B, K = 16, 8, 64, 3
+        rng = np.random.default_rng(4)
+        up = Adagrad(eta=0.5)
+        batch = {
+            "center": rng.integers(0, vocab, B).astype(np.int32),  # unsorted, with repeats
+            "context": rng.integers(0, vocab, B).astype(np.int32),
+            "negatives": rng.integers(0, vocab, (B, K)).astype(np.int32),
+            "mask": (rng.random(B) < 0.9).astype(np.float32),
+        }
+        start = {
+            "w": (rng.normal(size=(vocab, dim)) * 0.1).astype(np.float32),
+            "n": rng.random(size=(vocab, dim)).astype(np.float32),
+        }
+        step = make_w2v_spmd_train_step(up, up, mesh, vocab)
+        got_in, got_out, loss = step(
+            shard_state(start, mesh), shard_state(start, mesh),
+            {k: jax.numpy.asarray(v[None]) for k, v in batch.items()},
+        )
+        as_jax = lambda s: {k: jax.numpy.asarray(v) for k, v in s.items()}  # noqa: E731
+        want_in, want_out, want_loss = sgns_train_step(
+            up, up, as_jax(start), as_jax(start), {k: jax.numpy.asarray(v) for k, v in batch.items()}
+        )
+        np.testing.assert_allclose(float(loss), float(want_loss), rtol=1e-6)
+        for got, want in ((got_in, want_in), (got_out, want_out)):
+            for k in want:
+                np.testing.assert_allclose(np.asarray(got[k]), np.asarray(want[k]), rtol=2e-6, atol=1e-7, err_msg=k)

@@ -82,17 +82,18 @@ def compiled_text(topo):
             c.cell_contents for c in fn.__closure__
             if callable(c.cell_contents) and hasattr(c.cell_contents, "lower")
         ]
-        texts[key] = jitted.lower(*args).compile().as_text()
+        compiled = jitted.lower(*args).compile()
+        texts[key] = compiled.as_text()
+        texts[key, "memory"] = compiled.memory_analysis()
         return texts[key]
 
+    get.texts = texts
     return get
 
 
-def executed(text: str) -> list:
-    """(name, result shape, opcode, operand shapes) of every instruction a
-    profile would show: those outside fused and applied computations, less
-    the opcodes that run nothing of their own."""
-    inner = set(re.findall(r"(?:calls|to_apply)=%?([\w.\-]+)", text))
+def instructions(text: str) -> list:
+    """(computation, name, result shape, opcode, operand shapes, the rest of
+    the line) of every instruction of the module."""
     shape_of, rows, comp = {}, [], None
     for line in text.splitlines():
         head = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+) \(.*\) -> .*\{\s*$", line)
@@ -103,13 +104,23 @@ def executed(text: str) -> list:
         if m:
             shape_of[m.group(1)] = m.group(2)
             rows.append((comp, *m.groups()))
-    out = []
-    for comp, name, shape, opcode, rest in rows:
-        if comp in inner or opcode in _NOT_EXECUTED:
-            continue
-        operands = re.findall(r"%([\w.\-]+)", rest.split("), ")[0])
-        out.append((name, shape, opcode, [shape_of.get(o, "") for o in operands]))
-    return out
+    return [
+        (comp, name, shape, opcode,
+         [shape_of.get(o, "") for o in re.findall(r"%([\w.\-]+)", rest.split("), ")[0])], rest)
+        for comp, name, shape, opcode, rest in rows
+    ]
+
+
+def executed(text: str) -> list:
+    """(name, result shape, opcode, operand shapes) of every instruction a
+    profile would show: those outside fused and applied computations, less
+    the opcodes that run nothing of their own."""
+    inner = set(re.findall(r"(?:calls|to_apply)=%?([\w.\-]+)", text))
+    return [
+        (name, shape, opcode, operand_shapes)
+        for comp, name, shape, opcode, operand_shapes, _ in instructions(text)
+        if comp not in inner and opcode not in _NOT_EXECUTED
+    ]
 
 
 def elements(shape: str) -> int:
@@ -246,9 +257,8 @@ def wd_text(topo):
             if callable(c.cell_contents) and hasattr(c.cell_contents, "lower")
         ]
         compiled = jitted.lower(*args).compile()
-        mem = compiled.memory_analysis()
         texts[key] = compiled.as_text()
-        texts[key, "bytes"] = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        texts[key, "memory"] = compiled.memory_analysis()
         return texts[key]
 
     get.texts = texts
@@ -281,7 +291,8 @@ def test_wd_table_ops_are_scoped_by_table(wd_text, data, kv, program):
     # what the program and its state take of one chip: z + n + w + n unpadded
     # are 12.67 GiB of it at kv 1 (lane-padded to 128 they would be 96), and
     # no copy of a table is among the temporaries
-    assert wd_text.texts[(data, kv, program), "bytes"] < (13.5 if kv == 1 else 7.5) * 2**30
+    mem = wd_text.texts[(data, kv, program), "memory"]
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < (13.5 if kv == 1 else 7.5) * 2**30
 
 
 @pytest.mark.parametrize("data,kv,program", WD_CASES)
@@ -304,3 +315,53 @@ def test_wd_large_unscoped_instructions_are_the_known_kinds(wd_text, data, kv, p
         if opcode == "fusion" and not scopes[name]:
             # bookkeeping at batch size (a (U, 16) buffer at most), never a table op
             assert elements(shape) < 17 * UNIQUE and all(elements(s) < WD_ROWS // kv for s in operand_shapes), name
+
+
+# -- the push's scatter: told that its rows ascend (PERF.md section 6, PR 27) --
+PUSH_PROGRAMS = [("linear", 1, 1), ("linear", 2, 2), ("wd", 1, 1)]
+
+
+@pytest.mark.parametrize("app,data,kv", PUSH_PROGRAMS)
+def test_table_scatters_take_ascending_rows_in_place(compiled_text, wd_text, app, data, kv):
+    """Whether the push's mechanism engages is a property of the compiled
+    step: every scatter into a table carries ``indices_are_sorted=true``
+    (``_microstep``'s promise; at ``vdim`` 1 the chip's compiler then
+    leaves the emitter that serialises on every slot), its fusion sits
+    under ``ps.push/scatter`` and updates the table in place; nothing
+    table-sized is among the temporaries; and on one chip the push's
+    gathers are still merged with the pull's, two table gathers a
+    microstep and table, one index vector."""
+    from parameter_server_tpu.parallel import spmd
+
+    get, names = (compiled_text, frozenset()) if app == "linear" else (wd_text, WD_NAMES)
+    text = get(data, kv, "multistep")
+    mem = get.texts[(data, kv, "multistep"), "memory"]
+    _, scopes = spmd.hlo_scopes(text, names)
+    rows = ROWS_PER_CHIP if app == "linear" else spmd.padded_num_keys(WD_ROWS, kv) // kv
+    table = re.compile(rf"^f32\[(1,1,)?{rows}(,1|,16)?\]")
+    slots = 2 if app == "linear" else 4  # z, n; and emb's w, n
+    every = instructions(text)
+    scatters = [(comp, rest) for comp, _, shape, opcode, _, rest in every if opcode == "scatter" and table.match(shape)]
+    assert len(scatters) == slots, scatters
+    assert all("indices_are_sorted=true" in rest for _, rest in scatters), scatters
+    homes = {comp for comp, _ in scatters}
+    fusions = [
+        (name, rest) for _, name, shape, opcode, _, rest in every
+        if opcode == "fusion" and re.search(r"calls=%?([\w.\-]+)", rest).group(1) in homes
+    ]
+    assert len(fusions) == slots, fusions
+    for name, rest in fusions:
+        assert scopes[name].startswith("ps.push/scatter"), (name, scopes[name])
+        # operand 0, the table, is the result's buffer
+        assert re.search(r'"aliasing_operands":\{"lists":\[\{"indices":\["0"', rest), (name, rest[-300:])
+    table_bytes = sum(4 * rows * vdim for vdim in ((1, 1) if app == "linear" else (1, 1, 16, 16)))
+    assert mem.alias_size_in_bytes >= table_bytes  # the whole state is donated through the call
+    # the linear step's temporaries are batch-sized (15-18 MiB); W&D's hold its
+    # (NNZ, 16) activations too, and stay under its smallest table slot
+    assert mem.temp_size_in_bytes < (64 << 20 if app == "linear" else 4 * rows), mem.temp_size_in_bytes
+    if kv == 1:
+        gathers = [
+            operand_shapes[0] for _, _, _, opcode, operand_shapes, _ in every
+            if opcode == "gather" and table.match(operand_shapes[0])
+        ]
+        assert len(gathers) == slots, gathers

@@ -223,6 +223,92 @@ def test_store_rows_of_any_width_against_numpy(vdim):
     np.testing.assert_array_equal(np.asarray(out["n"]), n_want)
 
 
+PUSH_ROWS, PUSH_SLOTS = 4096, 12  # 4 / 2 / 1 whole 1024-row tiles a kv shard
+PUSH_CASES = ["shard_edges", "one_shard_owns_all", "all_pads", "same_keys_from_every_worker", "unsorted_no_promise"]
+
+
+def push_keys(case, workers):
+    """(workers, PUSH_SLOTS) key lists under the batch contract (slot 0 the
+    pad, ascending keys, pads to the end), but for the caller that makes
+    no promise."""
+    q = PUSH_ROWS // 4  # a kv shard's rows on 1x4; 2q on 2x2
+    lists = {
+        # this shard's last row and the next one's first, on kv 2 and on kv 4; the table's last
+        "shard_edges": [[1, q - 1, q, q + 1, 2 * q - 1, 2 * q, 3 * q - 1, 3 * q, PUSH_ROWS - 1],
+                        [2, q - 2, q, 2 * q - 1, 2 * q + 1, 3 * q, PUSH_ROWS - 2, PUSH_ROWS - 1]],
+        # the last shard owns every key: the others receive slot 0 or nothing
+        "one_shard_owns_all": [[3 * q, 3 * q + 1, 3 * q + 7, PUSH_ROWS - 1], [3 * q + 1, 3 * q + 2]],
+        "all_pads": [[], []],  # what data.batch.inert_like hands the step
+        "same_keys_from_every_worker": [[5, q, 2 * q + 3, PUSH_ROWS - 1]] * 2,
+        "unsorted_no_promise": [[9, 3, 3, PUSH_ROWS - 1, q, 7, 2 * q, q - 1], [q, 9, 9, 9, 1, 3 * q]],
+    }[case]
+    out = np.zeros((workers, PUSH_SLOTS), np.int32)
+    for w in range(workers):
+        real = lists[w % 2]
+        at = 0 if case == "unsorted_no_promise" else 1  # its ids are no batch's: no pad slot
+        out[w, at : at + len(real)] = real
+    return out
+
+
+@pytest.mark.parametrize("case", PUSH_CASES)
+@pytest.mark.parametrize("mesh_name", list(MESHES))
+@pytest.mark.parametrize("vdim", [1, 8, 16, 64])
+def test_mesh_push_of_any_width_against_numpy(vdim, mesh_name, case):
+    """``_local_push`` over the mesh, as ``_microstep`` calls it (every
+    worker's keys and gradients gathered, applied in worker order, the
+    scatter promised ascending rows), against NumPy: AdaGrad's ``n`` bit
+    for bit (each push applied once, to its row), ``w`` to an ulp (it
+    depends on the order of two workers' pushes of one key), every row
+    no key names untouched bit for bit. The caller that does not promise
+    (unsorted ids, repeats among them) goes through the same scatter, told
+    nothing about the order, and matches too."""
+    from jax import lax, shard_map
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from parameter_server_tpu.parallel import spmd
+
+    data, kv = MESHES[mesh_name]
+    mesh = make_mesh(data, kv)
+    shard = spmd._shard_size(PUSH_ROWS, kv)
+    rng = np.random.default_rng(vdim)
+    idx = push_keys(case, data)
+    grad = rng.normal(size=(data, PUSH_SLOTS, vdim)).astype(np.float32)
+    grad[idx == 0] = 0.0  # a pad's gradient is zero
+    ada = Adagrad(eta=0.05)
+    start = {
+        "w": rng.normal(size=(PUSH_ROWS, vdim)).astype(np.float32),
+        "n": rng.random(size=(PUSH_ROWS, vdim)).astype(np.float32),
+    }
+
+    def local(state_l, idx_l, grad_l):
+        return spmd._local_push(
+            ada, state_l, lax.all_gather(idx_l[0], "data"), lax.all_gather(grad_l[0], "data"),
+            shard, ascending=case != "unsorted_no_promise",
+        )
+
+    push = jax.jit(shard_map(
+        local, mesh=mesh, in_specs=(spmd.state_spec(), P("data"), P("data")),
+        out_specs=spmd.state_spec(), check_vma=False,
+    ))
+    state = {k: jax.device_put(v, NamedSharding(mesh, spmd.state_spec())) for k, v in start.items()}
+    got = push(state, jnp.asarray(idx), jnp.asarray(grad))
+
+    want = {k: v.copy() for k, v in start.items()}
+    for w in range(data):  # the server applies each worker's push as its own step
+        g = grad[w]
+        dn = g * g
+        dw = np.float32(-0.05) * g / (np.sqrt(want["n"][idx[w]] + dn) + np.float32(1e-8))
+        np.add.at(want["w"], idx[w], dw)
+        np.add.at(want["n"], idx[w], dn)
+    np.testing.assert_array_equal(np.asarray(got["n"]), want["n"])
+    # XLA's division is an ulp from NumPy's here and there; a push applied in
+    # the other order, twice, or to a neighbour's row is percents away
+    np.testing.assert_allclose(np.asarray(got["w"]), want["w"], rtol=1e-5, atol=1e-7)
+    untouched = np.ones(PUSH_ROWS, bool)
+    untouched[idx.ravel()] = False
+    np.testing.assert_array_equal(np.asarray(got["w"])[untouched], start["w"][untouched])
+
+
 @pytest.mark.parametrize("seed", [0, 7, 2**31 + 11])
 def test_device_made_embedding_is_the_reference_function(seed):
     rows = np.array([0, 1, 2, 63, 64, 999, 4095, 4096, 5000, 2**31 - 1], np.int64)

@@ -1,0 +1,134 @@
+"""The table ops of a push alone, on the chip (step 0 of ISSUE 27; PERF.md
+section 6, PR 27): one slot, host clock, ten calls back to back, three sets.
+Scatter-add of one real bucket's 524,289 slots (one 8192-example batch of
+the benchmark's Criteo-shaped traffic from the seed, keys as BatchBuilder
+writes them) into f32[2^30,1], f32[100000768,1] and f32[100000768,16], and
+into f32[2^30,1] as either kv shard of a 2^31-key table: the scatter the step
+had up to PR 26 (rows clamped to 0, deltas masked) against the one it has
+(``spmd._ascending_rows`` + ``spmd._add_rows``); and ``jnp.take`` as the step
+calls it against sorted rows with ``mode="fill"``, on the first and the third.
+Every form's result is checked on the chip against the deltas row by row,
+with the count of non-zero rows and the absolute sum. One JSON line a case,
+also appended to chiprun_out/probe_push_scatter.jsonl.
+
+    chiprun --timeout 1500 -- python3 tools/probe_push_scatter.py [SEED] [--rest]
+"""
+import json, os, sys, time
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+import numpy as np
+import jax, jax.numpy as jnp
+from jax import lax
+from benchmark.harness import criteo
+from parameter_server_tpu.parallel import spmd
+
+SEED = int(sys.argv[1]) if len(sys.argv) > 1 else 2270000001
+U = (1 << 19) + 1
+print("device", jax.devices()[0].platform, jax.devices()[0].device_kind, flush=True)
+assert jax.devices()[0].platform == "tpu"
+spec = json.load(open("benchmark/configs/ctr_ftrl_1chip.json"))["data"]
+
+
+def bucket_keys(num_keys):
+    """unique_keys of one 8192-example batch as BatchBuilder writes them."""
+    _, ints, cats = criteo.make_examples(SEED, 8192, spec)
+    rows, _ = criteo.features(ints, cats, num_keys)
+    uniq = np.unique(rows.ravel())
+    keys = np.zeros(U, np.int32)
+    keys[1 : 1 + len(uniq)] = uniq
+    return keys, 1 + len(uniq)
+
+
+def today(table, idx, d, begin, shard):  # the push's scatter up to PR 26
+    local = idx - begin
+    in_range = (local >= 0) & (local < shard)
+    safe = jnp.where(in_range, local, 0)
+    return table.at[safe].add(in_range[:, None].astype(d.dtype) * d)
+
+
+def ascending(table, idx, d, begin, shard):
+    local = idx - begin
+    return spmd._add_rows(table, spmd._ascending_rows(idx, local), d, True)
+
+
+def take_today(table, idx, begin, shard):
+    local = idx - begin
+    in_range = (local >= 0) & (local < shard)
+    return jnp.take(table, jnp.where(in_range, local, 0), axis=0)
+
+
+def take_sorted(table, idx, begin, shard):
+    local = idx - begin
+    rows = spmd._ascending_rows(idx, local)
+    return jnp.take(table, rows, axis=0, indices_are_sorted=True, mode="fill", fill_value=0)
+
+
+def timed(fn, first, rest, n=10, reps=3, chain=True):
+    """ms a call: n calls back to back, the best-of-reps mean (steady)."""
+    out = []
+    x = first
+    x = fn(x, *rest)  # compile + warm
+    jax.block_until_ready(x)
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            y = fn(x if chain else first, *rest)
+            if chain:
+                x = y
+        jax.block_until_ready(y)
+        out.append((time.perf_counter() - t0) / n * 1e3)
+    return out, (x if chain else first)
+
+
+def case(rows, vdim, num_keys, begin, label, gathers):
+    keys, n_uniq = bucket_keys(num_keys)
+    rng = np.random.default_rng(SEED)
+    d = rng.standard_normal((U, vdim)).astype(np.float32)
+    d[n_uniq:] = 0.0  # a pad's gradient is 0 and so is its delta
+    d[0] = 0.0
+    local = keys.astype(np.int64) - begin
+    mine = (local >= 0) & (local < rows)
+    mine[n_uniq:] = False
+    mine[0] = begin == 0
+    idx, dd = jnp.asarray(keys), jnp.asarray(d)
+    res = {"case": label, "rows": rows, "vdim": vdim, "begin": begin, "real_slots": int(n_uniq),
+           "slots_in_range": int(mine.sum()), "seed": SEED}
+    for name, form in (("today", today), ("ascending", ascending)):
+        f = jax.jit(lambda t, i, x, form=form: form(t, i, x, begin, rows), donate_argnums=0)
+        table = jnp.zeros((rows, vdim), jnp.float32)
+        # correctness on the chip: from zeros one add; every in-range real row == its delta, the rest untouched
+        table = f(table, idx, dd)
+        got = np.asarray(jnp.take(table, jnp.asarray(np.where(mine, local, 0).astype(np.int32)), axis=0))
+        ok_rows = bool(np.array_equal(got[mine], d[mine]))
+        total = float(jax.jit(lambda t: jnp.sum(jnp.abs(t), dtype=jnp.float32))(table))
+        want_total = float(np.abs(d[mine]).astype(np.float64).sum())
+        nz = int(jax.jit(lambda t: jnp.sum((t != 0).astype(jnp.int32)))(table))
+        want_nz = int(np.count_nonzero(d[mine]))
+        res[f"{name}_rows_equal"] = ok_rows
+        res[f"{name}_nonzero"] = [nz, want_nz]
+        res[f"{name}_abs_sum"] = [total, want_total]
+        ms, table = timed(f, table, (idx, dd))
+        res[f"scatter_{name}_ms"] = ms
+        if gathers:
+            for gname, gform in (("today", take_today), ("sorted", take_sorted)):
+                if name != "today":
+                    break
+                g = jax.jit(lambda t, i, gform=gform: gform(t, i, begin, rows))
+                gms, _ = timed(g, table, (idx,), chain=False)
+                res[f"gather_{gname}_ms"] = gms
+                a = np.asarray(jax.jit(lambda t, i: take_today(t, i, begin, rows))(table, idx))
+                b = np.asarray(g(table, idx))
+                res[f"gather_{gname}_equal_on_mine"] = bool(np.array_equal(a[mine], b[mine]))
+        del table
+    print(json.dumps(res), flush=True)
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/probe_push_scatter.jsonl", "a") as fh:
+        fh.write(json.dumps(res) + "\n")
+
+
+if "--rest" not in sys.argv:
+    case(1 << 30, 1, 1 << 30, 0, "f32[2^30,1]", True)
+    case(100_000_768, 1, 100_000_000, 0, "f32[100000768,1]", False)
+case(100_000_768, 16, 100_000_000, 0, "f32[100000768,16]", True)
+# what a kv shard of ctr2x2 sees: 2^31 keys, this shard's rows [2^30, 2^31) and then [0, 2^30)
+case(1 << 30, 1, 1 << 31, 1 << 30, "f32[2^30,1] as kv shard 1 of 2", False)
+case(1 << 30, 1, 1 << 31, 0, "f32[2^30,1] as kv shard 0 of 2", False)
