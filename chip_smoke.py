@@ -10,14 +10,12 @@ order, each timed, the first failure raising:
   1. train    the flagship at full width: sparse LR + FTRL over a
               2^27-key table (z+n f32 = 1 GiB), synthetic libsvm files ->
               native parse -> BatchBuilder -> PrefetchPipeline ->
-              PodTrainer scanned multistep -> retire -> AUC, configured as
-              bench.py:child_scale, then evaluate_files on a held-out file
+              PodTrainer scanned multistep -> retire -> AUC, then
+              evaluate_files on a held-out file
   2. server   one ShardServer holding its table on the chip answers
               push/pull over real TCP from a CPU-pinned ServerHandle
               child; pulled rows must equal a NumPy FTRL of the same pushes
-  3. kernels  each Pallas kernel compiled by Mosaic and checked against
-              its XLA reference at the shapes bench.py names
-  4. mesh     (only with >= 4 chips) the phase-1 run through
+  3. mesh     (only with >= 4 chips) the phase-1 run through
               cli.main(["train", ...]) on (data, kv) = (1, 4) and (2, 2),
               push_mode per_worker and aggregate, and
               cli.main(["backend", ...]) on MeshBackend kv = 4: shards on
@@ -116,7 +114,7 @@ def write_files(workdir: str) -> tuple[list[str], str]:
 
 def flagship_cfg(files: list[str], test: str, data_shards: int = 1,
                  kv_shards: int = 1, push_mode: str = "per_worker"):
-    """bench.py:child_scale's configuration at NUM_KEYS."""
+    """The flagship's configuration at NUM_KEYS."""
     from parameter_server_tpu.utils.config import PSConfig
 
     cfg = PSConfig()
@@ -125,7 +123,6 @@ def flagship_cfg(files: list[str], test: str, data_shards: int = 1,
     cfg.data.num_keys = NUM_KEYS
     cfg.data.pipeline_depth = 2
     cfg.data.bucket_nnz = True
-    cfg.data.compact_wire = True
     cfg.data.max_nnz_per_example = 4 * NNZ_PER
     cfg.solver.minibatch = MINIBATCH
     cfg.solver.steps_per_call = STEPS_PER_CALL
@@ -244,8 +241,8 @@ h.close()
 
 
 def numpy_ftrl(n_keys: int, pushes: list[tuple[np.ndarray, np.ndarray]]):
-    """Plain float32 FTRL over the same pushes (the math of
-    bench.py:bench_numpy_baseline); yields weights of each push's keys."""
+    """Plain float32 FTRL over the same pushes; yields weights of each
+    push's keys."""
     z = np.zeros(n_keys, np.float32)
     n = np.zeros(n_keys, np.float32)
 
@@ -338,76 +335,7 @@ def phase_server(workdir: str) -> None:
 
 
 # ---------------------------------------------------------------------------
-# phase 3: Pallas kernels, compiled by Mosaic
-# ---------------------------------------------------------------------------
-
-
-def phase_kernels() -> None:
-    import jax.numpy as jnp
-
-    from parameter_server_tpu.filters.fixed_point import FixedPointCodec
-    from parameter_server_tpu.kv.updaters import Ftrl
-    from parameter_server_tpu.ops.pallas_kernels import (
-        ftrl_delta_pallas,
-        quantize_stochastic_pallas,
-    )
-
-    rng = np.random.default_rng(3)
-    rows_n = 1 << 20
-    rows = {
-        "z": jnp.asarray(rng.normal(size=(rows_n, 1)).astype(np.float32)),
-        "n": jnp.asarray(np.abs(rng.normal(size=(rows_n, 1))).astype(np.float32)),
-    }
-    g = jnp.asarray(rng.normal(size=(rows_n, 1)).astype(np.float32))
-    kw = dict(alpha=ALPHA, beta=BETA, lambda_l1=L1, lambda_l2=L2)
-    hlo = ftrl_delta_pallas.lower(
-        rows["z"], rows["n"], g, alpha=ALPHA, beta=BETA, l1=L1, l2=L2
-    ).as_text()
-    if "tpu_custom_call" not in hlo:
-        raise AssertionError("ftrl_delta_pallas did not lower to a Mosaic call")
-    ref = Ftrl(**kw).delta(rows, g)
-    got = Ftrl(**kw, use_pallas=True).delta(rows, g)
-    for k in ("z", "n"):
-        np.testing.assert_allclose(
-            np.asarray(got[k]), np.asarray(ref[k]), atol=1e-6,
-            err_msg=f"ftrl_delta_pallas d{k} vs the jnp delta",
-        )
-    print(f"[smoke] ftrl_delta_pallas {rows_n}x1: Mosaic, matches jnp", flush=True)
-
-    x = jnp.asarray(4.0 * rng.normal(size=(1 << 24,)).astype(np.float32))
-    for num_bytes in (1, 2):
-        hlo = quantize_stochastic_pallas.lower(3, x, num_bytes=num_bytes).as_text()
-        if "tpu_custom_call" not in hlo:
-            raise AssertionError("quantize_stochastic_pallas did not lower to Mosaic")
-        codec = FixedPointCodec(num_bytes=num_bytes)
-        enc = codec.encode_fast(3, x)
-        err = codec.decode(enc) - x
-        scale = float(enc.scale)
-        worst = float(jnp.max(jnp.abs(err)))
-        mean = float(jnp.mean(err))
-        # one scale step, plus the f32 rounding of (q + zero) * scale + lo
-        slack = 16 * np.finfo(np.float32).eps * float(jnp.max(jnp.abs(x)))
-        if enc.q.dtype != (jnp.int8 if num_bytes == 1 else jnp.int16):
-            raise AssertionError(f"payload dtype {enc.q.dtype}")
-        if worst > scale + slack:
-            raise AssertionError(
-                f"int{8 * num_bytes} decode error {worst} > one scale step {scale}"
-            )
-        if abs(mean) > 0.01 * scale:
-            raise AssertionError(
-                f"int{8 * num_bytes} rounding is biased: mean error {mean}, "
-                f"scale {scale}"
-            )
-        print(
-            f"[smoke] quantize_stochastic_pallas int{8 * num_bytes} over "
-            f"{x.size} elements: Mosaic, max error {worst / scale:.4f} scale "
-            f"steps, mean {mean / scale:+.5f}",
-            flush=True,
-        )
-
-
-# ---------------------------------------------------------------------------
-# phase 4: four chips, through the CLI
+# phase 3: four chips, through the CLI
 # ---------------------------------------------------------------------------
 
 
@@ -551,7 +479,6 @@ def main() -> int:
         files, test = phases.run("write_files", write_files, workdir)
         losses = phases.run("train", phase_train, files, test)
         phases.run("server", phase_server, workdir)
-        phases.run("kernels", phase_kernels)
         if len(devices) >= 4:
             phases.run("mesh", phase_mesh, workdir, files, test, losses)
         else:

@@ -370,7 +370,7 @@ def _build_parser() -> argparse.ArgumentParser:
         "live `audit --once` and an offline `whylate --baseline` "
         "budget gate, and fold their verdicts into ONE tiered exit "
         "code (0 clean, 2 soft/over-budget only, 1 any hard failure) "
-        "— the single command CI and the bench workflow call",
+        "— the single command CI calls",
     )
     vf.add_argument(
         "--lint-baseline", default="", metavar="FILE",
@@ -839,9 +839,9 @@ def run_evaluate(cfg: PSConfig, args: argparse.Namespace) -> dict:
 def run_backend(cfg: PSConfig, args: argparse.Namespace) -> dict:
     """One synthetic linear workload through the configured PSBackend
     (the ``[mesh]`` section picks the transport): the canonical
-    ``train_linear`` loop that the backend-parity tests and the bench's
-    ``backend`` cell also drive — so what this command measures is the
-    production client path, not a demo fork of it."""
+    ``train_linear`` loop that the backend-parity tests also drive — so
+    what this command measures is the production client path, not a demo
+    fork of it."""
     import time
 
     import numpy as np
@@ -1126,8 +1126,7 @@ def run_verify(args: argparse.Namespace) -> int:
     1 when ANY stage failed hard (lint findings, model-checker
     violation, audit violations, whylate hard regression), else 2 when
     any stage was merely over budget (the whylate/pslint soft tier),
-    else 0. One command, one exit code: what CI and the bench README
-    workflow gate on."""
+    else 0. One command, one exit code: what CI gates on."""
     from parameter_server_tpu.analysis.__main__ import (
         check_main,
         main as lint_main,

@@ -33,9 +33,9 @@ class CSRBatch:
 
     ``unique_keys`` is int32 whenever num_keys fits (practically always)
     and ``row_splits`` carries the same row structure as ``row_ids`` in
-    B+1 ints instead of NNZ — together the compact wire format
-    (parallel.spmd CSR_COMPACT_FIELDS) that cuts host->device bytes ~40%
-    at typical densities; the device rebuilds row_ids by marking the
+    B+1 ints instead of NNZ — together the batch wire of the pod path
+    (parallel.spmd CSR_FIELDS): ``row_ids`` stays on the host, for the
+    host-side consumers, and the device rebuilds it by marking the
     splits and summing along the entries. The reference ships raw int64
     keys + per-entry row ids over ZeroMQ and leans on its filter pipeline
     instead (src/filter/); here the transfer layout itself is the

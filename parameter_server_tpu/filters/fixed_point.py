@@ -66,20 +66,6 @@ class FixedPointCodec:
             scale.astype(jnp.float32),
         )
 
-    def encode_fast(self, seed: int, x: jax.Array) -> Encoded:
-        """Device-path encode: Pallas kernel with the TPU hardware PRNG.
-        Raises off-TPU — use ``encode`` there."""
-        from parameter_server_tpu.ops.pallas_kernels import (
-            quantize_stochastic_pallas,
-            require_tpu,
-        )
-
-        require_tpu("FixedPointCodec.encode_fast")
-        q, lo, scale = quantize_stochastic_pallas(
-            seed, x, num_bytes=self.num_bytes
-        )
-        return Encoded(q, lo, scale)
-
     def decode(self, e: Encoded) -> jax.Array:
         zero = self._levels // 2
         return (e.q.astype(jnp.float32) + zero) * e.scale + e.lo

@@ -7,8 +7,7 @@ and the proximal operator in src/app/linear_method/penalty.h.
 Each updater is a frozen dataclass of hyperparameters with three pure
 methods over *row slices* (the touched keys' state), so the same code runs:
   - single-device (rows gathered by ``jnp.take``),
-  - SPMD (rows gathered from the local ``kv`` shard under ``shard_map``),
-  - inside a Pallas kernel (the math is elementwise over rows).
+  - SPMD (rows gathered from the local ``kv`` shard under ``shard_map``).
 
 State layout per table (vdim = values per key, reference's "value segments"):
   sgd:     {"w": (K, vdim)}
@@ -113,7 +112,6 @@ class Ftrl:
     beta: float = 1.0
     lambda_l1: float = 1.0
     lambda_l2: float = 0.0
-    use_pallas: bool = False  # fuse the delta in one Pallas VMEM pass (TPU only: raises elsewhere)
     name: str = "ftrl"
 
     def init(self, num_keys: int, vdim: int = 1, dtype: Any = jnp.float32) -> Rows:
@@ -123,19 +121,6 @@ class Ftrl:
         }
 
     def delta(self, rows: Rows, grad: Any) -> Rows:
-        if self.use_pallas:
-            from parameter_server_tpu.ops.pallas_kernels import (
-                ftrl_delta_pallas,
-                require_tpu,
-            )
-
-            require_tpu("Ftrl(use_pallas=True)")
-            dz, dn = ftrl_delta_pallas(
-                rows["z"], rows["n"], grad,
-                alpha=self.alpha, beta=self.beta,
-                l1=self.lambda_l1, l2=self.lambda_l2,
-            )
-            return {"z": dz, "n": dn}
         n = rows["n"]
         w = self.weights(rows)
         n_new = n + grad * grad

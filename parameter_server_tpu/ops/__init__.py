@@ -1,4 +1,4 @@
-"""Device kernels: CSR segment ops, losses, and (later) Pallas fusions."""
+"""Device kernels: CSR segment ops and losses."""
 
 from parameter_server_tpu.ops.sparse import (  # noqa: F401
     csr_grad,

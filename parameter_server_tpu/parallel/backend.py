@@ -22,12 +22,11 @@ tiers this repo grew in parallel universes:
   int8-quantized, EQuARX-style) scatter collective applying the server
   updater as a single sharded jitted update.
 
-Apps and benches write against the interface once; ``make_backend``
+Apps write against the interface once; ``make_backend``
 picks the transport from the ``[mesh]`` config section. The canonical
 :func:`train_linear` loop below runs UNMODIFIED on either backend —
-it is the loop the backend-parity tests and the ``backend`` bench cell
-drive, so "same trainer, different transport" is a checked property,
-not a claim.
+it is the loop the backend-parity tests drive, so "same trainer,
+different transport" is a checked property, not a claim.
 
 Key contract (both backends): ``keys`` are GLOBAL key indices —
 ``int64``, sorted, unique, each real key at most once, all strictly
@@ -93,7 +92,7 @@ class PSBackend(abc.ABC):
     def close(self) -> None:  # noqa: B027 — optional hook
         pass
 
-    # context-manager sugar: benches/tests hold a backend per arm
+    # context-manager sugar: tests hold a backend per arm
     def __enter__(self) -> "PSBackend":
         return self
 
@@ -310,9 +309,9 @@ def local_socket_backend(
     """Spin up ``num_servers`` in-process loopback ShardServers over an
     even key-range divide and wire connected handles into a
     SocketBackend that OWNS them — ``close()`` shuts the servers down.
-    The one assembly the bench's socket arms, ``cli backend`` and the
-    parity tests all share (a real deployment's topology comes from the
-    coordinator instead; see ``_connect_servers``)."""
+    The one assembly ``cli backend`` and the parity tests share (a real
+    deployment's topology comes from the coordinator instead; see
+    ``_connect_servers``)."""
     from parameter_server_tpu.parallel.multislice import (
         ServerHandle,
         ShardServer,
@@ -380,9 +379,9 @@ def train_linear(
 ) -> dict[str, Any]:
     """The canonical backend-agnostic linear trainer loop: per batch,
     pull touched weights -> logistic loss -> per-key mean gradient ->
-    push. ONE implementation drives both the backend-parity tests and
-    the ``backend`` bench cell, so the two transports are compared on
-    literally the same client code.
+    push. ONE implementation drives ``cli backend`` and the backend-parity
+    tests, so the two transports are compared on literally the same
+    client code.
 
     ``kb_all``: (N, nnz) feature indices in [0, num_keys - 2) — shifted
     by +1 on the wire so row 0 stays the pad row. ``y_all``: (N,) 0/1

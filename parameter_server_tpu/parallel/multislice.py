@@ -221,7 +221,7 @@ class ShardServer:
     semantics are unchanged — staleness was always bounded by the clock,
     not by this lock. ``[server] apply_queue = 0`` disables the engine
     (pushes apply inline under the lock — the serial pre-engine
-    discipline, kept as the bench baseline).
+    discipline, kept as the baseline the engine is compared with).
     """
 
     def __init__(
@@ -965,7 +965,7 @@ class ShardServer:
                 return DeferredReply(item.future), {}
             # serial path ([server] apply_queue = 0): apply inline under
             # the write lock — the pre-engine discipline, kept as the
-            # bench baseline and the raw-frame fallback
+            # engine's baseline and the raw-frame fallback
             with trace.span("server.updater", cat="ps", keys=len(keys)):
                 with self._lock:
                     rows = {k: v[keys] for k, v in self.state.items()}
@@ -1877,8 +1877,8 @@ class ServerHandle:
             # the caller's gradient array would let a reused buffer
             # silently corrupt an in-flight push
             arrays = {"g": np.array(g, dtype=np.float32)}
-        # push payload accounting (pre-compression, keys excluded): the
-        # bench's wire-bytes ratio divides the float-path total by the
+        # push payload accounting (pre-compression, keys excluded): a
+        # wire-bytes ratio divides the float-path total by the
         # quantized-path total on identical workloads
         wire_counters.inc(
             "wire_push_payload_bytes",

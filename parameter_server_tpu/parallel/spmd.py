@@ -391,14 +391,10 @@ def place_stacked(stacked: dict, mesh: Mesh) -> dict:
     return {k: jax.device_put(v, sh) for k, v in stacked.items()}
 
 
-CSR_FULL_FIELDS = (
-    "unique_keys", "local_ids", "row_ids", "values", "labels", "example_mask",
-)
-# Compact wire format: row structure rides as (B+1,) row_splits instead of
-# (NNZ,) row_ids — ~40% fewer host->device bytes at typical densities; the
-# device rebuilds row ids by marking the splits and summing along the
-# entries (see _row_ids_of).
-CSR_COMPACT_FIELDS = (
+# The batch wire: row structure rides as (B+1,) row_splits, not as the
+# batch's (NNZ,) row_ids; the device rebuilds row ids by marking the splits
+# and summing along the entries (see _row_ids_of).
+CSR_FIELDS = (
     "unique_keys", "local_ids", "row_splits", "values", "labels", "example_mask",
 )
 
@@ -409,7 +405,6 @@ _F16_MAX = 65504.0  # largest finite float16
 def stack_batches(
     batches: list[CSRBatch],
     mesh: Mesh | None = None,
-    compact: bool = False,
     values_f16: bool = False,
 ) -> Batch:
     """Stack D per-worker CSR batches; shard over "data".
@@ -422,9 +417,7 @@ def stack_batches(
     eval — gets the same wire."""
     import numpy as np
 
-    out = stack_fields(
-        batches, CSR_COMPACT_FIELDS if compact else CSR_FULL_FIELDS, None
-    )
+    out = stack_fields(batches, CSR_FIELDS, None)
     if values_f16:
         out["values"] = np.clip(out["values"], -_F16_MAX, _F16_MAX).astype(
             np.float16
@@ -433,17 +426,14 @@ def stack_batches(
 
 
 def _row_ids_of(b: Batch) -> jax.Array:
-    """Entry -> example-row ids for one shard's batch: passthrough for the
-    full wire format; for the compact one, rebuilt from the (B+1,)
-    row_splits with no data-dependent loop. An entry's row is the number
-    of interior splits at or before it, so the splits are marked in a
-    zeroed (NNZ,) vector and summed along it. Empty rows repeat a split
+    """Entry -> example-row ids for one shard's batch, rebuilt from the
+    (B+1,) row_splits with no data-dependent loop. An entry's row is the
+    number of interior splits at or before it, so the splits are marked in
+    a zeroed (NNZ,) vector and summed along it. Empty rows repeat a split
     and their marks add up; a split equal to NNZ (a buffer filled to its
     last entry) falls off the end and is dropped. Padded entries (value
     0) land on the last row and stay inert under the masked loss/grad
     ops."""
-    if "row_ids" in b:
-        return b["row_ids"]
     nnz = b["values"].shape[0]
     num_rows = b["labels"].shape[0]
     marks = jnp.zeros((nnz,), jnp.int32)

@@ -502,7 +502,6 @@ class PodTrainer:
         assert all(b.keys_in_order() for b in batches), "unique_keys out of order"
         stacked = stack_batches(
             pad_group(batches), None,
-            compact=self.cfg.data.compact_wire,
             values_f16=self.cfg.data.wire_values == "f16",
         )
         n = sum(b.num_examples for b in batches)
@@ -542,15 +541,12 @@ class PodTrainer:
                 .max(axis=0)
             )
         nnz_t, u_t = agreed
-        out = {
+        return {
             **stacked,
             "unique_keys": zero_extend(stacked["unique_keys"], int(u_t), axis=-1),
             "local_ids": zero_extend(stacked["local_ids"], int(nnz_t), axis=-1),
             "values": zero_extend(stacked["values"], int(nnz_t), axis=-1),
         }
-        if "row_ids" in stacked:  # absent in the compact wire format
-            out["row_ids"] = zero_extend(stacked["row_ids"], int(nnz_t), axis=-1)
-        return out
 
     def _train_epoch(self, streams: list[_WorkerStream], report_every: int) -> dict:
         window: list = []
@@ -917,7 +913,6 @@ class PodTrainer:
             )
             stacked = stack_batches(
                 batches, self.mesh,
-                compact=self.cfg.data.compact_wire,
                 values_f16=self.cfg.data.wire_values == "f16",
             )
             with self._new_shape_phase("eval.new_shapes", stacked):

@@ -42,12 +42,6 @@ class DataConfig:
     # density; jit compiles once per bucket (a handful of shapes).
     # Default ON; its gain on the chip is not measured (ROADMAP S1)
     bucket_nnz: bool = True
-    # compact wire format (on by default): int32 keys + (B+1,) row_splits
-    # instead of (NNZ,) row_ids on the host->device transfer — ~40% fewer
-    # bytes at typical densities; the device rebuilds row ids by marking
-    # the splits and summing along the entries (spmd._row_ids_of). False
-    # ships the full row_ids (debugging / parity runs)
-    compact_wire: bool = True
     # feature-value dtype on the host->device wire: "f32" (exact, default)
     # or "f16" — half the value bytes; IEEE round-to-nearest quantization,
     # cast back to f32 on-device before compute (the reference's
@@ -225,7 +219,7 @@ class ServerConfig:
 
     # bound of the decoded-push apply queue; 0 disables the engine
     # entirely (pushes apply inline under the write lock — the serial
-    # pre-engine discipline, kept as the bench baseline)
+    # pre-engine discipline, kept as the baseline it is compared with)
     apply_queue: int = 256
     # max pushes coalesced into one updater apply
     max_batch: int = 64

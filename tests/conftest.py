@@ -3,7 +3,8 @@
 This is the rebuild's analog of the reference's script/local.sh integration
 harness (spawn scheduler + N servers + M workers as processes on one host):
 multi-"node" logic runs on one host, with virtual devices standing in for
-chips. The chip itself is exercised by chip_smoke.py and bench.py.
+chips. The chip itself is exercised by chip_smoke.py and the benchmark
+(BENCHMARK.json, benchmark/run.py).
 """
 
 import os

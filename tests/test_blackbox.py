@@ -602,6 +602,15 @@ class TestKeyHeat:
 
 
 class TestPeakGaugeRoll:
+    @pytest.fixture(autouse=True)
+    def _fresh_counters(self):
+        """wire_counters is process-global and its cumulative peaks outlive
+        the test that made them: whatever file this worker ran before may
+        have left a deeper one. Pin each test to a zero baseline."""
+        wire_counters.reset()
+        yield
+        wire_counters.reset()
+
     def test_peaks_decay_per_telemetry_snapshot(self):
         """ISSUE 9 satellite: max-merging gauges must show
         peak-since-last-snapshot in cli stats, not peak-since-boot."""

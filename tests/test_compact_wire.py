@@ -1,11 +1,12 @@
-"""Compact wire format tests: int32 keys + (B+1,) row_splits must be an
-exact drop-in for int64 keys + (NNZ,) row_ids on every dispatch path.
+"""The batch wire of the pod path: int32 keys + (B+1,) row_splits, from
+which the device rebuilds the (NNZ,) row ids. Held to ``CSRBatch.row_ids``,
+which stays on the host: the rebuild against it, and the step over the wire
+against the host arithmetic on it.
 
 Reference analog: the reference attacks wire bytes with its filter
 pipeline (src/filter/ key-caching, compression, fixed-point floats); on a
 TPU host feed the same scarce resource is host->device bandwidth and the
-transfer LAYOUT itself is the filter (~40% fewer bytes at typical
-densities)."""
+transfer LAYOUT itself is the filter."""
 
 import numpy as np
 import pytest
@@ -104,7 +105,7 @@ ROW_ID_CASES = {
 
 @pytest.mark.parametrize("case", sorted(ROW_ID_CASES))
 def test_row_ids_rebuilt_from_row_splits(case):
-    """_row_ids_of on a compact batch against np.repeat, equal at every
+    """_row_ids_of on a stacked batch against np.repeat, equal at every
     position, the padding included: padded entries sit on the last row."""
     import jax
 
@@ -134,46 +135,91 @@ def test_unique_keys_dtype_tracks_key_space():
     assert big.build(labels, keys, vals).unique_keys.dtype == np.int64
 
 
+def _host_step(up, state, group, push_mode):
+    """One pod step in host arithmetic over each batch's own ``row_ids``
+    (``ops.sparse`` on one device, ``kv.store`` pull and push): every
+    worker's gradient against the step-start weights, then the pushes in
+    worker order, or their sum in one push under ``aggregate``. Returns
+    (state, loss_sum)."""
+    import jax.numpy as jnp
+
+    from parameter_server_tpu.kv.store import pull as kv_pull, push as kv_push
+    from parameter_server_tpu.models.linear import batch_to_device
+    from parameter_server_tpu.ops.sparse import csr_grad, csr_logits, logistic_loss
+
+    pushes, loss_sum = [], 0.0
+    for b in group:
+        dev = batch_to_device(b)
+        w_u = kv_pull(up, state, dev["unique_keys"])
+        logits = csr_logits(
+            w_u, dev["values"], dev["local_ids"], dev["row_ids"],
+            num_rows=dev["labels"].shape[0],
+        )
+        loss, err = logistic_loss(logits, dev["labels"], dev["example_mask"])
+        g = csr_grad(
+            err, dev["values"], dev["local_ids"], dev["row_ids"],
+            num_unique=dev["unique_keys"].shape[0],
+        )
+        pushes.append((dev["unique_keys"], g))
+        loss_sum += float(loss)
+    if push_mode == "aggregate":
+        dense = jnp.zeros((NUM_KEYS, 1), jnp.float32)
+        for idx, g in pushes:
+            dense = dense.at[idx].add(g)
+        touched = np.unique(np.concatenate([np.asarray(idx) for idx, _ in pushes]))
+        pushes = [(jnp.asarray(touched), dense[touched])]
+    for idx, g in pushes:
+        state = kv_push(up, state, idx, g)
+    return state, loss_sum
+
+
 @pytest.mark.parametrize("bucket", [False, True])
 @pytest.mark.parametrize("push_mode", ["per_worker", "aggregate"])
-def test_compact_step_matches_full(push_mode, bucket):
+def test_step_matches_host_arithmetic_on_row_ids(push_mode, bucket):
+    """The step over the wire (row ids rebuilt on the device from
+    ``row_splits``) against the host arithmetic on the batches' own
+    ``row_ids``: same losses and weights, step after step."""
     d, k = 4, 2
     up = Ftrl(alpha=0.3, lambda_l1=0.1)
     mesh = make_mesh(d, k)
     groups = _batches(d, 4, bucket=bucket)
     step = make_spmd_train_step(up, mesh, NUM_KEYS, push_mode=push_mode)
 
-    finals = []
-    for compact in (False, True):
-        state = shard_state(up.init(NUM_KEYS, 1), mesh)
-        losses = []
-        for g in groups:
-            state, out = step(state, stack_batches(g, None, compact=compact))
-            losses.append(float(out["loss_sum"]))
-        finals.append((losses, np.asarray(up.weights(state))))
-    np.testing.assert_allclose(finals[0][0], finals[1][0], rtol=1e-6)
-    np.testing.assert_allclose(finals[0][1], finals[1][1], rtol=1e-6, atol=1e-7)
+    state = shard_state(up.init(NUM_KEYS, 1), mesh)
+    ref = up.init(NUM_KEYS, 1)
+    for g in groups:
+        stacked = stack_batches(g, None)
+        assert "row_ids" not in stacked
+        state, out = step(state, stacked)
+        ref, ref_loss = _host_step(up, ref, g, push_mode)
+        assert float(out["loss_sum"]) == pytest.approx(ref_loss, rel=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(up.weights(state))[:NUM_KEYS], np.asarray(up.weights(ref)),
+        rtol=1e-5, atol=1e-6,
+    )
 
 
-def test_compact_multistep_group():
-    """Compact wire composes with K-microstep scanned dispatch (row_splits
-    is fixed-size, so group stacking needs no variable-axis padding)."""
+def test_multistep_group_matches_host_arithmetic_on_row_ids():
+    """The wire composes with K-microstep scanned dispatch (row_splits is
+    fixed-size, so group stacking needs no variable-axis padding): the
+    scanned program against K host steps on the batches' own row_ids."""
     d, K = 2, 3
     up = Ftrl(alpha=0.3, lambda_l1=0.1)
     mesh = make_mesh(d, 2)
     groups = _batches(d, K, bucket=True)
     stepK = make_spmd_train_multistep(up, mesh, NUM_KEYS)
 
-    finals = []
-    for compact in (False, True):
-        state = shard_state(up.init(NUM_KEYS, 1), mesh)
-        items = [stack_batches(g, None, compact=compact) for g in groups]
-        state, out = stepK(state, stack_step_groups(items))
-        finals.append(
-            (np.asarray(out["loss_sum"]), np.asarray(up.weights(state)))
-        )
-    np.testing.assert_allclose(finals[0][0], finals[1][0], rtol=1e-6)
-    np.testing.assert_allclose(finals[0][1], finals[1][1], rtol=1e-6, atol=1e-7)
+    state = shard_state(up.init(NUM_KEYS, 1), mesh)
+    state, out = stepK(state, stack_step_groups([stack_batches(g, None) for g in groups]))
+    ref, ref_losses = up.init(NUM_KEYS, 1), []
+    for g in groups:
+        ref, loss = _host_step(up, ref, g, "per_worker")
+        ref_losses.append(loss)
+    np.testing.assert_allclose(np.asarray(out["loss_sum"]), ref_losses, rtol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(up.weights(state))[:NUM_KEYS], np.asarray(up.weights(ref)),
+        rtol=1e-5, atol=1e-6,
+    )
 
 
 @pytest.fixture(scope="module")
@@ -235,26 +281,3 @@ def test_wire_values_f16_clips_overflow():
     assert stacked["values"].dtype == np.float16
     assert np.isfinite(stacked["values"].astype(np.float32)).all()
     assert stacked["values"].max() == np.float16(65504.0)
-
-
-def test_pod_trainer_compact_parity(files):
-    """compact_wire on/off trains to identical weights and eval metrics
-    through the full PodTrainer path (pipeline, bucketing, multistep)."""
-    runs = []
-    for compact in (True, False):
-        cfg = PSConfig()
-        cfg.data.num_keys = 1 << 12
-        cfg.data.compact_wire = compact
-        cfg.data.bucket_nnz = True
-        cfg.data.pipeline_depth = 2
-        cfg.solver.minibatch = 128
-        cfg.solver.steps_per_call = 2
-        cfg.penalty.lambda_l1 = 0.05
-        cfg.parallel.data_shards = 4
-        cfg.parallel.kv_shards = 2
-        t = PodTrainer(cfg, reporter=quiet())
-        t.train_files(files, key_mode="identity", report_every=100)
-        ev = t.evaluate_files(files[:1], key_mode="identity")
-        runs.append((t.full_weights(), ev))
-    np.testing.assert_allclose(runs[0][0], runs[1][0], rtol=1e-5, atol=1e-6)
-    assert runs[0][1]["auc"] == pytest.approx(runs[1][1]["auc"], abs=1e-6)

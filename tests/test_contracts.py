@@ -1,8 +1,7 @@
 """CI contract tests (ISSUE 4 satellite; ISSUE 5 migrated them onto
 pslint's DERIVED inventories): every counter bumped in code is visible
-in the cluster dashboard, every ``[server]``/``[wire]`` config key read
-by code exists with a default in ``utils/config.py``, and the bench
-compact line schema carries the ``server_apply`` acceptance cell.
+in the cluster dashboard, and every ``[server]``/``[wire]`` config key read
+by code exists with a default in ``utils/config.py``.
 
 The counter and config inventories are no longer regex lists maintained
 here — they come from ``parameter_server_tpu.analysis.contracts``
@@ -13,15 +12,8 @@ checkers gate CI with), so the lists can never drift from the code.
 from __future__ import annotations
 
 import dataclasses
-import sys
-from pathlib import Path
 
-ROOT = Path(__file__).resolve().parent.parent
-sys.path.insert(0, str(ROOT))
-
-import bench  # noqa: E402
-
-from parameter_server_tpu.analysis import (  # noqa: E402
+from parameter_server_tpu.analysis import (
     config_key_usage,
     counter_inventory,
     load_package,
@@ -152,116 +144,3 @@ class TestConfigKeyContract:
         assert cfg.server.apply_queue == 0 and cfg.server.max_batch == 7
         assert cfg.wire.adaptive_window is True
         assert cfg.wire.hdr_codec == "json"
-
-
-class TestBenchCompactServerCell:
-    def test_server_apply_cell_rides_the_compact_line(self):
-        import json
-
-        full = {
-            "metric": "m", "value": 1.0, "unit": "u", "vs_baseline": 1.0,
-            "platform": "cpu", "raw": {}, "suite_wall_s": 1.0,
-            "sub": {
-                "server_apply": {
-                    "batched_speedup_w8": 3.6,
-                    "push_rps_batched_w8": 284.0,
-                    "push_rps_serial_w8": 86.0,
-                    "hdr_speedup_4k": 1.38,
-                    "hdr_bytes_saved": 97410,
-                },
-            },
-        }
-        line = json.dumps(bench._compact_contract(full, "f.json"))
-        assert len(line) < 1500
-        c = json.loads(line)
-        assert c["sub"]["srv"] == {
-            "batched_speedup_w8": 3.6,
-            "push_rps_batched_w8": 284.0,
-            "hdr_speedup_4k": 1.38,
-        }
-
-    def test_server_apply_error_is_marked(self):
-        import json
-
-        full = {
-            "metric": "m", "value": 1.0, "unit": "u", "vs_baseline": 1.0,
-            "platform": "cpu", "raw": {}, "suite_wall_s": 1.0,
-            "sub": {"server_apply": {"error": "boom " * 100}},
-        }
-        c = bench._compact_contract(full, "f.json")
-        assert "error" in c["sub"]["srv"]
-        assert len(json.dumps(c)) < 1500
-
-
-class TestBenchCompactServeCell:
-    def test_serve_cell_rides_the_compact_line(self):
-        """ISSUE 7 acceptance plumbing: the serve cell's QPS speedup,
-        hit rate, coalesce ratio and shed p99 reach the driver-recorded
-        compact line."""
-        import json
-
-        full = {
-            "metric": "m", "value": 1.0, "unit": "u", "vs_baseline": 1.0,
-            "platform": "cpu", "raw": {}, "suite_wall_s": 1.0,
-            "sub": {
-                "serve": {
-                    "pull_qps_cached": 12345.6,
-                    "pull_qps_uncached": 321.0,
-                    "qps_speedup_cached": 38.4,
-                    "hit_rate": 0.957,
-                    "coalesce_ratio": 0.12,
-                    "p99_ms_shed": 62.5,
-                    "shed_count": 16,
-                },
-            },
-        }
-        line = json.dumps(bench._compact_contract(full, "f.json"))
-        assert len(line) < 1500
-        c = json.loads(line)
-        assert c["sub"]["serve"] == {
-            "pull_qps_cached": 12345.6,
-            "qps_speedup_cached": 38.4,
-            "hit_rate": 0.957,
-            "coalesce_ratio": 0.12,
-            "p99_ms_shed": 62.5,
-        }
-
-    def test_serve_error_is_marked(self):
-        import json
-
-        full = {
-            "metric": "m", "value": 1.0, "unit": "u", "vs_baseline": 1.0,
-            "platform": "cpu", "raw": {}, "suite_wall_s": 1.0,
-            "sub": {"serve": {"error": "boom " * 100}},
-        }
-        c = bench._compact_contract(full, "f.json")
-        assert "error" in c["sub"]["serve"]
-        assert len(json.dumps(c)) < 1500
-
-
-class TestBenchCompactObservabilityCell:
-    def test_observability_ratio_rides_the_compact_line(self):
-        """ISSUE 13 acceptance plumbing: the wire_rpc cell's full-
-        observability overhead ratio (flightrec + timeseries + profiler
-        armed vs all off) reaches the driver-recorded compact line."""
-        import json
-
-        full = {
-            "metric": "m", "value": 1.0, "unit": "u", "vs_baseline": 1.0,
-            "platform": "cpu", "raw": {}, "suite_wall_s": 1.0,
-            "sub": {
-                "wire_rpc": {
-                    "roundtrips_per_sec": 900.0,
-                    "pull_p50_ms": 1.0,
-                    "push_p99_ms": 4.1,
-                    "pipelined_speedup_w8": 3.4,
-                    "mb_s_1mib_pipelined": 700.0,
-                    "flightrec_ratio": 0.99,
-                    "observability_ratio": 0.97,
-                },
-            },
-        }
-        line = json.dumps(bench._compact_contract(full, "f.json"))
-        assert len(line) < 1500
-        c = json.loads(line)
-        assert c["sub"]["rpc"]["observability_ratio"] == 0.97

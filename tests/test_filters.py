@@ -60,11 +60,6 @@ class TestFixedPointCodec:
         with pytest.raises(ValueError):
             FixedPointCodec(num_bytes=4)
 
-    def test_encode_fast_raises_off_tpu(self, rng):
-        x = jnp.asarray(rng.normal(size=256).astype(np.float32))
-        with pytest.raises(RuntimeError, match="needs a TPU backend"):
-            FixedPointCodec(num_bytes=1).encode_fast(7, x)
-
 
 class TestCountMinSketch:
     def test_counts_never_underestimate(self, rng):

@@ -319,22 +319,16 @@ class TestWideDeepMultistep:
             make_wd_spmd_train_step,
             make_wd_spmd_train_multistep,
         )
-        from parameter_server_tpu.parallel.spmd import (
-            CSR_FULL_FIELDS,
-            shard_state,
-            stack_fields,
-        )
+        from parameter_server_tpu.parallel.spmd import shard_state
 
         d, K = 2, 3
         mesh = make_mesh(d, 2)
         batches = self._batches(n_batches=d * (K - 1))
         groups = [
-            stack_fields(batches[s * d : (s + 1) * d], CSR_FULL_FIELDS, None)
+            stack_batches(batches[s * d : (s + 1) * d], None)
             for s in range(K - 1)
         ]
-        inert = stack_fields(
-            [_inert_like(batches[0]) for _ in range(d)], CSR_FULL_FIELDS, None
-        )
+        inert = stack_batches([_inert_like(batches[0]) for _ in range(d)], None)
 
         outs = []
         for multi in (False, True):

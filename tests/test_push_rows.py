@@ -56,11 +56,11 @@ def key_vectors(source, key_mode, num_keys):
     elif source == "pad_group":
         out = [g.unique_keys for g in pad_group([b, big, inert_like(b)])]
     elif source == "stack_batches":
-        stacked = spmd.stack_batches(pad_group([b, big]), compact=True)["unique_keys"]
+        stacked = spmd.stack_batches(pad_group([b, big]))["unique_keys"]
         out = list(stacked)
     elif source == "stack_step_groups":
-        small = spmd.stack_batches([b, inert_like(b)], compact=True)
-        large = spmd.stack_batches([big, big], compact=True)
+        small = spmd.stack_batches([b, inert_like(b)])
+        large = spmd.stack_batches([big, big])
         grown = spmd.stack_step_groups([small, large, small])["unique_keys"]  # (D, K, U)
         out = list(grown.reshape(-1, grown.shape[-1]))
     else:

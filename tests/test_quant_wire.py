@@ -200,6 +200,12 @@ class TestQuantExactlyOnceUnderChaos:
         )
         try:
             keys = np.arange(1, 513, dtype=np.int64)
+            # one round trip first: its reply acks "qwire", so the window
+            # below starts quantized. Without it every push could be
+            # encoded while a healed connection was still un-acked (each
+            # reconnect renegotiates), all 16 rode floats, and the last
+            # assert failed one loaded run in a few (ROADMAP D13)
+            handle.pull(keys)
             total = np.zeros(512, np.float64)
             futs = []
             for i in range(16):
@@ -211,7 +217,7 @@ class TestQuantExactlyOnceUnderChaos:
             w = handle.pull(keys).astype(np.float64)
             exp = _expected_weights(handle, keys, total)
             np.testing.assert_allclose(w, exp, atol=1e-5)
-            # quant actually engaged (first push may have gone float)
+            # quant actually engaged
             assert srv.counters["pushes"] == 16
             assert wire_counters.get("wire_quant_bytes_saved") > 0
             if spec.startswith(("disconnect", "drop")):

@@ -217,7 +217,7 @@ class TestDisabledTracingIsFree:
     def test_noop_is_reference_stable_across_calls(self):
         # the disabled global tracer hands out the identical object every
         # time: the hot-path cost is one method call, zero allocations of
-        # spans (the "tracing disabled is free" contract bench relies on)
+        # spans (the "tracing disabled is free" contract the hot path relies on)
         t = trace.Tracer(None)
         assert len({id(t.span(f"s{i}")) for i in range(100)}) == 1
 

@@ -727,12 +727,13 @@ class TestAcceptanceDrill:
         )
         env[trace.TRACE_DIR_ENV] = str(trace_dir)
         env[trace.TRACE_SAMPLE_ENV] = "16"
-        # every 5th push frame sleeps 200 ms server-side BEFORE
+        # every 5th push frame sleeps 600 ms server-side BEFORE
         # dispatch: client-observed latency blows up, server spans stay
-        # fast — the signature of a wire/straggler fault. 200 ms also
-        # dominates the first batch's jit compile (~130 ms on CPU), so
-        # the slowest push is deterministically a FAULTED one.
-        env["PS_FAULT_PLAN"] = "delay,cmd=push,every=5,delay_s=0.2"
+        # fast — the signature of a wire/straggler fault. 600 ms also
+        # dominates the first batch's jit compile (~130 ms on an idle
+        # CPU, 217 ms seen beside five loaded xdist workers, where a
+        # 200 ms delay lost to it), so the slowest push is a FAULTED one.
+        env["PS_FAULT_PLAN"] = "delay,cmd=push,every=5,delay_s=0.6"
         child = subprocess.Popen(
             [sys.executable,
              str(HERE / "_whylate_child_server.py")],
@@ -766,8 +767,8 @@ class TestAcceptanceDrill:
             doc = json.loads(capsys.readouterr().out)
             push = doc["cmds"]["push"]
             slowest = push["slowest"][0]
-            # the slowest push is a delayed one (~200 ms vs ~1 ms)
-            assert slowest["dur_ms"] >= 150.0
+            # the slowest push is a delayed one (~600 ms vs ~1 ms)
+            assert slowest["dur_ms"] >= 450.0
             seg = slowest["segments"]
             named = sum(v for k, v in seg.items() if k != "other")
             # >= 90% of its wall time attributed to NAMED segments
