@@ -27,7 +27,7 @@ import time
 import numpy as np
 
 from benchmark.harness import criteo
-from benchmark.harness.checks import Check, norm_gap, worst_gap
+from benchmark.harness.checks import Check, element_gaps, norm_gap, worst_gap
 from benchmark.harness.readback import read_rows
 from benchmark.harness.ref_ftrl import RefFtrl, auc, logloss
 
@@ -99,14 +99,28 @@ class Problem:
         take = min(len(rest), SAMPLE_ROWS - len(hot))
         return np.concatenate([hot, rng.choice(rest, take, replace=False)])
 
-    def reference(self, assignment: list, precision: str = "float32", heldout: bool = True):
+    def score_spans(self) -> dict:
+        """The examples a run scores beside the prefix it trains: the
+        held-out files, and as many of the training files from the first
+        on (the guard of the window scores the table on what it trained)."""
+        fe, n_files = self.file_examples, len(self.labels) // self.file_examples
+        n_trained = min(n_files - self.n_train_files, self.n_train_files)
+        return {
+            "heldout": slice(self.n_train_files * fe, n_files * fe),
+            "trained": slice(0, n_trained * fe),
+        }
+
+    def new_reference(self, rows_universe: np.ndarray, precision: str):
+        return RefFtrl(rows_universe, self.hyper, precision)
+
+    def reference(self, assignment: list, precision: str = "float32", score: tuple = ("heldout",)):
         """The plain reference after the prefix's steps, its per-step
-        losses, and (idx, vals, labels) of the held-out examples."""
-        spans = [slice(0, self.prefix_files * self.file_examples)]
-        if heldout:
-            spans.append(slice(self.n_train_files * self.file_examples, len(self.labels)))
+        losses, and {name: (idx, vals, labels)} of the spans named in
+        ``score`` (``score_spans``), which its row universe then holds."""
+        named = self.score_spans()
+        spans = [slice(0, self.prefix_files * self.file_examples)] + [named[k] for k in score]
         feats = [criteo.features(self.ints[s], self.cats[s], self.num_keys) for s in spans]
-        ref = RefFtrl(np.concatenate([f[0].ravel() for f in feats]), self.hyper, precision)
+        ref = self.new_reference(np.concatenate([f[0].ravel() for f in feats]), precision)
         idx, vals = ref.index(feats[0][0]), feats[0][1]
         losses = []
         for per_worker in assignment:
@@ -117,16 +131,25 @@ class Problem:
                     sl = slice(lo, lo + self.minibatch)
                     batches.append((idx[sl], vals[sl], self.labels[sl]))
                 losses.append(ref.step(batches))
-        held = (ref.index(feats[1][0]), feats[1][1], self.labels[spans[1]]) if heldout else None
-        return ref, np.asarray(losses), held
+        scored = {
+            k: (ref.index(f[0]), f[1], self.labels[s]) for k, f, s in zip(score, feats[1:], spans[1:])
+        }
+        return ref, np.asarray(losses), scored
 
     @staticmethod
     def prefix_numbers(got_losses, got_state: dict, rows, ref: RefFtrl, ref_losses) -> dict:
-        """The prefix's compared numbers: ``got_*`` against ``ref``."""
+        """The prefix's compared numbers: ``got_*`` against ``ref``. A row's
+        ``z`` is what a sum of gradients left over, and ``n`` is the sum of
+        their squares: ``z``'s gap is measured against sqrt(n) where that is
+        the larger. An integer column's row takes every example's gradient
+        (n of 4e7 after one call) and may end inside the L1 dead zone,
+        |z| < lambda_l1, where both sides' weight is 0: float32 leaves 0.04
+        there, which is 6e-6 of sqrt(n) and was 0.109 of the median row
+        (``PERF.md`` section 2)."""
         at = ref.index(rows)
         return {
             "prefix.loss_gap": float(np.max(np.abs(got_losses - ref_losses) / np.abs(ref_losses))),
-            "prefix.z_gap": worst_gap(got_state["z"], ref.z[at]),
+            "prefix.z_gap": worst_gap(got_state["z"], ref.z[at], scale=np.sqrt(ref.n[at])),
             "prefix.n_gap": worst_gap(got_state["n"], ref.n[at]),
             "prefix.z_norm_gap": norm_gap(got_state["z"], ref.z[at]),
             "prefix.n_norm_gap": norm_gap(got_state["n"], ref.n[at]),
@@ -148,26 +171,30 @@ def control(ctx, precision: str = "bfloat16") -> dict:
     lower precision reads it. Needs no chip: the program is not in it."""
     prob = Problem(ctx, prepare(ctx, write=False))
     plan = prob.nominal_assignment()
-    ref, ref_losses, held = prob.reference(plan, "float32")
-    low, low_losses, _ = prob.reference(plan, precision, heldout=False)
+    ref, ref_losses, scored = prob.reference(plan, "float32", score=("heldout", "trained"))
+    low, low_losses, _ = prob.reference(plan, precision, score=())
     rows = prob.sample_rows()
     at = low.index(rows)
     out = Problem.prefix_numbers(low_losses, {"z": low.z[at], "n": low.n[at]}, rows, ref, ref_losses)
-    # the lower precision's held-out scores over the float32 reference's
-    # universe: copy its state across by row
+    # the lower precision's scores over the float32 reference's universe:
+    # copy its state across by row
     pos = ref.index(low.rows)
     wide = RefFtrl(ref.rows, prob.hyper, precision)
     wide.z[pos], wide.n[pos] = low.z, low.n
+    held = scored["heldout"]
     auc_l, ll_l, p_l = heldout_scores(wide, held)
     out.update(Problem.eval_numbers(p_l[: prob.minibatch], ll_l, auc_l, heldout_scores(ref, held)))
+    out.update(auc_below_reference(ref, wide, scored))
     return out
 
 
 class Session:
+    problem_type = Problem
+
     def __init__(self, ctx):
         self.ctx = ctx
         data = ctx.prepared or prepare(ctx)
-        self.problem = p = Problem(ctx, data)
+        self.problem = p = self.problem_type(ctx, data)
         self.settings = ctx.config["settings"]
         self.data_shards, self.minibatch = p.data_shards, p.minibatch
         self.kv_shards = int(ctx.config["mesh"]["kv"])
@@ -177,24 +204,21 @@ class Session:
         n_train = p.n_train_files
         paths = data["paths"]
         self.train_paths, self.heldout_paths = paths[:n_train], paths[n_train:]
+        # the training files the window's guard scores
+        self.trained_paths = self.train_paths[: p.score_spans()["trained"].stop // p.file_examples]
         self.n_train_files, self.prefix_files = n_train, p.prefix_files
         self.retired = 0  # device calls retired in the current epoch
         self.calls: list = []  # one dict per dispatched call of the current epoch
         self.on_retire = None
         self.keep_labels = False
         self.eval_first = None
+        self.eval_slots: list = []  # unique-key slots of each predict call of the last pass
+        self.heldout_auc = None  # the program's, right after the prefix
         self._build()
         import jax
 
         jax.block_until_ready(self.trainer.state)
         ctx.stage("trainer built, table on the device")
-
-    @property
-    def bucket_rows(self) -> int:
-        """Unique slots of the one bucket every batch of this data lands in:
-        39 features x minibatch entries, rounded up to a power of two, + 1."""
-        nnz = (criteo.N_INT + criteo.N_CAT) * self.minibatch
-        return (1 << (nnz - 1).bit_length()) + 1
 
     def file_list(self, n_files: int, start: int = 0) -> list:
         """``n_files`` distinct paths that cycle over the training files:
@@ -210,8 +234,8 @@ class Session:
         return out
 
     # -- the program ------------------------------------------------------
-    def _build(self) -> None:
-        from parameter_server_tpu.parallel.trainer import PodTrainer
+    def _config(self):
+        """The program's configuration for this cell's settings."""
         from parameter_server_tpu.utils.config import PSConfig
 
         st = self.settings
@@ -220,7 +244,6 @@ class Session:
         cfg.data.num_keys = self.num_keys
         cfg.data.pipeline_depth = int(st["pipeline_depth"])
         cfg.data.bucket_nnz = bool(st["bucket_nnz"])
-        cfg.data.compact_wire = bool(st["compact_wire"])
         cfg.data.max_nnz_per_example = int(st["max_nnz_per_example"])
         cfg.solver.algo = st["algo"]
         cfg.solver.minibatch = self.minibatch
@@ -232,8 +255,13 @@ class Session:
         cfg.parallel.data_shards = self.data_shards
         cfg.parallel.kv_shards = self.kv_shards
         cfg.parallel.push_mode = st["push_mode"]
-        self.cfg = cfg
-        self.trainer = tr = PodTrainer(cfg)
+        return cfg
+
+    def _build(self) -> None:
+        from parameter_server_tpu.parallel.trainer import PodTrainer
+
+        self.cfg = self._config()
+        self.trainer = tr = PodTrainer(self.cfg)
 
         finish = tr.clock.finish
 
@@ -254,6 +282,7 @@ class Session:
                 "seen_before": tr.examples_seen,
                 "loss": out["loss_sum"],
                 "examples": out["examples"],
+                "slots": batch["unique_keys"].shape[-1],
                 "labels": batch["labels"] if self.keep_labels else None,
             })
             return new_state, out
@@ -263,6 +292,7 @@ class Session:
 
         def predict_recorded(state, batch):
             probs = predict_fn(state, batch)
+            self.eval_slots.append(batch["unique_keys"].shape[-1])
             if self.eval_first is None:
                 self.eval_first = probs
             return probs
@@ -301,6 +331,11 @@ class Session:
         seen = [c["seen_before"] for c in self.calls] + [self.trainer.examples_seen]
         return [b - a for a, b in zip(seen, seen[1:])]
 
+    def call_slots(self) -> list:
+        """Unique-key slots a worker's microstep of each dispatched call
+        carried: the last axis of the ``unique_keys`` the step was handed."""
+        return [c["slots"] for c in self.calls]
+
     def call_outputs(self):
         """Per dispatched call: (K,) losses and (K,) device example counts.
         Blocks until every one of them is done."""
@@ -310,23 +345,31 @@ class Session:
         )
 
     def evaluate(self, files: list) -> dict:
-        self.eval_first = None
+        self.eval_first, self.eval_slots = None, []
         return self.trainer.evaluate_files(files)
 
     # -- the correctness prefix --------------------------------------------
-    def prefix(self) -> None:
+    def prefix(self, score_heldout: bool = False) -> None:
         """From the fresh table, the first ``prefix_calls`` device calls
         through the window's own call and feed; keeps what the reference is
-        compared with once the window has closed."""
+        compared with once the window has closed. ``score_heldout``: also
+        the AUC of the held-out files at this state, which has had the
+        training the reference will have had; those seconds are the
+        harness's own checking and not set-up (``ctx.excluded_s``)."""
         n_calls = int(self.ctx.traffic["prefix_calls"])
-        n_files = self.prefix_files
         self.keep_labels = True
         self.ctx.stage("prefix starts")
-        ran_out = self.train(self.file_list(n_files))
+        ran_out = self.train(self.file_list(self.prefix_files))
         self.ctx.stage("prefix epoch done")
         self.keep_labels = False
         if not ran_out:
             raise RuntimeError("the prefix epoch was stopped")
+        if score_heldout:
+            t = time.perf_counter()
+            self.heldout_auc = float(self.evaluate(self.heldout_paths)["auc"])
+            self.ctx.excluded_s += time.perf_counter() - t
+            self.ctx.stage("held-out files scored at the prefix's state (not set-up)")
+        self.prefix_epoch_done()
         work = self.call_work()
         real = [i for i, w in enumerate(work) if w > 0]
         if len(real) != n_calls or any(work[i] != self.call_examples for i in real):
@@ -335,8 +378,15 @@ class Session:
         self.prefix_losses = np.concatenate([losses[i] for i in real])
         self.prefix_labels = [np.asarray(self.calls[i]["labels"]) for i in real]
         self.sample_rows = self.problem.sample_rows()
-        self.sample_state = read_rows(self.trainer.state, self.sample_rows, SAMPLE_ROWS)
+        self.sample_state = self.read_state(self.sample_rows)
         self.ctx.stage("prefix trained and read back")
+
+    def prefix_epoch_done(self) -> None:
+        """For an app whose prefix leaves something behind that the window
+        must not see."""
+
+    def read_state(self, rows) -> dict:
+        return read_rows(self.trainer.state, rows, SAMPLE_ROWS)
 
     # -- the reference, run once the window has closed ----------------------
     def worker_files(self) -> list:
@@ -357,11 +407,12 @@ class Session:
             out.append(per_worker)
         return out
 
-    def reference(self, precision: str = "float32"):
-        return self.problem.reference(self.worker_files(), precision)
+    def reference(self, precision: str = "float32", score: tuple = ("heldout",)):
+        return self.problem.reference(self.worker_files(), precision, score)
 
     def prefix_checks(self, ref: RefFtrl, ref_losses: np.ndarray) -> list:
         lim = self.ctx.traffic["limits"]
+        print("\n".join(gap_lines(self.sample_state, self.sample_rows, ref)), flush=True)
         got = Problem.prefix_numbers(self.prefix_losses, self.sample_state, self.sample_rows, ref, ref_losses)
         return [Check(name, value, lim[name]) for name, value in got.items()]
 
@@ -369,7 +420,37 @@ class Session:
         shutil.rmtree(os.path.join(self.ctx.workdir, "data"), ignore_errors=True)
 
 
+def gap_lines(got_state: dict, rows: np.ndarray, ref: RefFtrl) -> list:
+    """``[gaps]`` lines, for whoever sets or doubts a limit: where the
+    sampled rows' gaps lie, and the worst row of each array with both
+    sides' ``z`` and ``n`` there. ``Problem.sample_rows`` puts the rows of
+    the 13 integer columns, which every example touches, first."""
+    at = ref.index(rows)
+    want = {"z": ref.z[at], "n": ref.n[at]}
+    scale = {"z": np.sqrt(want["n"]), "n": None}  # as ``Problem.prefix_numbers`` measures them
+    out = []
+    for name in ("z", "n"):
+        g = element_gaps(got_state[name], want[name], scale[name])
+        w = int(np.argmax(g))
+        qs = " ".join(f"p{q:g}={np.percentile(g, q):.3g}" for q in (50, 90, 99, 99.9, 99.99, 100))
+        out.append(
+            f"[gaps] {name}: {qs}; worst at table row {int(rows[w])} "
+            f"({'an integer column' if w < criteo.N_INT else 'a categorical value'}): "
+            f"z {got_state['z'][w]:.9g} for {want['z'][w]:.9g}, n {got_state['n'][w]:.9g} for {want['n'][w]:.9g}"
+        )
+    return out
+
+
 def heldout_scores(ref: RefFtrl, held) -> tuple[float, float, np.ndarray]:
     idx, vals, y = held
     p = ref.predict(idx, vals)
     return auc(y, p), logloss(y, p), p
+
+
+def auc_below_reference(ref, other, scored: dict) -> dict:
+    """``<span>.auc_below_reference`` of every scored span: the reference's
+    AUC there minus that of ``other``, a reference over the same universe."""
+    return {
+        f"{k}.auc_below_reference": heldout_scores(ref, s)[0] - heldout_scores(other, s)[0]
+        for k, s in scored.items()
+    }

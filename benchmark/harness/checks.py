@@ -25,23 +25,30 @@ class Check:
         )
 
 
-def worst_gap(got, want) -> float:
-    """Largest |got - want| measured against max(|want|, median |want|),
-    row by row: some rows are all but zero."""
+def element_gaps(got, want, scale=None):
+    """|got - want| of every element against max(|want|, median |want|):
+    some rows are all but zero. ``scale``, where given, is a further floor
+    an element: the size of what was summed into it, for a value that is
+    what a sum of far larger terms left over."""
     import numpy as np
 
     got = np.asarray(got, np.float64).ravel()
     want = np.asarray(want, np.float64).ravel()
-    if got.shape != want.shape:
-        return float("inf")
-    if not np.isfinite(got).all():
-        return float("inf")
-    if want.size == 0:
-        return 0.0
+    if got.shape != want.shape or not np.isfinite(got).all():
+        return np.full(max(want.size, 1), np.inf)
     mag = np.abs(want)
     nz = mag[mag > 0]
-    floor = float(np.median(nz)) if nz.size else 1.0
-    return float(np.max(np.abs(got - want) / np.maximum(mag, floor)))
+    mag = np.maximum(mag, float(np.median(nz)) if nz.size else 1.0)
+    if scale is not None:
+        mag = np.maximum(mag, np.asarray(scale, np.float64).ravel())
+    return np.abs(got - want) / mag
+
+
+def worst_gap(got, want, scale=None) -> float:
+    """The largest of ``element_gaps``; 0 where there is nothing to compare."""
+    import numpy as np
+
+    return float(np.max(element_gaps(got, want, scale))) if np.size(want) else 0.0
 
 
 def norm_gap(got, want) -> float:

@@ -20,6 +20,7 @@ T0 = time.perf_counter()  # process start, for setup_s
 
 import argparse
 import json
+import math
 from concurrent.futures import ThreadPoolExecutor
 import os
 import shutil
@@ -99,6 +100,11 @@ def report(ctx, rec, wanted, readers, devs, peaks, compiles) -> int:
     for row in rec["stamps"]:
         print("[stamp] " + json.dumps(row))
     print(f"[window] {json.dumps({k: v for k, v in win.items()})}")
+    if rec.get("timers_close"):
+        # seconds each named phase of the program took between the window's stamps:
+        # where a stall of the host went (fetch: the feed; retire: the chip; none: outside the loop)
+        spent = trace_report.timers_delta(rec.get("timers_open"), rec["timers_close"])
+        print("[timers] " + json.dumps({k: round(v["total_s"], 4) for k, v in sorted(spent.items()) if v["count"]}))
     for c in rec["checks"]:
         print(c.line())
     device = dev.describe(devs)
@@ -133,7 +139,13 @@ def report(ctx, rec, wanted, readers, devs, peaks, compiles) -> int:
         out["breakdown"] = breakdown
     out["compiles_in_window"] = compiles.count_between(win["t_open"], win["t_close"])
     out["compile_s"] = compiles.compile_seconds()
+    # each number compared beside its limit: last in the line, and the last lines of stderr
+    out["checks"] = {
+        c.name: {"value": c.value if math.isfinite(c.value) else repr(c.value), "limit": c.limit}
+        for c in rec["checks"]
+    }
     sys.stdout.flush()
+    print("\n".join(c.line() for c in rec["checks"]), file=sys.stderr, flush=True)
     print(json.dumps(out), flush=True)
     return 0
 

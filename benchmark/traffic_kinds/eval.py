@@ -54,12 +54,12 @@ def run(ctx, app) -> dict:
     attempted = n_held * len(results)
     scored = int(sum(work))
 
-    ref, ref_losses, held_ref = sess.reference("float32")
+    ref, ref_losses, ref_spans = sess.reference("float32")
     lim = t["limits"]
     aucs = np.array([r["auc"] for r in results])
     lls = np.array([r["logloss"] for r in results])
     got = app.Problem.eval_numbers(
-        first_probs[0], float(lls[-1]), float(aucs[-1]), app.heldout_scores(ref, held_ref)
+        first_probs[0], float(lls[-1]), float(aucs[-1]), app.heldout_scores(ref, ref_spans["heldout"])
     )
     checks = sess.prefix_checks(ref, ref_losses) + [
         Check(name, value, lim[name], note="the last pass against the reference's held-out scores")
@@ -83,7 +83,7 @@ def run(ctx, app) -> dict:
             "microsteps": win["units"] * (n_held // (sess.minibatch * sess.data_shards)),
             "data_shards": sess.data_shards,
             "kv_shards": sess.kv_shards,
-            "bucket_rows": sess.bucket_rows,
+            "bucket_rows": sum(sess.eval_slots) / len(sess.eval_slots),  # the last pass's calls
             "pushes_per_step": 0,
             "mode": "eval",
         },

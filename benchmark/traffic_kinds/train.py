@@ -9,6 +9,16 @@ or after ``--seconds``; the hook then stops the epoch. Examples retired
 between the two stamps over the time between them is ``ex_rate``: no
 partial call, no pipeline fill, no drain, no report inside.
 
+What decides ``correct`` compares states that had the same training, or
+can only get better with more of it, so that no number depends on how fast
+the program trains: the ``prefix.*`` gaps and
+``heldout.auc_below_reference`` hold the program's state right after the
+prefix against the reference's after the same prefix;
+``trained.auc_below_reference`` scores the table as the window left it on
+files the window trained, where every further pass of a sound program can
+only gain on the reference's state after the prefix, and a window that
+damaged the table falls under it.
+
 Parameters (the mix's JSON): ``train_files``/``heldout_files`` (files of
 ``steps_per_call x minibatch`` examples, cycled), ``prefix_calls``,
 ``warm_calls``, ``min_call_s`` (the shortest device call the file list is
@@ -18,7 +28,6 @@ sized for), ``limits``.
 from __future__ import annotations
 
 import math
-import time
 
 import numpy as np
 
@@ -34,7 +43,7 @@ def run(ctx, app) -> dict:
     t = ctx.traffic
     sess = app.Session(ctx)
     build_rate = sess.measure_build_rate()
-    sess.prefix()
+    sess.prefix(score_heldout=True)
 
     warm = int(t["warm_calls"])
     cap_calls = warm + math.ceil(ctx.seconds / float(t["min_call_s"])) + 1
@@ -74,23 +83,34 @@ def run(ctx, app) -> dict:
     ctx.stage("window closed")
     work = sess.call_work()
     win = window.summarize(stamps, work[: len(stamps)], open_at, ctx.seconds)
+    # unique-key slots a worker's microstep carried, over the window's calls
+    slots = sess.call_slots()[open_at + 1 : win["close_at"] + 1]
+    bucket_rows = sum(slots) / len(slots)
     losses, dev_examples = sess.call_outputs()  # every dispatched call, in flight ones too
     inside = range(open_at + 1, len(work))  # dispatched after the window opened
     attempted = int(sum(work[i] for i in inside))
     done = int(sum(dev_examples[i].sum() for i in inside if np.isfinite(losses[i]).all()))
     nonfinite = int(sum((~np.isfinite(l)).sum() for l in losses))
 
-    # after the window: held-out quality of the trained table, then the reference
-    ev = sess.evaluate(sess.heldout_paths)
-    ref, ref_losses, held = sess.reference("float32")
-    ref_auc, _, _ = app.heldout_scores(ref, held)
+    # after the window: the table as the window left it scores files it
+    # trained; then the reference, which has had the prefix and no more
+    ev = sess.evaluate(sess.trained_paths)
+    ref, ref_losses, scored = sess.reference("float32", score=("heldout", "trained"))
+    ref_auc = {k: app.heldout_scores(ref, s)[0] for k, s in scored.items()}
     lim = t["limits"]
     checks = sess.prefix_checks(ref, ref_losses) + [
         Check("window.nonfinite_losses", nonfinite, 0),
         Check("window.unretired_examples", attempted - done, 0),
         Check(
-            "heldout.auc_below_reference", ref_auc - float(ev["auc"]), lim["heldout.auc_below_reference"],
-            note=f"program after the window {ev['auc']:.4f}, reference after the prefix {ref_auc:.4f}",
+            "heldout.auc_below_reference", ref_auc["heldout"] - sess.heldout_auc,
+            lim["heldout.auc_below_reference"],
+            note=f"both after the prefix: program {sess.heldout_auc:.4f}, reference {ref_auc['heldout']:.4f}",
+        ),
+        Check(
+            "trained.auc_below_reference", ref_auc["trained"] - float(ev["auc"]),
+            lim["trained.auc_below_reference"],
+            note=f"on {len(sess.trained_paths)} training files: program after the window "
+                 f"{ev['auc']:.4f}, reference after the prefix {ref_auc['trained']:.4f}",
         ),
     ]
     ctx.stage("reference compared")
@@ -110,7 +130,7 @@ def run(ctx, app) -> dict:
             "microsteps": win["units"] * sess.steps_per_call,
             "data_shards": sess.data_shards,
             "kv_shards": sess.kv_shards,
-            "bucket_rows": sess.bucket_rows,
+            "bucket_rows": bucket_rows,
             "pushes_per_step": sess.data_shards,
             "mode": "train",
         },
