@@ -24,6 +24,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from parameter_server_tpu.utils import trace
 from parameter_server_tpu.utils.hashing import PAD_KEY, hash_keys
 
 
@@ -123,6 +124,8 @@ def pad_group(batches: list["CSRBatch"]) -> list["CSRBatch"]:
     group shapes stays small). Used before stacking D shards."""
     nnz_t = max(len(b.values) for b in batches)
     u_t = max(len(b.unique_keys) for b in batches)
+    for b in batches:  # real keys over the slots the device will work on
+        trace.counter("feed.unique_fill", b.num_unique / u_t)
     return [pad_batch(b, nnz_t, u_t) for b in batches]
 
 
@@ -334,7 +337,12 @@ class BatchBuilder:
 
         if self.bucket_nnz:
             nnz_cap = _nnz_bucket(nnz, self.nnz_capacity)
-            u_cap = min(nnz_cap + 1, self.unique_capacity, self.num_keys)
+            # the key axis gets a bucket of its own, by the keys the batch
+            # holds: repeated keys (Zipf traffic) leave far fewer keys than
+            # entries, and every slot is gathered, updated and scattered
+            u_cap = _nnz_bucket(
+                n_uniq, min(nnz_cap + 1, self.unique_capacity, self.num_keys)
+            )
         else:
             nnz_cap = self.nnz_capacity
             u_cap = self.unique_capacity

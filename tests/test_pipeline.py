@@ -194,7 +194,9 @@ class TestBucketedBatches:
         sz = len(big.values)
         assert sz >= n * 9 and sz & (sz - 1) == 0
         assert sz < b.nnz_capacity
-        assert len(big.unique_keys) == sz + 1
+        # the key axis has a bucket of its own: 10 keys (9 + PAD_KEY) in
+        # 8,100 entries take the floor, not entries + 1 slots
+        assert big.num_unique == 10 and len(big.unique_keys) == BUCKET_FLOOR
 
     def test_pad_batch_grows_only(self):
         from parameter_server_tpu.data.batch import BatchBuilder, pad_batch

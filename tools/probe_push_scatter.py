@@ -1,9 +1,10 @@
 """The table ops of a push alone, on the chip (step 0 of ISSUE 27; PERF.md
 section 6, PR 27): one slot, host clock, ten calls back to back, three sets.
-Scatter-add of one real bucket's 524,289 slots (one 8192-example batch of
-the benchmark's Criteo-shaped traffic from the seed, keys as BatchBuilder
-writes them) into f32[2^30,1], f32[100000768,1] and f32[100000768,16], and
-into f32[2^30,1] as either kv shard of a 2^31-key table: the scatter the step
+Scatter-add of one real bucket's key slots (one 8192-example batch of the
+benchmark's Criteo-shaped traffic from the seed, keys as BatchBuilder writes
+them: 65,536 slots since PR 31, 524,289 when PR 27's numbers were read) into
+f32[2^30,1], f32[100000768,1] and f32[100000768,16], and into f32[2^30,1] as
+either kv shard of a 2^31-key table: the scatter the step
 had up to PR 26 (rows clamped to 0, deltas masked) against the one it has
 (``spmd._ascending_rows`` + ``spmd._add_rows``); and ``jnp.take`` as the step
 calls it against sorted rows with ``mode="fill"``, on the first and the third.
@@ -22,7 +23,7 @@ from benchmark.harness import criteo
 from parameter_server_tpu.parallel import spmd
 
 SEED = int(sys.argv[1]) if len(sys.argv) > 1 else 2270000001
-U = (1 << 19) + 1
+U = 1 << 16  # BatchBuilder's bucket for a batch's about 40,000 keys
 print("device", jax.devices()[0].platform, jax.devices()[0].device_kind, flush=True)
 assert jax.devices()[0].platform == "tpu"
 spec = json.load(open("benchmark/configs/ctr_ftrl_1chip.json"))["data"]
@@ -33,6 +34,7 @@ def bucket_keys(num_keys):
     _, ints, cats = criteo.make_examples(SEED, 8192, spec)
     rows, _ = criteo.features(ints, cats, num_keys)
     uniq = np.unique(rows.ravel())
+    assert U // 2 < 1 + len(uniq) <= U, (len(uniq), U)  # the builder's bucket
     keys = np.zeros(U, np.int32)
     keys[1 : 1 + len(uniq)] = uniq
     return keys, 1 + len(uniq)
