@@ -1061,4 +1061,62 @@ int ps_parse_adfea(const char* buf, int64_t len,
   return 0;
 }
 
+// rating: "user item rating [more...]" (the matrix-factorization app's
+// triples; what follows the rating, a timestamp say, is dropped). One row
+// a line: the label is the rating AS READ (real-valued: the one format
+// whose label is not folded to 0/1), and two entries of value 1.0 in one
+// id space, the item first: key ``item`` and key ``num_items + user``,
+// which identity keying (+1 for the pad row) puts at table rows
+// 1..num_items and num_items+1..num_items+num_users. An item id at or past
+// ``num_items`` would name a user's row: a parse error, like a line that
+// does not start with two unsigned integers and a number.
+int ps_parse_rating(const char* buf, int64_t len,
+                    int64_t max_rows, int64_t max_nnz,
+                    float* labels, int64_t* row_splits,
+                    uint64_t* keys, float* vals, uint64_t* slots,
+                    int64_t* out_rows, int64_t* out_nnz, int64_t* err_line,
+                    uint64_t num_items) {
+  const char* p = buf;
+  const char* end = buf + len;
+  const bool any_cr = chunk_has_cr(buf, len);
+  int64_t rows = 0, nnz = 0, line = 0;
+  row_splits[0] = 0;
+  while (p < end) {
+    const char* next_line;
+    const char* line_end = find_line_end(p, end, &next_line, any_cr);
+    skip_ws(p, line_end);
+    if (p >= line_end) {  // blank line
+      p = next_line;
+      ++line;
+      continue;
+    }
+    if (rows >= max_rows || nnz + 2 > max_nnz) return -1;
+    uint64_t user = 0, item = 0;
+    bool ok = parse_u64(p, line_end, user);
+    skip_ws(p, line_end);
+    ok = ok && parse_u64(p, line_end, item) && item < num_items;
+    skip_ws(p, line_end);
+    const char* tok = p;
+    double y = ok ? parse_float(p, line_end) : 0.0;
+    // the rating must be a whole token (Python float() raises on junk)
+    if (!ok || p == tok || (p < line_end && *p != ' ' && *p != '\t')) {
+      *err_line = line;
+      return -2;
+    }
+    labels[rows] = static_cast<float>(y);
+    keys[nnz] = item;
+    keys[nnz + 1] = num_items + user;
+    vals[nnz] = vals[nnz + 1] = 1.0f;
+    if (slots) slots[nnz] = slots[nnz + 1] = 0;  // null for slotless callers
+    nnz += 2;
+    ++rows;
+    row_splits[rows] = nnz;
+    p = next_line;
+    ++line;
+  }
+  *out_rows = rows;
+  *out_nnz = nnz;
+  return 0;
+}
+
 }  // extern "C"

@@ -672,46 +672,42 @@ def _mesh_from_cfg(cfg: PSConfig):
 
 
 def _run_train_mf(cfg: PSConfig, args: argparse.Namespace) -> dict:
-    """matrix_fac app dispatch (ref: App::Create on the MF config)."""
+    """matrix_fac app dispatch (ref: App::Create on the MF config): the
+    ``PodTrainer`` the linear app and Wide&Deep run through, over the MF
+    app's description and ``user item rating`` files (``data.format``
+    "rating"; ``matrix_fac.pod_config`` puts [mf]'s settings where the
+    shared loop reads them)."""
     import numpy as np
 
-    from parameter_server_tpu.models.matrix_fac import MatrixFactorization
+    from parameter_server_tpu.models import matrix_fac
+    from parameter_server_tpu.parallel.trainer import PodTrainer
 
-    m = cfg.mf
-    app = MatrixFactorization(
-        m.num_users, m.num_items, rank=m.rank, eta=m.eta, l2=m.l2,
-        algo=m.algo, seed=cfg.seed, mesh=_mesh_from_cfg(cfg),
-        push_mode=cfg.parallel.push_mode,
-        max_delay=max(cfg.solver.max_delay, 0),
-        steps_per_call=cfg.solver.steps_per_call,
+    trainer = PodTrainer(matrix_fac.pod_config(cfg))
+    if args.resume:
+        if not args.ckpt_dir:
+            raise SystemExit("--resume requires --ckpt_dir")
+        trainer.load(args.ckpt_dir)
+    last = dict(
+        trainer.train_files(cfg.data.files, report_every=args.report_interval)
+        or {}
     )
-    rmse = app.train_files(
-        cfg.data.files, batch_size=m.batch_size,
-        epochs=max(1, cfg.solver.epochs), block_lines=m.block_lines,
-        seed=cfg.seed,
-    )
-    out: dict = {"train_rmse": rmse, "rank": m.rank}
+    if not trainer.examples_seen:
+        # a perfect 0.0 RMSE over zero parsed triples must never be reported
+        raise SystemExit(
+            f"no rating triples parsed from {cfg.data.files}: expected "
+            "whitespace-separated 'user item rating' lines"
+        )
+    out: dict = {**last, "rank": cfg.mf.rank}
+    if "objv" in last:  # the last report's mean squared error
+        out["train_rmse"] = float(np.sqrt(last["objv"]))
+    if args.ckpt_dir:
+        trainer.save(args.ckpt_dir)
     if cfg.data.val_files:
-        from parameter_server_tpu.models.matrix_fac import iter_rating_blocks
-
-        sse, n = 0.0, 0
-        for us, it, rt in iter_rating_blocks(cfg.data.val_files, m.block_lines):
-            p = app.predict(us, it)
-            sse += float(((p - rt) ** 2).sum())
-            n += len(rt)
-        if n == 0:
-            # mirror train_files: a perfect 0.0 RMSE over zero parsed
-            # triples must never be reported
-            raise SystemExit(
-                f"no rating triples parsed from val_files "
-                f"{cfg.data.val_files}: expected 'user item rating' lines"
-            )
-        out["val_rmse"] = float(np.sqrt(sse / n))
-        out["val_examples"] = n
+        ev = trainer.evaluate_files(cfg.data.val_files)
+        out.update({f"val_{k}": v for k, v in ev.items()})
     if args.model_out:
-        U = np.asarray(app.user_up.weights(app.user_state))
-        V = np.asarray(app.item_up.weights(app.item_state))
-        np.savez(args.model_out, user_factors=U, item_factors=V)
+        user_f, item_f = matrix_fac.factors(trainer)
+        np.savez(args.model_out, user_factors=user_f, item_factors=item_f)
         out["model_out"] = args.model_out
     return out
 

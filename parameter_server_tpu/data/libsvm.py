@@ -116,10 +116,69 @@ def iter_adfea(path: str | Path) -> Iterator[Row]:
             yield label, keys, np.ones(n, dtype=np.float32), slots
 
 
+def iter_rating(path: str | Path, num_items: int) -> Iterator[Row]:
+    """Parse ``user item rating [more...]`` lines, the matrix-factorization
+    app's triples (ids from 0; what follows the rating, a timestamp say, is
+    dropped). The label is the rating as read - the one format whose label
+    is real-valued - and a row holds two entries of value 1 in one id
+    space, the item first: key ``item`` and key ``num_items + user``, so
+    that identity keying (+1 for the pad row) puts items at table rows
+    1..num_items and users behind them. A line that does not start with
+    two unsigned integers and a number, or an item id at or past
+    ``num_items`` (it would name a user's row), raises ValueError."""
+    with _open(path) as f:
+        for n, line in enumerate(f):
+            parts = line.split()
+            if not parts:
+                continue
+            try:
+                user, item, rating = int(parts[0]), int(parts[1]), float(parts[2])
+                ok = user >= 0 and 0 <= item < num_items
+            except (ValueError, IndexError):
+                ok = False
+            if not ok:
+                raise ValueError(f"parse error at line {n} of {path} (rating)")
+            yield (
+                rating,
+                np.array([item, num_items + user], dtype=np.uint64),
+                np.ones(2, dtype=np.float32),
+                np.zeros(2, dtype=np.uint64),
+            )
+
+
 FORMATS = {"libsvm": iter_libsvm, "criteo": iter_criteo, "adfea": iter_adfea}
+
+# ``user item rating`` lines: the readers take it as "rating:<num_items>",
+# because a user's key lies behind the items' (``rating_format``); its keys
+# are ids of a dense space (``BatchBuilder``'s key_mode "identity"), not
+# features to hash.
+RATING = "rating"
+
+
+def rating_format(num_items: int) -> str:
+    return f"{RATING}:{int(num_items)}"
+
+
+def split_format(fmt: str) -> tuple[str, int | None]:
+    """(format name, its parameter or None): ("rating", 39780) of
+    "rating:39780", ("criteo", None) of "criteo"."""
+    name, _, arg = fmt.partition(":")
+    if name == RATING:
+        if not arg.isdigit():
+            raise ValueError(
+                f"the {RATING!r} format is read as 'rating:<num_items>' "
+                f"(data.libsvm.rating_format), got {fmt!r}"
+            )
+        return name, int(arg)
+    return fmt, None
 
 
 def iter_format(fmt: str, path: str | Path) -> Iterator[Row]:
-    if fmt not in FORMATS:
-        raise ValueError(f"unknown data format {fmt!r}; known: {sorted(FORMATS)}")
-    return FORMATS[fmt](path)
+    name, arg = split_format(fmt)
+    if name == RATING:
+        return iter_rating(path, arg)
+    if name not in FORMATS:
+        raise ValueError(
+            f"unknown data format {fmt!r}; known: {sorted([*FORMATS, RATING])}"
+        )
+    return FORMATS[name](path)

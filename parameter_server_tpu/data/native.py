@@ -27,6 +27,8 @@ from pathlib import Path
 
 import numpy as np
 
+from parameter_server_tpu.data.libsvm import split_format
+
 _NATIVE_DIR = Path(__file__).resolve().parent.parent.parent / "native"
 _LIB_ENV = "PS_TPU_NATIVE_LIB"
 
@@ -40,7 +42,15 @@ NATIVE_FORMATS = {
     "libsvm": "ps_parse_libsvm",
     "criteo": "ps_parse_criteo",
     "adfea": "ps_parse_adfea",
+    # read as "rating:<num_items>" (data.libsvm.split_format): the C parser
+    # takes the item count as a thirteenth argument
+    "rating": "ps_parse_rating",
 }
+
+
+def has_native(fmt: str) -> bool:
+    """Whether ``fmt`` (a name, or "rating:<num_items>") has a C parser."""
+    return fmt.partition(":")[0] in NATIVE_FORMATS
 
 _lib: ctypes.CDLL | None = None
 _lib_tried = False
@@ -110,8 +120,10 @@ def load_native() -> ctypes.CDLL | None:
         return None
     i64, u64p = ctypes.c_int64, ctypes.POINTER(ctypes.c_uint64)
     f32p, i64p = ctypes.POINTER(ctypes.c_float), ctypes.POINTER(ctypes.c_int64)
-    for fn in NATIVE_FORMATS.values():
-        f = getattr(lib, fn)
+    for name, fn in NATIVE_FORMATS.items():
+        f = getattr(lib, fn, None)
+        if f is None:
+            continue  # older prebuilt artifact: _parse_region says so
         f.restype = ctypes.c_int
         f.argtypes = [
             ctypes.c_char_p, i64,  # buf, len
@@ -120,6 +132,8 @@ def load_native() -> ctypes.CDLL | None:
             u64p, f32p, u64p,  # keys, vals, slots
             i64p, i64p, i64p,  # out_rows, out_nnz, err_line
         ]
+        if name == "rating":
+            f.argtypes = [*f.argtypes, ctypes.c_uint64]  # num_items
     try:
         c4 = lib.ps_count4
         c4.restype = None
@@ -198,7 +212,7 @@ def hash_localize(
 # zeros, so the wrapper returns None instead of copying megabytes of
 # zeros per chunk — downstream (BatchBuilder.build_flat) treats None as
 # salt 0, which hashes identically.
-SLOTLESS_FORMATS = frozenset({"libsvm"})
+SLOTLESS_FORMATS = frozenset({"libsvm", "rating"})
 
 # readable slack the C parsers may overread past the parse length (the
 # AVX2 span parsers issue one unguarded 8-byte load per token)
@@ -208,7 +222,9 @@ _PAD = 8
 # fourth needle per format for ps_count4 (first three are \n, \r, and the
 # format's entry marker); counts[3] refines the entry bound for libsvm
 # (space-preceded bare ``k`` entries) and adfea (ws-preceded entries)
-_COUNT_NEEDLES = {"libsvm": b": ", "criteo": b"\t\0", "adfea": b" \t"}
+_COUNT_NEEDLES = {
+    "libsvm": b": ", "criteo": b"\t\0", "adfea": b" \t", "rating": b" \t",
+}
 
 
 def _counts(lib, fmt: str, ba: bytearray, length: int) -> tuple[int, int]:
@@ -241,6 +257,8 @@ def _counts(lib, fmt: str, ba: bytearray, length: int) -> tuple[int, int]:
         nnz_cap = max(out[2], out[3]) + 1
     elif fmt == "criteo":
         nnz_cap = 39 * rows_cap + 1  # hard bound: <= 39 features per row
+    elif fmt == "rating":
+        nnz_cap = 2 * rows_cap  # exactly two entries a row
     else:  # adfea: every entry is preceded by at least one ws byte
         nnz_cap = out[2] + out[3] + 1
     return rows_cap, nnz_cap
@@ -254,9 +272,13 @@ def _parse_region(fmt: str, ba: bytearray, length: int) -> FlatRows:
     lib = load_native()
     if lib is None:
         raise RuntimeError("native parser not available")
+    fmt, arg = split_format(fmt)
     if fmt not in NATIVE_FORMATS:
         raise ValueError(f"native parser: unknown format {fmt!r}")
-    fn = getattr(lib, NATIVE_FORMATS[fmt])
+    fn = getattr(lib, NATIVE_FORMATS[fmt], None)
+    if fn is None:
+        raise RuntimeError(f"the native library has no {NATIVE_FORMATS[fmt]}")
+    extra = () if arg is None else (ctypes.c_uint64(arg),)
     rows_cap, nnz_cap = _counts(lib, fmt, ba, length)
     want_slots = fmt not in SLOTLESS_FORMATS
     buf_p = (ctypes.c_char * len(ba)).from_buffer(ba)
@@ -286,6 +308,7 @@ def _parse_region(fmt: str, ba: bytearray, length: int) -> FlatRows:
             ctypes.byref(out_rows),
             ctypes.byref(out_nnz),
             ctypes.byref(err_line),
+            *extra,
         )
         if rc == -1:
             # nnz bound undershoot (bare-key libsvm): rows_cap is exact

@@ -51,7 +51,7 @@ class MinibatchReader:
 
         self.use_native = backend == "native" or (
             backend == "auto"
-            and fmt in _native.NATIVE_FORMATS
+            and _native.has_native(fmt)
             and _native.native_available()
         )
         if backend == "native" and not _native.native_available():
@@ -199,6 +199,19 @@ class MinibatchReader:
             stop.set()
 
 
+def ingest_of(cfg) -> tuple[str, str]:
+    """(format, key mode) that the readers and ``BatchBuilder`` take for a
+    PSConfig's files. ``data.format`` decides both: ``rating`` lines carry
+    ids of one dense space, items first (``[mf].num_items`` says where the
+    users' begin), and are keyed by identity; every other format carries
+    features, hashed into ``data.num_keys``."""
+    from parameter_server_tpu.data.libsvm import RATING, rating_format
+
+    if cfg.data.format == RATING:
+        return rating_format(cfg.mf.num_items), "identity"
+    return cfg.data.format, "hash"
+
+
 def iter_flat_rows(files: list[str | Path], fmt: str):
     """Yield flat CSR chunks ``(labels, row_splits, keys, vals, slots)`` from
     text files — the raw-key stream consumed by ingest-side components that
@@ -209,7 +222,7 @@ def iter_flat_rows(files: list[str | Path], fmt: str):
     from parameter_server_tpu.data import native as _native
 
     paths = sorted(map(str, files))
-    if fmt in _native.NATIVE_FORMATS and _native.native_available():
+    if _native.has_native(fmt) and _native.native_available():
         for f in paths:
             yield from _native.iter_chunks(f, fmt)
         return
@@ -231,7 +244,7 @@ def iter_flat_rows(files: list[str | Path], fmt: str):
                 np.concatenate(vals) if vals else np.zeros(0, np.float32),
                 (
                     None
-                    if fmt in _native.SLOTLESS_FORMATS
+                    if fmt.partition(":")[0] in _native.SLOTLESS_FORMATS
                     else np.concatenate(slots)
                     if slots
                     else np.zeros(0, np.uint64)

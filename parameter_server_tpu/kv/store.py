@@ -64,24 +64,29 @@ def _fmix32(x: jax.Array) -> jax.Array:
     return x ^ (x >> 16)
 
 
-def hashed_uniform(
-    seed: int, rows: jax.Array, vdim: int, scale: float, live_rows: int
-) -> jax.Array:
-    """Starting values of an embedding table as a function of (seed, row,
-    lane) alone: (len(rows), vdim) float32, uniform with standard deviation
-    ``scale``, exactly zero for the pad row 0 and for rows at or past
-    ``live_rows`` (the kv-axis pad tail). A counter-based draw - two rounds
-    of a 32-bit mix over the row and the lane, the top 24 bits to
-    [-1, 1) - so that the table is made on the device slice by slice, no
-    host array of its size ever exists, and anything that knows the seed
-    can compute any row (the benchmark's reference does, bit for bit:
-    every step is exact in uint32 and float32 but the last product, which
-    IEEE rounds one way)."""
+def hashed_unit(seed: int, rows: jax.Array, vdim: int) -> jax.Array:
+    """(len(rows), vdim) float32 in [-1, 1) as a function of (seed, row,
+    lane) alone: a counter-based draw - two rounds of a 32-bit mix over the
+    row and the lane, the top 24 bits as a multiple of 2^-23 - so that a
+    table is made on the device slice by slice, no host array of its size
+    ever exists, and anything that knows the seed can compute any row
+    (the benchmark's references do, bit for bit: every step is exact in
+    uint32 and float32)."""
     r = rows.astype(jnp.uint32)[:, None]
     lane = jnp.arange(vdim, dtype=jnp.uint32)[None, :]
     x = _fmix32(r * jnp.uint32(0x9E3779B1) + jnp.uint32(seed & 0xFFFFFFFF))
     x = _fmix32(x ^ (lane * jnp.uint32(0x85EBCA77) + jnp.uint32(0xC2B2AE3D)))
-    unit = (x >> 8).astype(jnp.float32) * jnp.float32(2.0**-23) - jnp.float32(1.0)
+    return (x >> 8).astype(jnp.float32) * jnp.float32(2.0**-23) - jnp.float32(1.0)
+
+
+def hashed_uniform(
+    seed: int, rows: jax.Array, vdim: int, scale: float, live_rows: int
+) -> jax.Array:
+    """Starting values of an embedding table: ``hashed_unit`` scaled to a
+    uniform of standard deviation ``scale`` (one product, which IEEE rounds
+    one way), exactly zero for the pad row 0 and for rows at or past
+    ``live_rows`` (the kv-axis pad tail)."""
+    unit = hashed_unit(seed, rows, vdim)
     live = (rows > 0) & (rows < live_rows)
     return jnp.where(live[:, None], unit * jnp.float32(scale * 3.0**0.5), 0.0)
 

@@ -29,3 +29,16 @@ def logloss(labels: np.ndarray, probs: np.ndarray, eps: float = 1e-12) -> float:
     y = np.asarray(labels, dtype=np.float64)
     p = np.clip(np.asarray(probs, dtype=np.float64), eps, 1 - eps)
     return float(-(y * np.log(p) + (1 - y) * np.log(1 - p)).mean())
+
+
+def rmse(labels: np.ndarray, predictions: np.ndarray) -> float:
+    """Root mean squared error of real-valued predictions."""
+    d = np.asarray(predictions, dtype=np.float64) - np.asarray(labels, dtype=np.float64)
+    return float(np.sqrt(np.mean(d * d)))
+
+
+# What an app's description (``parallel.spmd.StepApp.score``) names as its
+# evaluator's scores, each a function of (labels, predictions); the first is
+# also the progress table's column.
+BINARY_SCORES = (("auc", auc), ("logloss", logloss))
+REGRESSION_SCORES = (("rmse", rmse),)

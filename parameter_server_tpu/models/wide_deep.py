@@ -31,6 +31,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 from parameter_server_tpu.data.batch import CSRBatch
 from parameter_server_tpu.kv.store import State, hashed_uniform
 from parameter_server_tpu.kv.updaters import Adagrad, Ftrl, Updater
+from parameter_server_tpu.models.metrics import BINARY_SCORES
 from parameter_server_tpu.ops.sparse import csr_logits
 from parameter_server_tpu.parallel.spmd import (
     DenseGroup,
@@ -136,6 +137,8 @@ def wide_deep_app(
         grad=_grad,
         logits=_logits,
         dense=DenseGroup("mlp", mlp_init, opt),
+        link=jax.nn.sigmoid,
+        score=BINARY_SCORES,
     )
 
 

@@ -40,6 +40,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from parameter_server_tpu.data.batch import CSRBatch
 from parameter_server_tpu.kv.updaters import Updater
+from parameter_server_tpu.models import metrics as M
 from parameter_server_tpu.ops.sparse import csr_grad, csr_logits, logistic_loss
 from parameter_server_tpu.utils.hashing import PAD_KEY
 
@@ -277,10 +278,17 @@ class StepApp:
     its replicated dense group if it has one, and the two functions of the
     pulled rows that are the model. ``grad(pulled, dense, b, row_ids)`` ->
     (summed loss, logits (B,), {table name: (U, vdim) gradient of the
-    pulled rows}, the dense parameters' gradient or None);
-    ``logits(pulled, dense, b, row_ids)`` -> (B,). ``pulled`` maps a table's
-    name to its (U, vdim) weights for ``b["unique_keys"]``; every table is
-    addressed by the batch's one key set (an app hashes one key space).
+    pulled rows}, the dense parameters' gradient or None): the loss is the
+    app's own (logistic for the CTR apps, squared error for matrix
+    factorization); ``logits(pulled, dense, b, row_ids)`` -> (B,).
+    ``pulled`` maps a table's name to its (U, vdim) weights for
+    ``b["unique_keys"]``; every table is addressed by the batch's one key
+    set (an app has one key space).
+
+    ``link`` turns the logits into what the step and the predict program
+    return as ``probs`` (the sigmoid of a logistic model, the identity of a
+    regression); ``score`` names the evaluator's scores of (labels,
+    predictions), the first of them also the progress table's column.
 
     The state of an app is one flat ``{name: array}`` dict: each table's
     slots (range-sharded over "kv") and the dense group's leaves
@@ -290,6 +298,8 @@ class StepApp:
     grad: Callable
     logits: Callable
     dense: DenseGroup | None = None
+    link: Callable = dataclasses.field(kw_only=True)
+    score: tuple[tuple[str, Callable], ...] = dataclasses.field(kw_only=True)
 
     def table(self, name: str) -> Table:
         (t,) = [t for t in self.tables if t.name == name]
@@ -341,7 +351,10 @@ def _linear_grad(pulled, dense, b: Batch, row_ids: jax.Array):
 def linear_app(updater: Updater) -> StepApp:
     """Sparse logistic regression over one unnamed ``vdim`` 1 table, the
     gradient hand-written (``ops.sparse.csr_grad``): the flagship."""
-    return StepApp((Table("", updater, 1),), _linear_grad, _linear_logits)
+    return StepApp(
+        (Table("", updater, 1),), _linear_grad, _linear_logits,
+        link=jax.nn.sigmoid, score=M.BINARY_SCORES,
+    )
 
 
 def _as_app(app: "StepApp | Updater") -> StepApp:
@@ -462,6 +475,36 @@ def _values_of(b: Batch) -> jax.Array:
     return v.astype(jnp.float32) if v.dtype != jnp.float32 else v
 
 
+# XLA's TPU gather reads a (K, vdim) table where it lies (rows minor,
+# unpadded: what the scatter works on) for rows of up to 32 lanes. A gather
+# of whole rows from a table of 64 lanes wants row-major (8, 128) tiles,
+# which pad 64 lanes to 128, and gets them by a copy of the WHOLE table,
+# twice its bytes, every microstep: a table that fills the chip does not
+# compile (PERF.md section 6, PR 32)
+_ROW_GATHER_LANES = 32
+
+
+def _take_rows(v: jax.Array, rows: jax.Array) -> jax.Array:
+    """``v[rows]`` for a (K, vdim) table slot. Rows of up to
+    ``_ROW_GATHER_LANES`` lanes: ``jnp.take``, one slice a row. Wider rows:
+    one gather of single elements, its index naming (row, lane), which XLA
+    leaves the table's layout alone for. ``rows`` are in range (the callers
+    clamp them)."""
+    vdim = v.shape[1]
+    if vdim <= _ROW_GATHER_LANES:
+        return jnp.take(v, rows, axis=0)
+    lanes = jnp.arange(vdim, dtype=rows.dtype)
+    at = jnp.stack(jnp.broadcast_arrays(rows[:, None], lanes[None, :]), axis=-1)
+    return lax.gather(  # (U, vdim)
+        v, at,
+        lax.GatherDimensionNumbers(
+            offset_dims=(), collapsed_slice_dims=(0, 1), start_index_map=(0, 1)
+        ),
+        slice_sizes=(1, 1),
+        mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS,
+    )
+
+
 def _local_pull(
     updater: Updater, state_l: State, idx: jax.Array, shard_size: int,
     table: str = "",
@@ -474,7 +517,7 @@ def _local_pull(
     in_range = (local >= 0) & (local < shard_size)
     safe = jnp.where(in_range, local, 0)
     with _sub_scope(table):
-        rows = {k: jnp.take(v, safe, axis=0) for k, v in state_l.items()}
+        rows = {k: _take_rows(v, safe) for k, v in state_l.items()}
         w = updater.weights(rows)
         return jnp.where(in_range[:, None], w, 0.0)
 
@@ -541,7 +584,7 @@ def _local_push(
         in_range = (local >= 0) & (local < shard_size)
         safe = jnp.where(in_range, local, 0)
         with jax.named_scope("gather"), _sub_scope(table):
-            rows = {k: jnp.take(v, safe, axis=0) for k, v in state_l.items()}
+            rows = {k: _take_rows(v, safe) for k, v in state_l.items()}
         with jax.named_scope("update"), _sub_scope(table):
             deltas = updater.delta(rows, g)
         with jax.named_scope("scatter"), _sub_scope(table):
@@ -747,7 +790,7 @@ def _microstep(
         }
     with jax.named_scope("ps.grad"):
         loss, logits, grads, g_dense = app.grad(pulled, dense[0], b, row_ids)
-        probs = jax.nn.sigmoid(logits)
+        probs = app.link(logits)
     new_state = dict(state_l)
     with jax.named_scope("ps.push"):
         for i, t in enumerate(app.tables):
@@ -800,7 +843,8 @@ def make_spmd_train_step(
       "loss_sum" — scalar, psum over data
       "examples" — scalar pod-wide real-example count (the host-side
           termination signal; see PodTrainer's drained contract)
-      "probs"    — (D, B) per-shard probabilities
+      "probs"    — (D, B) per-shard predictions (``app.link`` of the
+          logits: probabilities for a logistic app)
 
     ``batch["unique_keys"]`` obeys the padding contract of ``data.batch``
     on every shard: slot 0 ``PAD_KEY``, then strictly ascending keys, then
@@ -939,7 +983,7 @@ def make_spmd_predict_step(app: "StepApp | Updater", mesh: Mesh, num_keys: int):
             }
         with jax.named_scope("ps.grad"):
             logits = app.logits(pulled, dense, b, row_ids)
-            return jax.nn.sigmoid(logits)[None, :]
+            return app.link(logits)[None, :]
 
     step = shard_map(
         local_predict,

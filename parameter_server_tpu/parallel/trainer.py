@@ -30,8 +30,7 @@ import numpy as np
 
 from parameter_server_tpu.data.batch import BatchBuilder, CSRBatch, inert_like
 from parameter_server_tpu.data.pipeline import PrefetchPipeline
-from parameter_server_tpu.data.reader import MinibatchReader
-from parameter_server_tpu.models import metrics as M
+from parameter_server_tpu.data.reader import MinibatchReader, ingest_of
 from parameter_server_tpu.models.linear import updater_from_config
 from parameter_server_tpu.parallel.mesh import make_mesh
 from parameter_server_tpu.parallel.runtime import Runtime
@@ -142,6 +141,10 @@ def app_from_config(cfg: PSConfig) -> StepApp:
         from parameter_server_tpu.models import wide_deep
 
         return wide_deep.app_from_config(cfg)
+    if cfg.app == "matrix_fac":
+        from parameter_server_tpu.models import matrix_fac
+
+        return matrix_fac.app_from_config(cfg)
     return linear_app(updater_from_config(cfg))
 
 
@@ -168,10 +171,11 @@ class _EpochStream(_WorkerStream):
 
 
 class PodTrainer:
-    """Train ``cfg.app`` (the flagship sparse-LR app, or Wide&Deep) across
-    a data x kv device mesh: state, step, predict and checkpoint all come
-    from the app's description (``app_from_config``; ``app`` overrides
-    it)."""
+    """Train ``cfg.app`` (the flagship sparse-LR app, Wide&Deep or matrix
+    factorization) across a data x kv device mesh: state, step, predict,
+    scores and checkpoint all come from the app's description
+    (``app_from_config``; ``app`` overrides it), the files' format and key
+    mode from ``cfg.data.format`` (``data.reader.ingest_of``)."""
 
     def __init__(
         self,
@@ -284,6 +288,8 @@ class PodTrainer:
         # this process feeds only its own data rows (multi-host contract)
         self.local_data_shards = self.runtime.local_data_shards
         self.app = app if app is not None else app_from_config(cfg)
+        # how the files are read and keyed: ``data.format`` says both
+        self._format, self._key_mode = ingest_of(cfg)
         self.updater = self.app.tables[0].updater
         # K microsteps scanned per device call (see SolverConfig.steps_per
         # _call): amortizes the per-call host->device round-trip floor
@@ -344,18 +350,20 @@ class PodTrainer:
         # trainer.new_shapes / eval.new_shapes)
         self._dispatched_shapes: set[tuple[str, int, int]] = set()
 
-    def _builder(self, key_mode: str) -> BatchBuilder:
+    def _builder(self, key_mode: str | None) -> BatchBuilder:
         from parameter_server_tpu.data.batch import training_builder
 
-        return training_builder(self.cfg, key_mode)
+        return training_builder(self.cfg, key_mode or self._key_mode)
 
     def train_files(
         self,
         files: list[str],
-        key_mode: str = "hash",
+        key_mode: str | None = None,
         report_every: int = 20,
     ) -> dict:
-        """Run all epochs over ``files`` sharded across workers."""
+        """Run all epochs over ``files`` sharded across workers.
+        ``key_mode``: "hash" or "identity"; without it, what the files'
+        format says (``ingest_of``)."""
         with self._trace_cm():
             return self._run_epochs(files, key_mode, report_every)
 
@@ -398,7 +406,7 @@ class PodTrainer:
         self,
         files: list[str],
         coordinator: str,
-        key_mode: str = "hash",
+        key_mode: str | None = None,
         report_every: int = 20,
     ) -> dict:
         """Compose the two multi-process tiers (SURVEY §2.8/§5.8): the TCP
@@ -450,7 +458,7 @@ class PodTrainer:
             streams = [
                 _EpochStream(
                     self.runtime.process_index * self.local_data_shards + w,
-                    pool, cfg.data.format, self._builder(key_mode),
+                    pool, self._format, self._builder(key_mode),
                 )
                 for w in range(self.local_data_shards)
             ]
@@ -474,7 +482,7 @@ class PodTrainer:
             # upstream would double-shard and silently drop files)
             pool = WorkloadPool(self.runtime.shard_files(files))
             streams = [
-                _WorkerStream(w, pool, cfg.data.format, self._builder(key_mode))
+                _WorkerStream(w, pool, self._format, self._builder(key_mode))
                 for w in range(self.local_data_shards)
             ]
             last = self._train_epoch(streams, report_every) or last
@@ -720,10 +728,11 @@ class PodTrainer:
             ys.append(labels)
         y = np.concatenate(ys) if ys else np.zeros(0)
         p = np.concatenate(ps) if ps else np.zeros(0)
+        name, score = self.app.score[0]  # "auc" for the logistic apps
         return self.reporter.report(
             examples=self.examples_seen,
             objv=losses / max(n_since, 1),
-            auc=M.auc(y, p) if len(y) else float("nan"),
+            **{name: score(y, p) if len(y) else float("nan")},
             ex_per_sec=n_since / max(time.perf_counter() - t0, 1e-9),
             ssp=self.clock.progress(),
         )
@@ -821,15 +830,15 @@ class PodTrainer:
         self.examples_seen = int(meta.get("examples_seen", 0))
         return meta
 
-    def evaluate_files(self, files: list[str], key_mode: str = "hash") -> dict:
+    def evaluate_files(self, files: list[str], key_mode: str | None = None) -> dict:
         """Pod-wide batch evaluation using the predict step on shard 0's
         stream layout (eval is read-only; one worker suffices).
 
         Host phases (``trace.phase``): ``eval.open`` from entry to the
         return of the first predict call (builder, reader, first parse +
         build, stack, H2D, enqueue), ``eval.dispatch`` / ``eval.retire``
-        for each later call, ``eval.score`` for the AUC and logloss over
-        the pass; the device idles in the first and the last.
+        for each later call, ``eval.score`` for the app's scores (AUC
+        and logloss, or RMSE) over the pass; the device idles in the first and the last.
         ``eval.new_shapes`` times the first predict call of a bucket shape
         (the compile), which lies inside ``eval.open`` or ``eval.dispatch``."""
         if self.runtime.process_count > 1:
@@ -852,13 +861,13 @@ class PodTrainer:
                 self.cfg.data.num_keys,
                 batch_size=self.cfg.solver.minibatch,
                 max_nnz_per_example=self.cfg.data.max_nnz_per_example,
-                key_mode=key_mode,
+                key_mode=key_mode or self._key_mode,
             )
         from parameter_server_tpu.data.batch import eval_builder
 
         def open_reader():
-            builder = eval_builder(self.cfg, key_mode)
-            reader = MinibatchReader(files, self.cfg.data.format, builder)
+            builder = eval_builder(self.cfg, key_mode or self._key_mode)
+            reader = MinibatchReader(files, self._format, builder)
             return iter(reader), lambda: _pad_like(builder)
 
         with self._trace_cm():
@@ -873,13 +882,12 @@ class PodTrainer:
     def evaluate_batches(self, batches) -> dict:
         return self._score(*self._predict_pass(_opener(batches)))
 
-    @staticmethod
-    def _score(ys: list, ps: list) -> dict:
+    def _score(self, ys: list, ps: list) -> dict:
         with trace.phase("eval.score"):
             y = np.concatenate(ys)
             p = np.concatenate(ps)
             return {
-                "auc": M.auc(y, p), "logloss": M.logloss(y, p),
+                **{name: score(y, p) for name, score in self.app.score},
                 "examples": len(y),
             }
 

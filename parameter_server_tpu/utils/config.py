@@ -21,7 +21,7 @@ class DataConfig:
     """Ref: linear_method.proto DataConfig {format, file, ignore_feature_group}."""
 
     files: list[str] = field(default_factory=list)
-    format: str = "libsvm"  # libsvm | criteo | adfea | cache
+    format: str = "libsvm"  # libsvm | criteo | adfea | rating | cache
     num_keys: int = 1 << 22  # dense hashed key-space size (power of two + pad row)
     val_files: list[str] = field(default_factory=list)
     max_nnz_per_example: int = 512
@@ -109,7 +109,9 @@ class GraphConfig:
 @dataclass
 class MFConfig:
     """matrix_fac app settings (ref: the MF app's config; BASELINE's
-    MovieLens parity config). data.files = 'user item rating' text."""
+    MovieLens parity config). data.files = 'user item rating' text
+    (data.format "rating": models.matrix_fac.pod_config sets it, and
+    data.num_keys = 1 + num_items + num_users, for PodTrainer)."""
 
     num_users: int = 1000
     num_items: int = 1000
@@ -118,7 +120,6 @@ class MFConfig:
     l2: float = 0.01
     algo: str = "adagrad"  # adagrad | sgd
     batch_size: int = 4096
-    block_lines: int = 1 << 20  # streaming shuffle-block size
 
 
 @dataclass

@@ -1,4 +1,5 @@
-"""Tests for the MF, word2vec, and Wide&Deep apps.
+"""Tests for the word2vec and Wide&Deep apps (matrix factorization:
+tests/test_matrix_fac_pod.py).
 
 Reference test analog: each parity config in BASELINE.json gets a
 small-scale convergence check against task-appropriate baselines."""
@@ -8,10 +9,6 @@ import pytest
 
 from parameter_server_tpu.data.batch import BatchBuilder
 from parameter_server_tpu.models import metrics as M
-from parameter_server_tpu.models.matrix_fac import (
-    MatrixFactorization,
-    MFBatchBuilder,
-)
 from parameter_server_tpu.models.wide_deep import WideDeep
 from parameter_server_tpu.models.word2vec import NegativeSampler, Word2Vec
 from parameter_server_tpu.utils.metrics import ProgressReporter
@@ -19,49 +16,6 @@ from parameter_server_tpu.utils.metrics import ProgressReporter
 
 def quiet():
     return ProgressReporter(print_fn=lambda *_: None)
-
-
-def make_ratings(n_users=200, n_items=100, rank=4, n_obs=8000, noise=0.05, seed=0):
-    rng = np.random.default_rng(seed)
-    U = rng.normal(scale=1.0 / np.sqrt(rank), size=(n_users, rank))
-    V = rng.normal(scale=1.0 / np.sqrt(rank), size=(n_items, rank))
-    users = rng.integers(0, n_users, n_obs)
-    items = rng.integers(0, n_items, n_obs)
-    r = np.sum(U[users] * V[items], axis=1) + noise * rng.normal(size=n_obs)
-    return users, items, r.astype(np.float32)
-
-
-class TestMatrixFactorization:
-    def test_recovers_low_rank_structure(self):
-        users, items, r = make_ratings()
-        n_tr = 7000
-        mf = MatrixFactorization(
-            200, 100, rank=8, eta=0.1, l2=0.002, reporter=quiet(), seed=1
-        )
-        rmse0 = mf.rmse(users[n_tr:], items[n_tr:], r[n_tr:])
-        for ep in range(30):
-            mf.train_epoch(users[:n_tr], items[:n_tr], r[:n_tr], seed=ep)
-        rmse = mf.rmse(users[n_tr:], items[n_tr:], r[n_tr:])
-        assert rmse < rmse0 * 0.5, (rmse0, rmse)
-        assert rmse < 0.25, rmse  # close to the noise floor
-
-    def test_duplicate_pairs_in_batch(self):
-        mf = MatrixFactorization(4, 4, rank=2, reporter=quiet())
-        users = np.array([1, 1, 1, 2])
-        items = np.array([0, 0, 1, 1])
-        r = np.ones(4, dtype=np.float32)
-        for _ in range(5):
-            mf.train_epoch(users, items, r, batch_size=4)
-        assert np.isfinite(mf.predict(users, items)).all()
-
-    def test_builder_capacity(self):
-        b = MFBatchBuilder(batch_size=2)
-        with pytest.raises(ValueError, match="pairs"):
-            b.build(np.arange(3), np.arange(3), np.ones(3, dtype=np.float32))
-
-    def test_bad_algo(self):
-        with pytest.raises(ValueError, match="mf algo"):
-            MatrixFactorization(4, 4, algo="ftrl")
 
 
 class TestWord2Vec:
@@ -245,57 +199,3 @@ class TestWord2VecStreaming:
         within = np.mean([w2v.similarity(0, i) for i in range(1, 5)])
         across = np.mean([w2v.similarity(0, i) for i in range(5, 10)])
         assert within > across + 0.3, (within, across)
-
-
-class TestMatrixFactorizationFiles:
-    """File-driven MF (ref: the reference MF app consumes rating files;
-    BASELINE's MovieLens config): triples stream in bounded blocks."""
-
-    def _write_ratings(self, tmp_path, n=6000, n_u=96, n_i=64, seed=0):
-        us, it, r = make_ratings(
-            n_users=n_u - 1, n_items=n_i - 1, rank=4, n_obs=n, seed=seed
-        )
-        paths = []
-        for i in range(3):
-            p = tmp_path / f"ratings-{i}.txt"
-            sl = slice(i * n // 3, (i + 1) * n // 3)
-            with open(p, "w") as f:
-                for u, v, x in zip(us[sl], it[sl], r[sl]):
-                    f.write(f"{u} {v} {x:.5f}\n")
-            paths.append(str(p))
-        return paths, (us, it, r)
-
-    def test_blocks_roundtrip(self, tmp_path):
-        from parameter_server_tpu.models.matrix_fac import iter_rating_blocks
-
-        paths, (us, it, r) = self._write_ratings(tmp_path, n=600)
-        got_u, got_i, got_r = [], [], []
-        for bu, bi, br in iter_rating_blocks(paths, block_lines=100):
-            assert len(bu) <= 100
-            got_u.append(bu)
-            got_i.append(bi)
-            got_r.append(br)
-        np.testing.assert_array_equal(np.concatenate(got_u), us[:600])
-        np.testing.assert_allclose(np.concatenate(got_r), r[:600], atol=1e-4)
-
-    def test_trains_from_files_single_and_mesh(self, tmp_path):
-        from parameter_server_tpu.parallel import make_mesh
-
-        paths, _ = self._write_ratings(tmp_path)
-        for mesh in (None, make_mesh(2, 4)):
-            mf = MatrixFactorization(95, 63, rank=8, eta=0.1, l2=0.002,
-                                     reporter=quiet(), mesh=mesh)
-            first = mf.train_files(paths, batch_size=500, block_lines=1500,
-                                   seed=0)
-            last = first
-            for ep in range(1, 10):
-                last = mf.train_files(paths, batch_size=500,
-                                      block_lines=1500, seed=ep)
-            assert last < first * 0.7, (mesh, first, last)
-
-    def test_unparseable_files_raise(self, tmp_path):
-        p = tmp_path / "ratings.csv"
-        p.write_text("1,2,3.5\n4,5,2.0\n")  # comma-separated: wrong format
-        mf = MatrixFactorization(95, 63, rank=4, reporter=quiet())
-        with pytest.raises(ValueError, match="no rating triples"):
-            mf.train_files([str(p)])

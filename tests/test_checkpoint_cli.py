@@ -376,9 +376,9 @@ class TestCLIAppFactory:
             "data": {"files": [str(tr_p)], "val_files": [str(val_p)]},
             "mf": {"num_users": n_u - 1, "num_items": n_i - 1, "rank": 8,
                    "eta": 0.1, "l2": 0.002, "batch_size": 500},
-            # steps_per_call: the CLI must wire solver.steps_per_call into
-            # the app (scanned multistep dispatch)
-            "solver": {"epochs": 12, "steps_per_call": 3},
+            # through PodTrainer like the linear app and Wide&Deep: the
+            # scanned multistep, the (data, kv) mesh, rating files
+            "solver": {"epochs": 40, "steps_per_call": 3},
             "parallel": {"data_shards": 2, "kv_shards": 4},
         }
         p = tmp_path / "mf.json"
@@ -387,9 +387,11 @@ class TestCLIAppFactory:
                     "--model_out", str(tmp_path / "factors.npz"))
         assert r.returncode == 0, r.stderr[-2000:]
         out = json.loads(r.stdout.strip().splitlines()[-1])
-        assert out["val_rmse"] < 0.45, out
+        assert out["val_rmse"] < 0.45 and out["val_examples"] == 500, out
+        assert out["train_rmse"] < 0.45 and "auc" not in out, out
         z = np.load(tmp_path / "factors.npz")
-        assert z["user_factors"].shape == (n_u, 8)
+        assert z["user_factors"].shape == (n_u - 1, 8)
+        assert z["item_factors"].shape == (n_i - 1, 8)
 
     def test_wide_deep_app(self, tmp_path):
         """wide_deep through the factory end-to-end (BASELINE parity

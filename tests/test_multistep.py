@@ -233,45 +233,6 @@ class TestWord2VecMultistep:
         assert within > across
 
 
-class TestMatrixFacMultistep:
-    def _ratings(self, n=6000, nu=63, ni=31, rank_true=3, seed=7):
-        rng = np.random.default_rng(seed)
-        U = rng.normal(size=(nu, rank_true))
-        V = rng.normal(size=(ni, rank_true))
-        users = rng.integers(0, nu, n)
-        items = rng.integers(0, ni, n)
-        ratings = np.sum(U[users] * V[items], axis=1).astype(np.float32)
-        return users, items, ratings
-
-    @pytest.mark.parametrize("mesh_shape", [None, (2, 2)])
-    def test_mf_multistep_matches_single_step(self, mesh_shape):
-        """steps_per_call=3 reproduces the K=1 MF trajectory exactly on
-        both paths (stream length NOT divisible by 3: the tail group pads
-        with inert empty microsteps)."""
-        from parameter_server_tpu.models.matrix_fac import MatrixFactorization
-
-        users, items, ratings = self._ratings()
-        finals = []
-        for k in (1, 3):
-            kw = dict(
-                num_users=63, num_items=31, rank=8, eta=0.2, l2=0.01,
-                seed=0, reporter=quiet(), steps_per_call=k,
-            )
-            if mesh_shape is not None:
-                kw["mesh"] = make_mesh(*mesh_shape)
-            mf = MatrixFactorization(**kw)
-            rmses = [
-                mf.train_epoch(users, items, ratings, batch_size=512, seed=ep)
-                for ep in range(2)
-            ]
-            finals.append((rmses, mf.predict(users[:50], items[:50])))
-        np.testing.assert_allclose(finals[0][0], finals[1][0], rtol=1e-5)
-        np.testing.assert_allclose(
-            finals[0][1], finals[1][1], rtol=1e-4, atol=1e-6
-        )
-        assert finals[0][0][-1] < finals[0][0][0]  # it actually learns
-
-
 class TestWideDeepMultistep:
     def _batches(self, n_batches=7, n_per=64):
         labels, keys, vals, _ = make_sparse_logistic(

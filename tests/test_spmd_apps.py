@@ -1,4 +1,4 @@
-"""SPMD tests for the embedding apps (Wide&Deep, MF) on the CPU mesh:
+"""SPMD tests for the embedding apps (Wide&Deep, SGNS) on the CPU mesh:
 server-sharded embedding tables over the kv axis, batches over data."""
 
 import jax
@@ -6,12 +6,6 @@ import numpy as np
 import pytest
 
 from parameter_server_tpu.data.batch import BatchBuilder
-from parameter_server_tpu.models.matrix_fac import (
-    MatrixFactorization,
-    MFBatchBuilder,
-    make_mf_spmd_train_step,
-    stack_mf_batches,
-)
 from parameter_server_tpu.models.wide_deep import WideDeep, make_wd_spmd_train_step
 from parameter_server_tpu.parallel import make_mesh, shard_state, stack_batches
 from parameter_server_tpu.utils.metrics import ProgressReporter
@@ -64,122 +58,6 @@ class TestWideDeepSPMD:
         app.mlp_params = mlp
         ev = app.evaluate(batches)
         assert ev["auc"] > 0.9, ev
-
-
-class TestMFSPMD:
-    def test_converges_on_mesh(self):
-        mesh = make_mesh(2, 4)
-        rng = np.random.default_rng(0)
-        n_u, n_i, rank = 96, 64, 4
-        U = rng.normal(size=(n_u, rank)) / np.sqrt(rank)
-        V = rng.normal(size=(n_i, rank)) / np.sqrt(rank)
-        # ids stay in [0, n_u-1) so the max id maps to the LAST table row
-        # (key n_u-1), exercising the final kv shard's boundary
-        us = rng.integers(0, n_u - 1, 6000)
-        it = rng.integers(0, n_i - 1, 6000)
-        r = (np.sum(U[us] * V[it], 1) + 0.05 * rng.normal(size=6000)).astype(
-            np.float32
-        )
-        app = MatrixFactorization(n_u - 1, n_i - 1, rank=8, eta=0.1, l2=0.002,
-                                  reporter=quiet())
-        # row counts: num_users+1 must divide kv axis; 96/64 are multiples of 4
-        step = make_mf_spmd_train_step(
-            app.user_up, app.item_up, mesh, n_u, n_i, l2=0.002
-        )
-        user = shard_state(app.user_state, mesh)
-        item = shard_state(app.item_state, mesh)
-        builder = MFBatchBuilder(batch_size=750)
-        first = last = None
-        for epoch in range(12):
-            order = np.random.default_rng(epoch).permutation(6000)
-            for s in range(0, 6000, 1500):
-                sel = order[s : s + 1500]
-                bs = [
-                    builder.build(us[sel[i::2]], it[sel[i::2]], r[sel[i::2]])
-                    for i in range(2)
-                ]
-                user, item, loss = step(user, item, stack_mf_batches(bs, mesh))
-            if first is None:
-                first = float(loss)
-            last = float(loss)
-        assert last < first * 0.3, (first, last)
-
-
-class TestMFAggregatePush:
-    def _data(self, n_u=96, n_i=64, rank=4, n=3000, seed=0):
-        rng = np.random.default_rng(seed)
-        U = rng.normal(size=(n_u, rank)) / np.sqrt(rank)
-        V = rng.normal(size=(n_i, rank)) / np.sqrt(rank)
-        us = rng.integers(0, n_u - 1, n)
-        it = rng.integers(0, n_i - 1, n)
-        r = (np.sum(U[us] * V[it], 1) + 0.05 * rng.normal(size=n)).astype(
-            np.float32
-        )
-        return us, it, r
-
-    def test_aggregate_equals_per_worker_for_sgd(self):
-        """Plain SGD deltas are linear in the gradient, so pre-summing
-        across data shards (one psum) must reproduce the sequential
-        per-worker scan exactly (same claim the linear app's aggregate
-        mode is property-tested on)."""
-        mesh = make_mesh(2, 4)
-        n_u, n_i = 96, 64
-        us, it, r = self._data(n_u, n_i)
-        builder = MFBatchBuilder(batch_size=750)
-        finals = {}
-        for mode in ("per_worker", "aggregate"):
-            app = MatrixFactorization(n_u - 1, n_i - 1, rank=8, eta=0.05,
-                                      l2=0.002, algo="sgd", reporter=quiet())
-            step = make_mf_spmd_train_step(
-                app.user_up, app.item_up, mesh, n_u, n_i, l2=0.002,
-                push_mode=mode,
-            )
-            user = shard_state(app.user_state, mesh)
-            item = shard_state(app.item_state, mesh)
-            for s in range(0, 3000, 1500):
-                bs = [
-                    builder.build(
-                        us[s + i : s + 1500 : 2],
-                        it[s + i : s + 1500 : 2],
-                        r[s + i : s + 1500 : 2],
-                    )
-                    for i in range(2)
-                ]
-                user, item, _ = step(user, item, stack_mf_batches(bs, mesh))
-            finals[mode] = (
-                np.asarray(jax.device_get(user["w"])),
-                np.asarray(jax.device_get(item["w"])),
-            )
-        for a, b in zip(finals["per_worker"], finals["aggregate"]):
-            np.testing.assert_allclose(a, b, rtol=0, atol=2e-6)
-
-    def test_aggregate_adagrad_converges(self):
-        """AdaGrad aggregate mode follows a different trajectory
-        (sync-aggregation); it must still fit the ratings."""
-        mesh = make_mesh(4, 2)
-        n_u, n_i = 96, 64
-        us, it, r = self._data(n_u, n_i, n=6000)
-        app = MatrixFactorization(n_u - 1, n_i - 1, rank=8, eta=0.1, l2=0.002,
-                                  reporter=quiet())
-        step = make_mf_spmd_train_step(
-            app.user_up, app.item_up, mesh, n_u, n_i, l2=0.002,
-            push_mode="aggregate",
-        )
-        user = shard_state(app.user_state, mesh)
-        item = shard_state(app.item_state, mesh)
-        builder = MFBatchBuilder(batch_size=380)
-        first = last = None
-        for epoch in range(12):
-            order = np.random.default_rng(epoch).permutation(6000)
-            for s in range(0, 6000, 1500):
-                sel = order[s : s + 1500]
-                bs = [builder.build(us[sel[i::4]], it[sel[i::4]], r[sel[i::4]])
-                      for i in range(4)]
-                user, item, loss = step(user, item, stack_mf_batches(bs, mesh))
-            if first is None:
-                first = float(loss)
-            last = float(loss)
-        assert last < first * 0.3, (first, last)
 
 
 class TestWideDeepAggregatePush:
@@ -303,43 +181,13 @@ class TestWideDeepQuantizedFromConfig:
 
 
 class TestOneWorkerMeshStepAgainstSingleDevice:
-    """The embedding apps call ``_local_push`` with id lists of their own.
-    With one worker the mesh step is the single-device step (which pushes
-    through plain ``.at[].add``), whatever the kv sharding: MF's key lists
-    are ``np.unique`` output behind the pad slot, so its push promises
-    ascending rows; SGNS pushes word ids as the pairs come, repeats and
-    all, and promises nothing: the same scatter, told nothing of the order
-    (another shard's rows dropped there too, not added as zeros to row 0)."""
-
-    @pytest.mark.parametrize("mesh_shape", [(1, 1), (1, 2), (1, 4)])
-    def test_mf(self, mesh_shape):
-        from parameter_server_tpu.models.matrix_fac import batch_to_device, mf_train_step
-
-        mesh = make_mesh(*mesh_shape)
-        n_u, n_i = 96, 64  # rows: a kv shard's edge falls inside the ids used
-        rng = np.random.default_rng(3)
-        us, it = rng.integers(0, n_u - 1, 400), rng.integers(0, n_i - 1, 400)
-        us[:2], it[:2] = (0, n_u - 2), (0, n_i - 2)  # the first and the last real row
-        r = rng.normal(size=400).astype(np.float32)
-        app = MatrixFactorization(n_u - 1, n_i - 1, rank=8, eta=0.1, l2=0.002, reporter=quiet())
-        step = make_mf_spmd_train_step(app.user_up, app.item_up, mesh, n_u, n_i, l2=0.002)
-        b = MFBatchBuilder(batch_size=512).build(us, it, r)  # 112 pad slots behind the keys
-        start = [{k: np.asarray(v) for k, v in s.items()} for s in (app.user_state, app.item_state)]
-        user, item, loss = step(
-            shard_state(app.user_state, mesh), shard_state(app.item_state, mesh),
-            stack_mf_batches([b], mesh),
-        )
-        want_user, want_item, want_loss = mf_train_step(
-            app.user_up, app.item_up, *[{k: jax.numpy.asarray(v) for k, v in s.items()} for s in start],
-            batch_to_device(b), 0.002,
-        )
-        np.testing.assert_allclose(float(loss), float(want_loss), rtol=1e-6)
-        for got, want in ((user, want_user), (item, want_item)):
-            for k in want:
-                # row 0 is the pad row: the tail's pads no longer add their L2 term to it
-                np.testing.assert_allclose(
-                    np.asarray(got[k])[1:], np.asarray(want[k])[1:], rtol=1e-6, atol=1e-7, err_msg=k
-                )
+    """SGNS calls ``_local_push`` with id lists of its own. With one worker
+    the mesh step is the single-device step (which pushes through plain
+    ``.at[].add``), whatever the kv sharding: SGNS pushes word ids as the
+    pairs come, repeats and all, and promises nothing: the same scatter,
+    told nothing of the order (another shard's rows dropped there too, not
+    added as zeros to row 0). Matrix factorization runs through the shared
+    step since PR 32 (tests/test_matrix_fac_pod.py)."""
 
     @pytest.mark.parametrize("mesh_shape", [(1, 1), (1, 2), (1, 4)])
     def test_sgns(self, mesh_shape):

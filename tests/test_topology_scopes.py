@@ -365,3 +365,120 @@ def test_table_scatters_take_ascending_rows_in_place(compiled_text, wd_text, app
             if opcode == "gather" and table.match(operand_shapes[0])
         ]
         assert len(gathers) == slots, gathers
+
+
+# -- matrix factorization: one 64-lane table through the same step -------------
+MF_USERS, MF_ITEMS, MF_RANK = 50_082_603, 39_780, 64  # the cell mfhw.train
+MF_MINIBATCH, MF_SLOTS = 65_536, 131_072  # 2 entries a rating: one 2^17 bucket on both axes
+MF_CASES = [(1, 1, "multistep"), (1, 1, "predict")]
+MF_NAMES = frozenset({"mf"})
+
+
+@pytest.fixture(scope="module")
+def mf_text(topo):
+    """(data, kv, program) -> optimised HLO text of the matrix-factorization
+    programs at the cell's size (5.0e7 rows x 64 under SGD), compiled once."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding
+
+    from parameter_server_tpu.models import matrix_fac
+    from parameter_server_tpu.parallel import spmd
+    from parameter_server_tpu.utils.config import PSConfig
+
+    texts: dict = {}
+
+    def get(data: int, kv: int, program: str) -> str:
+        key = (data, kv, program)
+        if key in texts:
+            return texts[key]
+        cfg = PSConfig()
+        cfg.mf.num_users, cfg.mf.num_items, cfg.mf.rank = MF_USERS, MF_ITEMS, MF_RANK
+        cfg.mf.algo, cfg.mf.batch_size = "sgd", MF_MINIBATCH
+        cfg = matrix_fac.pod_config(cfg)
+        app = matrix_fac.app_from_config(cfg)
+        mesh = Mesh(np.array(topo.devices[: data * kv]).reshape(data, kv), ("data", "kv"))
+        rows = spmd.padded_num_keys(cfg.data.num_keys, kv)
+        table = NamedSharding(mesh, spmd.state_spec())
+        state = {"mf.w": jax.ShapeDtypeStruct((rows, MF_RANK), jnp.float32, sharding=table)}
+        feed = NamedSharding(mesh, spmd.batch_spec())
+        lead = (K,) if program == "multistep" else ()
+        fields = {
+            "unique_keys": ((MF_SLOTS,), jnp.int32), "local_ids": ((MF_SLOTS,), jnp.int32),
+            "row_splits": ((MF_MINIBATCH + 1,), jnp.int32), "values": ((MF_SLOTS,), jnp.float32),
+            "labels": ((MF_MINIBATCH,), jnp.float32), "example_mask": ((MF_MINIBATCH,), jnp.bool_),
+        }
+        batch = {
+            k: jax.ShapeDtypeStruct((data, *lead, *shape), dt, sharding=feed)
+            for k, (shape, dt) in fields.items()
+        }
+        if program == "multistep":
+            fn, args = spmd.make_spmd_train_multistep(app, mesh, cfg.data.num_keys), (state, batch, 0)
+        else:
+            fn, args = spmd.make_spmd_predict_step(app, mesh, cfg.data.num_keys), (state, batch)
+        (jitted,) = [
+            c.cell_contents for c in fn.__closure__
+            if callable(c.cell_contents) and hasattr(c.cell_contents, "lower")
+        ]
+        compiled = jitted.lower(*args).compile()
+        texts[key] = compiled.as_text()
+        texts[key, "memory"] = compiled.memory_analysis()
+        return texts[key]
+
+    get.texts = texts
+    return get
+
+
+@pytest.mark.parametrize("data,kv,program", MF_CASES)
+def test_mf_table_ops_are_scoped_and_the_table_is_read_where_it_lies(mf_text, data, kv, program):
+    """Every executed instruction that reads or writes the 64-lane table
+    sits under ``ps.pull/mf`` or ``ps.push/<stage>/mf``; and the table is
+    held once, unpadded (11.95 GiB): a gather of whole 64-lane rows would
+    have XLA copy it into lane-padded row-major tiles every microstep, 23.9
+    GiB more, and the step would not compile (``spmd._take_rows``)."""
+    from parameter_server_tpu.parallel import spmd
+
+    text = mf_text(data, kv, program)
+    _, scopes = spmd.hlo_scopes(text, MF_NAMES)
+    rows = spmd.padded_num_keys(1 + MF_ITEMS + MF_USERS, kv) // kv
+    table = re.compile(rf"\[{rows},{MF_RANK}\]")
+    touching = [
+        (name, scopes[name])
+        for name, shape, opcode, operand_shapes in executed(text)
+        if table.search(shape) or any(table.search(s) for s in operand_shapes)
+    ]
+    assert touching
+    assert all(re.match(r"^ps\.(pull|push/\w+)/mf$", scope) for _, scope in touching), touching
+    found = set(scopes.values())
+    assert {"ps.pull/mf", "ps.grad"} <= found, found
+    if program == "multistep":
+        assert "ps.push/scatter/mf" in found, found
+        scatters = [rest for _, _, shape, opcode, _, rest in instructions(text) if opcode == "scatter" and table.search(shape)]
+        assert len(scatters) == 1 and "indices_are_sorted=true" in scatters[0], scatters
+    mem = mf_text.texts[(data, kv, program), "memory"]
+    table_bytes = 4 * rows * MF_RANK
+    assert table_bytes == 12_831_424_512
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < table_bytes + (512 << 20)
+
+
+@pytest.mark.parametrize("data,kv,program", MF_CASES)
+def test_mf_large_unscoped_instructions_are_the_known_kinds(mf_text, data, kv, program):
+    """The list of unscoped kinds holds for the third app."""
+    from parameter_server_tpu.parallel import spmd
+
+    text = mf_text(data, kv, program)
+    _, scopes = spmd.hlo_scopes(text, MF_NAMES)
+    known = re.compile(
+        r"^(copy|copy-start|copy-done|custom-call|slice-start|slice-done|async-start|async-done"
+        r"|reduce|broadcast|dynamic-update-slice|all-reduce|fusion)$"
+    )
+    strays = [
+        (name, opcode, shape)
+        for name, shape, opcode, _ in executed(text)
+        if not scopes[name] and elements(shape) >= MF_SLOTS and not known.match(opcode)
+    ]
+    assert not strays, strays
+    for name, shape, opcode, operand_shapes in executed(text):
+        if opcode == "fusion" and not scopes[name]:
+            # bookkeeping at batch size (a (U, 64) buffer at most), never a table op
+            assert elements(shape) <= 2 * 64 * MF_SLOTS and all(elements(s) < MF_USERS for s in operand_shapes), name
