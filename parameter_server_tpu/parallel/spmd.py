@@ -480,29 +480,53 @@ def _values_of(b: Batch) -> jax.Array:
 # of whole rows from a table of 64 lanes wants row-major (8, 128) tiles,
 # which pad 64 lanes to 128, and gets them by a copy of the WHOLE table,
 # twice its bytes, every microstep: a table that fills the chip does not
-# compile (PERF.md section 6, PR 32)
+# compile (PERF.md section 6, PR 32). A (K, vdim // B, B) view of the table
+# is a bitcast of that layout when B is a multiple of 8, and a gather of
+# B-lane blocks from the view is the row gather again (PR 33). Rows of
+# whole 128-lane tiles have nothing to pad: XLA keeps such a table
+# row-major, and the gather of whole rows reads it there
 _ROW_GATHER_LANES = 32
+_TILE_LANES = 128
+
+
+def _block_lanes(vdim: int) -> int:
+    """The widest block a row of ``vdim`` lanes splits into that the row
+    gather reads in place: the largest multiple of 8 that divides ``vdim``
+    and is at most ``_ROW_GATHER_LANES``; 0 where there is none."""
+    return next(
+        (b for b in range(_ROW_GATHER_LANES, 0, -8) if vdim % b == 0), 0
+    )
 
 
 def _take_rows(v: jax.Array, rows: jax.Array) -> jax.Array:
     """``v[rows]`` for a (K, vdim) table slot. Rows of up to
-    ``_ROW_GATHER_LANES`` lanes: ``jnp.take``, one slice a row. Wider rows:
-    one gather of single elements, its index naming (row, lane), which XLA
-    leaves the table's layout alone for. ``rows`` are in range (the callers
-    clamp them)."""
+    ``_ROW_GATHER_LANES`` lanes, or of whole ``_TILE_LANES``-lane tiles:
+    ``jnp.take``, one slice a row. Other widths: one gather of
+    ``_block_lanes(vdim)``-lane blocks from the table viewed as (K, blocks,
+    lanes), its index naming (row, block), which XLA reads the table where
+    it lies for; a width with no such block: one gather of single elements,
+    index (row, lane), the only other form that leaves the layout alone.
+    ``rows`` are in range (the callers clamp them)."""
     vdim = v.shape[1]
-    if vdim <= _ROW_GATHER_LANES:
+    if vdim <= _ROW_GATHER_LANES or vdim % _TILE_LANES == 0:
         return jnp.take(v, rows, axis=0)
-    lanes = jnp.arange(vdim, dtype=rows.dtype)
-    at = jnp.stack(jnp.broadcast_arrays(rows[:, None], lanes[None, :]), axis=-1)
-    return lax.gather(  # (U, vdim)
-        v, at,
+    lanes = _block_lanes(vdim)
+    if lanes:
+        view = v.reshape(v.shape[0], vdim // lanes, lanes)
+        slice_sizes, offset_dims = (1, 1, lanes), (2,)
+    else:  # single elements: the "blocks" of a row are its lanes
+        view, slice_sizes, offset_dims = v, (1, 1), ()
+    minor = jnp.arange(view.shape[1], dtype=rows.dtype)
+    at = jnp.stack(jnp.broadcast_arrays(rows[:, None], minor[None, :]), axis=-1)
+    got = lax.gather(  # (U, blocks, lanes), or (U, vdim)
+        view, at,
         lax.GatherDimensionNumbers(
-            offset_dims=(), collapsed_slice_dims=(0, 1), start_index_map=(0, 1)
+            offset_dims=offset_dims, collapsed_slice_dims=(0, 1), start_index_map=(0, 1)
         ),
-        slice_sizes=(1, 1),
+        slice_sizes=slice_sizes,
         mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS,
     )
+    return got.reshape(rows.shape[0], vdim)
 
 
 def _local_pull(

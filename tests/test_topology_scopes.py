@@ -130,6 +130,12 @@ def elements(shape: str) -> int:
     )
 
 
+def copies_of(every: list, n: int) -> list:
+    """(name, shape) of the module's ``copy`` instructions of ``n`` elements
+    or more: a table relaid."""
+    return [(name, shape) for _, name, shape, opcode, _, _ in every if opcode == "copy" and elements(shape) >= n]
+
+
 CASES = [(1, 1, "multistep"), (1, 1, "predict"), (2, 2, "multistep"), (2, 2, "predict")]
 
 
@@ -431,17 +437,24 @@ def mf_text(topo):
 
 @pytest.mark.parametrize("data,kv,program", MF_CASES)
 def test_mf_table_ops_are_scoped_and_the_table_is_read_where_it_lies(mf_text, data, kv, program):
-    """Every executed instruction that reads or writes the 64-lane table
-    sits under ``ps.pull/mf`` or ``ps.push/<stage>/mf``; and the table is
-    held once, unpadded (11.95 GiB): a gather of whole 64-lane rows would
-    have XLA copy it into lane-padded row-major tiles every microstep, 23.9
-    GiB more, and the step would not compile (``spmd._take_rows``)."""
+    """Every executed instruction that reads or writes the 64-lane table,
+    as it is stored or through its view as 32-lane blocks, sits under
+    ``ps.pull/mf`` or ``ps.push/<stage>/mf``; and the table is held once,
+    unpadded (11.95 GiB), and read where it lies: a gather of whole 64-lane
+    rows would have XLA copy it into lane-padded row-major tiles every
+    microstep, 23.9 GiB more, and the step would not compile. The view
+    ``[rows, 2, 32]`` is a ``bitcast`` of that layout, and the gather of
+    its blocks the row gather of the narrower tables, not the gather of
+    8.4M single elements the step had until PR 33 (``spmd._take_rows``)."""
     from parameter_server_tpu.parallel import spmd
 
     text = mf_text(data, kv, program)
     _, scopes = spmd.hlo_scopes(text, MF_NAMES)
     rows = spmd.padded_num_keys(1 + MF_ITEMS + MF_USERS, kv) // kv
-    table = re.compile(rf"\[{rows},{MF_RANK}\]")
+    lanes = spmd._block_lanes(MF_RANK)
+    assert lanes == 32
+    stored, view = rf"\[{rows},{MF_RANK}\]", rf"\[{rows},{MF_RANK // lanes},{lanes}\]"
+    table = re.compile(rf"{stored}|{view}")
     touching = [
         (name, scopes[name])
         for name, shape, opcode, operand_shapes in executed(text)
@@ -451,14 +464,50 @@ def test_mf_table_ops_are_scoped_and_the_table_is_read_where_it_lies(mf_text, da
     assert all(re.match(r"^ps\.(pull|push/\w+)/mf$", scope) for _, scope in touching), touching
     found = set(scopes.values())
     assert {"ps.pull/mf", "ps.grad"} <= found, found
+    every = instructions(text)
+    bitcasts = [
+        shape for _, _, shape, opcode, operand_shapes, _ in every
+        if opcode == "bitcast" and re.search(view, shape) and re.search(stored, operand_shapes[0])
+    ]
+    assert bitcasts, "the gather's operand is not a view of the table where it lies"
+    assert not copies_of(every, rows * MF_RANK)
+    element_gathers = [name for _, name, shape, _, _, _ in every if re.match(rf"^f32\[{MF_SLOTS * MF_RANK}\]", shape)]
+    assert not element_gathers, element_gathers
     if program == "multistep":
         assert "ps.push/scatter/mf" in found, found
-        scatters = [rest for _, _, shape, opcode, _, rest in instructions(text) if opcode == "scatter" and table.search(shape)]
+        scatters = [rest for _, _, shape, opcode, _, rest in every if opcode == "scatter" and re.search(stored, shape)]
         assert len(scatters) == 1 and "indices_are_sorted=true" in scatters[0], scatters
     mem = mf_text.texts[(data, kv, program), "memory"]
     table_bytes = 4 * rows * MF_RANK
     assert table_bytes == 12_831_424_512
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < table_bytes + (512 << 20)
+
+
+@pytest.mark.parametrize("vdim", [128, 256])
+def test_rows_of_whole_tiles_are_taken_from_the_table_where_it_lies(topo, vdim):
+    """A table whose rows are whole 128-lane tiles has nothing to pad, so the
+    chip keeps it row-major and ``_take_rows`` takes whole rows from it: its
+    view as 32-lane blocks would be no bitcast there but a copy of the table
+    (11.9 GiB beside a table of 11.9: no fit). With the push's scatter in
+    the same program, at a table that fills the chip."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from parameter_server_tpu.parallel import spmd
+
+    one = SingleDeviceSharding(topo.devices[0])
+    rows = 25_000_960 * 128 // vdim
+    table = jax.ShapeDtypeStruct((rows, vdim), jnp.float32, sharding=one)
+    idx = jax.ShapeDtypeStruct((MF_SLOTS,), jnp.int32, sharding=one)
+
+    def pull_and_push(v, at):
+        got = spmd._take_rows(v, at)
+        return spmd._add_rows(v, at, 0.5 * got, True), got
+
+    compiled = jax.jit(pull_and_push, donate_argnums=0).lower(table, idx).compile()
+    assert not copies_of(instructions(compiled.as_text()), rows * vdim)
+    assert compiled.memory_analysis().temp_size_in_bytes < 512 << 20
 
 
 @pytest.mark.parametrize("data,kv,program", MF_CASES)

@@ -12,7 +12,14 @@ Every form's result is checked on the chip against the deltas row by row,
 with the count of non-zero rows and the absolute sum. One JSON line a case,
 also appended to chiprun_out/probe_push_scatter.jsonl.
 
-    chiprun --timeout 1500 -- python3 tools/probe_push_scatter.py [SEED] [--rest]
+``--wide`` (step 2 of ISSUE 33; PERF.md section 6, PR 33) runs the 64-lane
+case alone: the pull of one ``mfhw.train`` minibatch's key slots (65,536
+ratings of the benchmark's Hugewiki-shaped traffic from the seed: 131,072
+slots, about 75,500 of them keys) out of f32[50122752,64], the gather of
+single elements the step had in PR 32 against ``spmd._take_rows``, every
+form's rows checked against NumPy's; one JSON line a form.
+
+    chiprun --timeout 1500 -- python3 tools/probe_push_scatter.py [SEED] [--rest | --wide]
 """
 import json, os, sys, time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -22,7 +29,7 @@ from jax import lax
 from benchmark.harness import criteo
 from parameter_server_tpu.parallel import spmd
 
-SEED = int(sys.argv[1]) if len(sys.argv) > 1 else 2270000001
+SEED = next((int(a) for a in sys.argv[1:] if a.isdigit()), 2270000001)
 U = 1 << 16  # BatchBuilder's bucket for a batch's about 40,000 keys
 print("device", jax.devices()[0].platform, jax.devices()[0].device_kind, flush=True)
 assert jax.devices()[0].platform == "tpu"
@@ -81,6 +88,13 @@ def timed(fn, first, rest, n=10, reps=3, chain=True):
     return out, (x if chain else first)
 
 
+def emit(res):
+    print(json.dumps(res), flush=True)
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/probe_push_scatter.jsonl", "a") as fh:
+        fh.write(json.dumps(res) + "\n")
+
+
 def case(rows, vdim, num_keys, begin, label, gathers):
     keys, n_uniq = bucket_keys(num_keys)
     rng = np.random.default_rng(SEED)
@@ -121,12 +135,48 @@ def case(rows, vdim, num_keys, begin, label, gathers):
                 b = np.asarray(g(table, idx))
                 res[f"gather_{gname}_equal_on_mine"] = bool(np.array_equal(a[mine], b[mine]))
         del table
-    print(json.dumps(res), flush=True)
-    os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/probe_push_scatter.jsonl", "a") as fh:
-        fh.write(json.dumps(res) + "\n")
+    emit(res)
 
 
+def element_rows(v, rows):  # the pull of rows wider than 32 lanes in PR 32
+    lanes = jnp.arange(v.shape[1], dtype=rows.dtype)
+    at = jnp.stack(jnp.broadcast_arrays(rows[:, None], lanes[None, :]), axis=-1)
+    dnums = lax.GatherDimensionNumbers(offset_dims=(), collapsed_slice_dims=(0, 1), start_index_map=(0, 1))
+    return lax.gather(v, at, dnums, slice_sizes=(1, 1), mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+
+
+def wide_case():
+    from benchmark.harness import ratings, ref_mf
+    from parameter_server_tpu.models import matrix_fac
+
+    conf = json.load(open("benchmark/configs/mf_hugewiki_1chip.json"))
+    st = conf["settings"]
+    users, items, rank, n = st["num_users"], st["num_items"], st["rank"], st["minibatch"]
+    live = 1 + items + users
+    rows = spmd.padded_num_keys(live, 1)
+    slots = 2 * n  # BatchBuilder's bucket for a minibatch's about 75,500 keys
+    u, i, _ = ratings.make_ratings(SEED, n, conf["data"], users, items)
+    uniq = np.unique(np.concatenate(ratings.table_rows(u, i, items)))
+    assert slots // 2 < 1 + len(uniq) <= slots, (len(uniq), slots)
+    keys = np.zeros(slots, np.int32)  # slot 0 and the tail: PAD_KEY
+    keys[1 : 1 + len(uniq)] = uniq
+    want = ref_mf.init_factors(SEED, keys.astype(np.int64), rank, live)
+    table = jax.jit(lambda: matrix_fac.init_factors(SEED, jnp.arange(rows, dtype=jnp.int32), rank, live))()
+    idx = jnp.asarray(keys)
+    for name, form in (("element", element_rows), ("take_rows", spmd._take_rows)):
+        res = {"case": f"f32[{rows},{rank}] pull", "form": name, "slots": slots, "real_slots": 1 + len(uniq), "seed": SEED}
+        t0 = time.perf_counter()
+        g = jax.jit(form).lower(table, idx).compile()
+        res["compile_s"] = time.perf_counter() - t0
+        got = np.asarray(g(table, idx))
+        res["rows_equal_numpy"] = bool(np.array_equal(got, want)) and bool(want[1 : 1 + len(uniq)].any())
+        res["gather_ms"], _ = timed(g, table, (idx,), chain=False)
+        emit(res)
+
+
+if "--wide" in sys.argv:
+    wide_case()
+    sys.exit(0)
 if "--rest" not in sys.argv:
     case(1 << 30, 1, 1 << 30, 0, "f32[2^30,1]", True)
     case(100_000_768, 1, 100_000_000, 0, "f32[100000768,1]", False)
