@@ -1119,4 +1119,66 @@ int ps_parse_rating(const char* buf, int64_t len,
   return 0;
 }
 
+// sgns: "centre context neg_1 ... neg_k" (the skip-gram app's examples
+// with their negatives drawn: word ids from 0, no label, no values). One
+// row a line, label 1.0, one entry of value 1.0 a word IN THE LINE'S ORDER
+// (the app reads an entry's role off its position): key ``centre`` for the
+// first, key ``vocab_size + word`` for every other, which identity keying
+// (+1 for the pad row) puts at table rows 1..V (input vectors) and
+// V+1..2V (output vectors). Fewer than three ids on a line, a token that
+// is no unsigned integer, or an id at or past ``vocab_size``: a parse
+// error.
+int ps_parse_sgns(const char* buf, int64_t len,
+                  int64_t max_rows, int64_t max_nnz,
+                  float* labels, int64_t* row_splits,
+                  uint64_t* keys, float* vals, uint64_t* slots,
+                  int64_t* out_rows, int64_t* out_nnz, int64_t* err_line,
+                  uint64_t vocab_size) {
+  const char* p = buf;
+  const char* end = buf + len;
+  const bool any_cr = chunk_has_cr(buf, len);
+  int64_t rows = 0, nnz = 0, line = 0;
+  row_splits[0] = 0;
+  while (p < end) {
+    const char* next_line;
+    const char* line_end = find_line_end(p, end, &next_line, any_cr);
+    skip_ws(p, line_end);
+    if (p >= line_end) {  // blank line
+      p = next_line;
+      ++line;
+      continue;
+    }
+    if (rows >= max_rows) return -1;
+    const int64_t first = nnz;
+    while (p < line_end) {
+      const char* tok = p;
+      uint64_t id = 0;
+      // a whole token of at most 18 digits (no wrap-around), under V
+      if (!parse_u64(p, line_end, id) || p - tok > 18 || id >= vocab_size ||
+          (p < line_end && *p != ' ' && *p != '\t')) {
+        *err_line = line;
+        return -2;
+      }
+      if (nnz >= max_nnz) return -1;
+      keys[nnz] = nnz == first ? id : vocab_size + id;
+      vals[nnz] = 1.0f;
+      if (slots) slots[nnz] = 0;  // null for slotless callers
+      ++nnz;
+      skip_ws(p, line_end);
+    }
+    if (nnz - first < 3) {
+      *err_line = line;
+      return -2;
+    }
+    labels[rows] = 1.0f;
+    ++rows;
+    row_splits[rows] = nnz;
+    p = next_line;
+    ++line;
+  }
+  *out_rows = rows;
+  *out_nnz = nnz;
+  return 0;
+}
+
 }  // extern "C"

@@ -713,29 +713,44 @@ def _run_train_mf(cfg: PSConfig, args: argparse.Namespace) -> dict:
 
 
 def _run_train_w2v(cfg: PSConfig, args: argparse.Namespace) -> dict:
-    """word2vec app dispatch (ref: App::Create on the SGNS config)."""
+    """word2vec app dispatch (ref: App::Create on the SGNS config): the
+    corpus of word ids is turned into ``sgns`` example shards by the
+    module's own ``PairStream`` and ``NegativeSampler`` (the data-layer
+    job), then the ``PodTrainer`` every app runs through trains them over
+    the skip-gram description (``word2vec.pod_config`` puts [w2v]'s
+    settings where the shared loop reads them)."""
+    import tempfile
+
     import numpy as np
 
-    from parameter_server_tpu.models.word2vec import Word2Vec
+    from parameter_server_tpu.models import word2vec
+    from parameter_server_tpu.parallel.trainer import PodTrainer
 
-    w = cfg.w2v
-    app = Word2Vec(
-        vocab_size=w.vocab_size, dim=w.dim, eta=w.eta,
-        num_negatives=w.negatives, window=w.window, seed=cfg.seed,
-        mesh=_mesh_from_cfg(cfg), max_delay=max(cfg.solver.max_delay, 0),
-        push_mode=cfg.parallel.push_mode,
-        steps_per_call=cfg.solver.steps_per_call,
-    )
-    # one call: train_files runs its epoch loop internally and pays the
-    # vocab-counting pass ONCE, not once per epoch
-    mean = app.train_files(
-        cfg.data.files, batch_size=w.batch_size,
-        epochs=max(1, cfg.solver.epochs),
-        block_tokens=w.block_tokens, seed=cfg.seed,
-    )
-    out: dict = {"mean_loss": mean, "vocab_size": w.vocab_size, "dim": w.dim}
+    trainer = PodTrainer(word2vec.pod_config(cfg))
+    if args.resume:
+        if not args.ckpt_dir:
+            raise SystemExit("--resume requires --ckpt_dir")
+        trainer.load(args.ckpt_dir)
+    with tempfile.TemporaryDirectory() as tmp:
+        shards = word2vec.examples_from_corpus(
+            cfg.data.files, tmp, cfg, shards=trainer.local_data_shards
+        )
+        last = dict(
+            trainer.train_files(shards, report_every=args.report_interval) or {}
+        )
+    if not trainer.examples_seen:
+        raise SystemExit(
+            f"no skip-gram pairs made from {cfg.data.files}: expected "
+            "whitespace-separated word ids (or .npy) under w2v.vocab_size"
+        )
+    out: dict = {**last, "vocab_size": cfg.w2v.vocab_size, "dim": cfg.w2v.dim}
+    if "objv" in last:  # the last report's mean loss a pair
+        out["mean_loss"] = float(last["objv"])
+    if args.ckpt_dir:
+        trainer.save(args.ckpt_dir)
     if args.model_out:
-        np.save(args.model_out, app.embeddings())
+        in_v, out_v = word2vec.vectors(trainer)
+        np.savez(args.model_out, in_vectors=in_v, out_vectors=out_v)
         out["model_out"] = args.model_out
     return out
 

@@ -146,28 +146,69 @@ def iter_rating(path: str | Path, num_items: int) -> Iterator[Row]:
             )
 
 
+def iter_sgns(path: str | Path, vocab_size: int) -> Iterator[Row]:
+    """Parse ``centre context neg_1 ... neg_k`` lines of word ids from 0,
+    the skip-gram app's examples with their negatives drawn. No label and
+    no values are written: the label is 1.0 and every entry's value 1.0. A
+    row holds one entry a word IN THE LINE'S ORDER (the app reads an
+    entry's role off its position): key ``centre`` for the first, key
+    ``vocab_size + word`` for every other, so that identity keying (+1 for
+    the pad row) puts the input vectors at table rows 1..V and the output
+    vectors behind them. Fewer than three ids on a line, a token that is no
+    unsigned integer, or an id at or past ``vocab_size`` raises
+    ValueError."""
+    with _open(path) as f:
+        for n, line in enumerate(f):
+            # tokens between spaces and tabs, as the C parser splits them
+            parts = line.rstrip("\r\n").replace("\t", " ").split(" ")
+            parts = [t for t in parts if t]
+            if not parts:
+                continue
+            ok = len(parts) >= 3 and all(
+                t.isascii() and t.isdigit() and len(t) <= 18 for t in parts
+            )
+            ids = [int(t) for t in parts] if ok else []
+            ok = ok and max(ids) < vocab_size
+            if not ok:
+                raise ValueError(f"parse error at line {n} of {path} (sgns)")
+            keys = np.array(ids, dtype=np.uint64)
+            keys[1:] += np.uint64(vocab_size)
+            yield (
+                1.0, keys, np.ones(len(ids), dtype=np.float32),
+                np.zeros(len(ids), dtype=np.uint64),
+            )
+
+
 FORMATS = {"libsvm": iter_libsvm, "criteo": iter_criteo, "adfea": iter_adfea}
 
-# ``user item rating`` lines: the readers take it as "rating:<num_items>",
-# because a user's key lies behind the items' (``rating_format``); its keys
-# are ids of a dense space (``BatchBuilder``'s key_mode "identity"), not
-# features to hash.
+# Two formats carry ids of a dense space (``BatchBuilder``'s key_mode
+# "identity"), not features to hash, and are read with the size of the
+# first id range behind the name, because the second range's keys lie
+# behind it: ``user item rating`` lines as "rating:<num_items>"
+# (``rating_format``), ``centre context negatives...`` lines as
+# "sgns:<vocab_size>" (``sgns_format``).
 RATING = "rating"
+SGNS = "sgns"
+_SIZED = {RATING: ("num_items", iter_rating), SGNS: ("vocab_size", iter_sgns)}
 
 
 def rating_format(num_items: int) -> str:
     return f"{RATING}:{int(num_items)}"
 
 
+def sgns_format(vocab_size: int) -> str:
+    return f"{SGNS}:{int(vocab_size)}"
+
+
 def split_format(fmt: str) -> tuple[str, int | None]:
     """(format name, its parameter or None): ("rating", 39780) of
     "rating:39780", ("criteo", None) of "criteo"."""
     name, _, arg = fmt.partition(":")
-    if name == RATING:
+    if name in _SIZED:
         if not arg.isdigit():
             raise ValueError(
-                f"the {RATING!r} format is read as 'rating:<num_items>' "
-                f"(data.libsvm.rating_format), got {fmt!r}"
+                f"the {name!r} format is read as '{name}:<{_SIZED[name][0]}>' "
+                f"(data.libsvm.{name}_format), got {fmt!r}"
             )
         return name, int(arg)
     return fmt, None
@@ -175,10 +216,10 @@ def split_format(fmt: str) -> tuple[str, int | None]:
 
 def iter_format(fmt: str, path: str | Path) -> Iterator[Row]:
     name, arg = split_format(fmt)
-    if name == RATING:
-        return iter_rating(path, arg)
+    if name in _SIZED:
+        return _SIZED[name][1](path, arg)
     if name not in FORMATS:
         raise ValueError(
-            f"unknown data format {fmt!r}; known: {sorted([*FORMATS, RATING])}"
+            f"unknown data format {fmt!r}; known: {sorted([*FORMATS, *_SIZED])}"
         )
     return FORMATS[name](path)

@@ -45,6 +45,8 @@ NATIVE_FORMATS = {
     # read as "rating:<num_items>" (data.libsvm.split_format): the C parser
     # takes the item count as a thirteenth argument
     "rating": "ps_parse_rating",
+    # "sgns:<vocab_size>", the same way
+    "sgns": "ps_parse_sgns",
 }
 
 
@@ -132,8 +134,8 @@ def load_native() -> ctypes.CDLL | None:
             u64p, f32p, u64p,  # keys, vals, slots
             i64p, i64p, i64p,  # out_rows, out_nnz, err_line
         ]
-        if name == "rating":
-            f.argtypes = [*f.argtypes, ctypes.c_uint64]  # num_items
+        if name in ("rating", "sgns"):
+            f.argtypes = [*f.argtypes, ctypes.c_uint64]  # num_items / vocab_size
     try:
         c4 = lib.ps_count4
         c4.restype = None
@@ -212,7 +214,7 @@ def hash_localize(
 # zeros, so the wrapper returns None instead of copying megabytes of
 # zeros per chunk — downstream (BatchBuilder.build_flat) treats None as
 # salt 0, which hashes identically.
-SLOTLESS_FORMATS = frozenset({"libsvm", "rating"})
+SLOTLESS_FORMATS = frozenset({"libsvm", "rating", "sgns"})
 
 # readable slack the C parsers may overread past the parse length (the
 # AVX2 span parsers issue one unguarded 8-byte load per token)
@@ -224,6 +226,7 @@ _PAD = 8
 # (space-preceded bare ``k`` entries) and adfea (ws-preceded entries)
 _COUNT_NEEDLES = {
     "libsvm": b": ", "criteo": b"\t\0", "adfea": b" \t", "rating": b" \t",
+    "sgns": b" \t",
 }
 
 
@@ -259,6 +262,9 @@ def _counts(lib, fmt: str, ba: bytearray, length: int) -> tuple[int, int]:
         nnz_cap = 39 * rows_cap + 1  # hard bound: <= 39 features per row
     elif fmt == "rating":
         nnz_cap = 2 * rows_cap  # exactly two entries a row
+    elif fmt == "sgns":
+        # a line's first id, and one more behind each ws byte at most
+        nnz_cap = out[2] + out[3] + rows_cap
     else:  # adfea: every entry is preceded by at least one ws byte
         nnz_cap = out[2] + out[3] + 1
     return rows_cap, nnz_cap

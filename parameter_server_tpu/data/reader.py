@@ -201,14 +201,19 @@ class MinibatchReader:
 
 def ingest_of(cfg) -> tuple[str, str]:
     """(format, key mode) that the readers and ``BatchBuilder`` take for a
-    PSConfig's files. ``data.format`` decides both: ``rating`` lines carry
-    ids of one dense space, items first (``[mf].num_items`` says where the
-    users' begin), and are keyed by identity; every other format carries
-    features, hashed into ``data.num_keys``."""
-    from parameter_server_tpu.data.libsvm import RATING, rating_format
+    PSConfig's files. ``data.format`` decides both: ``rating`` and ``sgns``
+    lines carry ids of one dense space in two ranges (items then users,
+    ``[mf].num_items`` saying where the users' begin; input then output
+    vectors, ``[w2v].vocab_size`` apart), and are keyed by identity; every
+    other format carries features, hashed into ``data.num_keys``."""
+    from parameter_server_tpu.data.libsvm import (
+        RATING, SGNS, rating_format, sgns_format,
+    )
 
     if cfg.data.format == RATING:
         return rating_format(cfg.mf.num_items), "identity"
+    if cfg.data.format == SGNS:
+        return sgns_format(cfg.w2v.vocab_size), "identity"
     return cfg.data.format, "hash"
 
 

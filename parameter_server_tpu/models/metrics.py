@@ -37,8 +37,15 @@ def rmse(labels: np.ndarray, predictions: np.ndarray) -> float:
     return float(np.sqrt(np.mean(d * d)))
 
 
+def sgns_loss(labels: np.ndarray, predictions: np.ndarray) -> float:
+    """Mean negative-sampling loss an example: the skip-gram app predicts
+    an example's log-likelihood, the negated loss."""
+    return float(-np.mean(np.asarray(predictions, dtype=np.float64)))
+
+
 # What an app's description (``parallel.spmd.StepApp.score``) names as its
 # evaluator's scores, each a function of (labels, predictions); the first is
 # also the progress table's column.
 BINARY_SCORES = (("auc", auc), ("logloss", logloss))
 REGRESSION_SCORES = (("rmse", rmse),)
+SGNS_SCORES = (("sgns_loss", sgns_loss),)

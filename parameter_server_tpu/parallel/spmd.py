@@ -15,7 +15,7 @@ Reference analog, mapped one-to-one:
   Server updater application (FTRL/AdaGrad/SGD entries)  -> exact additive
     deltas scattered with ``.at[].add`` (deterministic under padding).
 
-State layout: every table is (num_keys, vdim) sharded over "kv" on axis 0.
+State layout: every table is (num_keys, row_stride(vdim)) sharded over "kv" on axis 0.
 ``num_keys`` need not divide the kv axis size: tables are zero-padded up
 to the next axis multiple, in whole tiles (``padded_num_keys``) and the pad rows stay
 exactly zero under the store's pad-row invariant (batch keys are always
@@ -187,7 +187,11 @@ class Table:
     one table of a single-table app goes unnamed ("z", "n"; "ps.pull").
     ``init`` makes the slots of ``rows`` rows where the updater's zeros
     will not do (an embedding's starting values): on the device, inside
-    ``Runtime.init_state``, never as a host array of table size."""
+    ``Runtime.init_state``, never as a host array of table size. A slot is
+    stored ``row_stride(vdim)`` lanes wide, which is ``vdim`` for every
+    width but those the chip can neither gather nor scatter in place (300:
+    384, the lanes past ``vdim`` zero for ever); pulled rows and gradients
+    are ``vdim`` wide whatever the stride."""
 
     name: str
     updater: Updater
@@ -205,6 +209,14 @@ class Table:
             self.init(rows) if self.init is not None
             else self.updater.init(rows, self.vdim)
         )
+        # ``init`` may make its slots as stored, the pad lanes zero (XLA does
+        # not fuse a pad into what it pads: a chip-filling table's would be a
+        # second table); slots that come ``vdim`` wide are widened here
+        stride = row_stride(self.vdim)
+        slots = {
+            k: v if v.shape[1] == stride else jnp.pad(v, ((0, 0), (0, stride - self.vdim)))
+            for k, v in slots.items()
+        }
         return {self.key(k): v for k, v in slots.items()}
 
     def of(self, state: State) -> State:
@@ -484,7 +496,10 @@ def _values_of(b: Batch) -> jax.Array:
 # is a bitcast of that layout when B is a multiple of 8, and a gather of
 # B-lane blocks from the view is the row gather again (PR 33). Rows of
 # whole 128-lane tiles have nothing to pad: XLA keeps such a table
-# row-major, and the gather of whole rows reads it there
+# row-major, and the gather of whole rows and the scatter both work on it
+# there. A width that is none of these (100, 300) is therefore STORED at
+# the next whole tile (``row_stride``; PR 34): at 300 lanes the scatter
+# copies a rows-minor table to row-major and back whatever the gather does
 _ROW_GATHER_LANES = 32
 _TILE_LANES = 128
 
@@ -498,32 +513,47 @@ def _block_lanes(vdim: int) -> int:
     )
 
 
-def _take_rows(v: jax.Array, rows: jax.Array) -> jax.Array:
-    """``v[rows]`` for a (K, vdim) table slot. Rows of up to
-    ``_ROW_GATHER_LANES`` lanes, or of whole ``_TILE_LANES``-lane tiles:
-    ``jnp.take``, one slice a row. Other widths: one gather of
-    ``_block_lanes(vdim)``-lane blocks from the table viewed as (K, blocks,
-    lanes), its index naming (row, block), which XLA reads the table where
-    it lies for; a width with no such block: one gather of single elements,
-    index (row, lane), the only other form that leaves the layout alone.
-    ``rows`` are in range (the callers clamp them)."""
-    vdim = v.shape[1]
+def row_stride(vdim: int) -> int:
+    """The lanes a table slot stores a row of ``vdim`` lanes in: ``vdim``
+    itself where the chip reads and writes such rows where they lie (up to
+    ``_ROW_GATHER_LANES`` lanes, whole ``_block_lanes`` blocks, whole
+    ``_TILE_LANES``-lane tiles), else the next whole tile. The one place
+    that decides a table's layout from its width."""
+    if vdim <= _ROW_GATHER_LANES or _block_lanes(vdim) or vdim % _TILE_LANES == 0:
+        return vdim
+    return -(-vdim // _TILE_LANES) * _TILE_LANES
+
+
+def _take_rows(v: jax.Array, rows: jax.Array, vdim: int | None = None) -> jax.Array:
+    """``v[rows]`` for a (K, ``row_stride(vdim)``) table slot -> (U, vdim);
+    ``vdim`` unsaid is the slot's own width. Rows of up to
+    ``_ROW_GATHER_LANES`` lanes, or stored in whole ``_TILE_LANES``-lane
+    tiles: ``jnp.take``, one slice a row, less the stride's pad lanes.
+    Other widths: one gather of ``_block_lanes(vdim)``-lane blocks from
+    the table viewed as (K, blocks, lanes), its index naming (row, block),
+    which XLA reads the table where it lies for. ``rows`` are in range
+    (the callers clamp them)."""
+    stride = v.shape[1]
+    vdim = stride if vdim is None else vdim
+    if stride != row_stride(vdim):
+        raise ValueError(
+            f"a slot of {vdim}-lane rows is stored {row_stride(vdim)} lanes "
+            f"wide (spmd.row_stride), not {stride}"
+        )
+    if stride != vdim:
+        return jnp.take(v, rows, axis=0)[:, :vdim]
     if vdim <= _ROW_GATHER_LANES or vdim % _TILE_LANES == 0:
         return jnp.take(v, rows, axis=0)
     lanes = _block_lanes(vdim)
-    if lanes:
-        view = v.reshape(v.shape[0], vdim // lanes, lanes)
-        slice_sizes, offset_dims = (1, 1, lanes), (2,)
-    else:  # single elements: the "blocks" of a row are its lanes
-        view, slice_sizes, offset_dims = v, (1, 1), ()
+    view = v.reshape(v.shape[0], vdim // lanes, lanes)
     minor = jnp.arange(view.shape[1], dtype=rows.dtype)
     at = jnp.stack(jnp.broadcast_arrays(rows[:, None], minor[None, :]), axis=-1)
-    got = lax.gather(  # (U, blocks, lanes), or (U, vdim)
+    got = lax.gather(  # (U, blocks, lanes)
         view, at,
         lax.GatherDimensionNumbers(
-            offset_dims=offset_dims, collapsed_slice_dims=(0, 1), start_index_map=(0, 1)
+            offset_dims=(2,), collapsed_slice_dims=(0, 1), start_index_map=(0, 1)
         ),
-        slice_sizes=slice_sizes,
+        slice_sizes=(1, 1, lanes),
         mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS,
     )
     return got.reshape(rows.shape[0], vdim)
@@ -531,17 +561,18 @@ def _take_rows(v: jax.Array, rows: jax.Array) -> jax.Array:
 
 def _local_pull(
     updater: Updater, state_l: State, idx: jax.Array, shard_size: int,
-    table: str = "",
+    table: str = "", vdim: int | None = None,
 ) -> jax.Array:
     """This shard's contribution to pulled weights for global ids ``idx``.
     ``table`` (here and in the pushes): the scope a named table's ops go
-    under, innermost."""
+    under, innermost; ``vdim``: the table's row width where its slots are
+    stored wider (``row_stride``), else unsaid."""
     begin = lax.axis_index("kv") * shard_size
     local = idx - begin
     in_range = (local >= 0) & (local < shard_size)
     safe = jnp.where(in_range, local, 0)
     with _sub_scope(table):
-        rows = {k: _take_rows(v, safe) for k, v in state_l.items()}
+        rows = {k: _take_rows(v, safe, vdim) for k, v in state_l.items()}
         w = updater.weights(rows)
         return jnp.where(in_range[:, None], w, 0.0)
 
@@ -568,7 +599,11 @@ def _add_rows(
     ``.at[].add(mode="drop")``, which wraps a negative row (an earlier
     shard's key) onto a valid one first. A false promise is undefined
     behaviour on the chip and invisible on the CPU, which ignores the
-    hint."""
+    hint. Deltas narrower than the table (a slot stored at ``row_stride``)
+    are widened with zeros: the pad lanes stay what they were, zero."""
+    pad = table.shape[1] - deltas.shape[1]
+    if pad:
+        deltas = jnp.pad(deltas, ((0, 0), (0, pad)))
     dnums = lax.ScatterDimensionNumbers(
         update_window_dims=(1,),
         inserted_window_dims=(0,),
@@ -588,6 +623,7 @@ def _local_push(
     shard_size: int,
     table: str = "",
     ascending: bool = False,
+    vdim: int | None = None,
 ) -> State:
     """Apply every worker's push to this kv shard, sequentially (ref: the
     server processes each worker's Push message as its own updater step).
@@ -608,7 +644,7 @@ def _local_push(
         in_range = (local >= 0) & (local < shard_size)
         safe = jnp.where(in_range, local, 0)
         with jax.named_scope("gather"), _sub_scope(table):
-            rows = {k: _take_rows(v, safe) for k, v in state_l.items()}
+            rows = {k: _take_rows(v, safe, vdim) for k, v in state_l.items()}
         with jax.named_scope("update"), _sub_scope(table):
             deltas = updater.delta(rows, g)
         with jax.named_scope("scatter"), _sub_scope(table):
@@ -652,9 +688,11 @@ def _local_push_aggregate(
     in_range = (local >= 0) & (local < shard_size)
     safe = jnp.where(in_range, local, 0)
     mask = in_range[:, None].astype(grad.dtype)
-    vdim = grad.shape[-1]
+    lanes = next(iter(state_l.values())).shape[1]  # the slots' stride
+    if lanes != grad.shape[-1]:
+        grad = jnp.pad(grad, ((0, 0), (0, lanes - grad.shape[-1])))
     with jax.named_scope("scatter"), _sub_scope(table):
-        g_slice = jnp.zeros((shard_size, vdim), grad.dtype).at[safe].add(
+        g_slice = jnp.zeros((shard_size, lanes), grad.dtype).at[safe].add(
             mask * grad
         )
         touched = jnp.zeros((shard_size, 1), grad.dtype).at[safe].add(mask)
@@ -678,6 +716,7 @@ def _local_push_quantized(
     stream: int = 0,  # static sub-stream tag (multi-table apps: one per table)
     table: str = "",
     ascending: bool = False,  # forwarded to ``_local_push``
+    vdim: int | None = None,  # forwarded to ``_local_push``
 ) -> State:
     """Per-worker push with int8-quantized gradients on the wire (the
     reference's fixing_float filter re-expressed as a quantized
@@ -707,7 +746,7 @@ def _local_push_quantized(
     all_scale = lax.all_gather(scale, "data")  # (D,)
     all_grad = all_q.astype(grad.dtype) * all_scale[:, None, None]
     return _local_push(
-        updater, state_l, all_idx, all_grad, shard_size, table, ascending
+        updater, state_l, all_idx, all_grad, shard_size, table, ascending, vdim
     )
 
 
@@ -807,7 +846,9 @@ def _microstep(
     with jax.named_scope("ps.pull"):
         pulled = {
             t.name: lax.psum(
-                _local_pull(t.updater, t.of(state_l), idx, shard_size, t.name),
+                _local_pull(
+                    t.updater, t.of(state_l), idx, shard_size, t.name, t.vdim
+                ),
                 "kv",
             )  # Pull: slice + merge (ref kv_vector match)
             for t in app.tables
@@ -829,7 +870,7 @@ def _microstep(
                 new = _local_push_quantized(
                     t.updater, tab, idx, g, shard_size, push_seed,
                     stream=i + 1 if len(app.tables) > 1 else 0, table=t.name,
-                    ascending=True,
+                    ascending=True, vdim=t.vdim,
                 )
             else:
                 # Push: every data shard's (keys, grads) reach every kv shard.
@@ -837,7 +878,7 @@ def _microstep(
                 all_grad = lax.all_gather(g, "data")  # (D, U, vdim)
                 new = _local_push(
                     t.updater, tab, all_idx, all_grad, shard_size, t.name,
-                    ascending=True,
+                    ascending=True, vdim=t.vdim,
                 )
             new_state.update({t.key(k): v for k, v in new.items()})
     loss_sum = lax.psum(loss, "data")
@@ -999,7 +1040,7 @@ def make_spmd_predict_step(app: "StepApp | Updater", mesh: Mesh, num_keys: int):
                 t.name: lax.psum(
                     _local_pull(
                         t.updater, t.of(state_l), b["unique_keys"],
-                        shard_size, t.name,
+                        shard_size, t.name, t.vdim,
                     ),
                     "kv",
                 )

@@ -27,9 +27,9 @@ from parameter_server_tpu.utils.metrics import observe_scalar, wire_counters
 
 class DispatchWindow:
     """The host-side bounded async-dispatch window every trainer shares
-    (the single home of the gate arithmetic — PodTrainer, the in-memory
-    word2vec epoch, and the streaming word2vec path all retire through
-    here, so the wait_time semantics can't silently diverge).
+    (the single home of the gate arithmetic — every app trains through
+    PodTrainer and retires through here, so the wait_time semantics can't
+    silently diverge).
 
     Protocol, for step t about to be dispatched:
         window.gate(t)          # retire every entry <= t - max_delay - 1

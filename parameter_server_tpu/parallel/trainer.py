@@ -145,6 +145,10 @@ def app_from_config(cfg: PSConfig) -> StepApp:
         from parameter_server_tpu.models import matrix_fac
 
         return matrix_fac.app_from_config(cfg)
+    if cfg.app == "word2vec":
+        from parameter_server_tpu.models import word2vec
+
+        return word2vec.app_from_config(cfg)
     return linear_app(updater_from_config(cfg))
 
 
@@ -171,8 +175,9 @@ class _EpochStream(_WorkerStream):
 
 
 class PodTrainer:
-    """Train ``cfg.app`` (the flagship sparse-LR app, Wide&Deep or matrix
-    factorization) across a data x kv device mesh: state, step, predict,
+    """Train ``cfg.app`` (the flagship sparse-LR app, Wide&Deep, matrix
+    factorization or skip-gram) across a data x kv device mesh: state, step,
+    predict,
     scores and checkpoint all come from the app's description
     (``app_from_config``; ``app`` overrides it), the files' format and key
     mode from ``cfg.data.format`` (``data.reader.ingest_of``)."""
@@ -792,7 +797,7 @@ class PodTrainer:
         host = self.runtime.state_to_host(t.of(self.state))
         return np.asarray(
             t.updater.weights({k: jnp.asarray(v) for k, v in host.items()})
-        )[: self.cfg.data.num_keys]
+        )[: self.cfg.data.num_keys, : t.vdim]
 
     def save(self, ckpt_dir, meta: dict | None = None) -> None:
         """Per-host sharded checkpoint (each host writes its key-range

@@ -21,7 +21,7 @@ class DataConfig:
     """Ref: linear_method.proto DataConfig {format, file, ignore_feature_group}."""
 
     files: list[str] = field(default_factory=list)
-    format: str = "libsvm"  # libsvm | criteo | adfea | rating | cache
+    format: str = "libsvm"  # libsvm | criteo | adfea | rating | sgns | cache
     num_keys: int = 1 << 22  # dense hashed key-space size (power of two + pad row)
     val_files: list[str] = field(default_factory=list)
     max_nnz_per_example: int = 512
@@ -124,14 +124,19 @@ class MFConfig:
 
 @dataclass
 class W2VConfig:
-    """word2vec app settings (ref: BASELINE's SGNS parity config).
-    data.files = whitespace-separated token-id text (or .npy)."""
+    """word2vec app settings (ref: BASELINE's SGNS parity config; Mikolov
+    et al., arXiv:1310.4546). data.files = whitespace-separated token-id
+    text (or .npy), turned into ``sgns`` example files
+    (models.word2vec.examples_from_corpus; data.format "sgns":
+    models.word2vec.pod_config sets it, and data.num_keys = 1 + 2 x
+    vocab_size) and trained by plain SGD, the summed gradient of a
+    minibatch applied once a row."""
 
     vocab_size: int = 1 << 16
     dim: int = 64
     window: int = 2
     negatives: int = 5
-    eta: float = 0.3
+    eta: float = 0.025  # word2vec.c's starting rate for skip-gram
     batch_size: int = 8192
     block_tokens: int = 1 << 20
 

@@ -368,23 +368,33 @@ def test_device_made_factors_are_the_benchmark_references_function(seed):
 
 
 @pytest.mark.parametrize(
-    "vdim,sliced", [(1, "1, 1"), (16, "1, 16"), (32, "1, 32"), (64, "1, 1, 32"), (128, "1, 128"), (48, "1, 1, 24"), (100, "1, 1")]
+    "vdim,sliced",
+    [(1, "1, 1"), (16, "1, 16"), (32, "1, 32"), (64, "1, 1, 32"), (128, "1, 128"), (48, "1, 1, 24"), (100, "1, 128"), (300, "1, 384")],
 )
 def test_take_rows_of_any_width_against_numpy(vdim, sliced):
     """``_take_rows`` is ``jnp.take`` up to 32 lanes, the linear app's and
-    Wide&Deep's gather left as it was, and for rows of whole 128-lane tiles,
-    which the chip keeps row-major; for other widths a gather of the widest
-    blocks of 8k lanes, at most 32, that a row splits into; and an element
-    gather where a row splits into none: the same rows, to the bit."""
+    Wide&Deep's gather left as it was, and for rows stored in whole 128-lane
+    tiles, which the chip keeps row-major; for other widths a gather of the
+    widest blocks of 8k lanes, at most 32, that a row splits into. A width
+    that splits into none (100, 300) is STORED at the next whole tile
+    (``row_stride``; the ONE form for such widths since PR 34: the gather
+    of single elements is gone) and comes back cut to its own lanes: the
+    same rows, to the bit."""
     rng = np.random.default_rng(vdim)
-    table = rng.normal(size=(1000, vdim)).astype(np.float32)
+    stride = spmd.row_stride(vdim)
+    table = np.zeros((1000, stride), np.float32)
+    table[:, :vdim] = rng.normal(size=(1000, vdim))
     rows = np.concatenate([[0, 0, 999, 1, 999], rng.integers(0, 1000, 60)]).astype(np.int32)
-    got = jax.jit(spmd._take_rows)(jnp.asarray(table), jnp.asarray(rows))
-    np.testing.assert_array_equal(np.asarray(got), table[rows])
-    # what the lowered gather slices: a whole row up to 32 lanes and at 128, a
-    # block of the (rows, blocks, lanes) view between, single elements where no block is
-    text = jax.jit(spmd._take_rows).lower(jnp.asarray(table), jnp.asarray(rows)).as_text()
+    take = jax.jit(lambda v, i: spmd._take_rows(v, i, vdim))
+    got = take(jnp.asarray(table), jnp.asarray(rows))
+    assert got.shape == (len(rows), vdim)
+    np.testing.assert_array_equal(np.asarray(got), table[rows][:, :vdim])
+    # what the lowered gather slices: a whole stored row up to 32 lanes and at whole
+    # tiles, a block of the (rows, blocks, lanes) view between; never single elements
+    text = take.lower(jnp.asarray(table), jnp.asarray(rows)).as_text()
     assert text.count("slice_sizes") == 1 and f"slice_sizes = array<i64: {sliced}>" in text, text
+    if stride == vdim:  # the width unsaid is the slot's own
+        np.testing.assert_array_equal(np.asarray(spmd._take_rows(jnp.asarray(table), jnp.asarray(rows))), table[rows])
 
 
 def test_cli_dump_holds_the_factors_by_id(tmp_path):

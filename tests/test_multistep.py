@@ -175,64 +175,6 @@ class TestPodTrainerMultistep:
                 runs["k1"][2]["objv"], rel=1e-5
             )
 
-class TestWord2VecMultistep:
-    def _corpus(self):
-        rng = np.random.default_rng(4)
-        # two clusters of co-occurring words (the quality signal the
-        # existing w2v tests use)
-        return np.concatenate(
-            [
-                rng.choice(np.arange(5) + 5 * (i % 2), size=40)
-                for i in range(500)
-            ]
-        )
-
-    @pytest.mark.parametrize("mesh_shape", [None, (2, 2)])
-    def test_w2v_multistep_matches_single_step(self, mesh_shape):
-        """steps_per_call=3 reproduces the K=1 trajectory exactly on both
-        the single-device and mesh paths (sampler draws are consumed in
-        identical order; the tail group pads with inert microsteps)."""
-        from parameter_server_tpu.models.word2vec import Word2Vec
-
-        corpus = self._corpus()
-        embs, losses = [], []
-        for k in (1, 3):
-            kw = dict(
-                vocab_size=16, dim=8, eta=0.5, num_negatives=4, window=2,
-                seed=0, reporter=quiet(), steps_per_call=k,
-            )
-            if mesh_shape is not None:
-                kw["mesh"] = make_mesh(*mesh_shape)
-            w2v = Word2Vec(**kw)
-            losses.append(w2v.train_epoch(corpus, batch_size=512, seed=1))
-            embs.append(w2v.embeddings())
-        assert losses[0] == pytest.approx(losses[1], rel=1e-5)
-        np.testing.assert_allclose(embs[0], embs[1], rtol=1e-4, atol=1e-6)
-
-    def test_w2v_streaming_multistep(self, tmp_path):
-        """The streaming corpus path groups K pipeline items per device
-        call and still counts every real pair."""
-        from parameter_server_tpu.models.word2vec import Word2Vec
-
-        corpus = self._corpus()
-        p = tmp_path / "corpus.txt"
-        p.write_text(" ".join(str(t) for t in corpus))
-        embs = []
-        for k in (1, 3):
-            w2v = Word2Vec(
-                vocab_size=16, dim=8, eta=0.5, num_negatives=4, window=2,
-                seed=0, reporter=quiet(), mesh=make_mesh(2, 2),
-                steps_per_call=k,
-            )
-            w2v.train_files([str(p)], batch_size=512, epochs=1,
-                            pipeline_depth=2, seed=3)
-            embs.append(w2v.embeddings())
-        np.testing.assert_allclose(embs[0], embs[1], rtol=1e-4, atol=1e-6)
-        within = np.mean([w2v.similarity(0, i) for i in range(1, 5)])
-        across = np.mean([w2v.similarity(0, i) for i in range(5, 10)])
-        assert within > across
-
-
 class TestWideDeepMultistep:
     def _batches(self, n_batches=7, n_per=64):
         labels, keys, vals, _ = make_sparse_logistic(

@@ -123,39 +123,6 @@ class TestWideDeepQuantizedPush:
         assert app.trainer.calls_trained == len(batches) // (2 * 2)
 
 
-class TestWord2VecSPMD:
-    @pytest.mark.parametrize("push_mode", ["per_worker", "aggregate"])
-    def test_learns_structure_on_mesh(self, push_mode):
-        """BASELINE's word2vec config on the mesh: both embedding tables
-        range-sharded over kv, pair batches over data, SSP-gated dispatch
-        (max_delay=1) with no per-batch device sync. Aggregate mode is the
-        AdaGrad sync-aggregation trajectory — quality must hold there too."""
-        from parameter_server_tpu.models.word2vec import Word2Vec
-
-        mesh = make_mesh(2, 4)
-        rng = np.random.default_rng(0)
-        chunks = []
-        for _ in range(600):
-            topic = rng.integers(0, 2)
-            chunks.append(rng.integers(0, 5, size=8) + 5 * topic)
-        corpus = np.concatenate(chunks)
-        # vocab padded to 16 (divisible by the kv axis); rows 10-15 unused.
-        # batch_size is per data shard — the same 2048 the single-device
-        # test converges with (smaller per-push batches decay Adagrad's
-        # effective lr too fast on this tiny corpus)
-        w2v = Word2Vec(vocab_size=16, dim=16, eta=0.5, num_negatives=4,
-                       window=2, reporter=quiet(), mesh=mesh, max_delay=1,
-                       push_mode=push_mode)
-        losses = [
-            w2v.train_epoch(corpus, batch_size=2048, seed=ep)
-            for ep in range(8)
-        ]
-        assert losses[-1] < losses[0]
-        within = np.mean([w2v.similarity(0, i) for i in range(1, 5)])
-        across = np.mean([w2v.similarity(0, i) for i in range(5, 10)])
-        assert within > across + 0.3, (within, across)
-
-
 class TestWideDeepQuantizedFromConfig:
     def test_factory_accepts_quantized_and_trains(self, tmp_path):
         """The config path (TOML [parallel] push_mode = quantized ->
@@ -178,46 +145,3 @@ class TestWideDeepQuantizedFromConfig:
         app.train(batches, report_every=10**6)
         assert app.push_mode == "quantized"
         assert app.trainer.calls_trained == len(batches) // (2 * 2)
-
-
-class TestOneWorkerMeshStepAgainstSingleDevice:
-    """SGNS calls ``_local_push`` with id lists of its own. With one worker
-    the mesh step is the single-device step (which pushes through plain
-    ``.at[].add``), whatever the kv sharding: SGNS pushes word ids as the
-    pairs come, repeats and all, and promises nothing: the same scatter,
-    told nothing of the order (another shard's rows dropped there too, not
-    added as zeros to row 0). Matrix factorization runs through the shared
-    step since PR 32 (tests/test_matrix_fac_pod.py)."""
-
-    @pytest.mark.parametrize("mesh_shape", [(1, 1), (1, 2), (1, 4)])
-    def test_sgns(self, mesh_shape):
-        from parameter_server_tpu.kv.updaters import Adagrad
-        from parameter_server_tpu.models.word2vec import make_w2v_spmd_train_step, sgns_train_step
-
-        mesh = make_mesh(*mesh_shape)
-        vocab, dim, B, K = 16, 8, 64, 3
-        rng = np.random.default_rng(4)
-        up = Adagrad(eta=0.5)
-        batch = {
-            "center": rng.integers(0, vocab, B).astype(np.int32),  # unsorted, with repeats
-            "context": rng.integers(0, vocab, B).astype(np.int32),
-            "negatives": rng.integers(0, vocab, (B, K)).astype(np.int32),
-            "mask": (rng.random(B) < 0.9).astype(np.float32),
-        }
-        start = {
-            "w": (rng.normal(size=(vocab, dim)) * 0.1).astype(np.float32),
-            "n": rng.random(size=(vocab, dim)).astype(np.float32),
-        }
-        step = make_w2v_spmd_train_step(up, up, mesh, vocab)
-        got_in, got_out, loss = step(
-            shard_state(start, mesh), shard_state(start, mesh),
-            {k: jax.numpy.asarray(v[None]) for k, v in batch.items()},
-        )
-        as_jax = lambda s: {k: jax.numpy.asarray(v) for k, v in s.items()}  # noqa: E731
-        want_in, want_out, want_loss = sgns_train_step(
-            up, up, as_jax(start), as_jax(start), {k: jax.numpy.asarray(v) for k, v in batch.items()}
-        )
-        np.testing.assert_allclose(float(loss), float(want_loss), rtol=1e-6)
-        for got, want in ((got_in, want_in), (got_out, want_out)):
-            for k in want:
-                np.testing.assert_allclose(np.asarray(got[k]), np.asarray(want[k]), rtol=2e-6, atol=1e-7, err_msg=k)

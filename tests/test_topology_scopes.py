@@ -483,7 +483,7 @@ def test_mf_table_ops_are_scoped_and_the_table_is_read_where_it_lies(mf_text, da
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < table_bytes + (512 << 20)
 
 
-@pytest.mark.parametrize("vdim", [128, 256])
+@pytest.mark.parametrize("vdim", [128, 256, 300])
 def test_rows_of_whole_tiles_are_taken_from_the_table_where_it_lies(topo, vdim):
     """A table whose rows are whole 128-lane tiles has nothing to pad, so the
     chip keeps it row-major and ``_take_rows`` takes whole rows from it: its
@@ -497,17 +497,19 @@ def test_rows_of_whole_tiles_are_taken_from_the_table_where_it_lies(topo, vdim):
     from parameter_server_tpu.parallel import spmd
 
     one = SingleDeviceSharding(topo.devices[0])
-    rows = 25_000_960 * 128 // vdim
-    table = jax.ShapeDtypeStruct((rows, vdim), jnp.float32, sharding=one)
+    stride = spmd.row_stride(vdim)  # 300 lanes are stored in 384: whole tiles too
+    rows = 25_000_960 * 128 // stride
+    table = jax.ShapeDtypeStruct((rows, stride), jnp.float32, sharding=one)
     idx = jax.ShapeDtypeStruct((MF_SLOTS,), jnp.int32, sharding=one)
 
     def pull_and_push(v, at):
-        got = spmd._take_rows(v, at)
+        got = spmd._take_rows(v, at, vdim)
         return spmd._add_rows(v, at, 0.5 * got, True), got
 
     compiled = jax.jit(pull_and_push, donate_argnums=0).lower(table, idx).compile()
     assert not copies_of(instructions(compiled.as_text()), rows * vdim)
-    assert compiled.memory_analysis().temp_size_in_bytes < 512 << 20
+    # batch-sized: at 384 lanes three (slots, 384) buffers, 576 MiB beside a table of 11.9 GiB
+    assert compiled.memory_analysis().temp_size_in_bytes < 768 << 20
 
 
 @pytest.mark.parametrize("data,kv,program", MF_CASES)
@@ -531,3 +533,145 @@ def test_mf_large_unscoped_instructions_are_the_known_kinds(mf_text, data, kv, p
         if opcode == "fusion" and not scopes[name]:
             # bookkeeping at batch size (a (U, 64) buffer at most), never a table op
             assert elements(shape) <= 2 * 64 * MF_SLOTS and all(elements(s) < MF_USERS for s in operand_shapes), name
+
+
+# -- skip-gram: one 300-lane table, stored 384 wide, through the same step -----
+SGNS_VOCAB, SGNS_DIM, SGNS_NEG = 3_000_000, 300, 5  # the cell sgns3m.train
+SGNS_MINIBATCH = 16_384
+SGNS_ENTRIES, SGNS_SLOTS = 7 * SGNS_MINIBATCH, 7 * SGNS_MINIBATCH + 1  # the builder's caps: 114,688 and one more
+SGNS_CASES = [(1, 1, "multistep"), (1, 1, "predict")]
+SGNS_NAMES = frozenset({"sgns"})
+
+
+@pytest.fixture(scope="module")
+def sgns_text(topo):
+    """(data, kv, program) -> optimised HLO text of the skip-gram programs at
+    the cell's size (6,000,640 rows x 300 lanes under SGD), compiled once:
+    about 16 s for the step and 3 for predict, one mesh (the 2x2 programs
+    are the small tests': ``tests/test_word2vec_pod.py``)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding
+
+    from parameter_server_tpu.models import word2vec
+    from parameter_server_tpu.parallel import spmd
+    from parameter_server_tpu.utils.config import PSConfig
+
+    texts: dict = {}
+
+    def get(data: int, kv: int, program: str) -> str:
+        key = (data, kv, program)
+        if key in texts:
+            return texts[key]
+        cfg = PSConfig()
+        cfg.w2v.vocab_size, cfg.w2v.dim, cfg.w2v.negatives = SGNS_VOCAB, SGNS_DIM, SGNS_NEG
+        cfg.w2v.batch_size = SGNS_MINIBATCH
+        cfg = word2vec.pod_config(cfg)
+        app = word2vec.app_from_config(cfg)
+        mesh = Mesh(np.array(topo.devices[: data * kv]).reshape(data, kv), ("data", "kv"))
+        rows = spmd.padded_num_keys(cfg.data.num_keys, kv)
+        shapes = jax.eval_shape(lambda: app.init_tables(rows))
+        table = NamedSharding(mesh, spmd.state_spec())
+        state = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=table) for k, v in shapes.items()}
+        feed = NamedSharding(mesh, spmd.batch_spec())
+        lead = (K,) if program == "multistep" else ()
+        fields = {
+            "unique_keys": ((SGNS_SLOTS,), jnp.int32), "local_ids": ((SGNS_ENTRIES,), jnp.int32),
+            "row_splits": ((SGNS_MINIBATCH + 1,), jnp.int32), "values": ((SGNS_ENTRIES,), jnp.float32),
+            "labels": ((SGNS_MINIBATCH,), jnp.float32), "example_mask": ((SGNS_MINIBATCH,), jnp.bool_),
+        }
+        batch = {
+            k: jax.ShapeDtypeStruct((data, *lead, *shape), dt, sharding=feed)
+            for k, (shape, dt) in fields.items()
+        }
+        if program == "multistep":
+            fn, args = spmd.make_spmd_train_multistep(app, mesh, cfg.data.num_keys), (state, batch, 0)
+        else:
+            fn, args = spmd.make_spmd_predict_step(app, mesh, cfg.data.num_keys), (state, batch)
+        (jitted,) = [
+            c.cell_contents for c in fn.__closure__
+            if callable(c.cell_contents) and hasattr(c.cell_contents, "lower")
+        ]
+        compiled = jitted.lower(*args).compile()
+        texts[key] = compiled.as_text()
+        texts[key, "memory"] = compiled.memory_analysis()
+        # the table made on the device at its stride in one pass: no second table to pad the first
+        if program == "multistep":
+            made = jax.jit(lambda: app.init_tables(rows), out_shardings=table).lower().compile()
+            texts["init", "memory"] = made.memory_analysis()
+        return texts[key]
+
+    get.texts = texts
+    return get
+
+
+@pytest.mark.parametrize("data,kv,program", SGNS_CASES)
+def test_sgns_table_ops_are_scoped_and_the_table_is_worked_on_where_it_lies(sgns_text, data, kv, program):
+    """A slot of 300-lane rows is stored ``row_stride(300)`` = 384 lanes
+    wide, whole tiles, which the chip keeps row-major: the gather of whole
+    rows and the push's scatter both work on it in place. At 300 lanes as
+    stored until PR 34 the scatter copied the table to row-major and back
+    every microstep (8.98 GiB of temporaries beside 6.8: no fit), whatever
+    the gather. Every executed instruction that reads or writes the table
+    sits under ``ps.pull/sgns`` or ``ps.push/<stage>/sgns``; nothing with the
+    table's elements is copied; the temporaries are batch-sized; and the
+    table's start is made at its stride in one pass."""
+    from parameter_server_tpu.parallel import spmd
+
+    text = sgns_text(data, kv, program)
+    _, scopes = spmd.hlo_scopes(text, SGNS_NAMES)
+    rows = spmd.padded_num_keys(1 + 2 * SGNS_VOCAB, kv) // kv
+    stride = spmd.row_stride(SGNS_DIM)
+    assert (rows, stride) == (6_000_640, 384)
+    table = re.compile(rf"\[{rows},{stride}\]")
+    touching = [
+        (name, scopes[name])
+        for name, shape, opcode, operand_shapes in executed(text)
+        if table.search(shape) or any(table.search(s) for s in operand_shapes)
+    ]
+    assert touching
+    assert all(re.match(r"^ps\.(pull|push/\w+)/sgns$", scope) for _, scope in touching), touching
+    found = set(scopes.values())
+    assert {"ps.pull/sgns", "ps.grad"} <= found, found
+    assert "ps.row_ids" not in found  # the entries' slots are read off row_splits: XLA drops the row ids
+    every = instructions(text)
+    assert not copies_of(every, rows * SGNS_DIM)
+    # the table is row-major wherever it appears, and no instruction makes a 300-lane table of it
+    assert all("{1,0:" in shape for _, _, shape, _, _, _ in every if table.search(shape) and shape.startswith("f32")), "rows-minor"
+    assert not [name for _, name, shape, _, _, _ in every if re.search(rf"\[{rows},{SGNS_DIM}\]", shape)]
+    gathers = [s for _, _, _, opcode, ops, _ in every if opcode == "gather" for s in ops[:1] if table.search(s)]
+    assert len(gathers) == 1, gathers  # on one chip the push's gather is the pull's
+    if program == "multistep":
+        assert {"ps.push/scatter/sgns", "ps.push/update/sgns"} <= found, found
+        scatters = [rest for _, _, shape, opcode, _, rest in every if opcode == "scatter" and table.search(shape)]
+        assert len(scatters) == 1 and "indices_are_sorted=true" in scatters[0], scatters
+        init = sgns_text.texts["init", "memory"]
+        assert init.temp_size_in_bytes < 64 << 20, init.temp_size_in_bytes
+    mem = sgns_text.texts[(data, kv, program), "memory"]
+    table_bytes = 4 * rows * stride
+    assert table_bytes == 9_216_983_040
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < table_bytes + (768 << 20)
+    assert mem.temp_size_in_bytes < 640 << 20, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("data,kv,program", SGNS_CASES)
+def test_sgns_large_unscoped_instructions_are_the_known_kinds(sgns_text, data, kv, program):
+    """The list of unscoped kinds holds for the fourth app."""
+    from parameter_server_tpu.parallel import spmd
+
+    text = sgns_text(data, kv, program)
+    _, scopes = spmd.hlo_scopes(text, SGNS_NAMES)
+    known = re.compile(
+        r"^(copy|copy-start|copy-done|custom-call|slice-start|slice-done|async-start|async-done"
+        r"|reduce|broadcast|dynamic-update-slice|all-reduce|fusion)$"
+    )
+    strays = [
+        (name, opcode, shape)
+        for name, shape, opcode, _ in executed(text)
+        if not scopes[name] and elements(shape) >= SGNS_SLOTS and not known.match(opcode)
+    ]
+    assert not strays, strays
+    for name, shape, opcode, operand_shapes in executed(text):
+        if opcode == "fusion" and not scopes[name]:
+            # bookkeeping at batch size (a (U, 384) buffer at most), never a table op
+            assert elements(shape) <= 384 * SGNS_SLOTS and all(elements(s) < SGNS_VOCAB for s in operand_shapes), name

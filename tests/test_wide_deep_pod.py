@@ -252,7 +252,7 @@ def push_keys(case, workers):
 
 @pytest.mark.parametrize("case", PUSH_CASES)
 @pytest.mark.parametrize("mesh_name", list(MESHES))
-@pytest.mark.parametrize("vdim", [1, 8, 16, 64])
+@pytest.mark.parametrize("vdim", [1, 8, 16, 64, 300])
 def test_mesh_push_of_any_width_against_numpy(vdim, mesh_name, case):
     """``_local_push`` over the mesh, as ``_microstep`` calls it (every
     worker's keys and gradients gathered, applied in worker order, the
@@ -261,7 +261,9 @@ def test_mesh_push_of_any_width_against_numpy(vdim, mesh_name, case):
     depends on the order of two workers' pushes of one key), every row
     no key names untouched bit for bit. The caller that does not promise
     (unsorted ids, repeats among them) goes through the same scatter, told
-    nothing about the order, and matches too."""
+    nothing about the order, and matches too. A slot of 300-lane rows is
+    stored 384 lanes wide (``spmd.row_stride``): the gradients stay 300
+    wide, the lanes past them stay zero."""
     from jax import lax, shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -279,19 +281,22 @@ def test_mesh_push_of_any_width_against_numpy(vdim, mesh_name, case):
         "w": rng.normal(size=(PUSH_ROWS, vdim)).astype(np.float32),
         "n": rng.random(size=(PUSH_ROWS, vdim)).astype(np.float32),
     }
+    pad = ((0, 0), (0, spmd.row_stride(vdim) - vdim))  # the slots as stored
 
     def local(state_l, idx_l, grad_l):
         return spmd._local_push(
             ada, state_l, lax.all_gather(idx_l[0], "data"), lax.all_gather(grad_l[0], "data"),
-            shard, ascending=case != "unsorted_no_promise",
+            shard, ascending=case != "unsorted_no_promise", vdim=vdim,
         )
 
     push = jax.jit(shard_map(
         local, mesh=mesh, in_specs=(spmd.state_spec(), P("data"), P("data")),
         out_specs=spmd.state_spec(), check_vma=False,
     ))
-    state = {k: jax.device_put(v, NamedSharding(mesh, spmd.state_spec())) for k, v in start.items()}
+    state = {k: jax.device_put(np.pad(v, pad), NamedSharding(mesh, spmd.state_spec())) for k, v in start.items()}
     got = push(state, jnp.asarray(idx), jnp.asarray(grad))
+    assert all(not np.asarray(v)[:, vdim:].any() for v in got.values())
+    got = {k: np.asarray(v)[:, :vdim] for k, v in got.items()}
 
     want = {k: v.copy() for k, v in start.items()}
     for w in range(data):  # the server applies each worker's push as its own step

@@ -12,14 +12,16 @@ Every form's result is checked on the chip against the deltas row by row,
 with the count of non-zero rows and the absolute sum. One JSON line a case,
 also appended to chiprun_out/probe_push_scatter.jsonl.
 
-``--wide`` (step 2 of ISSUE 33; PERF.md section 6, PR 33) runs the 64-lane
-case alone: the pull of one ``mfhw.train`` minibatch's key slots (65,536
-ratings of the benchmark's Hugewiki-shaped traffic from the seed: 131,072
-slots, about 75,500 of them keys) out of f32[50122752,64], the gather of
-single elements the step had in PR 32 against ``spmd._take_rows``, every
-form's rows checked against NumPy's; one JSON line a form.
+``--wide VDIM ROWS [SLOTS REAL]`` (PERF.md section 6, PRs 33 and 34) runs
+one wide table alone: the pull of REAL ascending keys in SLOTS key slots
+(131,072 and 75,528, ``mfhw.train``'s, unless given; ``sgns3m.train``'s are
+114,689 and 72,100) out of f32[ROWS,VDIM], the gather of single elements
+the step had in PR 32 (``--no-element`` leaves it out) against
+``spmd._take_rows`` on the slot as the store keeps it, and the push's
+``spmd._add_rows`` into it, every form's rows checked against NumPy's; one
+JSON line a form. The seed comes first: ``SEED --wide 300 6000640 114689 72100``.
 
-    chiprun --timeout 1500 -- python3 tools/probe_push_scatter.py [SEED] [--rest | --wide]
+    chiprun --timeout 1500 -- python3 tools/probe_push_scatter.py [SEED] [--rest | --wide VDIM ROWS]
 """
 import json, os, sys, time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -29,7 +31,7 @@ from jax import lax
 from benchmark.harness import criteo
 from parameter_server_tpu.parallel import spmd
 
-SEED = next((int(a) for a in sys.argv[1:] if a.isdigit()), 2270000001)
+SEED = int(sys.argv[1]) if len(sys.argv) > 1 and sys.argv[1].isdigit() else 2270000001
 U = 1 << 16  # BatchBuilder's bucket for a batch's about 40,000 keys
 print("device", jax.devices()[0].platform, jax.devices()[0].device_kind, flush=True)
 assert jax.devices()[0].platform == "tpu"
@@ -145,37 +147,63 @@ def element_rows(v, rows):  # the pull of rows wider than 32 lanes in PR 32
     return lax.gather(v, at, dnums, slice_sizes=(1, 1), mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS)
 
 
-def wide_case():
-    from benchmark.harness import ratings, ref_mf
-    from parameter_server_tpu.models import matrix_fac
+def wide_case(vdim, rows, slots, real):
+    """Pull and push of ``real`` ascending keys in ``slots`` key slots at a
+    (rows, vdim) table filled from the seed's hash: the gather of single
+    elements from the table as XLA lays out (rows, vdim) against
+    ``spmd._take_rows`` on the slot as the store keeps it (``row_stride``
+    lanes wide), rows checked against NumPy's copy of the hash to the bit;
+    then ``spmd._add_rows`` into the stored slot, checked row by row."""
+    from benchmark.harness import ref_mf
+    from parameter_server_tpu.kv import store
 
-    conf = json.load(open("benchmark/configs/mf_hugewiki_1chip.json"))
-    st = conf["settings"]
-    users, items, rank, n = st["num_users"], st["num_items"], st["rank"], st["minibatch"]
-    live = 1 + items + users
-    rows = spmd.padded_num_keys(live, 1)
-    slots = 2 * n  # BatchBuilder's bucket for a minibatch's about 75,500 keys
-    u, i, _ = ratings.make_ratings(SEED, n, conf["data"], users, items)
-    uniq = np.unique(np.concatenate(ratings.table_rows(u, i, items)))
-    assert slots // 2 < 1 + len(uniq) <= slots, (len(uniq), slots)
+    rng = np.random.default_rng(SEED)
     keys = np.zeros(slots, np.int32)  # slot 0 and the tail: PAD_KEY
-    keys[1 : 1 + len(uniq)] = uniq
-    want = ref_mf.init_factors(SEED, keys.astype(np.int64), rank, live)
-    table = jax.jit(lambda: matrix_fac.init_factors(SEED, jnp.arange(rows, dtype=jnp.int32), rank, live))()
+    keys[1 : 1 + real] = np.sort(rng.choice(np.arange(1, rows, dtype=np.int64), real, replace=False))
+    with np.errstate(over="ignore"):
+        r = keys.astype(np.uint32)[:, None]
+        lane = np.arange(vdim, dtype=np.uint32)[None, :]
+        x = ref_mf._fmix32(r * np.uint32(0x9E3779B1) + np.uint32(SEED & 0xFFFFFFFF))
+        x = ref_mf._fmix32(x ^ (lane * np.uint32(0x85EBCA77) + np.uint32(0xC2B2AE3D)))
+    want = (x >> np.uint32(8)).astype(np.float32) * np.float32(2.0**-23) - np.float32(1.0)
     idx = jnp.asarray(keys)
-    for name, form in (("element", element_rows), ("take_rows", spmd._take_rows)):
-        res = {"case": f"f32[{rows},{rank}] pull", "form": name, "slots": slots, "real_slots": 1 + len(uniq), "seed": SEED}
+    stride = spmd.row_stride(vdim)
+    fill = lambda lanes: jnp.where(  # noqa: E731 - the lanes past the row's width zero, as the store keeps them
+        jnp.arange(lanes)[None, :] < vdim, store.hashed_unit(SEED, jnp.arange(rows, dtype=jnp.int32), lanes), 0.0
+    )
+    head = {"case": f"f32[{rows},{vdim}] stored {stride} wide", "slots": slots, "real_slots": real, "seed": SEED}
+    forms = [("take_rows", stride, lambda v, i: spmd._take_rows(v, i, vdim))]
+    if "--no-element" not in sys.argv:
+        forms.insert(0, ("element", vdim, element_rows))
+    for name, lanes, form in forms:
+        res = {**head, "form": name, "op": "pull"}
+        table = jax.jit(lambda lanes=lanes: fill(lanes))()
         t0 = time.perf_counter()
         g = jax.jit(form).lower(table, idx).compile()
         res["compile_s"] = time.perf_counter() - t0
         got = np.asarray(g(table, idx))
-        res["rows_equal_numpy"] = bool(np.array_equal(got, want)) and bool(want[1 : 1 + len(uniq)].any())
-        res["gather_ms"], _ = timed(g, table, (idx,), chain=False)
+        res["rows_equal_numpy"] = bool(np.array_equal(got, want)) and bool(want[1 : 1 + real].any())
+        res["gather_ms"] = timed(g, table, (idx,), chain=False)[0]  # no second name for the table
         emit(res)
+        if name != "take_rows":
+            del table, got, g
+    # the push into the stored slot: every real row moves by its delta, no other
+    d = rng.standard_normal((slots, vdim)).astype(np.float32)
+    d[0], d[1 + real :] = 0.0, 0.0
+    push = jax.jit(lambda t, i, x: spmd._add_rows(t, spmd._ascending_rows(i, i), x, True), donate_argnums=0)
+    res = {**head, "form": "add_rows", "op": "push"}
+    dd = jnp.asarray(d)
+    table = push(table, idx, dd)
+    got = np.asarray(jax.jit(lambda v, i: spmd._take_rows(v, i, vdim))(table, idx))
+    res["rows_equal_numpy"] = bool(np.array_equal(got[1 : 1 + real], (want + d)[1 : 1 + real]))
+    res["pad_lanes_zero"] = bool(float(jnp.abs(table[:, vdim:]).sum()) == 0.0) if stride > vdim else None
+    res["scatter_ms"], table = timed(push, table, (idx, dd))
+    emit(res)
 
 
 if "--wide" in sys.argv:
-    wide_case()
+    nums = [int(a) for a in sys.argv[sys.argv.index("--wide") + 1 :] if a.isdigit()]
+    wide_case(*nums[:2], *(nums[2:4] or (131_072, 75_528)))
     sys.exit(0)
 if "--rest" not in sys.argv:
     case(1 << 30, 1, 1 << 30, 0, "f32[2^30,1]", True)
