@@ -42,6 +42,7 @@ from parameter_server_tpu.data.batch import CSRBatch
 from parameter_server_tpu.kv.updaters import Updater
 from parameter_server_tpu.models import metrics as M
 from parameter_server_tpu.ops.sparse import csr_grad, csr_logits, logistic_loss
+from parameter_server_tpu.utils import trace
 from parameter_server_tpu.utils.hashing import PAD_KEY
 
 State = dict[str, jax.Array]
@@ -589,28 +590,68 @@ def _ascending_rows(idx: jax.Array, local: jax.Array) -> jax.Array:
     return jnp.where(pad, jnp.iinfo(local.dtype).max, local)
 
 
+# Whether a scatter-add into a table is told that its rows ascend. At one
+# stored lane the hint moves XLA's TPU scatter from an emitter that takes
+# its slots in turn to one that streams the whole table slot whatever
+# lands. Alone on a v5e, ms a scatter of one real batch's 65,536 key slots
+# (40,058 keys; tools/probe_push_scatter.py, PERF.md section 6, PR 35):
+#
+#     rows x lanes     rows a slot   hinted   unhinted
+#     2^26 x 1               1,024     1.28       1.31
+#     100,000,768 x 1        1,526     1.67       1.70
+#     2^27 x 1               2,048     2.08       5.72
+#     2^28 x 1               4,096     3.68       5.91
+#     2^29 x 1               8,192     6.91       5.93
+#     2^30 x 1              16,384    13.27       5.79   (5.55 as a kv shard of 2^31)
+#     2^30 x 1, 2,048 slots (all pads) 12.88      0.21
+#     2^30 x 1, 524,289 slots (PR 27)  16.43     47.86
+#     100,000,768 x 16       1,526     6.46       6.46
+#
+# Hinted: 11.9 ps a row and 6 ns a slot. Unhinted: 88 ns a slot above 10^8
+# rows, below it XLA streams of its own accord. The two cross where a slot
+# stands for 82 ns / 11.9 ps = 6,900 rows. Wider tables keep the hint they
+# had: level at 16 and at 64 lanes (23.92 / 23.92 at 50,122,752 x 64); at
+# 6,000,640 x 384 the probe read 33.05 hinted against 12.28, a lead this
+# rule does not take yet (PERF.md section 7).
+_STREAM_ROWS_A_SLOT = 6_900
+
+
+def scatter_rows_sorted(rows: int, lanes: int, slots: int) -> bool:
+    """Whether the scatter-add of ``slots`` ascending rows into a table
+    slot of ``rows`` x ``lanes`` on this chip carries ``indices_are_sorted``:
+    yes, unless the table is one lane wide and holds more than
+    ``_STREAM_ROWS_A_SLOT`` rows for each slot scattered, where streaming it
+    costs more than taking the slots in turn. Static shapes in, so one
+    choice a traced program; beside ``row_stride``, the other place that
+    reads a table's treatment off its shape."""
+    return lanes > 1 or rows <= _STREAM_ROWS_A_SLOT * slots
+
+
 def _add_rows(
     table: jax.Array, rows: jax.Array, deltas: jax.Array, ascending: bool
 ) -> jax.Array:
     """``table[rows] += deltas``, rows outside the table dropped.
-    ``ascending`` tells XLA that ``rows`` is non-decreasing: at ``vdim`` 1
-    it then leaves the 33-tile emitter that serialises on every slot
-    (PERF.md section 6, PR 27). ``lax.scatter_add`` and not
-    ``.at[].add(mode="drop")``, which wraps a negative row (an earlier
-    shard's key) onto a valid one first. A false promise is undefined
-    behaviour on the chip and invisible on the CPU, which ignores the
-    hint. Deltas narrower than the table (a slot stored at ``row_stride``)
-    are widened with zeros: the pad lanes stay what they were, zero."""
+    ``ascending`` is the caller's promise that ``rows`` is non-decreasing;
+    XLA is told so (``indices_are_sorted``) where ``scatter_rows_sorted``
+    says that pays at these shapes, and may always be left to find out for
+    itself: the same rows in the same order, the same sums to the bit.
+    ``lax.scatter_add`` and not ``.at[].add(mode="drop")``, which wraps a
+    negative row (an earlier shard's key) onto a valid one first. A false
+    promise is undefined behaviour on the chip and invisible on the CPU,
+    which ignores the hint. Deltas narrower than the table (a slot stored
+    at ``row_stride``) are widened with zeros: the pad lanes stay what they
+    were, zero."""
     pad = table.shape[1] - deltas.shape[1]
     if pad:
         deltas = jnp.pad(deltas, ((0, 0), (0, pad)))
+    hint = ascending and scatter_rows_sorted(*table.shape, rows.shape[0])
     dnums = lax.ScatterDimensionNumbers(
         update_window_dims=(1,),
         inserted_window_dims=(0,),
         scatter_dims_to_operand_dims=(0,),
     )
     return lax.scatter_add(
-        table, rows[:, None], deltas, dnums, indices_are_sorted=ascending,
+        table, rows[:, None], deltas, dnums, indices_are_sorted=hint,
         mode=lax.GatherScatterMode.FILL_OR_DROP,
     )
 
@@ -632,7 +673,10 @@ def _local_push(
     never by configuration, that each worker's ids obey the batch contract
     of ``data.batch`` (slot 0 ``PAD_KEY``, then strictly ascending keys,
     then ``PAD_KEY`` to the end). The scatter then sends the tail's pads
-    past the table and tells XLA that its rows ascend. Promised or not,
+    past the table and tells XLA that its rows ascend where that pays
+    (``scatter_rows_sorted``; a traced scatter leaves one
+    ``push.scatter_sorted`` sample, 1 or 0, with the table's scope and its
+    shapes, when the tracer is on). Promised or not,
     a row that is not this shard's is dropped, not added as a zero to
     row 0. The gathers keep the clamped index vector they share with
     ``_local_pull``: XLA merges the two on one chip."""
@@ -649,6 +693,14 @@ def _local_push(
             deltas = updater.delta(rows, g)
         with jax.named_scope("scatter"), _sub_scope(table):
             to = _ascending_rows(idx, local) if ascending else local
+            for slot in state_l.values():  # what each scatter below is told
+                n_rows, lanes, slots = (*slot.shape, to.shape[0])
+                trace.counter(
+                    "push.scatter_sorted",
+                    ascending and scatter_rows_sorted(n_rows, lanes, slots),
+                    scope="ps.push/scatter" + (f"/{table}" if table else ""),
+                    rows=n_rows, lanes=lanes, slots=slots,
+                )
             new = {
                 k: _add_rows(state_l[k], to, deltas[k], ascending)
                 for k in state_l

@@ -694,12 +694,15 @@ class Tracer:
             "args": args,
         })
 
-    def counter(self, name: str, value: float, cat: str = "") -> None:
+    def counter(
+        self, name: str, value: float, cat: str = "", **args: Any
+    ) -> None:
         """Perfetto counter-track sample (Chrome ``"C"`` event): numeric
         series rendered as a stepped counter track next to the spans —
         the histogram-export-as-counter-track form the PR-2 ROADMAP item
         asked for. Used for queue depth and apply-batch size; free when
-        tracing is disabled (same contract as ``span``)."""
+        tracing is disabled (same contract as ``span``). ``args`` ride
+        beside the sample's ``value`` (what the sample is of)."""
         if self._dir is None:
             return
         self._record({
@@ -709,7 +712,7 @@ class Tracer:
             "ts": _now_us(),
             "pid": _pid,
             "tid": _tid(),
-            "args": {"value": float(value)},
+            "args": {**args, "value": float(value)},
         })
 
     def flow_start(
@@ -933,8 +936,8 @@ def instant(
     tracer.instant(name, cat, ctx=ctx, **args)
 
 
-def counter(name: str, value: float, cat: str = "") -> None:
-    tracer.counter(name, value, cat)
+def counter(name: str, value: float, cat: str = "", **args: Any) -> None:
+    tracer.counter(name, value, cat, **args)
 
 
 def flow_start(

@@ -152,3 +152,109 @@ def test_trainer_refuses_a_batch_out_of_order():
     b.unique_keys[[1, 2]] = b.unique_keys[[2, 1]]
     with pytest.raises(AssertionError, match="out of order"):
         PodTrainer._prepare(None, [b])
+
+
+# -- whether XLA is told: ``spmd.scatter_rows_sorted`` (PERF.md section 6, PR 35) --
+# (rows a chip, stored lanes, key slots) of every table scatter the cells'
+# steps hold, and what the rule says there
+CELL_SCATTERS = {
+    "ctr1.train, ctr2x2.train: z, n": ((1 << 30, 1, 1 << 16), False),
+    "wd100m.train: wide.z, wide.n": ((100_000_768, 1, 1 << 16), True),
+    "wd100m.train: emb.w, emb.n": ((100_000_768, 16, 1 << 16), True),
+    "mfhw.train: mf.w": ((50_122_752, 64, 131_072), True),
+    "sgns3m.train: sgns.w": ((6_000_640, 384, 114_689), True),
+    # the call that ends an epoch (``builder.build`` of no example: 2048 slots, all pads)
+    "linear, the inert call": ((1 << 30, 1, 2048), False),
+    "wide_deep, the inert call: wide": ((100_000_768, 1, 2048), False),
+    "wide_deep, the inert call: emb": ((100_000_768, 16, 2048), True),
+    # what the step scattered until PR 30, and PR 27 measured the hint three times faster at
+    "2^30 rows under 524,289 slots": ((1 << 30, 1, (1 << 19) + 1), True),
+}
+
+
+@pytest.mark.parametrize("name", list(CELL_SCATTERS))
+def test_sorted_hint_on_the_shapes_the_cells_have(name):
+    shape, sorted_hint = CELL_SCATTERS[name]
+    assert spmd.scatter_rows_sorted(*shape) is sorted_hint
+
+
+@pytest.mark.parametrize("slots", [2048, 1 << 16, 131_072, (1 << 19) + 1])
+@pytest.mark.parametrize("lanes", [1, 16, 64, 384])
+def test_sorted_hint_is_monotone_in_rows(lanes, slots):
+    """At fixed lanes and slots the hint goes with the smaller tables and
+    never comes back as the rows grow; only one stored lane ever loses it."""
+    says = [spmd.scatter_rows_sorted(1 << log2, lanes, slots) for log2 in range(10, 32)]
+    assert says[0] and says == sorted(says, reverse=True), says
+    assert all(says) or lanes == 1
+
+
+@pytest.mark.parametrize("mesh_name", ["1x1", "2x2"])
+@pytest.mark.parametrize("vdim", [1, 16])
+def test_push_is_the_same_to_the_bit_whatever_the_rule_says(vdim, mesh_name, monkeypatch, tmp_path):
+    """``_local_push`` with the promise, the rule forced either way: the
+    same rows in the same order, other shards' rows and the pads dropped in
+    both, so both tables are equal bit for bit (the CPU ignores the hint:
+    what is shown here is that nothing but the hint hangs on the rule). Each
+    traced scatter leaves one ``push.scatter_sorted`` sample that says which
+    way it went, its table's scope and its shapes; none with the tracer off."""
+    import jax
+    from jax import lax, shard_map
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from parameter_server_tpu.kv.updaters import Adagrad
+    from parameter_server_tpu.parallel import make_mesh
+    from parameter_server_tpu.utils import trace
+
+    data, kv = {"1x1": (1, 1), "2x2": (2, 2)}[mesh_name]
+    mesh = make_mesh(data, kv)
+    rows, slots = 4096, 64
+    shard = spmd._shard_size(rows, kv)
+    rng = np.random.default_rng(vdim + kv)
+    idx = np.zeros((data, slots), np.int32)  # the batch contract: pad, ascending keys, pads
+    for w in range(data):
+        real = np.sort(rng.choice(np.arange(1, rows), 40, replace=False))
+        real[:4] = (1, shard - 1, shard, rows - 1) if kv > 1 else (1, 2, rows - 2, rows - 1)
+        idx[w, 1:41] = np.sort(real)
+    grad = rng.normal(size=(data, slots, vdim)).astype(np.float32)
+    grad[idx == 0] = 0.0
+    start = {
+        "w": rng.normal(size=(rows, vdim)).astype(np.float32),
+        "n": rng.random(size=(rows, vdim)).astype(np.float32),
+    }
+
+    def local(state_l, idx_l, grad_l):
+        return spmd._local_push(
+            Adagrad(eta=0.05), state_l, lax.all_gather(idx_l[0], "data"),
+            lax.all_gather(grad_l[0], "data"), shard, "emb", ascending=True, vdim=vdim,
+        )
+
+    def pushed(says):
+        monkeypatch.setattr(spmd, "scatter_rows_sorted", lambda *shape: says)
+        push = jax.jit(shard_map(
+            local, mesh=mesh, in_specs=(spmd.state_spec(), P("data"), P("data")),
+            out_specs=spmd.state_spec(), check_vma=False,
+        ))
+        state = {k: jax.device_put(v, NamedSharding(mesh, spmd.state_spec())) for k, v in start.items()}
+        hlo = push.lower(state, jnp.asarray(idx), jnp.asarray(grad)).as_text()
+        assert hlo.count("indices_are_sorted = true") == (2 if says else 0), hlo
+        return {k: np.asarray(v) for k, v in push(state, jnp.asarray(idx), jnp.asarray(grad)).items()}
+
+    assert not trace.enabled()
+    hinted = pushed(True)
+    tracer = trace.configure(str(tmp_path), process_name="push")
+    try:
+        unhinted = pushed(False)
+        samples = [e["args"] for e in tracer.events() if e["ph"] == "C" and e["name"] == "push.scatter_sorted"]
+    finally:
+        trace.configure(None)
+    for k in start:
+        np.testing.assert_array_equal(hinted[k], unhinted[k])
+        assert not np.array_equal(hinted[k], start[k])
+    touched = np.zeros(rows, bool)
+    touched[idx.ravel()] = True
+    touched[0] = False  # the pad's row takes zeros
+    np.testing.assert_array_equal(hinted["n"][~touched], start["n"][~touched])
+    assert (hinted["n"][touched] != start["n"][touched]).all()
+    want = {"value": 0.0, "scope": "ps.push/scatter/emb", "rows": shard, "lanes": vdim, "slots": slots}
+    assert samples and all(s == want for s in samples), samples
+    assert len(samples) % 2 == 0  # w and n, each time the push is traced

@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 
 MINIBATCH, K, NNZ = 8192, 8, 1 << 19  # the cells' one bucket: 39 x 8192 entries
-UNIQUE = NNZ + 1
+UNIQUE = 1 << 16  # and its key axis' (since PR 31: a batch holds about 40,000 keys)
 ROWS_PER_CHIP = 1 << 30
 # opcodes that run nothing of their own: a container's time is its body's,
 # the others only name a value
@@ -204,7 +204,7 @@ def test_large_unscoped_instructions_are_the_known_kinds(compiled_text, data, kv
     # an unscoped fusion at this size is bookkeeping, never a table op
     for name, shape, opcode, operand_shapes in executed(compiled_text(data, kv, program)):
         if opcode == "fusion" and not scopes[name]:
-            assert elements(shape) < 4 * UNIQUE and all(elements(s) < ROWS_PER_CHIP for s in operand_shapes), name
+            assert elements(shape) < 4 * NNZ and all(elements(s) < ROWS_PER_CHIP for s in operand_shapes), name
 
 
 # -- Wide&Deep: two tables and a dense tower through the same step ------------
@@ -323,20 +323,26 @@ def test_wd_large_unscoped_instructions_are_the_known_kinds(wd_text, data, kv, p
             assert elements(shape) < 17 * UNIQUE and all(elements(s) < WD_ROWS // kv for s in operand_shapes), name
 
 
-# -- the push's scatter: told that its rows ascend (PERF.md section 6, PR 27) --
+# -- the push's scatter: told that its rows ascend where that pays (PERF.md section 6, PRs 27 and 35) --
 PUSH_PROGRAMS = [("linear", 1, 1), ("linear", 2, 2), ("wd", 1, 1)]
+
+
+def sorted_hint(scatter_rest: str) -> bool:
+    """What a compiled ``scatter`` was told (HLO prints the attribute only when set)."""
+    return "indices_are_sorted=true" in scatter_rest
 
 
 @pytest.mark.parametrize("app,data,kv", PUSH_PROGRAMS)
 def test_table_scatters_take_ascending_rows_in_place(compiled_text, wd_text, app, data, kv):
     """Whether the push's mechanism engages is a property of the compiled
-    step: every scatter into a table carries ``indices_are_sorted=true``
-    (``_microstep``'s promise; at ``vdim`` 1 the chip's compiler then
-    leaves the emitter that serialises on every slot), its fusion sits
-    under ``ps.push/scatter`` and updates the table in place; nothing
-    table-sized is among the temporaries; and on one chip the push's
-    gathers are still merged with the pull's, two table gathers a
-    microstep and table, one index vector."""
+    step: every scatter into a table carries the ``indices_are_sorted`` that
+    ``spmd.scatter_rows_sorted`` gives its (rows a chip, lanes, key slots):
+    off at 2^30 one-lane rows under 65,536 slots, where the emitter the
+    hint selects would stream the whole table, on at every table of
+    Wide&Deep; its fusion sits under ``ps.push/scatter`` and updates the
+    table in place; nothing table-sized is among the temporaries; and on
+    one chip the push's gathers are still merged with the pull's, two
+    table gathers a microstep and table, one index vector."""
     from parameter_server_tpu.parallel import spmd
 
     get, names = (compiled_text, frozenset()) if app == "linear" else (wd_text, WD_NAMES)
@@ -347,10 +353,15 @@ def test_table_scatters_take_ascending_rows_in_place(compiled_text, wd_text, app
     table = re.compile(rf"^f32\[(1,1,)?{rows}(,1|,16)?\]")
     slots = 2 if app == "linear" else 4  # z, n; and emb's w, n
     every = instructions(text)
-    scatters = [(comp, rest) for comp, _, shape, opcode, _, rest in every if opcode == "scatter" and table.match(shape)]
+    scatters = [
+        (comp, rest, 16 if table.match(shape).group(2) == ",16" else 1)
+        for comp, _, shape, opcode, _, rest in every if opcode == "scatter" and table.match(shape)
+    ]
     assert len(scatters) == slots, scatters
-    assert all("indices_are_sorted=true" in rest for _, rest in scatters), scatters
-    homes = {comp for comp, _ in scatters}
+    for _, rest, lanes in scatters:
+        assert sorted_hint(rest) is spmd.scatter_rows_sorted(rows, lanes, UNIQUE), (lanes, rest)
+        assert sorted_hint(rest) is (app == "wd"), (lanes, rest)
+    homes = {comp for comp, _, _ in scatters}
     fusions = [
         (name, rest) for _, name, shape, opcode, _, rest in every
         if opcode == "fusion" and re.search(r"calls=%?([\w.\-]+)", rest).group(1) in homes
@@ -476,7 +487,8 @@ def test_mf_table_ops_are_scoped_and_the_table_is_read_where_it_lies(mf_text, da
     if program == "multistep":
         assert "ps.push/scatter/mf" in found, found
         scatters = [rest for _, _, shape, opcode, _, rest in every if opcode == "scatter" and re.search(stored, shape)]
-        assert len(scatters) == 1 and "indices_are_sorted=true" in scatters[0], scatters
+        assert len(scatters) == 1, scatters
+        assert spmd.scatter_rows_sorted(rows, MF_RANK, MF_SLOTS) and sorted_hint(scatters[0]), scatters
     mem = mf_text.texts[(data, kv, program), "memory"]
     table_bytes = 4 * rows * MF_RANK
     assert table_bytes == 12_831_424_512
@@ -644,7 +656,8 @@ def test_sgns_table_ops_are_scoped_and_the_table_is_worked_on_where_it_lies(sgns
     if program == "multistep":
         assert {"ps.push/scatter/sgns", "ps.push/update/sgns"} <= found, found
         scatters = [rest for _, _, shape, opcode, _, rest in every if opcode == "scatter" and table.search(shape)]
-        assert len(scatters) == 1 and "indices_are_sorted=true" in scatters[0], scatters
+        assert len(scatters) == 1, scatters
+        assert spmd.scatter_rows_sorted(rows, stride, SGNS_SLOTS) and sorted_hint(scatters[0]), scatters
         init = sgns_text.texts["init", "memory"]
         assert init.temp_size_in_bytes < 64 << 20, init.temp_size_in_bytes
     mem = sgns_text.texts[(data, kv, program), "memory"]

@@ -1,29 +1,46 @@
-"""The table ops of a push alone, on the chip (step 0 of ISSUE 27; PERF.md
-section 6, PR 27): one slot, host clock, ten calls back to back, three sets.
-Scatter-add of one real bucket's key slots (one 8192-example batch of the
-benchmark's Criteo-shaped traffic from the seed, keys as BatchBuilder writes
-them: 65,536 slots since PR 31, 524,289 when PR 27's numbers were read) into
-f32[2^30,1], f32[100000768,1] and f32[100000768,16], and into f32[2^30,1] as
-either kv shard of a 2^31-key table: the scatter the step
-had up to PR 26 (rows clamped to 0, deltas masked) against the one it has
-(``spmd._ascending_rows`` + ``spmd._add_rows``); and ``jnp.take`` as the step
-calls it against sorted rows with ``mode="fill"``, on the first and the third.
-Every form's result is checked on the chip against the deltas row by row,
-with the count of non-zero rows and the absolute sum. One JSON line a case,
-also appended to chiprun_out/probe_push_scatter.jsonl.
+"""The table ops of a push alone, on the chip (step 0 of ISSUEs 27 and 35;
+PERF.md section 6, PRs 27 and 35): one slot, host clock, ten calls back to
+back, three sets. Scatter-add of one real bucket's key slots (one
+8192-example batch of the benchmark's Criteo-shaped traffic from the seed,
+keys as BatchBuilder writes them: 65,536 slots since PR 31, 524,289 when PR
+27's numbers were read) into f32[2^30,1], f32[100000768,1] and
+f32[100000768,16], into f32[2^30,1] as either kv shard of a 2^31-key table,
+into one lane at 2^26 to 2^29 rows, and of the 2048 pad slots of the call
+that ends an epoch into f32[2^30,1]. Three forms a case: ``today``, the
+scatter the step had up to PR 26 (rows clamped to 0, deltas masked, XLA
+told nothing); ``ascending``, the step's from PR 27 to PR 34
+(``spmd._ascending_rows`` + ``spmd._add_rows`` with ``indices_are_sorted``
+whatever the shapes); ``unhinted``, the same rows with pads and other
+shards' keys dropped and XLA told nothing, which is what the step takes
+since PR 35 where ``spmd.scatter_rows_sorted`` says the hint does not pay
+(``rule_sorted`` on the case's line is what it says there). ``--gathers``
+adds ``jnp.take`` as the step calls it against sorted rows with
+``mode="fill"``, on the first and the third (a dead lead: PERF.md section
+7 (b)). Every form's result is checked on the chip against the deltas row
+by row, with the count of non-zero rows and the absolute sum. One JSON
+line a case, also appended to chiprun_out/probe_push_scatter.jsonl.
 
-``--wide VDIM ROWS [SLOTS REAL]`` (PERF.md section 6, PRs 33 and 34) runs
+What PR 35 read (seed 2350000001, 40,058 real slots; ms a scatter, hinted /
+unhinted / today): f32[2^30,1] 13.27 / 5.79 / 6.19, as a kv shard 13.26 /
+5.55 / 6.40; 2^29 rows 6.91 / 5.93 / 5.97; 2^28 3.68 / 5.91 / 6.16; 2^27
+2.08 / 5.72 / 6.12; 2^26 1.28 / 1.31 / 1.37; f32[100000768,1] 1.67 / 1.70
+/ 1.76; f32[100000768,16] 6.46 / 6.46 / 6.56; the inert call 12.88 / 0.21 /
+0.25. ``--wide``: f32[50122752,64] 23.92 hinted, 23.92 not; f32[6000640,300]
+stored 384 wide 33.05 hinted, 12.28 not.
+
+``--wide VDIM ROWS [SLOTS REAL]`` (PERF.md section 6, PRs 33 to 35) runs
 one wide table alone: the pull of REAL ascending keys in SLOTS key slots
 (131,072 and 75,528, ``mfhw.train``'s, unless given; ``sgns3m.train``'s are
 114,689 and 72,100) out of f32[ROWS,VDIM], the gather of single elements
 the step had in PR 32 (``--no-element`` leaves it out) against
 ``spmd._take_rows`` on the slot as the store keeps it, and the push's
-``spmd._add_rows`` into it, every form's rows checked against NumPy's; one
-JSON line a form. The seed comes first: ``SEED --wide 300 6000640 114689 72100``.
+``spmd._add_rows`` into it as the step calls it and then with the promise
+withheld (no hint), every form's rows checked against NumPy's; one JSON
+line a form. The seed comes first: ``SEED --wide 300 6000640 114689 72100``.
 
-    chiprun --timeout 1500 -- python3 tools/probe_push_scatter.py [SEED] [--rest | --wide VDIM ROWS]
+    chiprun --timeout 1500 -- python3 tools/probe_push_scatter.py [SEED] [--rest | --gathers | --wide VDIM ROWS]
 """
-import json, os, sys, time
+import contextlib, json, os, sys, time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import numpy as np
 import jax, jax.numpy as jnp
@@ -56,9 +73,25 @@ def today(table, idx, d, begin, shard):  # the push's scatter up to PR 26
     return table.at[safe].add(in_range[:, None].astype(d.dtype) * d)
 
 
-def ascending(table, idx, d, begin, shard):
+@contextlib.contextmanager
+def rule(says):
+    """``spmd.scatter_rows_sorted`` answering ``says`` while a form is traced."""
+    kept, spmd.scatter_rows_sorted = spmd.scatter_rows_sorted, lambda *shape: says
+    try:
+        yield
+    finally:
+        spmd.scatter_rows_sorted = kept
+
+
+def ascending(table, idx, d, begin, shard):  # the step's from PR 27 to PR 34: the hint whatever the shapes
     local = idx - begin
-    return spmd._add_rows(table, spmd._ascending_rows(idx, local), d, True)
+    with rule(True):
+        return spmd._add_rows(table, spmd._ascending_rows(idx, local), d, True)
+
+
+def unhinted(table, idx, d, begin, shard):  # the same rows, pads and foreign keys dropped, XLA told nothing
+    local = idx - begin
+    return spmd._add_rows(table, spmd._ascending_rows(idx, local), d, False)
 
 
 def take_today(table, idx, begin, shard):
@@ -97,10 +130,12 @@ def emit(res):
         fh.write(json.dumps(res) + "\n")
 
 
-def case(rows, vdim, num_keys, begin, label, gathers):
-    keys, n_uniq = bucket_keys(num_keys)
+def case(rows, vdim, num_keys, begin, label, gathers, inert_slots=0):
+    """``inert_slots``: the key vector of the call that ends an epoch
+    (``data.batch.inert_like``: that many slots, every one ``PAD_KEY``)."""
+    keys, n_uniq = (np.zeros(inert_slots, np.int32), 1) if inert_slots else bucket_keys(num_keys)
     rng = np.random.default_rng(SEED)
-    d = rng.standard_normal((U, vdim)).astype(np.float32)
+    d = rng.standard_normal((len(keys), vdim)).astype(np.float32)
     d[n_uniq:] = 0.0  # a pad's gradient is 0 and so is its delta
     d[0] = 0.0
     local = keys.astype(np.int64) - begin
@@ -108,9 +143,9 @@ def case(rows, vdim, num_keys, begin, label, gathers):
     mine[n_uniq:] = False
     mine[0] = begin == 0
     idx, dd = jnp.asarray(keys), jnp.asarray(d)
-    res = {"case": label, "rows": rows, "vdim": vdim, "begin": begin, "real_slots": int(n_uniq),
-           "slots_in_range": int(mine.sum()), "seed": SEED}
-    for name, form in (("today", today), ("ascending", ascending)):
+    res = {"case": label, "rows": rows, "vdim": vdim, "begin": begin, "slots": len(keys), "real_slots": int(n_uniq),
+           "slots_in_range": int(mine.sum()), "seed": SEED, "rule_sorted": spmd.scatter_rows_sorted(rows, vdim, len(keys))}
+    for name, form in (("today", today), ("ascending", ascending), ("unhinted", unhinted)):
         f = jax.jit(lambda t, i, x, form=form: form(t, i, x, begin, rows), donate_argnums=0)
         table = jnp.zeros((rows, vdim), jnp.float32)
         # correctness on the chip: from zeros one add; every in-range real row == its delta, the rest untouched
@@ -190,25 +225,35 @@ def wide_case(vdim, rows, slots, real):
     # the push into the stored slot: every real row moves by its delta, no other
     d = rng.standard_normal((slots, vdim)).astype(np.float32)
     d[0], d[1 + real :] = 0.0, 0.0
-    push = jax.jit(lambda t, i, x: spmd._add_rows(t, spmd._ascending_rows(i, i), x, True), donate_argnums=0)
-    res = {**head, "form": "add_rows", "op": "push"}
     dd = jnp.asarray(d)
-    table = push(table, idx, dd)
-    got = np.asarray(jax.jit(lambda v, i: spmd._take_rows(v, i, vdim))(table, idx))
-    res["rows_equal_numpy"] = bool(np.array_equal(got[1 : 1 + real], (want + d)[1 : 1 + real]))
-    res["pad_lanes_zero"] = bool(float(jnp.abs(table[:, vdim:]).sum()) == 0.0) if stride > vdim else None
-    res["scatter_ms"], table = timed(push, table, (idx, dd))
-    emit(res)
+    # ``_add_rows`` as the step calls it (the hint by ``scatter_rows_sorted``), then with the promise withheld
+    for name, promise in (("add_rows", True), ("add_rows_unhinted", False)):
+        push = jax.jit(lambda t, i, x, p=promise: spmd._add_rows(t, spmd._ascending_rows(i, i), x, p), donate_argnums=0)
+        res = {**head, "form": name, "op": "push", "sorted_hint": promise and spmd.scatter_rows_sorted(rows, stride, slots)}
+        table = push(table, idx, dd)
+        got = np.asarray(jax.jit(lambda v, i: spmd._take_rows(v, i, vdim))(table, idx))
+        want = want + d  # every real row has moved by its delta once more, no other
+        res["rows_equal_numpy"] = bool(np.array_equal(got[1 : 1 + real], want[1 : 1 + real]))
+        res["pad_lanes_zero"] = bool(float(jnp.abs(table[:, vdim:]).sum()) == 0.0) if stride > vdim else None
+        res["scatter_ms"], table = timed(push, table, (idx, dd))
+        want = np.asarray(jax.jit(lambda v, i: spmd._take_rows(v, i, vdim))(table, idx))  # after the timed adds
+        emit(res)
 
 
 if "--wide" in sys.argv:
     nums = [int(a) for a in sys.argv[sys.argv.index("--wide") + 1 :] if a.isdigit()]
     wide_case(*nums[:2], *(nums[2:4] or (131_072, 75_528)))
     sys.exit(0)
+gathers = "--gathers" in sys.argv  # PERF.md section 7 (b): dead, so only on request
 if "--rest" not in sys.argv:
-    case(1 << 30, 1, 1 << 30, 0, "f32[2^30,1]", True)
+    case(1 << 30, 1, 1 << 30, 0, "f32[2^30,1]", gathers)
     case(100_000_768, 1, 100_000_000, 0, "f32[100000768,1]", False)
-case(100_000_768, 16, 100_000_000, 0, "f32[100000768,16]", True)
+case(100_000_768, 16, 100_000_000, 0, "f32[100000768,16]", gathers)
 # what a kv shard of ctr2x2 sees: 2^31 keys, this shard's rows [2^30, 2^31) and then [0, 2^30)
 case(1 << 30, 1, 1 << 31, 1 << 30, "f32[2^30,1] as kv shard 1 of 2", False)
 case(1 << 30, 1, 1 << 31, 0, "f32[2^30,1] as kv shard 0 of 2", False)
+# one lane between 10^8 and 2^30 rows: where streaming the table passes taking the slots in turn
+for log2 in (26, 27, 28, 29):
+    case(1 << log2, 1, 1 << log2, 0, f"f32[2^{log2},1]", False)
+# the 2048 pad slots of the call that ends an epoch
+case(1 << 30, 1, 1 << 30, 0, "f32[2^30,1] inert call", False, inert_slots=2048)
