@@ -6,7 +6,7 @@ text parsing (SURVEY §2.2 threading/queues, §7.4 "the C++ parser must
 sustain ≥ GB/s/host"). That feed structure is what keeps reference
 workers busy; this module is its pod analog.
 
-Topology: D builder threads (one per worker stream, each owning its own
+Topology: D producer threads (one per worker stream, each owning its own
 stateful BatchBuilder so admission filters stay single-threaded) push
 per-worker batches into per-stream bounded queues; one stacker thread
 assembles them into ready global step items — stacked arrays plus the
@@ -15,12 +15,23 @@ queue. The dispatch loop then only pops + dispatches the device step,
 overlapping host parse/build with device compute instead of serializing
 D batch builds inline before every step.
 
+A training stream over files is two threads deep before the stacker:
+``next_batch()`` is ``next()`` on a ``MinibatchReader`` iterator, and the
+parse and the ``BatchBuilder`` run in that reader's own thread
+(``reader.parse`` / ``reader.build`` / ``reader.put_wait``, see
+``data/reader.py``), up to its queue of four batches ahead. So: reader
+thread -> producer thread -> stacker thread -> dispatch loop.
+
 Named phases (``trace.phase``: a named timer each, and a span in the
 profiler's and the tracer's timelines when those run): ``feed.build`` one
-``next_batch()`` in a producer thread (count: batches), ``feed.put_wait``
-a producer blocked on its full queue (the feed's slack), ``feed.stack``
-``prepare`` + ``assemble`` in the stacker thread (count: emitted items;
-one thread serves every stream, so its busy share has a wall at 100%).
+``next_batch()`` in a producer thread (count: batches): over a
+``MinibatchReader`` that is the producer's WAIT for the reader's thread
+(plus, once a file, the start of the next reader and its thread), not the
+building, which ``reader.build`` times where it happens;
+``feed.put_wait`` a producer blocked on its full queue (the feed's slack);
+``feed.stack`` ``prepare`` + ``assemble`` in the stacker thread (count:
+emitted items; one thread serves every stream, so its busy share has a
+wall at 100%).
 
 Draining contract: ``get()`` returns ``None`` once every stream is
 exhausted (and forever after). Callers that must keep issuing collectives

@@ -2,6 +2,18 @@
 
 Reference analog: learner/sgd.h MinibatchReader (parser thread feeding a
 threadsafe queue) + data/stream_reader.h (multi-file, gz-aware streaming).
+
+Every iteration of a ``MinibatchReader`` starts one producer thread, the
+reader's thread, and the parse and the ``BatchBuilder`` run there, in
+training and in evaluation alike: whoever iterates only takes finished
+batches off a queue. Named phases of that thread (``trace.phase``; one a
+chunk or a batch, never one a row): ``reader.parse`` one step of
+``iter_chunks`` (read + native parse of a 2 MiB chunk, and its merge with
+the rows the chunk before left over; count: chunks), ``reader.build`` one
+``BatchBuilder.build_flat`` / ``build`` (hash, unique, localize, bucket;
+count: batches), ``reader.put_wait`` the thread blocked on its full queue
+(the reader's slack: near 0 means this thread sets the pace). The Python
+parsers yield a row at a time, so that path has no ``reader.parse``.
 """
 
 from __future__ import annotations
@@ -15,10 +27,15 @@ import numpy as np
 
 from parameter_server_tpu.data.batch import BatchBuilder, CSRBatch
 from parameter_server_tpu.data.libsvm import iter_format
+from parameter_server_tpu.utils import trace
 
 
 class MinibatchReader:
-    """Streams CSRBatches from text files through a prefetch thread.
+    """Streams CSRBatches from text files through a prefetch thread: the
+    reader's thread parses and builds up to ``prefetch`` batches ahead of
+    whoever iterates (a ``PrefetchPipeline`` producer thread in training,
+    the caller's thread in ``evaluate_files``), and carries the
+    ``reader.*`` phases of the module's docstring.
 
     ``epochs`` and ``drop_remainder`` control the stream; a worker id /
     num_workers pair shards *files* across workers the way the reference's
@@ -57,6 +74,12 @@ class MinibatchReader:
         if backend == "native" and not _native.native_available():
             raise RuntimeError("native parser requested but not available")
 
+    def _build(self, build, labels, *entries) -> CSRBatch:
+        """One batch through ``build`` (the builder's ``build_flat`` or
+        ``build``), timed where it happens."""
+        with trace.phase("reader.build", examples=len(labels)):
+            return build(labels, *entries)
+
     def _epoch_rows(self) -> Iterator:
         for f in self.files:
             yield from iter_format(self.fmt, f)
@@ -85,7 +108,8 @@ class MinibatchReader:
                 )
                 j = max(i + 1, min(j_row, j))
                 if j < n or (n - i) >= bs:
-                    yield self.builder.build_flat(
+                    yield self._build(
+                        self.builder.build_flat,
                         labels[i:j],
                         (splits[i : j + 1] - base),
                         keys[base : splits[j]],
@@ -120,8 +144,15 @@ class MinibatchReader:
         for _ in range(self.epochs):
             leftover = None
             for f in self.files:
-                for flat in iter_chunks(f, self.fmt):
-                    merged = cat(leftover, flat) if leftover is not None else flat
+                chunks = iter_chunks(f, self.fmt)
+                while True:
+                    with trace.phase("reader.parse") as parse:
+                        flat = next(chunks, None)
+                        if flat is None:
+                            parse.count = 0  # the step that finds the file at its end
+                            break
+                        parse.set(examples=len(flat[0]))
+                        merged = cat(leftover, flat) if leftover is not None else flat
                     gen = slices(merged)
                     while True:
                         try:
@@ -131,7 +162,7 @@ class MinibatchReader:
                             break
             # epoch boundary flushes (epochs=N == N runs of epochs=1)
             if leftover is not None and len(leftover[0]) and not self.drop_remainder:
-                yield self.builder.build_flat(*leftover)
+                yield self._build(self.builder.build_flat, *leftover)
 
     def _batches(self) -> Iterator[CSRBatch]:
         if self.use_native:
@@ -149,7 +180,7 @@ class MinibatchReader:
                     len(labels) == self.builder.batch_size
                     or nnz + len(k) > self.builder.nnz_capacity
                 ):
-                    yield self.builder.build(np.array(labels), keys, vals, slots)
+                    yield self._build(self.builder.build, np.array(labels), keys, vals, slots)
                     labels, keys, vals, slots, nnz = [], [], [], [], 0
                 labels.append(label)
                 keys.append(k)
@@ -157,7 +188,7 @@ class MinibatchReader:
                 slots.append(s)
                 nnz += len(k)
             if labels and not self.drop_remainder:
-                yield self.builder.build(np.array(labels), keys, vals, slots)
+                yield self._build(self.builder.build, np.array(labels), keys, vals, slots)
 
     def __iter__(self) -> Iterator[CSRBatch]:
         q: queue.Queue = queue.Queue(maxsize=self.prefetch)
@@ -166,12 +197,19 @@ class MinibatchReader:
         stop = threading.Event()
 
         def _put(item) -> bool:
-            while not stop.is_set():
-                try:
-                    q.put(item, timeout=0.1)
-                    return True
-                except queue.Full:
-                    continue
+            try:
+                q.put_nowait(item)
+                return True
+            except queue.Full:
+                pass
+            # the reader's slack: this thread is ahead of whoever iterates
+            with trace.phase("reader.put_wait"):
+                while not stop.is_set():
+                    try:
+                        q.put(item, timeout=0.1)
+                        return True
+                    except queue.Full:
+                        continue
             return False
 
         def produce() -> None:

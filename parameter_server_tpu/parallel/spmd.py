@@ -42,7 +42,6 @@ from parameter_server_tpu.data.batch import CSRBatch
 from parameter_server_tpu.kv.updaters import Updater
 from parameter_server_tpu.models import metrics as M
 from parameter_server_tpu.ops.sparse import csr_grad, csr_logits, logistic_loss
-from parameter_server_tpu.utils import trace
 from parameter_server_tpu.utils.hashing import PAD_KEY
 
 State = dict[str, jax.Array]
@@ -674,12 +673,10 @@ def _local_push(
     of ``data.batch`` (slot 0 ``PAD_KEY``, then strictly ascending keys,
     then ``PAD_KEY`` to the end). The scatter then sends the tail's pads
     past the table and tells XLA that its rows ascend where that pays
-    (``scatter_rows_sorted``; a traced scatter leaves one
-    ``push.scatter_sorted`` sample, 1 or 0, with the table's scope and its
-    shapes, when the tracer is on). Promised or not,
-    a row that is not this shard's is dropped, not added as a zero to
-    row 0. The gathers keep the clamped index vector they share with
-    ``_local_pull``: XLA merges the two on one chip."""
+    (``scatter_rows_sorted``). Promised or not, a row that is not this
+    shard's is dropped, not added as a zero to row 0. The gathers keep the
+    clamped index vector they share with ``_local_pull``: XLA merges the
+    two on one chip."""
     begin = lax.axis_index("kv") * shard_size
 
     def body(state_l: State, push: tuple[jax.Array, jax.Array]):
@@ -693,14 +690,6 @@ def _local_push(
             deltas = updater.delta(rows, g)
         with jax.named_scope("scatter"), _sub_scope(table):
             to = _ascending_rows(idx, local) if ascending else local
-            for slot in state_l.values():  # what each scatter below is told
-                n_rows, lanes, slots = (*slot.shape, to.shape[0])
-                trace.counter(
-                    "push.scatter_sorted",
-                    ascending and scatter_rows_sorted(n_rows, lanes, slots),
-                    scope="ps.push/scatter" + (f"/{table}" if table else ""),
-                    rows=n_rows, lanes=lanes, slots=slots,
-                )
             new = {
                 k: _add_rows(state_l[k], to, deltas[k], ascending)
                 for k in state_l

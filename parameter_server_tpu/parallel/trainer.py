@@ -839,13 +839,28 @@ class PodTrainer:
         """Pod-wide batch evaluation using the predict step on shard 0's
         stream layout (eval is read-only; one worker suffices).
 
-        Host phases (``trace.phase``): ``eval.open`` from entry to the
-        return of the first predict call (builder, reader, first parse +
-        build, stack, H2D, enqueue), ``eval.dispatch`` / ``eval.retire``
-        for each later call, ``eval.score`` for the app's scores (AUC
-        and logloss, or RMSE) over the pass; the device idles in the first and the last.
-        ``eval.new_shapes`` times the first predict call of a bucket shape
-        (the compile), which lies inside ``eval.open`` or ``eval.dispatch``."""
+        Two threads: the ``MinibatchReader``'s own thread parses and builds
+        up to four batches ahead (``reader.parse`` / ``reader.build`` /
+        ``reader.put_wait``, see ``data/reader.py``); the caller's thread
+        takes them off its queue, pads, stacks, ships and enqueues them,
+        retires results and scores.
+
+        Host phases of the caller's thread (``trace.phase``). ``eval.pass``
+        is the whole pass, and six leaves add up to it but for loop
+        overhead: ``eval.open_reader`` (builder and reader), ``eval.read``
+        (one group of ``data_shards`` batches taken off the reader's queue:
+        the wait for the reader's thread, which the pass's first read
+        starts; count: groups), ``eval.stack`` (``pad_group`` +
+        ``stack_batches``: host stack + H2D), ``eval.enqueue`` (the predict
+        call), ``eval.retire`` (the blocking read of the oldest call's
+        result), ``eval.score`` (the app's scores over the pass: AUC and
+        logloss, or RMSE). Around them,
+        since PR 24: ``eval.open`` from entry to the return of the first
+        predict call (the first open_reader, read, stack and enqueue),
+        ``eval.dispatch`` each later call's stack + enqueue; the device
+        idles in ``eval.open`` and ``eval.score``. ``eval.new_shapes`` times
+        the first predict call of a bucket shape (the compile), inside
+        ``eval.enqueue``."""
         if self.runtime.process_count > 1:
             # multi-host: evaluate host-locally against the full weight
             # vector (every host holds a complete replica) — no cross-host
@@ -876,7 +891,7 @@ class PodTrainer:
             return iter(reader), lambda: _pad_like(builder)
 
         with self._trace_cm():
-            return self._score(*self._predict_pass(open_reader))
+            return self._scored_pass(open_reader)
 
     def predict_batches(self, batches) -> tuple[np.ndarray, np.ndarray]:
         """(labels, probabilities) of an in-memory CSRBatch stream through
@@ -885,7 +900,11 @@ class PodTrainer:
         return np.concatenate(ys), np.concatenate(ps)
 
     def evaluate_batches(self, batches) -> dict:
-        return self._score(*self._predict_pass(_opener(batches)))
+        return self._scored_pass(_opener(batches))
+
+    def _scored_pass(self, open_batches) -> dict:
+        with trace.phase("eval.pass"):
+            return self._score(*self._predict_pass(open_batches))
 
     def _score(self, ys: list, ps: list) -> dict:
         with trace.phase("eval.score"):
@@ -921,24 +940,32 @@ class PodTrainer:
         def _dispatch(group: list[CSRBatch]) -> None:
             # fill every data shard with real batches (D at a time); only
             # the tail group pads with inert batches
-            batches = pad_group(
-                group + [pad() for _ in range(self.data_shards - len(group))]
-            )
-            stacked = stack_batches(
-                batches, self.mesh,
-                values_f16=self.cfg.data.wire_values == "f16",
-            )
-            with self._new_shape_phase("eval.new_shapes", stacked):
+            with trace.phase("eval.stack"):
+                batches = pad_group(
+                    group + [pad() for _ in range(self.data_shards - len(group))]
+                )
+                stacked = stack_batches(
+                    batches, self.mesh,
+                    values_f16=self.cfg.data.wire_values == "f16",
+                )
+            with trace.phase("eval.enqueue"), self._new_shape_phase("eval.new_shapes", stacked):
                 probs_dev = self.predict_fn(self.state, stacked)
             pending.append(
                 (probs_dev, [b.labels[: b.num_examples] for b in group])
             )
 
+        def _read() -> list[CSRBatch]:
+            # the wait for whoever makes the batches (a reader's thread)
+            with trace.phase("eval.read") as read:
+                group = list(itertools.islice(reader, self.data_shards))
+                if not group:
+                    read.count = 0  # the probe that finds the stream at its end
+            return group
+
         with trace.phase("eval.open"):
-            reader, pad = open_batches()
-            groups = iter(
-                lambda: list(itertools.islice(reader, self.data_shards)), []
-            )
+            with trace.phase("eval.open_reader"):
+                reader, pad = open_batches()
+            groups = iter(_read, [])
             first = next(groups, None)
             if first is not None:
                 _dispatch(first)

@@ -694,15 +694,12 @@ class Tracer:
             "args": args,
         })
 
-    def counter(
-        self, name: str, value: float, cat: str = "", **args: Any
-    ) -> None:
+    def counter(self, name: str, value: float, cat: str = "") -> None:
         """Perfetto counter-track sample (Chrome ``"C"`` event): numeric
         series rendered as a stepped counter track next to the spans —
         the histogram-export-as-counter-track form the PR-2 ROADMAP item
         asked for. Used for queue depth and apply-batch size; free when
-        tracing is disabled (same contract as ``span``). ``args`` ride
-        beside the sample's ``value`` (what the sample is of)."""
+        tracing is disabled (same contract as ``span``)."""
         if self._dir is None:
             return
         self._record({
@@ -712,7 +709,7 @@ class Tracer:
             "ts": _now_us(),
             "pid": _pid,
             "tid": _tid(),
-            "args": {**args, "value": float(value)},
+            "args": {"value": float(value)},
         })
 
     def flow_start(
@@ -936,8 +933,8 @@ def instant(
     tracer.instant(name, cat, ctx=ctx, **args)
 
 
-def counter(name: str, value: float, cat: str = "", **args: Any) -> None:
-    tracer.counter(name, value, cat, **args)
+def counter(name: str, value: float, cat: str = "") -> None:
+    tracer.counter(name, value, cat)
 
 
 def flow_start(
@@ -982,6 +979,11 @@ class _Phase:
         #: grows by at exit. Set it to 0 inside the block for time that
         #: belongs to the phase but finishes no unit of its own.
         self.count = 1
+
+    def set(self, **args: Any) -> None:
+        """Label the tracer's span with what is known only inside the block
+        (the profiler's annotation takes its labels at entry)."""
+        self._span.set(**args)
 
     def __enter__(self) -> "_Phase":
         self._span.__enter__()

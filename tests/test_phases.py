@@ -1,6 +1,7 @@
 """Phase names: ``ps.*`` scopes in the step and predict programs and what
 ``op_scopes()`` reads back from them; ``trace.phase`` in its three planes;
-the timers the feed, the trainer and the evaluator leave behind."""
+the timers the reader's thread, the feed, the trainer and the evaluator
+leave behind."""
 
 import glob
 import os
@@ -10,7 +11,9 @@ import jax
 import numpy as np
 import pytest
 
+from parameter_server_tpu.data.batch import BatchBuilder, eval_builder
 from parameter_server_tpu.data.pipeline import PrefetchPipeline
+from parameter_server_tpu.data.reader import MinibatchReader
 from parameter_server_tpu.data.synthetic import make_sparse_logistic, write_libsvm
 from parameter_server_tpu.kv import store
 from parameter_server_tpu.models.linear import updater_from_config
@@ -27,6 +30,10 @@ NUM_KEYS, B, NNZ, U = 1 << 10, 16, 64, 65
 
 def _count(name: str) -> int:
     return timers.snapshot().get(name, {"count": 0})["count"]
+
+
+def _total(name: str) -> float:
+    return timers.snapshot().get(name, {"total_s": 0.0})["total_s"]
 
 
 def _batch(data: int, lead: tuple = ()) -> dict:
@@ -226,19 +233,91 @@ class TestFeedTimers:
         assert w1["count"] > w0["count"] and w1["total_s"] - w0["total_s"] > 0.1
 
 
-def _files(tmp_path, nnz_per_example: int, tag: str) -> list:
+READER_PHASES = ("reader.parse", "reader.build", "reader.put_wait")
+
+
+def _reader(tmp_path, backend: str = "auto", prefetch: int = 4) -> MinibatchReader:
+    """512 examples in batches of 64."""
+    builder = BatchBuilder(num_keys=NUM_KEYS, batch_size=64, max_nnz_per_example=16)
+    return MinibatchReader(_files(tmp_path, 8, "r"), "libsvm", builder, prefetch=prefetch, backend=backend)
+
+
+class TestReaderTimers:
+    @pytest.mark.parametrize("backend", ["native", "python"])
+    def test_one_build_a_batch_and_a_parse_a_chunk(self, tmp_path, backend):
+        before = {n: _count(n) for n in READER_PHASES}
+        batches = list(_reader(tmp_path, backend))
+        grew = {n: _count(n) - before[n] for n in before}
+        assert sum(b.num_examples for b in batches) == 512 and len(batches) == 8
+        assert grew["reader.build"] == 8
+        # the file is one chunk (the step that finds its end counts nothing);
+        # the Python parsers hand over a row at a time: no phase a row
+        assert grew["reader.parse"] == (1 if backend == "native" else 0)
+
+    def test_a_full_queue_adds_to_put_wait(self, tmp_path):
+        n0, s0 = _count("reader.put_wait"), _total("reader.put_wait")
+        it = iter(_reader(tmp_path, prefetch=1))
+        first = next(it)
+        time.sleep(0.3)  # the reader's thread runs ahead of a caller that is not there
+        rest = list(it)
+        assert first.num_examples == 64 and len(rest) == 7
+        assert _count("reader.put_wait") > n0 and _total("reader.put_wait") - s0 > 0.1
+
+    def test_an_abandoned_reader_ends_its_wait(self, tmp_path):
+        n0 = _count("reader.put_wait")
+        it = iter(_reader(tmp_path, prefetch=1))
+        next(it)
+        it.close()  # the caller leaves: the thread blocked on its full queue is let go
+        deadline = time.time() + 5
+        while _count("reader.put_wait") == n0 and time.time() < deadline:
+            time.sleep(0.01)
+        assert _count("reader.put_wait") > n0
+
+    def test_armed_tracer_spans_say_how_many_examples(self, tmp_path):
+        t = trace.configure(str(tmp_path / "spans"), process_name="reader-test")
+        try:
+            list(_reader(tmp_path))
+            evs = [e for e in t.events() if e["name"] in READER_PHASES]
+        finally:
+            trace.configure(None)
+        # a chunk's count is known once it is parsed: set inside the phase;
+        # the step that finds the file at its end is a span too, and says nothing
+        assert [e["args"].get("examples") for e in evs if e["name"] == "reader.parse"] == [512, None]
+        assert [e["args"]["examples"] for e in evs if e["name"] == "reader.build"] == [64] * 8
+        assert {e["cat"] for e in evs} == {"reader"} and len({e["tid"] for e in evs}) == 1
+
+    def test_names_are_host_events_of_the_readers_thread(self, tmp_path):
+        reader = _reader(tmp_path, prefetch=1)
+        with jax.profiler.trace(str(tmp_path / "prof")):
+            with trace.phase("test.reader_caller"):
+                it = iter(reader)
+                next(it)
+                time.sleep(0.2)  # a full queue, so that the thread waits
+                list(it)
+        (path,) = glob.glob(os.path.join(str(tmp_path / "prof"), "plugins", "profile", "*", "*.xplane.pb"))
+        prof = jax.profiler.ProfileData.from_file(path)
+        threads = [
+            {ev.name for ev in ln.events}
+            for p in prof.planes if not p.name.startswith("/device:") for ln in p.lines
+        ]
+        (readers,) = [names for names in threads if "reader.build" in names]
+        assert set(READER_PHASES) <= readers
+        assert "test.reader_caller" not in readers  # not the thread that iterates
+
+
+def _files(tmp_path, nnz_per_example: int, tag: str, examples: int = 512) -> list:
     labels, keys, vals, _ = make_sparse_logistic(
-        512, 300, nnz_per_example=nnz_per_example, noise=0.3, seed=5
+        examples, 300, nnz_per_example=nnz_per_example, noise=0.3, seed=5
     )
     path = tmp_path / f"{tag}.svm"
     write_libsvm(path, labels, keys, vals)
     return [str(path)]
 
 
-def _trainer(**data) -> PodTrainer:
+def _trainer(minibatch: int = 128, **data) -> PodTrainer:
     cfg = PSConfig()
     cfg.data.num_keys = 1 << 12
-    cfg.solver.minibatch = 128
+    cfg.solver.minibatch = minibatch
     cfg.solver.epochs = 1
     cfg.parallel.data_shards = 2
     cfg.parallel.kv_shards = 2
@@ -247,13 +326,16 @@ def _trainer(**data) -> PodTrainer:
     return PodTrainer(cfg, reporter=ProgressReporter(print_fn=lambda *_: None))
 
 
+EVAL_LEAVES = ("eval.open_reader", "eval.read", "eval.stack", "eval.enqueue", "eval.retire", "eval.score")
+
+
 class TestTrainerAndEvaluatorTimers:
     def test_one_pass_is_one_open_and_one_score(self, tmp_path):
         t = _trainer()
         files = _files(tmp_path, 8, "a")
         t.train_files(files, report_every=100)
         names = ("eval.open", "eval.score", "eval.dispatch", "eval.retire", "eval.new_shapes")
-        before = {n: _count(n) for n in names}
+        before = {n: _count(n) for n in names + ("eval.pass",)}
         ev = t.evaluate_files(files)
         after = {n: _count(n) - before[n] for n in before}
         calls = -(-ev["examples"] // (128 * 2))  # predict calls: two data shards a call
@@ -261,9 +343,40 @@ class TestTrainerAndEvaluatorTimers:
         assert after == {
             "eval.open": 1, "eval.score": 1, "eval.dispatch": calls - 1, "eval.retire": calls,
             "eval.new_shapes": 1,  # the pass's one shape, first dispatched here
+            "eval.pass": 1,
         }
         t.evaluate_files(files)
         assert _count("eval.new_shapes") - before["eval.new_shapes"] == 1  # and not again
+
+    @pytest.mark.parametrize("source", ["files", "batches"])
+    def test_the_leaves_add_up_to_the_pass(self, tmp_path, source):
+        t = _trainer(minibatch=1024)  # a size at which the calls, not the loop, are the pass
+        files = _files(tmp_path, 8, "p", examples=16 * 1024 + 512)
+        if source == "files":
+            evaluate = lambda: t.evaluate_files(files)  # noqa: E731
+        else:
+            batches = list(MinibatchReader(files, "libsvm", eval_builder(t.cfg, "hash")))
+            evaluate = lambda: t.evaluate_batches(batches)  # noqa: E731
+        evaluate()  # the pass that compiles
+        whole = ("eval.pass", "eval.open", "eval.dispatch", "eval.new_shapes")
+        before = {n: (_count(n), _total(n)) for n in EVAL_LEAVES + whole}
+        ev = evaluate()
+        count = {n: _count(n) - before[n][0] for n in before}
+        spent = {n: _total(n) - before[n][1] for n in before}
+        batches_read = -(-ev["examples"] // 1024)  # 17: the last one is short
+        calls = -(-batches_read // 2)  # two data shards a call: 9, the last one half inert
+        assert ev["examples"] == 16 * 1024 + 512 and calls == 9
+        assert count == {
+            "eval.pass": 1, "eval.open_reader": 1, "eval.score": 1,
+            "eval.read": calls,  # a group a call; not the probe that finds the stream at its end
+            "eval.stack": calls, "eval.enqueue": calls, "eval.retire": calls,
+            "eval.open": 1, "eval.dispatch": calls - 1, "eval.new_shapes": 0,
+        }
+        leaves = sum(spent[n] for n in EVAL_LEAVES)
+        assert 0.9 * spent["eval.pass"] <= leaves <= spent["eval.pass"], spent
+        # the two enclosing phases hold their calls' leaves
+        assert spent["eval.open"] + spent["eval.dispatch"] >= spent["eval.stack"] + spent["eval.enqueue"]
+        assert spent["eval.open"] >= spent["eval.open_reader"]
 
     def test_the_three_trainer_phases_keep_their_timers(self, tmp_path):
         before = {n: _count(n) for n in ("trainer.fetch", "trainer.dispatch", "trainer.retire")}
