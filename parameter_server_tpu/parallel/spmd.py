@@ -589,41 +589,74 @@ def _ascending_rows(idx: jax.Array, local: jax.Array) -> jax.Array:
     return jnp.where(pad, jnp.iinfo(local.dtype).max, local)
 
 
-# Whether a scatter-add into a table is told that its rows ascend. At one
-# stored lane the hint moves XLA's TPU scatter from an emitter that takes
-# its slots in turn to one that streams the whole table slot whatever
-# lands. Alone on a v5e, ms a scatter of one real batch's 65,536 key slots
-# (40,058 keys; tools/probe_push_scatter.py, PERF.md section 6, PR 35):
+# Whether a scatter-add into a table is told that its rows ascend. Where the
+# chip keeps a table row-major (one stored lane, or whole ``_TILE_LANES``-lane
+# tiles: what ``row_stride`` sends 300 to) the hint moves XLA's TPU scatter
+# from an emitter that takes its slots in turn to one that streams the whole
+# table slot whatever lands. Alone on a v5e, ms a scatter
+# (tools/probe_push_scatter.py; PERF.md section 6, PRs 35 and 38). One lane,
+# one real batch's 65,536 key slots (40,058 keys), PR 35:
 #
-#     rows x lanes     rows a slot   hinted   unhinted
-#     2^26 x 1               1,024     1.28       1.31
-#     100,000,768 x 1        1,526     1.67       1.70
-#     2^27 x 1               2,048     2.08       5.72
-#     2^28 x 1               4,096     3.68       5.91
-#     2^29 x 1               8,192     6.91       5.93
-#     2^30 x 1              16,384    13.27       5.79   (5.55 as a kv shard of 2^31)
-#     2^30 x 1, 2,048 slots (all pads) 12.88      0.21
-#     2^30 x 1, 524,289 slots (PR 27)  16.43     47.86
-#     100,000,768 x 16       1,526     6.46       6.46
+#     rows x lanes     elements a slot   hinted   unhinted
+#     2^26 x 1                   1,024     1.28       1.31
+#     100,000,768 x 1            1,526     1.67       1.70
+#     2^27 x 1                   2,048     2.08       5.72
+#     2^28 x 1                   4,096     3.68       5.91
+#     2^29 x 1                   8,192     6.91       5.93
+#     2^30 x 1                  16,384    13.27       5.79   (5.55 as a kv shard of 2^31)
+#     2^30 x 1, 2,048 slots (all pads)    12.88       0.21
+#     2^30 x 1, 524,289 slots (PR 27)     16.43      47.86
 #
-# Hinted: 11.9 ps a row and 6 ns a slot. Unhinted: 88 ns a slot above 10^8
-# rows, below it XLA streams of its own accord. The two cross where a slot
-# stands for 82 ns / 11.9 ps = 6,900 rows. Wider tables keep the hint they
-# had: level at 16 and at 64 lanes (23.92 / 23.92 at 50,122,752 x 64); at
-# 6,000,640 x 384 the probe read 33.05 hinted against 12.28, a lead this
-# rule does not take yet (PERF.md section 7).
-_STREAM_ROWS_A_SLOT = 6_900
+# Whole tiles, 114,689 key slots (72,100 keys: ``sgns3m.train``'s), PR 38:
+#
+#     131,072 x 128                146     0.98       1.35
+#     4,194,304 x 128            4,681     7.38       7.97
+#     5,242,880 x 128            5,851     9.02       8.16
+#     6,000,640 x 128            6,697    10.23       8.16
+#     18,000,896 x 128          20,090    29.24       8.23
+#     2,097,152 x 256            4,681     8.17       9.52
+#     2,621,440 x 256            5,851     9.88       9.69
+#     6,000,640 x 256           13,394    21.01       9.74
+#     131,072 x 384                439     2.47       3.16   (the same with every slot a key)
+#     524,288 x 384              1,755     4.51       5.20
+#     1,048,576 x 384            3,511     7.24      11.12
+#     1,572,864 x 384            5,266     9.98      11.23
+#     2,097,152 x 384            7,022    12.77      11.14
+#     6,000,640 x 384           20,091    33.03      11.27   (12.26 for 300-lane deltas: the cell's)
+#     1,048,576 x 512            4,681     9.60      11.99
+#     2,097,152 x 512            9,362    17.15      12.03
+#     6,000,640 x 384 under 2,048 pads / 65,536 / 131,072 slots (0.6 keys):
+#                                   30.28 / 31.01 / 31.78     0.25 / 6.25 / 12.40
+#     the same, every slot a key:           31.03 / 31.79            6.56 / 13.00
+#     131,072 x 384 under 2,048 pads        0.75       0.24
+#
+# Every other width the chip keeps rows minor, and the two are level there:
+# 100,000,768 x 16 6.46 / 6.46, 50,122,752 x 64 23.92 / 23.92 (PR 35, and
+# again in PR 38).
+#
+# Hinted, a row-major table costs its elements, 11.9-13.5 ps each (5.2 ns a
+# 384-lane row: the table read and written at 590 GB/s), and 6-15 ns a
+# slot. Unhinted it costs its slots, pads included, 70-100 ns each, and
+# nothing for the table above about 10^6 rows (below, less). The two cross
+# between 5,266 and 5,851 table elements a slot at every width measured
+# (interpolated: 6,900 at one lane, 5,200 / 5,700 / 6,000 / 6,200 at 128 /
+# 256 / 384 / 512): the constant lies in that bracket, so the rule is right
+# at every point above. No cell is near it: 1,526 (hinted), 16,384 and
+# 20,091 (not). Not measured: a kv shard's view of a whole-tile table.
+_STREAM_ELEMENTS_A_SLOT = 5_600
 
 
 def scatter_rows_sorted(rows: int, lanes: int, slots: int) -> bool:
     """Whether the scatter-add of ``slots`` ascending rows into a table
     slot of ``rows`` x ``lanes`` on this chip carries ``indices_are_sorted``:
-    yes, unless the table is one lane wide and holds more than
-    ``_STREAM_ROWS_A_SLOT`` rows for each slot scattered, where streaming it
-    costs more than taking the slots in turn. Static shapes in, so one
-    choice a traced program; beside ``row_stride``, the other place that
-    reads a table's treatment off its shape."""
-    return lanes > 1 or rows <= _STREAM_ROWS_A_SLOT * slots
+    yes, unless the chip keeps the table row-major (one lane, or whole
+    ``_TILE_LANES``-lane tiles) and it holds more than
+    ``_STREAM_ELEMENTS_A_SLOT`` elements for each slot scattered, where
+    streaming it costs more than taking the slots in turn. Static shapes
+    in, so one choice a traced program; beside ``row_stride``, the other
+    place that reads a table's treatment off its shape."""
+    streamed = lanes == 1 or lanes % _TILE_LANES == 0
+    return not streamed or rows * lanes <= _STREAM_ELEMENTS_A_SLOT * slots
 
 
 def _add_rows(

@@ -154,7 +154,7 @@ def test_trainer_refuses_a_batch_out_of_order():
         PodTrainer._prepare(None, [b])
 
 
-# -- whether XLA is told: ``spmd.scatter_rows_sorted`` (PERF.md section 6, PR 35) --
+# -- whether XLA is told: ``spmd.scatter_rows_sorted`` (PERF.md section 6, PRs 35 and 38) --
 # (rows a chip, stored lanes, key slots) of every table scatter the cells'
 # steps hold, and what the rule says there
 CELL_SCATTERS = {
@@ -162,13 +162,33 @@ CELL_SCATTERS = {
     "wd100m.train: wide.z, wide.n": ((100_000_768, 1, 1 << 16), True),
     "wd100m.train: emb.w, emb.n": ((100_000_768, 16, 1 << 16), True),
     "mfhw.train: mf.w": ((50_122_752, 64, 131_072), True),
-    "sgns3m.train: sgns.w": ((6_000_640, 384, 114_689), True),
+    "sgns3m.train: sgns.w": ((6_000_640, 384, 114_689), False),
     # the call that ends an epoch (``builder.build`` of no example: 2048 slots, all pads)
     "linear, the inert call": ((1 << 30, 1, 2048), False),
     "wide_deep, the inert call: wide": ((100_000_768, 1, 2048), False),
     "wide_deep, the inert call: emb": ((100_000_768, 16, 2048), True),
+    "word2vec, the inert call": ((6_000_640, 384, 2048), False),
     # what the step scattered until PR 30, and PR 27 measured the hint three times faster at
     "2^30 rows under 524,289 slots": ((1 << 30, 1, (1 << 19) + 1), True),
+    # PR 38's grid of whole-tile tables under sgns3m.train's slots: the corners, and
+    # the two shapes at each width that the crossing lies between
+    "6,000,640 x 128": ((6_000_640, 128, 114_689), False),
+    "6,000,640 x 256": ((6_000_640, 256, 114_689), False),
+    "18,000,896 x 128": ((18_000_896, 128, 114_689), False),
+    "a small table of whole tiles: 131,072 x 128": ((131_072, 128, 114_689), True),
+    "a small table of whole tiles: 131,072 x 384": ((131_072, 384, 114_689), True),
+    "131,072 x 384, the inert call": ((131_072, 384, 2048), False),
+    "4,194,304 x 128 (hinted 7.38 ms, unhinted 7.97)": ((4_194_304, 128, 114_689), True),
+    "5,242,880 x 128 (9.02, 8.16)": ((5_242_880, 128, 114_689), False),
+    "2,097,152 x 256 (8.17, 9.52)": ((2_097_152, 256, 114_689), True),
+    "2,621,440 x 256 (9.88, 9.69)": ((2_621_440, 256, 114_689), False),
+    "1,572,864 x 384 (9.98, 11.23)": ((1_572_864, 384, 114_689), True),
+    "2,097,152 x 384 (12.77, 11.14)": ((2_097_152, 384, 114_689), False),
+    "1,048,576 x 512 (9.60, 11.99)": ((1_048_576, 512, 114_689), True),
+    "2,097,152 x 512 (17.15, 12.03)": ((2_097_152, 512, 114_689), False),
+    # one lane between the two shapes PR 35 measured (3.68 / 5.91 and 6.91 / 5.93)
+    "2^28 x 1 under 65,536 slots": ((1 << 28, 1, 1 << 16), True),
+    "2^29 x 1 under 65,536 slots": ((1 << 29, 1, 1 << 16), False),
 }
 
 
@@ -178,18 +198,51 @@ def test_sorted_hint_on_the_shapes_the_cells_have(name):
     assert spmd.scatter_rows_sorted(*shape) is sorted_hint
 
 
+def streamed(lanes):
+    """Whether the chip keeps a table of such rows row-major, where the
+    hinted scatter streams it: one lane, or whole 128-lane tiles."""
+    return lanes == 1 or lanes % 128 == 0
+
+
 @pytest.mark.parametrize("slots", [2048, 1 << 16, 131_072, (1 << 19) + 1])
-@pytest.mark.parametrize("lanes", [1, 16, 64, 384])
+@pytest.mark.parametrize("lanes", [1, 16, 64, 128, 256, 384])
 def test_sorted_hint_is_monotone_in_rows(lanes, slots):
     """At fixed lanes and slots the hint goes with the smaller tables and
-    never comes back as the rows grow; only one stored lane ever loses it."""
+    never comes back as the rows grow; only a table the chip keeps
+    row-major ever loses it, and where it does is one count of table
+    elements a slot whatever the width."""
     says = [spmd.scatter_rows_sorted(1 << log2, lanes, slots) for log2 in range(10, 32)]
     assert says[0] and says == sorted(says, reverse=True), says
-    assert all(says) or lanes == 1
+    assert all(says) or streamed(lanes)
+    if streamed(lanes):
+        last = (spmd._STREAM_ELEMENTS_A_SLOT * slots) // lanes  # the largest table still hinted
+        assert spmd.scatter_rows_sorted(last, lanes, slots)
+        assert not spmd.scatter_rows_sorted(last + 1, lanes, slots)
+
+
+@pytest.mark.parametrize("log2_rows", [10, 23])
+@pytest.mark.parametrize("vdim", [100, 128, 300, 384, 640])
+def test_add_rows_asks_the_rule_at_the_stored_width(vdim, log2_rows):
+    """``_add_rows`` asks with the slot's stored width (``row_stride``: 100
+    -> 128, 300 -> 384), which is the table XLA lays out, and what it
+    lowers carries the answer: the hint at a small table of whole tiles,
+    none at a large one."""
+    import jax
+
+    stride, rows, slots = spmd.row_stride(vdim), 1 << log2_rows, 1 << 16
+    assert streamed(stride)
+    text = jax.jit(lambda t, i, d: spmd._add_rows(t, i, d, True)).lower(
+        jax.ShapeDtypeStruct((rows, stride), jnp.float32),
+        jax.ShapeDtypeStruct((slots,), jnp.int32),
+        jax.ShapeDtypeStruct((slots, vdim), jnp.float32),
+    ).as_text()
+    want = spmd.scatter_rows_sorted(rows, stride, slots)
+    assert want is (log2_rows == 10)
+    assert ("indices_are_sorted = true" in text) is want
 
 
 @pytest.mark.parametrize("mesh_name", ["1x1", "2x2"])
-@pytest.mark.parametrize("vdim", [1, 16])
+@pytest.mark.parametrize("vdim", [1, 16, 128, 300])
 def test_push_is_the_same_to_the_bit_whatever_the_rule_says(vdim, mesh_name, monkeypatch):
     """``_local_push`` with the promise, the rule forced either way: the
     same rows in the same order, other shards' rows and the pads dropped in
@@ -214,10 +267,13 @@ def test_push_is_the_same_to_the_bit_whatever_the_rule_says(vdim, mesh_name, mon
         idx[w, 1:41] = np.sort(real)
     grad = rng.normal(size=(data, slots, vdim)).astype(np.float32)
     grad[idx == 0] = 0.0
+    stride = spmd.row_stride(vdim)  # 300 is stored 384 lanes wide, the pad lanes zero
     start = {
-        "w": rng.normal(size=(rows, vdim)).astype(np.float32),
-        "n": rng.random(size=(rows, vdim)).astype(np.float32),
+        "w": rng.normal(size=(rows, stride)).astype(np.float32),
+        "n": rng.random(size=(rows, stride)).astype(np.float32),
     }
+    for v in start.values():
+        v[:, vdim:] = 0.0
 
     def local(state_l, idx_l, grad_l):
         return spmd._local_push(
@@ -245,4 +301,6 @@ def test_push_is_the_same_to_the_bit_whatever_the_rule_says(vdim, mesh_name, mon
     touched[idx.ravel()] = True
     touched[0] = False  # the pad's row takes zeros
     np.testing.assert_array_equal(hinted["n"][~touched], start["n"][~touched])
-    assert (hinted["n"][touched] != start["n"][touched]).all()
+    # every touched row moved (an element whose squared gradient rounds away may not)
+    assert (hinted["n"][touched, :vdim] != start["n"][touched, :vdim]).any(axis=1).all()
+    assert not hinted["n"][:, vdim:].any() and not hinted["w"][:, vdim:].any()

@@ -332,6 +332,19 @@ def sorted_hint(scatter_rest: str) -> bool:
     return "indices_are_sorted=true" in scatter_rest
 
 
+def fusions_calling(every: list, homes: set) -> list:
+    """(name, the rest of the line) of the fusions whose computation is one of ``homes``."""
+    return [
+        (name, rest) for _, name, _, opcode, _, rest in every
+        if opcode == "fusion" and re.search(r"calls=%?([\w.\-]+)", rest).group(1) in homes
+    ]
+
+
+def aliases_operand_0(fusion_rest: str) -> bool:
+    """Whether operand 0, the table, is the fusion's result buffer."""
+    return bool(re.search(r'"aliasing_operands":\{"lists":\[\{"indices":\["0"', fusion_rest))
+
+
 @pytest.mark.parametrize("app,data,kv", PUSH_PROGRAMS)
 def test_table_scatters_take_ascending_rows_in_place(compiled_text, wd_text, app, data, kv):
     """Whether the push's mechanism engages is a property of the compiled
@@ -361,16 +374,11 @@ def test_table_scatters_take_ascending_rows_in_place(compiled_text, wd_text, app
     for _, rest, lanes in scatters:
         assert sorted_hint(rest) is spmd.scatter_rows_sorted(rows, lanes, UNIQUE), (lanes, rest)
         assert sorted_hint(rest) is (app == "wd"), (lanes, rest)
-    homes = {comp for comp, _, _ in scatters}
-    fusions = [
-        (name, rest) for _, name, shape, opcode, _, rest in every
-        if opcode == "fusion" and re.search(r"calls=%?([\w.\-]+)", rest).group(1) in homes
-    ]
+    fusions = fusions_calling(every, {comp for comp, _, _ in scatters})
     assert len(fusions) == slots, fusions
     for name, rest in fusions:
         assert scopes[name].startswith("ps.push/scatter"), (name, scopes[name])
-        # operand 0, the table, is the result's buffer
-        assert re.search(r'"aliasing_operands":\{"lists":\[\{"indices":\["0"', rest), (name, rest[-300:])
+        assert aliases_operand_0(rest), (name, rest[-300:])
     table_bytes = sum(4 * rows * vdim for vdim in ((1, 1) if app == "linear" else (1, 1, 16, 16)))
     assert mem.alias_size_in_bytes >= table_bytes  # the whole state is donated through the call
     # the linear step's temporaries are batch-sized (15-18 MiB); W&D's hold its
@@ -655,9 +663,16 @@ def test_sgns_table_ops_are_scoped_and_the_table_is_worked_on_where_it_lies(sgns
     assert len(gathers) == 1, gathers  # on one chip the push's gather is the pull's
     if program == "multistep":
         assert {"ps.push/scatter/sgns", "ps.push/update/sgns"} <= found, found
-        scatters = [rest for _, _, shape, opcode, _, rest in every if opcode == "scatter" and table.search(shape)]
+        scatters = [(comp, rest) for comp, _, shape, opcode, _, rest in every if opcode == "scatter" and table.search(shape)]
         assert len(scatters) == 1, scatters
-        assert spmd.scatter_rows_sorted(rows, stride, SGNS_SLOTS) and sorted_hint(scatters[0]), scatters
+        ((home, told),) = scatters
+        # a row-major table of 20,091 elements a slot: no hint, the slots taken in turn
+        # (PERF.md section 6, PR 38), the table still updated where it lies
+        assert sorted_hint(told) is spmd.scatter_rows_sorted(rows, stride, SGNS_SLOTS), told
+        assert not sorted_hint(told), told
+        ((name, rest),) = fusions_calling(every, {home})
+        assert scopes[name] == "ps.push/scatter/sgns", (name, scopes[name])
+        assert aliases_operand_0(rest), (name, rest[-300:])
         init = sgns_text.texts["init", "memory"]
         assert init.temp_size_in_bytes < 64 << 20, init.temp_size_in_bytes
     mem = sgns_text.texts[(data, kv, program), "memory"]

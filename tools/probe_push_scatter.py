@@ -13,7 +13,8 @@ told nothing); ``ascending``, the step's from PR 27 to PR 34
 whatever the shapes); ``unhinted``, the same rows with pads and other
 shards' keys dropped and XLA told nothing, which is what the step takes
 since PR 35 where ``spmd.scatter_rows_sorted`` says the hint does not pay
-(``rule_sorted`` on the case's line is what it says there). ``--gathers``
+(``rule_sorted`` on the case's line is what it says there; since PR 38 it
+says so of whole-tile tables too, by their elements a slot). ``--gathers``
 adds ``jnp.take`` as the step calls it against sorted rows with
 ``mode="fill"``, on the first and the third (a dead lead: PERF.md section
 7 (b)). Every form's result is checked on the chip against the deltas row
@@ -27,6 +28,27 @@ unhinted / today): f32[2^30,1] 13.27 / 5.79 / 6.19, as a kv shard 13.26 /
 / 1.76; f32[100000768,16] 6.46 / 6.46 / 6.56; the inert call 12.88 / 0.21 /
 0.25. ``--wide``: f32[50122752,64] 23.92 hinted, 23.92 not; f32[6000640,300]
 stored 384 wide 33.05 hinted, 12.28 not.
+
+What PR 38 read (``SEED --wide VDIM ROWS SLOTS REAL --no-element``, seeds
+2380000001-26 in the order given, 114,689 slots of which 72,100 real unless
+said; ms a scatter, hinted / unhinted; every line ``rows_equal_numpy``): the
+hinted scatter streams a table of whole 128-lane tiles as it streams a
+one-lane one, 12-13.5 ps a table element, and the unhinted one takes the
+slots in turn, 70-100 ns each. f32[6000640,300] stored 384 wide 33.04 /
+12.26; f32[6000640,384] 33.03 / 11.27; f32[6000640,256] 21.01 / 9.74;
+f32[6000640,128] 10.23 / 8.16. 384 lanes by rows: 2097152 12.77 / 11.14,
+524288 4.51 / 5.20, 131072 2.47 / 3.16 (the same with 114,688 real).
+f32[6000640,384] by slots and real slots: 2048 / 0 (the inert call) 30.28 /
+0.25; 65536 / 39300 31.01 / 6.25; 65536 / 65535 31.03 / 6.56; 114689 /
+114688 33.03 / 11.74; 131072 / 78600 31.77 / 12.40; 131072 / 131071 31.79
+/ 13.00. f32[131072,384] under 2048 pads 0.75 / 0.24. f32[131072,128] 0.98
+/ 1.35; f32[18000896,128] 29.24 / 8.23. The control, f32[50122752,64]
+under 131072 / 75528: 23.92 / 23.92. Around the crossing: f32[1048576,384]
+7.24 / 11.12, f32[1572864,384] 9.98 / 11.23; f32[2097152,256] 8.17 / 9.52,
+f32[2621440,256] 9.88 / 9.69; f32[4194304,128] 7.38 / 7.97,
+f32[5242880,128] 9.02 / 8.16; f32[1048576,512] 9.60 / 11.99,
+f32[2097152,512] 17.15 / 12.03: between 5,266 and 5,851 table elements a
+slot at every width, which is ``spmd._STREAM_ELEMENTS_A_SLOT``'s bracket.
 
 ``--wide VDIM ROWS [SLOTS REAL]`` (PERF.md section 6, PRs 33 to 35) runs
 one wide table alone: the pull of REAL ascending keys in SLOTS key slots
