@@ -516,19 +516,13 @@ def run_train(cfg: PSConfig, args: argparse.Namespace) -> dict:
     if cfg.app == "wide_deep":
         return _run_train_wd(cfg, args)
     if cfg.solver.algo == "darlin":
-        from parameter_server_tpu.data.batch import BatchBuilder
-        from parameter_server_tpu.data.reader import MinibatchReader
+        from parameter_server_tpu.data.blockcache import cached_column_blocks
         from parameter_server_tpu.models.darlin import Darlin
-        from parameter_server_tpu.utils.checkpoint import (
-            dump_weights_text,
-            save_checkpoint,
-        )
+        from parameter_server_tpu.parallel import make_mesh
+        from parameter_server_tpu.utils.checkpoint import dump_weights_text
 
-        if args.resume:
-            raise SystemExit(
-                "--resume is not supported for the darlin batch solver "
-                "(it restarts from its cached column blocks)"
-            )
+        if args.resume and not args.ckpt_dir:
+            raise SystemExit("--resume requires --ckpt_dir")
         if args.coordinator:
             # silently ignoring the flag would run N independent solvers
             # clobbering each other's cache/model outputs
@@ -537,43 +531,25 @@ def run_train(cfg: PSConfig, args: argparse.Namespace) -> dict:
                 "(distributed darlin runs on one process's mesh via "
                 "parallel.data_shards/kv_shards)"
             )
-        mesh = None
-        if cfg.parallel.data_shards * cfg.parallel.kv_shards > 1:
-            from parameter_server_tpu.parallel import make_mesh
-
-            mesh = make_mesh(cfg.parallel.data_shards, cfg.parallel.kv_shards)
-        app = Darlin(cfg, mesh=mesh)
+        # one program over a (data, kv) mesh, 1x1 included
+        app = Darlin(
+            cfg, mesh=make_mesh(cfg.parallel.data_shards, cfg.parallel.kv_shards)
+        )
         # SlotReader behavior: with data.cache_dir set, the first run parses
         # text and writes the columnar block cache; re-runs mmap it instead.
-        from parameter_server_tpu.data.blockcache import cached_column_blocks
-
-        res = app.fit_blocks(cached_column_blocks(cfg))
-        if args.ckpt_dir:
-            save_checkpoint(
-                args.ckpt_dir,
-                {"w": app.w},
-                meta={"algo": "darlin", "num_keys": cfg.data.num_keys},
-            )
+        # With --ckpt_dir the table is saved after every finished pass, and
+        # --resume takes the solve up from the last one saved.
+        res = app.fit_blocks(
+            cached_column_blocks(cfg), ckpt_dir=args.ckpt_dir or "",
+            resume=bool(args.resume),
+        )
         if args.model_out:
             dump_weights_text(app.w, args.model_out)
         out = {k: res[k] for k in ("objv", "iters", "nnz_w", "train_auc")}
         if cfg.data.val_files:
-            builder = BatchBuilder(
-                num_keys=cfg.data.num_keys,
-                batch_size=cfg.solver.minibatch,
-                max_nnz_per_example=cfg.data.max_nnz_per_example,
-            )
-            val = list(
-                MinibatchReader(cfg.data.val_files, cfg.data.format, builder)
-            )
-            p = app.predict(val)
-            import numpy as np
-
-            from parameter_server_tpu.models import metrics as M
-
-            y = np.concatenate([b.labels[: b.num_examples] for b in val])
-            out["val_auc"] = M.auc(y, p)
-            out["val_logloss"] = M.logloss(y, p)
+            # the solved table through the one evaluator (PodTrainer)
+            ev = app.evaluate_files(cfg.data.val_files)
+            out.update({f"val_{k}": v for k, v in ev.items()})
         return out
 
     # pod path: a mesh bigger than 1x1 (or an explicit coordinator) routes

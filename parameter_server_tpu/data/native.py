@@ -22,6 +22,7 @@ import ctypes
 import os
 import subprocess
 import sys
+import threading
 from collections.abc import Iterator
 from pathlib import Path
 
@@ -89,6 +90,9 @@ def _build() -> Path | None:
     return so if so.exists() else None
 
 
+_load_lock = threading.Lock()
+
+
 def _tune_malloc() -> None:
     """Raise glibc's mmap threshold so the multi-MB per-chunk output
     arrays are served from the (warm, reusable) heap instead of fresh
@@ -106,7 +110,17 @@ def _tune_malloc() -> None:
 
 
 def load_native() -> ctypes.CDLL | None:
-    """Load (building if needed) the native parser library, or None."""
+    """Load (building if needed) the native parser library, or None. The
+    first caller loads it under a lock: reader threads that start together
+    (a cache build's pool) would otherwise see "tried, none" while the first
+    of them is still building, and parse in Python."""
+    if _lib is not None:
+        return _lib
+    with _load_lock:
+        return _load_native_locked()
+
+
+def _load_native_locked() -> ctypes.CDLL | None:
     global _lib, _lib_tried
     if _lib is not None or _lib_tried:
         return _lib

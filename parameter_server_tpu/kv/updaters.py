@@ -1,4 +1,5 @@
-"""Server-side updaters: SGD, AdaGrad, FTRL-proximal.
+"""Server-side updaters: SGD, AdaGrad, FTRL-proximal, and the batch
+solver's block proximal-Newton step.
 
 Reference analog: the Entry types applied by the server KV store on push —
 SGD/AdaGrad/FTRL entries in src/app/linear_method/async_sgd.h (server side)
@@ -13,6 +14,7 @@ State layout per table (vdim = values per key, reference's "value segments"):
   sgd:     {"w": (K, vdim)}
   adagrad: {"w": (K, vdim), "n": (K, vdim)}
   ftrl:    {"z": (K, vdim), "n": (K, vdim)}   -- w is DERIVED lazily
+  prox_newton: {"w": (K, 1), "active": (K, 1)}  -- the batch solver's
 FTRL stores no w: the weight is materialized from (z, n) on pull, which is
 exactly the reference's lazy L1 sparsification (untouched keys stay exactly
 zero without ever being written).
@@ -134,9 +136,89 @@ class Ftrl:
         return -shrunk / denom
 
 
+@dataclass(frozen=True)
+class ProxNewton:
+    """The server's half of one DARLIN block step (Li et al., OSDI 2014,
+    Algorithm 3; ref: the proximal update of src/app/linear_method/darlin.h
+    server side and its KKT filter). Not an online updater: the worker
+    pushes a block's gradient AND curvature ``(g, h)`` for a contiguous key
+    range, the server answers with a direction, and the step's scale comes
+    back from the worker's line search before the weights move - so the
+    step is the pair ``direction`` / ``apply`` over the range's rows, not
+    one ``delta``.
+
+    Per coordinate j of the block, with h' = h + lambda_l2 + 1e-6:
+        z = w h' - eta g
+        d = sign(z) max(|z| - eta lambda_l1, 0) / h' - w   (0 where skipped)
+        skipped: outside the active set and w == 0
+        violation = |g + sign(w) lambda_l1|       where w != 0
+                  = max(|g| - lambda_l1, 0)       where w == 0
+    ``active`` is kept as 1.0 / 0.0 in the table's dtype, so the solver's
+    table is two float32 slots like every other updater's."""
+
+    eta: float = 1.0
+    lambda_l1: float = 1.0
+    lambda_l2: float = 0.0
+    name: str = "prox_newton"
+
+    def init(self, num_keys: int, vdim: int = 1, dtype: Any = jnp.float32) -> Rows:
+        return {
+            "w": jnp.zeros((num_keys, vdim), dtype),
+            "active": jnp.ones((num_keys, vdim), dtype),
+        }
+
+    def weights(self, rows: Rows) -> Any:
+        return rows["w"]
+
+    def delta(self, rows: Rows, grad: Any) -> Rows:
+        raise NotImplementedError(
+            "prox_newton steps a key range from (g, h) through direction / "
+            "apply (models.darlin); it has no minibatch delta"
+        )
+
+    def violation(self, rows: Rows, g: Any) -> Any:
+        """KKT violation per coordinate: the filter's score."""
+        w = rows["w"]
+        return jnp.where(
+            w != 0.0,
+            jnp.abs(g + jnp.sign(w) * self.lambda_l1),
+            jnp.maximum(jnp.abs(g) - self.lambda_l1, 0.0),
+        )
+
+    def direction(self, rows: Rows, g: Any, h: Any) -> Any:
+        """Proximal Newton direction per coordinate (diagonal model)."""
+        w = rows["w"]
+        h_safe = h + self.lambda_l2 + 1e-6
+        z = w * h_safe - self.eta * g
+        w_cand = (
+            jnp.sign(z)
+            * jnp.maximum(jnp.abs(z) - self.eta * self.lambda_l1, 0.0)
+            / h_safe
+        )
+        skip = (rows["active"] == 0.0) & (w == 0.0)
+        return jnp.where(skip, 0.0, w_cand - w)
+
+    def apply(self, rows: Rows, d: Any, alpha: Any) -> Rows:
+        """The rows after a step of scale ``alpha`` along ``d``."""
+        return {"w": rows["w"] + alpha * d, "active": rows["active"]}
+
+    def refresh(self, rows: Rows, g: Any, threshold: Any) -> Rows:
+        """The rows with the active set taken anew: a coordinate stays in
+        while it holds a weight or violates by more than ``threshold``."""
+        keep = (rows["w"] != 0.0) | (self.violation(rows, g) > threshold)
+        return {"w": rows["w"], "active": keep.astype(rows["active"].dtype)}
+
+    def penalty(self, w: Any) -> Any:
+        """lambda_l1 |w|_1 + lambda_l2 / 2 |w|^2 summed over ``w``'s last
+        axis (a leading axis, where given, is the line search's scales)."""
+        return self.lambda_l1 * jnp.abs(w).sum(axis=-1) + 0.5 * self.lambda_l2 * (
+            w * w
+        ).sum(axis=-1)
+
+
 def make_updater(algo: str, **kw: Any) -> Updater:
     """Factory by config name (ref: solver/penalty fields of the app proto)."""
-    table = {"sgd": Sgd, "adagrad": Adagrad, "ftrl": Ftrl}
+    table = {"sgd": Sgd, "adagrad": Adagrad, "ftrl": Ftrl, "prox_newton": ProxNewton}
     if algo not in table:
         raise ValueError(f"unknown updater '{algo}'; known: {sorted(table)}")
     cls = table[algo]

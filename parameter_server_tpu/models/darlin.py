@@ -1,227 +1,106 @@
 """DARLIN: delayed block proximal gradient for L1 logistic regression.
 
 Reference analog: src/app/linear_method/darlin.* / batch_solver.* — the
-reference's batch solver. Its anatomy, re-expressed for TPU:
+reference's batch solver (Li et al., OSDI 2014, Algorithm 3). Its anatomy,
+re-expressed for TPU, through the one store:
 
   reference                                this module
   ---------                                -----------
-  SlotReader column-block cache            ColumnBlocks: entries sorted by
-    (parse once, per-slot binary cache)      feature block, padded to a
-                                             static per-block size, stacked
-                                             into (n_blocks, E) arrays
-  worker keeps prediction vector Xw        pred (N,) device-resident, updated
+  SlotReader column-block cache            ``data.blockcache.ColumnBlocks``:
+    (parse once, per-slot binary cache)      entries by feature block, sorted
+                                             by feature, in fixed-length
+                                             chunks resident in HBM
+  servers hold w by key range              a ``spmd.Table`` (slots ``w`` and
+                                             ``active``) in the one state
+                                             dict, range-sharded over "kv"
+  worker keeps prediction vector Xw        pred (N,) over "data", updated
                                              incrementally per block
-  per-block grad + diag-Hessian push       segment_sums over block entries
-  server proximal (soft-threshold) step    prox_newton_block (elementwise)
-  KKT filter active-set bitmap             active (K,) bool array; inactive
-                                             coordinates get delta == 0
-  bounded-delay block pipelining           ``delay`` blocks compute their
-                                             gradients against the same stale
-                                             pred inside one lax.scan carry
+  per block: pull w_b, push (g_b, u_b)     ``spmd.pull_range`` / the psum of
+                                             (g, h) over "data" / ``spmd.
+                                             push_range`` of the block's rows
+  server proximal (soft-threshold) step    ``kv.updaters.ProxNewton``:
+    + KKT filter                             ``direction`` / ``apply`` /
+                                             ``refresh``
+  bounded-delay block pipelining           ``max_delay`` + 1 device calls in
+                                             flight; inside a call groups of
+                                             ``max_delay`` + 1 blocks take
+                                             their gradients against one
+                                             stale pred
 
-The whole pass over blocks is ONE jitted lax.scan — block steps are the
-reference's unit of work and remain so here, but scheduling is compiled
-instead of message-driven.
+ONE program runs on every mesh, 1x1 included. A pass is a sequence of device
+calls of a fixed number of blocks (``solver.steps_per_call``; streaming,
+``solver.block_chunk`` > 0, uploads just those blocks' chunks for the same
+program), dispatched as ``PodTrainer`` dispatches its calls; the objective,
+the largest KKT violation and the count of non-zero weights come back as
+scalars with each call's retire, never the table.
+
+One departure from the source: upstream bounds each coordinate's step by a
+per-coordinate trust region; here one step scale a block comes from an
+eight-point search on the true objective (``_line_search_alpha``).
 """
 
 from __future__ import annotations
 
 import functools
+import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from parameter_server_tpu.data.batch import CSRBatch
-from parameter_server_tpu.data.blockcache import ColumnBlocks
+from parameter_server_tpu.data.blockcache import ENTRY_ARRAYS, ColumnBlocks
+from parameter_server_tpu.kv.updaters import ProxNewton
 from parameter_server_tpu.models import metrics as M
+from parameter_server_tpu.parallel import spmd
+from parameter_server_tpu.parallel.ssp import DispatchWindow
+from parameter_server_tpu.utils import trace
 from parameter_server_tpu.utils.config import PSConfig
-from parameter_server_tpu.utils.metrics import ProgressReporter
+from parameter_server_tpu.utils.metrics import ProgressReporter, observe_scalar
 
 __all__ = [
     "ColumnBlocks",
     "Darlin",
-    "darlin_pass",
-    "make_darlin_spmd_fns",
+    "make_darlin_fns",
     "shard_blocks_for_mesh",
+    "updater_from_config",
 ]
 
 
-# ---------------------------------------------------------------------------
-# Per-block coordinate math, shared verbatim by the single-device and SPMD
-# solvers — the 2e-4 trajectory-parity contract between them depends on the
-# formulas living in exactly one place. The distributed path injects its
-# cross-shard reduction through ``reduce`` (identity vs psum over "data").
-# ---------------------------------------------------------------------------
-
-
-def _kkt_viol(w_b: jax.Array, g: jax.Array, lambda_l1: float) -> jax.Array:
-    """KKT violation per coordinate (ref: the filter score deciding the
-    active set)."""
-    return jnp.where(
-        w_b != 0.0,
-        jnp.abs(g + jnp.sign(w_b) * lambda_l1),
-        jnp.maximum(jnp.abs(g) - lambda_l1, 0.0),
+def updater_from_config(cfg: PSConfig) -> ProxNewton:
+    """The server's half of a block step, from the solver's settings."""
+    return ProxNewton(
+        eta=cfg.lr.eta,
+        lambda_l1=cfg.penalty.lambda_l1,
+        lambda_l2=cfg.penalty.lambda_l2,
     )
 
 
-def _prox_newton_direction(
-    w_b: jax.Array,
-    g: jax.Array,
-    h: jax.Array,
-    skip: jax.Array,
-    lambda_l1: float,
-    lambda_l2: float,
-    learning_rate: float,
-) -> jax.Array:
-    """Proximal Newton direction per coordinate (diagonal model):
-    z = w*h - eta*g ; d = soft_threshold(z, eta*lambda_l1)/h - w."""
-    h_safe = h + lambda_l2 + 1e-6
-    z = w_b * h_safe - learning_rate * g
-    w_cand = (
-        jnp.sign(z)
-        * jnp.maximum(jnp.abs(z) - learning_rate * lambda_l1, 0.0)
-        / h_safe
-    )
-    return jnp.where(skip, 0.0, w_cand - w_b)
-
-
-def _line_search_alpha(
-    pred: jax.Array,
-    Xd: jax.Array,
-    y: jax.Array,
-    w_b: jax.Array,
-    d: jax.Array,
-    lambda_l1: float,
-    lambda_l2: float,
-    mask: jax.Array | None = None,
-    reduce=lambda x: x,
-):
+def _line_search_alpha(pred, Xd, y, mask, w_b, d, updater: ProxNewton):
     """Simultaneous coordinate updates can overshoot when block features
     co-occur (the diagonal model ignores coupling; the reference's bounded
     update is its safeguard). Safeguard here: evaluate the TRUE objective at
     8 geometric step scales in parallel and take the best — one fused (T, N)
-    softplus sweep, fully static for XLA. ``reduce`` sums nll terms across
-    example shards in the distributed solver."""
+    softplus sweep, fully static for XLA, its terms summed over the example
+    shards ("data")."""
     alphas = 0.5 ** jnp.arange(8, dtype=jnp.float32)  # 1, 1/2, ..., 1/128
     zs = pred[None, :] + alphas[:, None] * Xd[None, :]  # (T, N)
-    terms = jax.nn.softplus(zs) - y[None, :] * zs
-    terms0 = jax.nn.softplus(pred) - y * pred
-    if mask is not None:
-        terms = terms * mask[None, :]
-        terms0 = terms0 * mask
-    nll = reduce(jnp.sum(terms, axis=1))
-    wa = w_b[None, :] + alphas[:, None] * d[None, :]  # (T, block)
-    reg = lambda_l1 * jnp.abs(wa).sum(axis=1) + 0.5 * lambda_l2 * (wa * wa).sum(axis=1)
-    obj_a = nll + reg
-    obj_0 = (
-        reduce(jnp.sum(terms0))
-        + lambda_l1 * jnp.abs(w_b).sum()
-        + 0.5 * lambda_l2 * (w_b * w_b).sum()
-    )
+    terms = (jax.nn.softplus(zs) - y[None, :] * zs) * mask[None, :]
+    terms0 = (jax.nn.softplus(pred) - y * pred) * mask
+    nll = jax.lax.psum(jnp.sum(terms, axis=1), "data")
+    obj_a = nll + updater.penalty(w_b[None, :] + alphas[:, None] * d[None, :])
+    obj_0 = jax.lax.psum(jnp.sum(terms0), "data") + updater.penalty(w_b)
     best = jnp.argmin(obj_a)
     return jnp.where(obj_a[best] < obj_0, alphas[best], 0.0)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("block_size", "num_examples", "delay")
-)
-def darlin_pass(
-    w: jax.Array,  # (K,)
-    pred: jax.Array,  # (N,)
-    active: jax.Array,  # (K,) bool — KKT active set
-    blocks: dict[str, jax.Array],  # stacked block arrays + block order
-    labels: jax.Array,
-    lambda_l1: float,
-    lambda_l2: float,
-    learning_rate: float,
-    kkt_threshold: float,
-    block_size: int,
-    num_examples: int,
-    delay: int = 0,
-) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """One pass over all feature blocks. Returns (w, pred, active, viol_max).
-
-    ``delay`` > 0 reproduces the reference's bounded-delay pipelining: the
-    gradient of block t is computed against the prediction vector as of
-    block t - (t mod (delay+1)) — i.e. groups of delay+1 consecutive blocks
-    all read the same stale pred, then their updates land together.
-    """
-    y = labels
-
-    def block_step(carry, blk):
-        w, pred, stale_pred, active, viol_max, i = carry
-        # bounded delay: refresh the stale snapshot every (delay+1) blocks
-        refresh = (i % (delay + 1)) == 0
-        stale_pred = jnp.where(refresh, pred, stale_pred)
-
-        fl, rows, vals, b_idx = (
-            blk["feat_local"],
-            blk["rows"],
-            blk["values"],
-            blk["block_idx"],
-        )
-        begin = b_idx * block_size
-        p = jax.nn.sigmoid(stale_pred)
-        err = p - y
-        h_ex = p * (1.0 - p)
-        g = jax.ops.segment_sum(
-            vals * jnp.take(err, rows), fl, num_segments=block_size
-        )
-        h = jax.ops.segment_sum(
-            vals * vals * jnp.take(h_ex, rows), fl, num_segments=block_size
-        )
-        w_b = jax.lax.dynamic_slice(w, (begin,), (block_size,))
-        act_b = jax.lax.dynamic_slice(active, (begin,), (block_size,))
-
-        viol = _kkt_viol(w_b, g, lambda_l1)
-        viol_max = jnp.maximum(viol_max, viol.max())
-        # inactive zero-weight coords with tiny gradient are skipped
-        skip = (~act_b) & (w_b == 0.0)
-        d = _prox_newton_direction(
-            w_b, g, h, skip, lambda_l1, lambda_l2, learning_rate
-        )
-        Xd = jax.ops.segment_sum(
-            vals * jnp.take(d, fl), rows, num_segments=num_examples
-        )
-        alpha = _line_search_alpha(
-            pred, Xd, y, w_b, d, lambda_l1, lambda_l2
-        )
-
-        w = jax.lax.dynamic_update_slice(w, w_b + alpha * d, (begin,))
-        # incremental prediction update: pred += alpha * X_b @ d (ref: Xw)
-        pred = pred + alpha * Xd
-        return (w, pred, stale_pred, active, viol_max, i + 1), None
-
-    init = (w, pred, pred, active, jnp.float32(0.0), jnp.int32(0))
-    (w, pred, _, active, viol_max, _), _ = jax.lax.scan(
-        block_step, init, blocks
-    )
-    return w, pred, active, viol_max
-
-
-@functools.partial(jax.jit, static_argnames=())
-def _objective(
-    w: jax.Array, pred: jax.Array, labels: jax.Array, lambda_l1: float, lambda_l2: float
-) -> jax.Array:
-    nll = jnp.sum(jax.nn.softplus(pred) - labels * pred)
-    return nll + lambda_l1 * jnp.abs(w).sum() + 0.5 * lambda_l2 * (w * w).sum()
-
-
 # ---------------------------------------------------------------------------
-# Distributed DARLIN over the (data, kv) mesh
-#
-# Reference analog (SURVEY §3.3): workers hold example shards (their column
-# blocks + their slice of the prediction vector Xw), servers hold the weight
-# by key range. Per block: each worker computes its shard's gradient /
-# diag-Hessian contribution (push == psum over "data"), the owning server
-# range computes the proximal step, and the direction is broadcast back
-# (pull == masked psum over "kv") so every worker can update its Xw slice.
+# Host-side placement of the column blocks over the "data" axis
 # ---------------------------------------------------------------------------
 
 
 def shard_examples_for_mesh(cb: ColumnBlocks, data_shards: int) -> dict:
-    """(labels, mask) reshaped to (D, per) — examples padded to D * per."""
+    """(labels, mask) of D * per examples — padded up to D equal shards."""
     D = data_shards
     N = cb.num_examples
     per = -(-N // D)
@@ -230,8 +109,8 @@ def shard_examples_for_mesh(cb: ColumnBlocks, data_shards: int) -> dict:
     labels[:N] = np.asarray(cb.labels, dtype=np.float32)
     mask[:N] = 1.0
     return {
-        "labels": labels.reshape(D, per),
-        "mask": mask.reshape(D, per),
+        "labels": labels,
+        "mask": mask,
         "per_shard_examples": per,
     }
 
@@ -242,367 +121,352 @@ def shard_blocks_for_mesh(
     blocks: np.ndarray | None = None,
     pad_pow2: bool = False,
 ) -> dict:
-    """Host-side prep: partition block entries by example shard — fully
-    vectorized (one argsort over the selected entries; no per-block Python
-    loops).
+    """Host-side prep: the selected blocks' chunks, partitioned by example
+    shard (contiguous ranges of ``per`` examples, rows LOCAL to the shard).
 
     blocks: optional subset/order of block indices to pack. The streaming
-      solver packs one chunk at a time straight from the (possibly mmap'd)
-      block cache, so only the chunk's rows are ever read into RAM.
-    pad_pow2: round the entry width E up to a power of two, bounding jit
-      recompilation across streamed chunks to O(log E) distinct shapes.
+      solver packs one call's blocks at a time straight from the (possibly
+      mmap'd) block cache, so only those chunks are ever read into RAM.
+    pad_pow2: round the chunk count up to a power of two, bounding jit
+      recompilation across streamed calls to O(log chunks) distinct shapes.
 
     Returns numpy arrays ready for device_put:
-      feat_local/rows/values: (B, D, E) with rows LOCAL to the shard and
-        E = the max per-(block, shard) entry count of THIS selection (not
-        a global max — padding stays bounded by the selection's own skew)
-      block_idx: (B,) absolute block ids; counts: (B, D) real entry counts
-    (labels/mask come from ``shard_examples_for_mesh`` — computed once per
-    solve, not per packed chunk).
+      feat_local/rows/values: (D, n_chunks, C), each shard's chunks of the
+        selection back to back, a block's entries still ascending by feature
+        and only its last chunk padded (the ``ColumnBlocks`` contract, shard
+        by shard)
+      spans: (D, B, 2) int32 — block j's chunks [begin, end) in shard d
+      block_idx: (B,) absolute block ids; counts: (B, D) real entry counts.
+    On one data shard the whole set in order is the cache's own arrays,
+    viewed, not copied.
     """
-    D = data_shards
-    N = cb.num_examples
-    per = -(-N // D)  # ceil: examples padded to D * per
+    D, C = data_shards, cb.chunk_len
+    per = -(-cb.num_examples // D)
     sel = (
         np.arange(cb.n_blocks, dtype=np.int64)
         if blocks is None
         else np.asarray(blocks, dtype=np.int64)
     )
     B = len(sel)
-    # fancy-index (mmap-friendly: reads only the selected blocks' rows)
-    feat_src = np.asarray(cb.feat_local[sel])
-    rows_src = np.asarray(cb.rows[sel])
-    vals_src = np.asarray(cb.values[sel])
-    E_src = feat_src.shape[1]
-    s = rows_src // per  # (B, E_src) example shard per entry (contiguous
-    # ranges); cb pad entries (value == 0) sit at row 0 => shard 0, inert
-    key = (
-        np.arange(B, dtype=np.int64)[:, None] * D + s
-    ).ravel()  # group = (block, shard)
-    order = np.argsort(key, kind="stable")
-    k_sorted = key[order]
-    counts = np.bincount(key, minlength=B * D)
-    starts = np.zeros(B * D + 1, dtype=np.int64)
-    np.cumsum(counts, out=starts[1:])
-    pos = np.arange(B * E_src, dtype=np.int64) - starts[k_sorted]
-    E = max(1, int(counts.max()))
+    counts = np.zeros((B, D), np.int64)
+    if D == 1:
+        counts[:, 0] = cb.entries[sel]
+        n_chunk = (cb.chunk_begin[sel + 1] - cb.chunk_begin[sel]).astype(np.int64)
+        if blocks is None:
+            packed = {k: np.asarray(getattr(cb, k))[None] for k in ENTRY_ARRAYS}
+            begin = cb.chunk_begin[:-1]
+        else:
+            at = np.concatenate(
+                [np.arange(cb.chunk_begin[b], cb.chunk_begin[b + 1]) for b in sel]
+                or [np.zeros(0, np.int64)]
+            )
+            packed = {k: np.asarray(getattr(cb, k)[at])[None] for k in ENTRY_ARRAYS}
+            begin = np.cumsum(n_chunk) - n_chunk
+        spans = np.stack([begin, begin + n_chunk], axis=-1)[None].astype(np.int32)
+    else:
+        per_shard: list[list] = [[] for _ in range(D)]
+        for j, b in enumerate(sel):
+            feat, rows, vals = cb.block(int(b))
+            s = rows // per
+            order = np.argsort(s, kind="stable")  # feature order survives
+            counts[j] = np.bincount(s, minlength=D)
+            ends = np.cumsum(counts[j])
+            for d in range(D):
+                part = order[ends[d] - counts[j, d] : ends[d]]
+                per_shard[d].append((feat[part], rows[part] - d * per, vals[part]))
+        n_chunk = -(-counts // C)  # (B, D)
+        begin = np.cumsum(n_chunk, axis=0) - n_chunk
+        spans = np.stack([begin, begin + n_chunk], axis=-1).transpose(1, 0, 2).astype(np.int32)
+        total = max(int(n_chunk.sum(axis=0).max()), 1)
+        packed = {
+            k: np.zeros((D, total * C), np.float32 if k == "values" else np.int32)
+            for k in ENTRY_ARRAYS
+        }
+        for d in range(D):
+            for j, (feat, rows, vals) in enumerate(per_shard[d]):
+                lo, n = int(begin[j, d]) * C, len(feat)
+                packed["feat_local"][d, lo : lo + n] = feat
+                packed["rows"][d, lo : lo + n] = rows
+                packed["values"][d, lo : lo + n] = vals
+                if n:  # a block's pad repeats its last feature: still sorted
+                    packed["feat_local"][d, lo + n : (int(begin[j, d]) + int(n_chunk[j, d])) * C] = feat[-1]
+        packed = {k: v.reshape(D, total, C) for k, v in packed.items()}
+    have = packed["values"].shape[1]
+    want = max(have, 1)
     if pad_pow2:
-        E = 1 << (E - 1).bit_length()
-    feat = np.zeros((B * D, E), dtype=feat_src.dtype)
-    rows = np.zeros((B * D, E), dtype=rows_src.dtype)
-    vals = np.zeros((B * D, E), dtype=vals_src.dtype)
-    local_rows = rows_src - s * per  # localize BEFORE packing: packed
-    # padding slots stay 0 (a valid inert local row), never negative
-    feat[k_sorted, pos] = feat_src.ravel()[order]
-    rows[k_sorted, pos] = local_rows.ravel()[order]
-    vals[k_sorted, pos] = vals_src.ravel()[order]
+        want = 1 << (want - 1).bit_length()
+    if want != have:
+        packed = {
+            k: np.concatenate([v, np.zeros((D, want - have, C), v.dtype)], axis=1)
+            for k, v in packed.items()
+        }
     return {
-        "feat_local": feat.reshape(B, D, E),
-        "rows": rows.reshape(B, D, E),
-        "values": vals.reshape(B, D, E),
+        **packed,
+        "spans": spans,
         "block_idx": sel.astype(np.int32),
-        "counts": counts.reshape(B, D),
+        "counts": counts,
         "per_shard_examples": per,
     }
 
 
-class DarlinSpmdFns:
-    """The jitted mesh programs of the distributed solver.
+# ---------------------------------------------------------------------------
+# The solver's programs over the (data, kv) mesh
+#
+# Reference analog (SURVEY §3.3): workers hold example shards (their column
+# blocks + their slice of the prediction vector Xw), servers hold the weight
+# by key range. Per block: each worker computes its shard's gradient /
+# diag-Hessian contribution (push == psum over "data"), the owning server
+# range computes the proximal step, and the direction is broadcast back
+# (the range pull over "kv") so every worker can update its Xw slice.
+# ---------------------------------------------------------------------------
 
-    pass_resident / kkt_resident — scan over a permutation array, gathering
-      each block's entries from DEVICE-RESIDENT stacked arrays (device_put
-      once per solve; the per-iteration block shuffle never re-uploads or
-      re-materializes the data).
-    pass_chunk / kkt_chunk — scan over a streamed chunk of blocks handed in
-      as its own (C, D, E) arrays (the bounded-memory path; each distinct
-      (C, E) pair compiles once — the streaming driver pads E to powers of
-      two to bound that).
-    obj — pod-wide objective; place — put host arrays with solver sharding.
+
+class DarlinFns:
+    """The jitted mesh programs of the solver, each over one call's blocks
+    (``spans``/``blk``/``live`` say which chunks, which key ranges, and
+    which of the call's slots hold a block at all):
+
+    block_call — the blocks' proximal steps in order; returns the state,
+      pred and {alphas (G,), obj, viol_max, nnz_w} after the call.
+    refresh_call — the KKT filter's active set taken anew for the blocks
+      from the gradient at the state as it stands (one sweep of their
+      entries where a step makes three; neither weights nor pred move);
+      returns the state and {n_active}, the active coordinates among them.
+    xw_call — pred += X_b w_b for the blocks (a restart recomputes Xw from
+      the table by one sweep).
+    objective — (objective, non-zero weights) of (state, pred).
+    place / place_blocks — host arrays onto the mesh with the solver's
+      shardings.
     """
 
     def __init__(self, **fns):
         self.__dict__.update(fns)
 
 
-def make_darlin_spmd_fns(
+def make_darlin_fns(
     mesh,
+    table: spmd.Table,
     *,
     num_keys: int,
     block_size: int,
     per_shard_examples: int,
-    lambda_l1: float,
-    lambda_l2: float,
-    learning_rate: float,
     delay: int,
-) -> DarlinSpmdFns:
-    """Build the solver's jitted mesh programs (see DarlinSpmdFns).
+) -> DarlinFns:
+    """Build the solver's jitted mesh programs (see DarlinFns).
 
-    Layout: w/active P("kv"); pred/labels/mask P("data", None); block entry
-    arrays P(None, "data", None). Requires num_keys divisible by kv and
-    every block wholly inside one kv range (n_blocks % kv_shards == 0 with
-    contiguous equal blocks).
+    Layout: the table's slots P("kv", None); pred/labels/mask (D * per,)
+    P("data"); chunk arrays (D * n_chunks, C) P("data", None). Requires every block wholly
+    inside one kv range (contiguous equal blocks that divide the shard).
     """
     from jax import lax, shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     kv = mesh.shape["kv"]
-    if num_keys % kv:
-        raise ValueError(f"num_keys {num_keys} not divisible by kv={kv}")
-    shard_size = num_keys // kv
+    shard_size = spmd._shard_size(num_keys, kv)
     if shard_size % block_size:
         raise ValueError(
             f"kv range {shard_size} not aligned to block_size {block_size}: "
             "each feature block must live wholly on one kv shard"
         )
     per = per_shard_examples
+    updater: ProxNewton = table.updater
 
-    def _bcast_from_owner(x, is_owner):
-        """Broadcast the owning kv shard's value to all (pull)."""
-        return lax.psum(jnp.where(is_owner, x, jnp.zeros_like(x)), "kv")
-
-    def _block_grad(pred_l, y_l, mask_l, fl, rows, vals):
-        p = jax.nn.sigmoid(pred_l)
-        err = (p - y_l) * mask_l
-        h_ex = p * (1.0 - p) * mask_l
-        g = jax.ops.segment_sum(
-            vals * jnp.take(err, rows), fl, num_segments=block_size
-        )
-        h = jax.ops.segment_sum(
-            vals * vals * jnp.take(h_ex, rows), fl, num_segments=block_size
-        )
-        return lax.psum(g, "data"), lax.psum(h, "data")  # push
-
-    def _block_body(carry, fl, rows, vals, b_idx, y_l, mask_l):
-        """One block's proximal step — shared by both pass variants so the
-        trajectory-parity contract with the single-device solver lives in
-        exactly one place."""
-        w_l, pred_l, stale_pred, active_l, viol_max, i = carry
-        refresh = (i % (delay + 1)) == 0
-        stale_pred = jnp.where(refresh, pred_l, stale_pred)
-        my_k = lax.axis_index("kv")
-        begin = b_idx * block_size
-        owner = begin // shard_size
-        is_owner = owner == my_k
-        safe_begin = jnp.where(is_owner, begin - owner * shard_size, 0)
-
-        g, h = _block_grad(stale_pred, y_l, mask_l, fl, rows, vals)
-        w_b = _bcast_from_owner(
-            lax.dynamic_slice(w_l, (safe_begin,), (block_size,)), is_owner
-        )
-        act_b = (
-            _bcast_from_owner(
-                lax.dynamic_slice(
-                    active_l.astype(jnp.float32), (safe_begin,), (block_size,)
-                ),
-                is_owner,
-            )
-            > 0
-        )
-
-        viol = _kkt_viol(w_b, g, lambda_l1)
-        viol_max = jnp.maximum(viol_max, viol.max())
-        skip = (~act_b) & (w_b == 0.0)
-        d = _prox_newton_direction(
-            w_b, g, h, skip, lambda_l1, lambda_l2, learning_rate
-        )
-        # my example shard's X_b @ d; the line-search objective is the
-        # TRUE pod-wide objective (masked nll psum'd over "data")
-        Xd_l = jax.ops.segment_sum(
-            vals * jnp.take(d, fl), rows, num_segments=per
-        )
-        alpha = _line_search_alpha(
-            pred_l, Xd_l, y_l, w_b, d, lambda_l1, lambda_l2,
-            mask=mask_l, reduce=lambda x: lax.psum(x, "data"),
-        )
-
-        new_w_b = w_b + alpha * d
-        w_l = jnp.where(
-            is_owner,
-            lax.dynamic_update_slice(w_l, new_w_b, (safe_begin,)),
-            w_l,
-        )
-        pred_l = pred_l + alpha * Xd_l
-        return (w_l, pred_l, stale_pred, active_l, viol_max, i + 1)
-
-    def _kkt_body(active_l, w_l, pred_l, y_l, mask_l, thr, fl, rows, vals, b_idx):
-        my_k = lax.axis_index("kv")
-        begin = b_idx * block_size
-        owner = begin // shard_size
-        is_owner = owner == my_k
-        safe_begin = jnp.where(is_owner, begin - owner * shard_size, 0)
-        g, _ = _block_grad(pred_l, y_l, mask_l, fl, rows, vals)
-        w_b = _bcast_from_owner(
-            lax.dynamic_slice(w_l, (safe_begin,), (block_size,)), is_owner
-        )
-        new_act = (w_b != 0.0) | (_kkt_viol(w_b, g, lambda_l1) > thr)
-        return jnp.where(
-            is_owner,
-            lax.dynamic_update_slice(active_l, new_act, (safe_begin,)),
-            active_l,
-        )
-
-    def _take_block(blocks_l, idx):
-        """Gather block ``idx``'s local entries from the device-resident
-        stacks (each a local (n_blocks, 1, E) slice under shard_map)."""
+    def _chunk(chunks_l, c):
         return tuple(
-            lax.dynamic_index_in_dim(blocks_l[k], idx, 0, keepdims=False)[0]
-            for k in ("feat_local", "rows", "values")
+            lax.dynamic_index_in_dim(chunks_l[k], c, 0, keepdims=False)
+            for k in ENTRY_ARRAYS
         )
 
-    def local_pass_resident(w_l, pred_l, active_l, blocks_l, order, y_l, mask_l):
-        # squeeze this device's singleton data-axis slice
-        pred_l, y_l, mask_l = pred_l[0], y_l[0], mask_l[0]
+    def _block_grad(err, h_ex, chunks_l, span, want_h: bool = True):
+        """This shard's (g, h) of one block: two sorted segment sums a
+        chunk, each taken from zero and then added to the block's. A key
+        that every example holds has 10^7 terms: added one by one into one
+        float32 they stop counting near 2^24 (the cell's integer columns: h
+        read 1.7% low); by chunks no sum is longer than a chunk or than the
+        chunks of a block. The barrier keeps the compiler from folding the
+        add back into one scatter onto the running sum."""
+        zero = jnp.zeros(block_size, jnp.float32)
 
-        def block_step(carry, idx):
-            fl, rows, vals = _take_block(blocks_l, idx)
-            return _block_body(carry, fl, rows, vals, idx, y_l, mask_l), None
+        def chunk_sum(fl, terms):
+            return lax.optimization_barrier(zero.at[fl].add(terms, indices_are_sorted=True))
 
-        init = (w_l, pred_l, pred_l, active_l, jnp.float32(0.0), jnp.int32(0))
-        (w_l, pred_l, _, active_l, viol_max, _), _ = lax.scan(
-            block_step, init, order
+        def body(c, gh):
+            fl, rows, vals = _chunk(chunks_l, c)
+            g = gh[0] + chunk_sum(fl, vals * jnp.take(err, rows))
+            if not want_h:
+                return g, gh[1]
+            return g, gh[1] + chunk_sum(fl, vals * vals * jnp.take(h_ex, rows))
+
+        return lax.fori_loop(span[0], span[1], body, (zero, zero))
+
+    def _block_xd(d, chunks_l, span):
+        """This shard's X_b d: the block's entries scattered over its
+        examples, chunk by chunk."""
+
+        def body(c, xd):
+            fl, rows, vals = _chunk(chunks_l, c)
+            return xd.at[rows].add(vals * jnp.take(d, fl))
+
+        return lax.fori_loop(span[0], span[1], body, jnp.zeros(per, jnp.float32))
+
+    def _rows_1d(rows):
+        return {k: v[:, 0] for k, v in rows.items()}
+
+    def _objective(state_l, pred_l, y_l, mask_l):
+        w_l = table.of(state_l)["w"][:, 0]
+        nll = lax.psum(jnp.sum(mask_l * (jax.nn.softplus(pred_l) - y_l * pred_l)), "data")
+        reg = lax.psum(updater.penalty(w_l), "kv")
+        nnz = lax.psum(jnp.sum(w_l != 0.0), "kv")
+        return nll + reg, nnz
+
+    def local_block_call(state_l, pred_l, y_l, mask_l, chunks_l, spans_l, blk, live):
+        spans_l = spans_l[0]
+
+        def block_step(carry, x):
+            state_l, pred_l, stale, viol_max, i = carry
+            span, b_idx, on = x
+            if delay:  # bounded delay: refresh the stale snapshot every (delay+1) blocks
+                stale = jnp.where((i % (delay + 1)) == 0, pred_l, stale)
+                seen = stale
+            else:
+                seen = pred_l
+            begin = b_idx * block_size
+            with jax.named_scope("ps.grad"):
+                p = jax.nn.sigmoid(seen)
+                g, h = _block_grad((p - y_l) * mask_l, p * (1.0 - p) * mask_l, chunks_l, span)
+            with jax.named_scope("ps.pull"):
+                rows = _rows_1d(spmd.pull_range(table, state_l, begin, block_size, shard_size, kv))
+            with jax.named_scope("ps.push"):
+                g, h = lax.psum(g, "data"), lax.psum(h, "data")  # the workers' push of (g, u)
+                viol = jnp.where(on, updater.violation(rows, g).max(), 0.0)
+                d = jnp.where(on, updater.direction(rows, g, h), 0.0)
+            with jax.named_scope("darlin.xd"):
+                xd = _block_xd(d, chunks_l, span)
+            with jax.named_scope("darlin.linesearch"):
+                alpha = _line_search_alpha(pred_l, xd, y_l, mask_l, rows["w"], d, updater)
+            with jax.named_scope("ps.push"):
+                new = {k: v[:, None] for k, v in updater.apply(rows, d, alpha).items()}
+                state_l = spmd.push_range(table, state_l, begin, new, shard_size, kv)
+            with jax.named_scope("darlin.xd"):
+                # incremental prediction update: pred += alpha * X_b @ d (ref: Xw)
+                pred_l = pred_l + alpha * xd
+            return (state_l, pred_l, stale, jnp.maximum(viol_max, viol), i + 1), alpha
+
+        stale0 = pred_l if delay else jnp.zeros((), jnp.float32)
+        init = (state_l, pred_l, stale0, jnp.float32(0.0), jnp.int32(0))
+        (state_l, pred_l, _, viol_max, _), alphas = lax.scan(block_step, init, (spans_l, blk, live))
+        with jax.named_scope("darlin.linesearch"):
+            obj, nnz = _objective(state_l, pred_l, y_l, mask_l)
+        out = {"alphas": alphas, "obj": obj, "viol_max": viol_max, "nnz_w": nnz}
+        return state_l, pred_l, out
+
+    @jax.named_scope("darlin.refresh")  # whole under the one name: the step's five scopes stay a step's
+    def local_refresh_call(state_l, pred_l, y_l, mask_l, chunks_l, spans_l, blk, live, thr):
+        spans_l = spans_l[0]
+        err = (jax.nn.sigmoid(pred_l) - y_l) * mask_l
+
+        def block_step(carry, x):
+            state_l, n_active = carry
+            span, b_idx, on = x
+            begin = b_idx * block_size
+            g, _ = _block_grad(err, err, chunks_l, span, want_h=False)
+            rows = _rows_1d(spmd.pull_range(table, state_l, begin, block_size, shard_size, kv))
+            new = updater.refresh(rows, lax.psum(g, "data"), thr)
+            new = {k: jnp.where(on, v, rows[k])[:, None] for k, v in new.items()}
+            state_l = spmd.push_range(table, state_l, begin, new, shard_size, kv)
+            n_active = n_active + jnp.where(on, jnp.sum(new["active"]), 0.0)
+            return (state_l, n_active), None
+
+        (state_l, n_active), _ = lax.scan(
+            block_step, (state_l, jnp.float32(0.0)), (spans_l, blk, live)
         )
-        return w_l, pred_l[None, :], viol_max
+        return state_l, {"n_active": n_active}
 
-    def local_pass_chunk(w_l, pred_l, active_l, chunk_l, y_l, mask_l):
-        pred_l, y_l, mask_l = pred_l[0], y_l[0], mask_l[0]
+    def local_xw_call(state_l, pred_l, chunks_l, spans_l, blk, live):
+        spans_l = spans_l[0]
 
-        def block_step(carry, blk):
-            return (
-                _block_body(
-                    carry,
-                    blk["feat_local"][0], blk["rows"][0], blk["values"][0],
-                    blk["block_idx"], y_l, mask_l,
-                ),
-                None,
-            )
+        def block_step(pred_l, x):
+            span, b_idx, on = x
+            with jax.named_scope("ps.pull"):
+                rows = _rows_1d(
+                    spmd.pull_range(table, state_l, b_idx * block_size, block_size, shard_size, kv)
+                )
+            with jax.named_scope("darlin.xd"):
+                w_b = jnp.where(on, updater.weights(rows), 0.0)
+                return pred_l + _block_xd(w_b, chunks_l, span), None
 
-        init = (w_l, pred_l, pred_l, active_l, jnp.float32(0.0), jnp.int32(0))
-        (w_l, pred_l, _, active_l, viol_max, _), _ = lax.scan(
-            block_step, init, chunk_l
+        pred_l, _ = lax.scan(block_step, pred_l, (spans_l, blk, live))
+        return pred_l
+
+    state_s = {table.key(k): spmd.state_spec() for k in table.slots()}
+    ex_s, dat, span_s = P("data"), P("data", None), P("data", None, None)
+    # a shard's chunks are a plain (n_chunks, C) array on its device: the
+    # shards' stacked on the first axis (a leading axis of one would have the
+    # chip relay the whole set at every call)
+    chunks_s = {k: dat for k in ENTRY_ARRAYS}
+    call_s = (chunks_s, span_s, P(None), P(None))  # chunks, spans, blk, live
+    scalars = {"alphas": P(), "obj": P(), "viol_max": P(), "nnz_w": P()}
+
+    def program(local, in_specs, out_specs, donate):
+        jitted = jax.jit(
+            shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False),
+            donate_argnums=donate,
         )
-        return w_l, pred_l[None, :], viol_max
+        seen: set = set()
 
-    def local_kkt_resident(w_l, pred_l, active_l, blocks_l, order, y_l, mask_l, thr):
-        pred_l, y_l, mask_l = pred_l[0], y_l[0], mask_l[0]
+        @functools.wraps(local)
+        def call(*args):
+            spmd.note_program(jitted, seen, frozenset(), *args)
+            return jitted(*args)
 
-        def block_step(active_l, idx):
-            fl, rows, vals = _take_block(blocks_l, idx)
-            return (
-                _kkt_body(
-                    active_l, w_l, pred_l, y_l, mask_l, thr, fl, rows, vals, idx
-                ),
-                None,
-            )
+        call.jitted = jitted
+        return call
 
-        active_l, _ = lax.scan(block_step, active_l, order)
-        return active_l
+    def place(arr: np.ndarray):
+        """A (D * per,) vector over the examples onto the "data" axis."""
+        return jax.device_put(jnp.asarray(arr), NamedSharding(mesh, ex_s))
 
-    def local_kkt_chunk(w_l, pred_l, active_l, chunk_l, y_l, mask_l, thr):
-        pred_l, y_l, mask_l = pred_l[0], y_l[0], mask_l[0]
-
-        def block_step(active_l, blk):
-            return (
-                _kkt_body(
-                    active_l, w_l, pred_l, y_l, mask_l, thr,
-                    blk["feat_local"][0], blk["rows"][0], blk["values"][0],
-                    blk["block_idx"],
-                ),
-                None,
-            )
-
-        active_l, _ = lax.scan(block_step, active_l, chunk_l)
-        return active_l
-
-    def local_obj(w_l, pred_l, y_l, mask_l):
-        pred_l, y_l, mask_l = pred_l[0], y_l[0], mask_l[0]
-        nll = lax.psum(
-            jnp.sum(mask_l * (jax.nn.softplus(pred_l) - y_l * pred_l)), "data"
-        )
-        reg = lax.psum(
-            lambda_l1 * jnp.abs(w_l).sum() + 0.5 * lambda_l2 * (w_l * w_l).sum(),
-            "kv",
-        )
-        return nll + reg
-
-    kv_s, dat, blk_s = P("kv"), P("data", None), P(None, "data", None)
-    resident_spec = {"feat_local": blk_s, "rows": blk_s, "values": blk_s}
-    chunk_spec = {**resident_spec, "block_idx": P(None)}
-    pass_resident = jax.jit(
-        shard_map(
-            local_pass_resident, mesh=mesh,
-            in_specs=(kv_s, dat, kv_s, resident_spec, P(None), dat, dat),
-            out_specs=(kv_s, dat, P()),
-            check_vma=False,
-        ),
-        donate_argnums=(0, 1),
-    )
-    pass_chunk = jax.jit(
-        shard_map(
-            local_pass_chunk, mesh=mesh,
-            in_specs=(kv_s, dat, kv_s, chunk_spec, dat, dat),
-            out_specs=(kv_s, dat, P()),
-            check_vma=False,
-        ),
-        donate_argnums=(0, 1),
-    )
-    kkt_resident = jax.jit(
-        shard_map(
-            local_kkt_resident, mesh=mesh,
-            in_specs=(kv_s, dat, kv_s, resident_spec, P(None), dat, dat, P()),
-            out_specs=kv_s,
-            check_vma=False,
-        )
-    )
-    kkt_chunk = jax.jit(
-        shard_map(
-            local_kkt_chunk, mesh=mesh,
-            in_specs=(kv_s, dat, kv_s, chunk_spec, dat, dat, P()),
-            out_specs=kv_s,
-            check_vma=False,
-        )
-    )
-    obj_fn = jax.jit(
-        shard_map(
-            local_obj, mesh=mesh,
-            in_specs=(kv_s, dat, dat, dat),
-            out_specs=P(),
-            check_vma=False,
-        )
-    )
-
-    def place(name: str, arr: np.ndarray):
-        spec = {"w": kv_s, "active": kv_s, "pred": dat, "labels": dat, "mask": dat}[name]
-        return jax.device_put(jnp.asarray(arr), NamedSharding(mesh, spec))
-
-    def place_blocks(sharded: dict, with_idx: bool):
-        sh = NamedSharding(mesh, blk_s)
-        out = {
-            k: jax.device_put(jnp.asarray(sharded[k]), sh)
-            for k in ("feat_local", "rows", "values")
+    def place_blocks(sharded: dict) -> dict:
+        sh = NamedSharding(mesh, dat)
+        return {
+            k: jax.device_put(sharded[k].reshape(-1, sharded[k].shape[-1]), sh)
+            for k in ENTRY_ARRAYS
         }
-        if with_idx:
-            out["block_idx"] = jax.device_put(
-                jnp.asarray(sharded["block_idx"]), NamedSharding(mesh, P(None))
-            )
-        return out
 
-    return DarlinSpmdFns(
-        pass_resident=pass_resident,
-        pass_chunk=pass_chunk,
-        kkt_resident=kkt_resident,
-        kkt_chunk=kkt_chunk,
-        obj=obj_fn,
+    return DarlinFns(
+        block_call=program(
+            local_block_call, (state_s, ex_s, ex_s, ex_s, *call_s), (state_s, ex_s, scalars), (0, 1)
+        ),
+        refresh_call=program(
+            local_refresh_call, (state_s, ex_s, ex_s, ex_s, *call_s, P()),
+            (state_s, {"n_active": P()}), (0,),
+        ),
+        xw_call=program(local_xw_call, (state_s, ex_s, *call_s), ex_s, (1,)),
+        objective=program(_objective, (state_s, ex_s, ex_s, ex_s), (P(), P()), ()),
         place=place,
         place_blocks=place_blocks,
     )
 
 
 class Darlin:
-    """Batch L1-LR solver app (scheduler role of the reference's Darlin*).
+    """Batch L1-LR solver app (scheduler role of the reference's Darlin*)
+    over a (data, kv) device mesh — 1x1 unless ``mesh`` or ``cfg.parallel``
+    says otherwise: example shards over "data", weight ranges over "kv", the
+    reference's worker/server split (SURVEY §3.3).
 
-    With ``mesh`` (a (data, kv) device mesh) the solver runs distributed:
-    example shards over "data", weight ranges over "kv" — the reference's
-    worker/server split (SURVEY §3.3)."""
+    Its state is the store's: ``self.state`` holds the slots of
+    ``self.table`` (``w``, ``active``) as every app's flat state dict does,
+    placed by ``spmd.shard_state``, saved and restored by
+    ``utils.checkpoint``; ``pred`` = Xw is the worker's and is recomputed
+    from the table on a restart, not saved.
+
+    ``fit_blocks`` is ``begin`` + ``solve``; a caller that wants to look at
+    the state between device calls (the benchmark) uses the two, and
+    ``run_calls`` for a part of a pass. ``on_retire(record)`` is called
+    right after the blocking read of each call's scalars, a step's and a
+    refresh's alike."""
+
+    CKPT_ALGO = "darlin"
 
     def __init__(
         self,
@@ -610,237 +474,249 @@ class Darlin:
         reporter: ProgressReporter | None = None,
         mesh=None,
     ):
+        from parameter_server_tpu.parallel import make_mesh
+
         self.cfg = cfg
         self.reporter = reporter or ProgressReporter()
-        self.mesh = mesh
+        self.mesh = mesh or make_mesh(cfg.parallel.data_shards, cfg.parallel.kv_shards)
+        self.updater = updater_from_config(cfg)
+        self.table = spmd.Table("", self.updater, 1)
+        self.delay = max(cfg.solver.max_delay, 0)
+        self.on_retire = None
+        self.state: dict | None = None
+        self.max_inflight = 0
 
-    def fit(
-        self,
-        batches: list[CSRBatch],
-        shuffle_blocks: bool = True,
+    # -- set-up ------------------------------------------------------------
+
+    def begin(self, cb: ColumnBlocks, shuffle_blocks: bool = True, resume_dir: str = "") -> None:
+        """Place the data, make (or restore) the table, and stand at the
+        start of pass ``self.passes_done``."""
+        cfg = self.cfg
+        self.cb, self.shuffle = cb, shuffle_blocks
+        D = self.mesh.shape["data"]
+        ex = shard_examples_for_mesh(cb, D)
+        self.per = ex["per_shard_examples"]
+        self.fns = make_darlin_fns(
+            self.mesh, self.table, num_keys=cb.num_keys, block_size=cb.block_size,
+            per_shard_examples=self.per, delay=self.delay,
+        )
+        self.labels = self.fns.place(ex["labels"])
+        self.mask = self.fns.place(ex["mask"])
+        self.pred = self.fns.place(np.zeros(D * self.per, np.float32))
+        # blocks a device call: a streamed call uploads block_chunk blocks'
+        # chunks, a resident one walks steps_per_call blocks of the set in HBM
+        stream = cfg.solver.block_chunk
+        self.call_blocks = stream if stream > 0 else max(cfg.solver.steps_per_call, 1)
+        self._resident = None
+        if stream <= 0:
+            sharded = shard_blocks_for_mesh(cb, D)
+            self._resident = (self.fns.place_blocks(sharded), sharded["spans"])
+        self.passes_done, self.history, self.prev_obj, self.converged = 0, [], None, False
+        if resume_dir:
+            self._load(resume_dir)
+        else:
+            self.state = spmd.shard_state(self.table.init_slots(cb.num_keys), self.mesh)
+        if self.prev_obj is None:
+            self.prev_obj = float(self.fns.objective(self.state, self.pred, self.labels, self.mask)[0])
+
+    def block_order(self, it: int) -> np.ndarray:
+        """Pass ``it``'s order of the blocks: shuffled from (seed, it), so
+        that a restart takes up the sequence where it stopped (ref:
+        randomized block order per iteration)."""
+        n = self.cb.n_blocks
+        if not self.shuffle:
+            return np.arange(n)
+        return np.random.default_rng([self.cfg.seed, it]).permutation(n)
+
+    def _call_args(self, group: np.ndarray) -> tuple:
+        """(chunks, spans, blk, live) of one call over ``group``'s blocks,
+        padded to the call's fixed number of blocks with slots that hold
+        none."""
+        G, n = self.call_blocks, len(group)
+        if self._resident is not None:
+            chunks, all_spans = self._resident
+            spans = all_spans[:, group]
+        else:
+            sharded = shard_blocks_for_mesh(
+                self.cb, self.mesh.shape["data"], blocks=group, pad_pow2=True
+            )
+            chunks, spans = self.fns.place_blocks(sharded), sharded["spans"]
+        spans = np.concatenate([spans, np.zeros((spans.shape[0], G - n, 2), np.int32)], axis=1)
+        blk = np.concatenate([group, np.zeros(G - n, group.dtype)]).astype(np.int32)
+        return chunks, spans, blk, np.arange(G) < n
+
+    def _groups(self, order: np.ndarray) -> list:
+        G = self.call_blocks
+        return [order[lo : lo + G] for lo in range(0, len(order), G)]
+
+    # -- the loop ----------------------------------------------------------
+
+    def run_calls(
+        self, order: np.ndarray, first: int = 0, count: int | None = None,
+        refresh_at: float | None = None,
+    ) -> list:
+        """Dispatch calls ``first .. first + count`` of a pass over
+        ``order`` (to its end, unsaid), at most ``max_delay`` + 1 in flight,
+        and return their retired records once every one has retired: {call,
+        blocks, alphas, obj, viol_max, nnz_w} of a call that steps its
+        blocks, {call, blocks, n_active} of one that refreshes their active
+        set instead (``refresh_at``: the KKT filter's threshold)."""
+        records: list = []
+        refresh = refresh_at is not None
+
+        def retire(idx: int, entry) -> None:
+            group, out = entry
+            with trace.phase("darlin.retire", call=idx):
+                # the blocking read: the bound on calls in flight taking effect
+                out = {k: np.asarray(v) for k, v in out.items()}
+            if refresh:
+                rec = {"call": idx, "blocks": group, "n_active": float(out["n_active"])}
+            else:
+                rec = {
+                    "call": idx, "blocks": group, "alphas": out["alphas"][: len(group)],
+                    "obj": float(out["obj"]), "viol_max": float(out["viol_max"]),
+                    "nnz_w": int(out["nnz_w"]),
+                }
+            records.append(rec)
+            if self.on_retire is not None:
+                self.on_retire(rec)
+
+        gate = DispatchWindow(self.delay, retire)
+        groups = self._groups(order)
+        last = len(groups) if count is None else min(first + count, len(groups))
+        for idx in range(first, last):
+            gate.gate(idx)
+            with trace.phase("darlin.dispatch", call=idx):
+                args = (self.state, self.pred, self.labels, self.mask, *self._call_args(groups[idx]))
+                if refresh:
+                    self.state, out = self.fns.refresh_call(*args, np.float32(refresh_at))
+                else:
+                    self.state, self.pred, out = self.fns.block_call(*args)
+            gate.add(idx, (groups[idx], out))
+            self.max_inflight = max(self.max_inflight, gate.max_inflight)
+        gate.wait_all()  # the pass's sync point: every dispatched call retired
+        return records
+
+    def _refresh(self, order: np.ndarray, viol_max: float) -> float:
+        """The KKT filter's active set, taken anew from the violation scale
+        of the pass (ref: the filter's adaptive threshold) by one more round
+        of calls over the pass's blocks; returns the share of the key space
+        left active."""
+        thr = self.cfg.solver.kkt_filter_threshold * max(viol_max, 1e-12)
+        recs = self.run_calls(order, refresh_at=thr)
+        return sum(r["n_active"] for r in recs) / self.cb.num_keys
+
+    def solve(self, first_call: int = 0, ckpt_dir: str = "") -> dict:
+        """Passes from ``self.passes_done`` on (the first from its call
+        ``first_call``) until the relative fall of the objective is under
+        ``solver.epsilon`` or ``solver.block_iters`` passes are done."""
+        cfg, cb = self.cfg, self.cb
+        for it in range(self.passes_done, 0 if self.converged else cfg.solver.block_iters):
+            order = self.block_order(it)
+            recs = self.run_calls(order, first_call)
+            first_call = 0
+            obj, nnz = recs[-1]["obj"], recs[-1]["nnz_w"]
+            viol = max(r["viol_max"] for r in recs)
+            observe_scalar("darlin.viol_max", viol)
+            if cfg.solver.kkt_filter_threshold > 0:
+                observe_scalar("darlin.active_share", self._refresh(order, viol))
+            rel = (self.prev_obj - obj) / max(abs(self.prev_obj), 1e-12)
+            self.reporter.report(
+                examples=cb.num_examples, objv=obj / cb.num_examples,
+                nnz_w=nnz, auc=float("nan"),
+            )
+            self.history.append(obj)
+            self.passes_done = it + 1
+            self.converged = 0 <= rel < cfg.solver.epsilon and it > 0
+            self.prev_obj = obj
+            if ckpt_dir:
+                self.save(ckpt_dir)
+            if self.converged:
+                break
+        probs = 1.0 / (1.0 + np.exp(-self.predictions()))
+        return {
+            "objv": self.history[-1] / cb.num_examples,
+            "iters": len(self.history),
+            "nnz_w": int((self.w != 0).sum()),
+            "train_auc": M.auc(np.asarray(cb.labels), probs),
+            "history": list(self.history),
+        }
+
+    def fit_blocks(
+        self, cb: ColumnBlocks, shuffle_blocks: bool = True,
+        ckpt_dir: str = "", resume: bool = False,
     ) -> dict:
+        """Run the solver on prebuilt (possibly disk-cached) column blocks;
+        with ``ckpt_dir`` the table is saved after every finished pass, and
+        ``resume`` takes the solve up from the last one saved there."""
+        self.begin(cb, shuffle_blocks, resume_dir=ckpt_dir if resume else "")
+        return self.solve(ckpt_dir=ckpt_dir)
+
+    def fit(self, batches: list[CSRBatch], shuffle_blocks: bool = True) -> dict:
         cb = ColumnBlocks.from_batches(
             batches, self.cfg.data.num_keys, self.cfg.solver.feature_blocks
         )
         return self.fit_blocks(cb, shuffle_blocks=shuffle_blocks)
 
-    def fit_blocks(self, cb: ColumnBlocks, shuffle_blocks: bool = True) -> dict:
-        if self.mesh is not None:
-            return self._fit_blocks_spmd(cb, shuffle_blocks=shuffle_blocks)
-        return self._fit_blocks_single(cb, shuffle_blocks=shuffle_blocks)
+    # -- the state, off the device -------------------------------------------
 
-    def _fit_blocks_spmd(self, cb: ColumnBlocks, shuffle_blocks: bool = True) -> dict:
-        """Distributed solve over the mesh (see module section above).
+    @property
+    def w(self) -> np.ndarray:
+        """(num_keys,) weights on this host (the table read back whole:
+        for the model dump and the tests, never inside the loop)."""
+        return np.asarray(self.state[self.table.key("w")])[: self.cb.num_keys, 0]
 
-        Two data-residency modes (cfg.solver.block_chunk):
-          0 (default) — resident: the packed (n_blocks, D, E) entry arrays
-            are device_put ONCE; the per-iteration block shuffle is just a
-            permutation array the on-device scan gathers through.
-          C > 0 — streaming: each pass packs+uploads C blocks at a time
-            straight from the (possibly mmap'd) block cache, so device and
-            host memory hold one chunk, not the dataset (ref: SlotReader's
-            stream-per-block design, SURVEY §3.3). Chunk widths pad to
-            powers of two to bound recompilation. With delay > 0 the stale
-            snapshot refreshes at chunk boundaries (a conservative
-            deviation: pick C a multiple of delay+1 to keep parity).
-        """
-        cfg = self.cfg
-        mesh = self.mesh
-        D = mesh.shape["data"]
-        chunk = cfg.solver.block_chunk
-        ex = shard_examples_for_mesh(cb, D)
-        per = ex["per_shard_examples"]
-        fns = make_darlin_spmd_fns(
-            mesh,
-            num_keys=cb.num_keys,
-            block_size=cb.block_size,
-            per_shard_examples=per,
-            lambda_l1=cfg.penalty.lambda_l1,
-            lambda_l2=cfg.penalty.lambda_l2,
-            learning_rate=cfg.lr.eta,
-            delay=cfg.solver.max_delay if cfg.solver.max_delay > 0 else 0,
+    def predictions(self) -> np.ndarray:
+        """(N,) Xw of the real examples."""
+        return np.asarray(self.pred)[np.asarray(self.mask) > 0]
+
+    def save(self, ckpt_dir: str) -> None:
+        from parameter_server_tpu.utils.checkpoint import save_checkpoint
+
+        save_checkpoint(
+            ckpt_dir,
+            {k: np.asarray(v)[: self.cb.num_keys] for k, v in self.state.items()},
+            meta={
+                "algo": self.CKPT_ALGO, "num_keys": self.cb.num_keys,
+                "passes_done": self.passes_done, "history": list(self.history),
+                "converged": self.converged,
+            },
         )
-        w = fns.place("w", np.zeros(cb.num_keys, np.float32))
-        active = fns.place("active", np.ones(cb.num_keys, bool))
-        pred = fns.place("pred", np.zeros((D, per), np.float32))
-        labels = fns.place("labels", ex["labels"])
-        mask = fns.place("mask", ex["mask"])
-        rng = np.random.default_rng(cfg.seed)
 
-        resident_blocks = None
-        if chunk <= 0:
-            resident_blocks = fns.place_blocks(
-                shard_blocks_for_mesh(cb, D), with_idx=False
-            )
+    def _load(self, ckpt_dir: str) -> None:
+        """The table as the last finished pass left it; Xw by one sweep of
+        the blocks over it."""
+        from parameter_server_tpu.utils.checkpoint import load_checkpoint
 
-        def _chunks(order):
-            for lo in range(0, len(order), chunk):
-                yield fns.place_blocks(
-                    shard_blocks_for_mesh(
-                        cb, D, blocks=order[lo : lo + chunk], pad_pow2=True
-                    ),
-                    with_idx=True,
-                )
-
-        prev_obj = float(fns.obj(w, pred, labels, mask))
-        history = []
-        for it in range(cfg.solver.block_iters):
-            order = (
-                rng.permutation(cb.n_blocks)
-                if shuffle_blocks
-                else np.arange(cb.n_blocks)
-            )
-            if resident_blocks is not None:
-                w, pred, viol = fns.pass_resident(
-                    w, pred, active, resident_blocks,
-                    order.astype(np.int32), labels, mask,
-                )
-            else:
-                viol = jnp.float32(0.0)
-                for blk in _chunks(order):
-                    w, pred, v = fns.pass_chunk(
-                        w, pred, active, blk, labels, mask
-                    )
-                    viol = jnp.maximum(viol, v)
-            if cfg.solver.kkt_filter_threshold > 0:
-                thr = cfg.solver.kkt_filter_threshold * max(float(viol), 1e-12)
-                if resident_blocks is not None:
-                    active = fns.kkt_resident(
-                        w, pred, active, resident_blocks,
-                        order.astype(np.int32), labels, mask, jnp.float32(thr),
-                    )
-                else:
-                    for blk in _chunks(order):
-                        active = fns.kkt_chunk(
-                            w, pred, active, blk, labels, mask, jnp.float32(thr)
-                        )
-            obj = float(fns.obj(w, pred, labels, mask))
-            rel = (prev_obj - obj) / max(abs(prev_obj), 1e-12)
-            nnz = int((np.asarray(w) != 0).sum())
-            self.reporter.report(
-                examples=cb.num_examples, objv=obj / cb.num_examples,
-                nnz_w=nnz, auc=float("nan"),
-            )
-            history.append(obj)
-            if 0 <= rel < cfg.solver.epsilon and it > 0:
-                break
-            prev_obj = obj
-
-        self.w = np.asarray(w)
-        real = np.asarray(mask).ravel() > 0
-        self.pred = np.asarray(pred).ravel()[real]
-        probs = 1.0 / (1.0 + np.exp(-self.pred))
-        return {
-            "objv": history[-1] / cb.num_examples,
-            "iters": len(history),
-            "nnz_w": int((self.w != 0).sum()),
-            "train_auc": M.auc(cb.labels, probs),
-            "history": history,
-        }
-
-    def _fit_blocks_single(self, cb: ColumnBlocks, shuffle_blocks: bool = True) -> dict:
-        """Run the solver on prebuilt (possibly disk-cached) column blocks."""
-        cfg = self.cfg
-        K, N = cb.num_keys, cb.num_examples
-        w = jnp.zeros(K, dtype=jnp.float32)
-        pred = jnp.zeros(N, dtype=jnp.float32)
-        active = jnp.ones(K, dtype=bool)
-        labels = jnp.asarray(cb.labels)
-        rng = np.random.default_rng(cfg.seed)
-
-        prev_obj = float(_objective(w, pred, labels, cfg.penalty.lambda_l1, cfg.penalty.lambda_l2))
-        history = []
-        for it in range(cfg.solver.block_iters):
-            order = (
-                rng.permutation(cb.n_blocks)
-                if shuffle_blocks
-                else np.arange(cb.n_blocks)
-            )  # ref: randomized block order per iteration
-            blocks = {
-                "feat_local": jnp.asarray(cb.feat_local[order]),
-                "rows": jnp.asarray(cb.rows[order]),
-                "values": jnp.asarray(cb.values[order]),
-                "block_idx": jnp.asarray(order.astype(np.int32)),
-            }
-            w, pred, active, viol = darlin_pass(
-                w,
-                pred,
-                active,
-                blocks,
-                labels,
-                cfg.penalty.lambda_l1,
-                cfg.penalty.lambda_l2,
-                cfg.lr.eta,
-                cfg.solver.kkt_filter_threshold,
-                block_size=cb.block_size,
-                num_examples=N,
-                delay=cfg.solver.max_delay if cfg.solver.max_delay > 0 else 0,
-            )
-            if cfg.solver.kkt_filter_threshold > 0:
-                # refresh the active set from the violation scale (ref: the
-                # KKT filter's adaptive threshold)
-                active = self._kkt_active(
-                    w, pred, labels, cb, float(viol)
-                )
-            obj = float(
-                _objective(w, pred, labels, cfg.penalty.lambda_l1, cfg.penalty.lambda_l2)
-            )
-            rel = (prev_obj - obj) / max(abs(prev_obj), 1e-12)
-            nnz = int((np.asarray(w) != 0).sum())
-            rec = self.reporter.report(
-                examples=N, objv=obj / N, nnz_w=nnz, auc=float("nan")
-            )
-            history.append(obj)
-            if 0 <= rel < cfg.solver.epsilon and it > 0:
-                break
-            prev_obj = obj
-
-        self.w = np.asarray(w)
-        self.pred = np.asarray(pred)
-        probs = 1.0 / (1.0 + np.exp(-self.pred))
-        return {
-            "objv": history[-1] / N,
-            "iters": len(history),
-            "nnz_w": int((self.w != 0).sum()),
-            "train_auc": M.auc(cb.labels, probs),
-            "history": history,
-        }
-
-    def _kkt_active(self, w, pred, labels, cb: ColumnBlocks, viol_max: float):
-        """Recompute the active bitmap: keep coords with weight, or with
-        gradient violation above threshold * max violation."""
-        thr = self.cfg.solver.kkt_filter_threshold * max(viol_max, 1e-12)
-        p = jax.nn.sigmoid(pred)
-        err = p - labels
-        g = np.zeros(cb.num_keys, dtype=np.float32)
-        for i in range(cb.n_blocks):
-            gi = jax.ops.segment_sum(
-                jnp.asarray(cb.values[i])
-                * jnp.take(err, jnp.asarray(cb.rows[i])),
-                jnp.asarray(cb.feat_local[i]),
-                num_segments=cb.block_size,
-            )
-            g[i * cb.block_size : (i + 1) * cb.block_size] = np.asarray(gi)
-        w_np = np.asarray(w)
-        viol = np.asarray(
-            _kkt_viol(jnp.asarray(w_np), jnp.asarray(g), self.cfg.penalty.lambda_l1)
+        if not os.path.exists(os.path.join(ckpt_dir, "manifest.json")):
+            raise FileNotFoundError(f"no checkpoint to resume from in {ckpt_dir!r}")
+        host, meta = load_checkpoint(ckpt_dir)
+        if meta.get("algo") != self.CKPT_ALGO or meta.get("num_keys") != self.cb.num_keys:
+            raise ValueError(f"{ckpt_dir!r} holds no darlin table of {self.cb.num_keys} keys: {meta}")
+        self.state = spmd.shard_state(
+            {self.table.key(k): jnp.asarray(host[self.table.key(k)]) for k in self.table.slots()},
+            self.mesh,
         )
-        return jnp.asarray((w_np != 0.0) | (viol > thr))
+        self.passes_done = int(meta["passes_done"])
+        self.history = [float(x) for x in meta["history"]]
+        self.converged = bool(meta.get("converged", False))
+        self.prev_obj = self.history[-1] if self.history else None
+        for group in self._groups(np.arange(self.cb.n_blocks)):
+            self.pred = self.fns.xw_call(self.state, self.pred, *self._call_args(group))
 
-    def predict(self, batches: list[CSRBatch]) -> np.ndarray:
-        from parameter_server_tpu.models.linear import batch_to_device
-        from parameter_server_tpu.ops.sparse import csr_logits
+    def evaluate_files(self, files: list[str]) -> dict:
+        """Score held-out files on the solved table with the one evaluator:
+        ``PodTrainer.evaluate_files`` over the linear app on this table."""
+        import copy
 
-        out = []
-        w = jnp.asarray(self.w)[:, None]
-        for b in batches:
-            dev = batch_to_device(b)
-            w_u = jnp.take(w, dev["unique_keys"], axis=0)
-            logits = csr_logits(
-                w_u, dev["values"], dev["local_ids"], dev["row_ids"],
-                num_rows=dev["labels"].shape[0],
-            )
-            out.append(
-                np.asarray(jax.nn.sigmoid(logits))[: b.num_examples]
-            )
-        return np.concatenate(out)
+        from parameter_server_tpu.parallel.trainer import PodTrainer
+
+        cfg = copy.deepcopy(self.cfg)  # the trainer holds cfg.parallel to its mesh
+        cfg.parallel.data_shards = self.mesh.shape["data"]
+        cfg.parallel.kv_shards = self.mesh.shape["kv"]
+        trainer = PodTrainer(
+            cfg, mesh=self.mesh, reporter=self.reporter,
+            app=spmd.linear_app(self.updater),
+        )
+        trainer.state = self.state
+        return trainer.evaluate_files(files)

@@ -57,7 +57,15 @@ Batch = dict[str, jax.Array]
 # "ps.push/scatter/emb"), so a reader of "ps.pull" sums over tables; its
 # dense group's forward and backward lie under "ps.grad/<group>" and the
 # group's ``psum`` and optimizer step under "ps.dense".
-PHASE_SCOPES = ("ps.row_ids", "ps.pull", "ps.grad", "ps.push", "ps.dense")
+# The batch solver's call (``models.darlin``) pulls, takes its gradient and
+# pushes under the same names, by key RANGE (``pull_range`` / ``push_range``),
+# and has phases no online step has, beside them: the scatter of X_b d over
+# the examples, the line search's objective terms, and the KKT filter's
+# refresh of the active set (a sweep of its own, whole under its one name).
+PHASE_SCOPES = (
+    "ps.row_ids", "ps.pull", "ps.grad", "ps.push", "ps.dense",
+    "darlin.xd", "darlin.linesearch", "darlin.refresh",
+)
 _PUSH_STAGES = ("gather", "update", "scatter")
 
 
@@ -95,7 +103,7 @@ def forget_programs() -> None:
     _forgotten += 1
 
 
-def _note_program(jitted, seen: set, names: frozenset, *args) -> None:
+def note_program(jitted, seen: set, names: frozenset, *args) -> None:
     """Remember the shapes, dtypes and shardings a step or predict program
     is called with, once a distinct set: a dict lookup on the dispatch
     path, nothing compiled or read here."""
@@ -577,6 +585,62 @@ def _local_pull(
         return jnp.where(in_range[:, None], w, 0.0)
 
 
+def _range_owner(begin: jax.Array, shard_size: int, kv: int):
+    """(this shard owns the range that starts at global row ``begin``, the
+    range's first row in the owner's shard - 0 on the other shards, whose
+    slice is read and thrown away). A range never straddles two shards: the
+    callers cut the key space into ranges that divide ``shard_size``."""
+    if kv == 1:
+        return True, begin
+    owner = begin // shard_size
+    is_owner = owner == lax.axis_index("kv")
+    return is_owner, jnp.where(is_owner, begin - owner * shard_size, 0)
+
+
+def pull_range(
+    table: Table, state_l: State, begin: jax.Array, size: int,
+    shard_size: int, kv: int,
+) -> State:
+    """The pull of a CONTIGUOUS key range: rows ``[begin, begin + size)`` of
+    every slot of ``table``, {slot: (size, stride)}, on every device. What
+    the parameter server's key-range design exists for: on the chip a range
+    is a slice of the owner's shard (``dynamic_slice``), broadcast over
+    ``kv`` by a ``psum`` of the owner's slice and the others' zeros; on one
+    kv shard the slice alone, no gather and no collective. Call it inside
+    ``shard_map``, under the scope ``ps.pull``."""
+    is_owner, at = _range_owner(begin, shard_size, kv)
+    with _sub_scope(table.name):
+        out = {}
+        for k, v in table.of(state_l).items():
+            rows = lax.dynamic_slice(v, (at, 0), (size, v.shape[1]))
+            if kv > 1:
+                rows = lax.psum(jnp.where(is_owner, rows, jnp.zeros_like(rows)), "kv")
+            out[k] = rows
+        return out
+
+
+def push_range(
+    table: Table, state_l: State, begin: jax.Array, rows: State,
+    shard_size: int, kv: int,
+) -> State:
+    """The push of a contiguous key range: ``rows`` ({slot: (size, stride)},
+    the same on every device, as the server's updater left them) written
+    over ``[begin, begin + size)`` of the owner's shard in place
+    (``dynamic_update_slice``); the other shards write their own rows back.
+    Returns the state with ``table``'s slots replaced. Inside ``shard_map``,
+    under the scope ``ps.push``."""
+    is_owner, at = _range_owner(begin, shard_size, kv)
+    out = dict(state_l)
+    with _sub_scope(table.name):
+        for k, new in rows.items():
+            v = state_l[table.key(k)]
+            if kv > 1:
+                old = lax.dynamic_slice(v, (at, 0), new.shape)
+                new = jnp.where(is_owner, new, old)
+            out[table.key(k)] = lax.dynamic_update_slice(v, new.astype(v.dtype), (at, 0))
+    return out
+
+
 def _ascending_rows(idx: jax.Array, local: jax.Array) -> jax.Array:
     """The rows a push of ascending keys scatters to, non-decreasing on
     every kv shard: a key's row in this shard's frame (``local``, monotone
@@ -876,7 +940,7 @@ def _wrap_stepper(step, push_mode: str, names: frozenset = frozenset()):
                     "call step(state, batch, step_index)"
                 )
             push_seed = 0
-        _note_program(_jitted, seen, names, state, batch, push_seed)
+        note_program(_jitted, seen, names, state, batch, push_seed)
         return _jitted(state, batch, push_seed)
 
     return stepper
@@ -1135,7 +1199,7 @@ def make_spmd_predict_step(app: "StepApp | Updater", mesh: Mesh, num_keys: int):
     seen: set = set()
 
     def predict(state: State, batch: Batch) -> jax.Array:
-        _note_program(jitted, seen, app.scope_names(), state, batch)
+        note_program(jitted, seen, app.scope_names(), state, batch)
         return jitted(state, batch)
 
     return predict

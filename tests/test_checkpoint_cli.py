@@ -232,7 +232,7 @@ class TestCLI:
         out = json.loads(r.stdout.strip().splitlines()[-1])
         assert out["train_auc"] > 0.7
 
-    def test_cli_darlin_resume_rejected_and_val_eval(self, svm_files, tmp_path):
+    def test_cli_darlin_resumes_and_scores_val_files(self, svm_files, tmp_path):
         tr, te = svm_files
         cfg_path = tmp_path / "cfg.json"
         cfg_path.write_text(
@@ -250,17 +250,26 @@ class TestCLI:
                 }
             )
         )
-        r = self._run(
-            "train", "--app_file", str(cfg_path), "--resume", "--ckpt_dir", str(tmp_path / "x")
-        )
-        assert r.returncode != 0 and "not supported" in r.stderr
+        r = self._run("train", "--app_file", str(cfg_path), "--resume")
+        assert r.returncode != 0 and "--resume requires --ckpt_dir" in r.stderr
         r2 = self._run(
             "train", "--app_file", str(cfg_path), "--ckpt_dir", str(tmp_path / "ck")
         )
         assert r2.returncode == 0, r2.stderr[-2000:]
         out = json.loads(r2.stdout.strip().splitlines()[-1])
-        assert "val_auc" in out
-        assert (tmp_path / "ck" / "manifest.json").exists()
+        # held-out files go through PodTrainer.evaluate_files, as every app's
+        assert out["val_auc"] > 0.7 and "val_logloss" in out
+        manifest = json.loads((tmp_path / "ck" / "manifest.json").read_text())
+        assert manifest["meta"]["algo"] == "darlin" and manifest["meta"]["passes_done"] == 4
+        assert set(manifest["arrays"]) == {"w", "active"}  # the table's slots, as the store saves them
+        # a restart from the last finished pass has none left to run
+        r3 = self._run(
+            "train", "--app_file", str(cfg_path), "--resume", "--ckpt_dir", str(tmp_path / "ck")
+        )
+        assert r3.returncode == 0, r3.stderr[-2000:]
+        out3 = json.loads(r3.stdout.strip().splitlines()[-1])
+        assert out3["iters"] == 4 and out3["objv"] == pytest.approx(out["objv"], rel=1e-6)
+        assert out3["val_auc"] == pytest.approx(out["val_auc"], abs=1e-4)
 
     def test_cli_missing_files_errors(self, tmp_path):
         cfg_path = tmp_path / "cfg.json"

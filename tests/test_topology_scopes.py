@@ -703,3 +703,93 @@ def test_sgns_large_unscoped_instructions_are_the_known_kinds(sgns_text, data, k
         if opcode == "fusion" and not scopes[name]:
             # bookkeeping at batch size (a (U, 384) buffer at most), never a table op
             assert elements(shape) <= 384 * SGNS_SLOTS and all(elements(s) < SGNS_VOCAB for s in operand_shapes), name
+
+
+# -- the batch solver: a block call at the cell darlin1.pass's shapes ----------
+DARLIN_KEYS, DARLIN_BLOCKS, DARLIN_N = 1 << 26, 64, 11_460_154
+DARLIN_CHUNK, DARLIN_CHUNKS, DARLIN_CALL = 1 << 16, 6_880, 4  # 447M entries in chunks of 2^16
+DARLIN_SCOPES = {"ps.pull", "ps.grad", "ps.push", "darlin.xd", "darlin.linesearch"}  # a step's
+
+
+@pytest.fixture(scope="module")
+def darlin_compiled(topo):
+    """program -> (optimised HLO text, memory analysis) of the solver's
+    block call and KKT refresh on one chip at the cell's shapes."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from parameter_server_tpu.kv.updaters import ProxNewton
+    from parameter_server_tpu.models.darlin import make_darlin_fns
+    from parameter_server_tpu.parallel import spmd
+
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "kv"))
+    fns = make_darlin_fns(
+        mesh, spmd.Table("", ProxNewton(), 1), num_keys=DARLIN_KEYS,
+        block_size=DARLIN_KEYS // DARLIN_BLOCKS, per_shard_examples=DARLIN_N, delay=0,
+    )
+
+    def arg(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, spec))
+
+    state = {k: arg((DARLIN_KEYS, 1), jnp.float32, spmd.state_spec()) for k in ("w", "active")}
+    ex = arg((DARLIN_N,), jnp.float32, P("data"))
+    chunks = {
+        k: arg((DARLIN_CHUNKS, DARLIN_CHUNK), jnp.float32 if k == "values" else jnp.int32, P("data", None))
+        for k in ("feat_local", "rows", "values")
+    }
+    call = (
+        chunks, arg((1, DARLIN_CALL, 2), jnp.int32, P("data", None, None)),
+        arg((DARLIN_CALL,), jnp.int32, P(None)), arg((DARLIN_CALL,), jnp.bool_, P(None)),
+    )
+    out = {}
+    for name, args in (
+        ("block_call", (state, ex, ex, ex, *call)),
+        ("refresh_call", (state, ex, ex, ex, *call, arg((), jnp.float32, P()))),
+    ):
+        compiled = getattr(fns, name).jitted.lower(*args).compile()
+        out[name] = (compiled.as_text(), compiled.memory_analysis())
+    return out
+
+
+def test_darlin_block_call_carries_the_five_scopes_at_the_cells_shapes(darlin_compiled):
+    """Every instruction of the block call that reads the resident entries,
+    the table or a vector over the examples sits under one of the five
+    scopes the ``step.*`` readers find device time by; the range pull and
+    push are slices in place, no gather, scatter or copy of the table. The
+    refresh's program lies whole under its one name, so that a window that
+    holds both leaves the five a step's."""
+    from parameter_server_tpu.parallel import spmd
+
+    _, refresh_scopes = spmd.hlo_scopes(darlin_compiled["refresh_call"][0])
+    assert {s for s in refresh_scopes.values() if s} == {"darlin.refresh"}
+    text, _ = darlin_compiled["block_call"]
+    _, scopes = spmd.hlo_scopes(text)
+    found = {s.split("/")[0] for s in scopes.values() if s}
+    assert found == DARLIN_SCOPES, found
+    big = re.compile(rf"\[({DARLIN_CHUNKS},{DARLIN_CHUNK}|{DARLIN_KEYS}(,1)?|{DARLIN_N}|8,{DARLIN_N})\]")
+    strays = [
+        (name, opcode, shape)
+        for name, shape, opcode, operand_shapes in executed(text)
+        if not scopes[name] and opcode not in ("copy-start", "copy-done", "custom-call")
+        and (big.search(shape) or any(big.search(s) for s in operand_shapes))
+        and opcode != "dynamic-slice"  # the loop's own slicing of a chunk out of the set
+    ]
+    assert not strays, strays
+    every = instructions(text)
+    assert not copies_of(every, DARLIN_KEYS), "the table is copied"
+    table = re.compile(rf"\[{DARLIN_KEYS}(,1)?\]")
+    for _, name, shape, opcode, operand_shapes, _ in every:
+        if opcode in ("gather", "scatter"):
+            assert not any(table.search(s) for s in operand_shapes), (name, "a range is a slice, not a gather")
+
+
+def test_darlin_programs_hold_the_entries_once(darlin_compiled):
+    """Peak at the cell's shapes, by the compiler's own count: the resident
+    entries (5.0 GiB), the table and the vectors over the examples, and
+    under 1 GiB of temporaries - no second copy of the chunk arrays (a
+    leading axis of one on them cost one, 5.3 GiB, in this PR's first
+    form)."""
+    for name, (_, mem) in darlin_compiled.items():
+        assert mem.temp_size_in_bytes < 1 << 30, (name, mem.temp_size_in_bytes)
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8 << 30, name
