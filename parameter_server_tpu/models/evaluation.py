@@ -48,7 +48,7 @@ def evaluate_model(
             jnp.asarray(b.values),
             jnp.asarray(b.local_ids),
             jnp.asarray(b.row_ids),
-            num_rows=len(b.labels),
+            jnp.asarray(b.row_splits),
         )
         ps.append(np.asarray(jax.nn.sigmoid(logits))[: b.num_examples])
         ys.append(b.labels[: b.num_examples])

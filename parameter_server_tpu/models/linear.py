@@ -52,18 +52,18 @@ def train_step(
     updater: Updater, state: State, batch: dict[str, jax.Array]
 ) -> tuple[State, dict[str, jax.Array]]:
     """One fused pull -> grad -> push step. ``batch`` holds device arrays of
-    a CSRBatch (unique_keys/local_ids/row_ids/values/labels/example_mask)."""
+    a CSRBatch (``batch_to_device``)."""
     idx = batch["unique_keys"]
     rows = {k: jnp.take(v, idx, axis=0) for k, v in state.items()}
     w_u = updater.weights(rows)  # pull
     logits = csr_logits(
         w_u, batch["values"], batch["local_ids"], batch["row_ids"],
-        num_rows=batch["labels"].shape[0],
+        batch["row_splits"],
     )
     loss, err = logistic_loss(logits, batch["labels"], batch["example_mask"])
     g = csr_grad(
         err, batch["values"], batch["local_ids"], batch["row_ids"],
-        num_unique=idx.shape[0],
+        batch["row_splits"], num_unique=idx.shape[0],
     )
     deltas = updater.delta(rows, g)  # push: server-side updater ...
     new_state = {k: state[k].at[idx].add(deltas[k]) for k in state}  # ... scatter-add
@@ -84,7 +84,7 @@ def predict_step(
     w_u = updater.weights(rows)
     logits = csr_logits(
         w_u, batch["values"], batch["local_ids"], batch["row_ids"],
-        num_rows=batch["labels"].shape[0],
+        batch["row_splits"],
     )
     return jax.nn.sigmoid(logits)
 
@@ -94,6 +94,7 @@ def batch_to_device(b: CSRBatch) -> dict[str, jax.Array]:
         "unique_keys": jnp.asarray(b.unique_keys),
         "local_ids": jnp.asarray(b.local_ids),
         "row_ids": jnp.asarray(b.row_ids),
+        "row_splits": jnp.asarray(b.row_splits),
         "values": jnp.asarray(b.values),
         "labels": jnp.asarray(b.labels),
         "example_mask": jnp.asarray(b.example_mask),

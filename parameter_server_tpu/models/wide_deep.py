@@ -32,7 +32,7 @@ from parameter_server_tpu.data.batch import CSRBatch
 from parameter_server_tpu.kv.store import State, hashed_uniform
 from parameter_server_tpu.kv.updaters import Adagrad, Ftrl, Updater
 from parameter_server_tpu.models.metrics import BINARY_SCORES
-from parameter_server_tpu.ops.sparse import csr_logits
+from parameter_server_tpu.ops.sparse import csr_logits, sum_by_example
 from parameter_server_tpu.parallel.spmd import (
     DenseGroup,
     StepApp,
@@ -74,18 +74,13 @@ def _mlp_apply(params, x):
 def _logits(pulled, mlp_params, b, row_ids):
     """Wide logits over the pulled ``wide`` rows + the tower over the
     examples' mean-pooled ``emb`` rows -> (B,)."""
-    num_rows = b["labels"].shape[0]
-    values = _values_of(b)
-    wide = csr_logits(
-        pulled["wide"], values, b["local_ids"], row_ids, num_rows=num_rows
-    )
+    values, row_splits = _values_of(b), b["row_splits"]
+    wide = csr_logits(pulled["wide"], values, b["local_ids"], row_ids, row_splits)
     # mean-pool the batch's unique-key embeddings per example
     ent_emb = jnp.take(pulled["emb"], b["local_ids"], axis=0)  # (NNZ, d)
     ones = (values != 0).astype(jnp.float32)
-    num = jax.ops.segment_sum(
-        ent_emb * ones[:, None], row_ids, num_segments=num_rows
-    )
-    cnt = jax.ops.segment_sum(ones, row_ids, num_segments=num_rows)
+    num = sum_by_example(ent_emb * ones[:, None], row_ids, row_splits)
+    cnt = sum_by_example(ones, row_ids, row_splits)
     pooled = num / jnp.maximum(cnt, 1.0)[:, None]
     with _sub_scope("mlp"):
         deep = _mlp_apply(mlp_params, pooled)

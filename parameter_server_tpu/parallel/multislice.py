@@ -2333,12 +2333,12 @@ def run_worker(
     builder = training_builder(cfg)
 
     @jax.jit
-    def grad_step(w_u, values, local_ids, row_ids, labels, mask):
-        logits = csr_logits(
-            w_u, values, local_ids, row_ids, num_rows=labels.shape[0]
-        )
+    def grad_step(w_u, values, local_ids, row_ids, row_splits, labels, mask):
+        logits = csr_logits(w_u, values, local_ids, row_ids, row_splits)
         loss, err = logistic_loss(logits, labels, mask)
-        g = csr_grad(err, values, local_ids, row_ids, num_unique=w_u.shape[0])
+        g = csr_grad(
+            err, values, local_ids, row_ids, row_splits, num_unique=w_u.shape[0]
+        )
         return loss, jax.nn.sigmoid(logits), g
 
     from parameter_server_tpu.parallel.ssp import PushWindow
@@ -2434,8 +2434,8 @@ def run_worker(
                     w_u = np.zeros(len(b.unique_keys), dtype=np.float32)
                     w_u[1 : b.num_unique] = pulled.ravel()
                     loss, probs, g = grad_step(
-                        w_u, b.values, b.local_ids, b.row_ids, b.labels,
-                        b.example_mask,
+                        w_u, b.values, b.local_ids, b.row_ids, b.row_splits,
+                        b.labels, b.example_mask,
                     )
                     g_real = np.asarray(g).ravel()[1 : b.num_unique]
                 # pushes stay in flight past this span's exit; the flow

@@ -353,8 +353,7 @@ class StepApp:
 
 def _linear_logits(pulled, dense, b: Batch, row_ids: jax.Array) -> jax.Array:
     return csr_logits(
-        pulled[""], _values_of(b), b["local_ids"], row_ids,
-        num_rows=b["labels"].shape[0],
+        pulled[""], _values_of(b), b["local_ids"], row_ids, b["row_splits"]
     )
 
 
@@ -362,7 +361,7 @@ def _linear_grad(pulled, dense, b: Batch, row_ids: jax.Array):
     logits = _linear_logits(pulled, dense, b, row_ids)
     loss, err = logistic_loss(logits, b["labels"], b["example_mask"])
     g = csr_grad(
-        err, _values_of(b), b["local_ids"], row_ids,
+        err, _values_of(b), b["local_ids"], row_ids, b["row_splits"],
         num_unique=b["unique_keys"].shape[0],
     )
     return loss, logits, {"": g}, None

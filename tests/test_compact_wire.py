@@ -153,12 +153,12 @@ def _host_step(up, state, group, push_mode):
         w_u = kv_pull(up, state, dev["unique_keys"])
         logits = csr_logits(
             w_u, dev["values"], dev["local_ids"], dev["row_ids"],
-            num_rows=dev["labels"].shape[0],
+            dev["row_splits"],
         )
         loss, err = logistic_loss(logits, dev["labels"], dev["example_mask"])
         g = csr_grad(
             err, dev["values"], dev["local_ids"], dev["row_ids"],
-            num_unique=dev["unique_keys"].shape[0],
+            dev["row_splits"], num_unique=dev["unique_keys"].shape[0],
         )
         pushes.append((dev["unique_keys"], g))
         loss_sum += float(loss)

@@ -210,13 +210,14 @@ class TestShardServer:
             )
             logits = csr_logits(
                 jax.numpy.asarray(w_u), b.values, b.local_ids, b.row_ids,
-                num_rows=len(b.labels),
+                b.row_splits,
             )
             _, err = logistic_loss(
                 logits, jax.numpy.asarray(b.labels), jax.numpy.asarray(b.example_mask)
             )
             g = csr_grad(
-                err, b.values, b.local_ids, b.row_ids, num_unique=len(b.unique_keys)
+                err, b.values, b.local_ids, b.row_ids, b.row_splits,
+                num_unique=len(b.unique_keys),
             )
             g_real = np.asarray(g).ravel()[1 : b.num_unique]
             for s, h in enumerate(handles):

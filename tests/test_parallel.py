@@ -65,12 +65,12 @@ def test_spmd_matches_single_device(mesh_shape):
         w_u = kv_pull(up, state_ref, dev["unique_keys"])
         logits = csr_logits(
             w_u, dev["values"], dev["local_ids"], dev["row_ids"],
-            num_rows=dev["labels"].shape[0],
+            dev["row_splits"],
         )
         _, err = logistic_loss(logits, dev["labels"], dev["example_mask"])
         g = csr_grad(
             err, dev["values"], dev["local_ids"], dev["row_ids"],
-            num_unique=dev["unique_keys"].shape[0],
+            dev["row_splits"], num_unique=dev["unique_keys"].shape[0],
         )
         pushes.append((dev["unique_keys"], g))
     for idx, g in pushes:
@@ -235,12 +235,12 @@ def test_num_keys_padded_to_kv_axis():
     w_u = kv_pull(up, state_ref, dev["unique_keys"])
     logits = csr_logits(
         w_u, dev["values"], dev["local_ids"], dev["row_ids"],
-        num_rows=dev["labels"].shape[0],
+        dev["row_splits"],
     )
     _, err = logistic_loss(logits, dev["labels"], dev["example_mask"])
     g = csr_grad(
         err, dev["values"], dev["local_ids"], dev["row_ids"],
-        num_unique=dev["unique_keys"].shape[0],
+        dev["row_splits"], num_unique=dev["unique_keys"].shape[0],
     )
     state_ref = kv_push(up, state_ref, dev["unique_keys"], g)
 
