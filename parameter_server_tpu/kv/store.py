@@ -79,16 +79,32 @@ def hashed_unit(seed: int, rows: jax.Array, vdim: int) -> jax.Array:
     return (x >> 8).astype(jnp.float32) * jnp.float32(2.0**-23) - jnp.float32(1.0)
 
 
+def live_lanes(live: jax.Array, vdim: int, lanes: int | None = None) -> jax.Array:
+    """The mask of a slot's elements that hold a value: rows ``live`` (N,),
+    lanes below ``vdim`` of the ``lanes`` the slot is stored at
+    (``spmd.row_stride``; ``vdim`` unsaid). (N, 1) where the slot is as wide
+    as its rows, (N, lanes) where it is stored wider: a slot made ``lanes``
+    wide under this mask in one elementwise pass equals the narrow one to
+    the bit in its first ``vdim`` lanes (``hashed_unit`` is a function of
+    the lane) and is zero past them, and no pad makes a second table."""
+    keep = live[:, None]
+    if lanes is None or lanes == vdim:
+        return keep
+    return keep & (jnp.arange(lanes) < vdim)[None, :]
+
+
 def hashed_uniform(
-    seed: int, rows: jax.Array, vdim: int, scale: float, live_rows: int
+    seed: int, rows: jax.Array, vdim: int, scale: float, live_rows: int,
+    lanes: int | None = None,
 ) -> jax.Array:
     """Starting values of an embedding table: ``hashed_unit`` scaled to a
     uniform of standard deviation ``scale`` (one product, which IEEE rounds
     one way), exactly zero for the pad row 0 and for rows at or past
-    ``live_rows`` (the kv-axis pad tail)."""
-    unit = hashed_unit(seed, rows, vdim)
-    live = (rows > 0) & (rows < live_rows)
-    return jnp.where(live[:, None], unit * jnp.float32(scale * 3.0**0.5), 0.0)
+    ``live_rows`` (the kv-axis pad tail). ``lanes`` (``vdim`` unsaid) is
+    the width made, zero past ``vdim`` (``live_lanes``)."""
+    keep = live_lanes((rows > 0) & (rows < live_rows), vdim, lanes)
+    unit = hashed_unit(seed, rows, lanes or vdim)
+    return jnp.where(keep, unit * jnp.float32(scale * 3.0**0.5), 0.0)
 
 
 @functools.partial(jax.jit, static_argnums=0)

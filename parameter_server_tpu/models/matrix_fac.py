@@ -3,7 +3,8 @@
 Reference analog: the reference's matrix-factorization app (rank-r factors
 on a bipartite rating graph; workers hold rating blocks and Push/Pull the
 row/column factor vectors they touch — named in BASELINE.json's north star
-alongside linear_method; parity config 3: rank-64 SGD, async push/pull).
+alongside linear_method; parity config 3: SGD at a configured rank, 64
+there and 100 in NOMAD's Hugewiki runs, async push/pull).
 
 TPU re-expression: ONE table of ``vdim = rank`` over one key space, as the
 reference's KV layer has one: items take keys 1..num_items, users the keys
@@ -24,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from parameter_server_tpu.data.libsvm import RATING
-from parameter_server_tpu.kv.store import State, hashed_unit
+from parameter_server_tpu.kv.store import hashed_unit, live_lanes
 from parameter_server_tpu.kv.updaters import Adagrad, Sgd, Updater
 from parameter_server_tpu.models.metrics import REGRESSION_SCORES
 from parameter_server_tpu.parallel.spmd import StepApp, Table
@@ -77,28 +78,30 @@ def _grad(l2: float):
     return grad
 
 
-def init_factors(seed: int, rows: jax.Array, rank: int, live_rows: int) -> jax.Array:
+def init_factors(
+    seed: int, rows: jax.Array, rank: int, live_rows: int, lanes: int | None = None
+) -> jax.Array:
     """Starting factors of table rows ``rows``: uniform in [0, 1/sqrt(rank))
     as a hash of (seed, row, lane) (``kv.store.hashed_unit`` moved to [0, 2),
     which is exact, times half the width: one rounding), so that a product
     of two fresh rows is about 1/4 and has a gradient; the pad row and the
-    rows at or past ``live_rows`` are zero."""
-    live = (rows > 0) & (rows < live_rows)
-    unit = hashed_unit(seed, rows, rank) + jnp.float32(1.0)
-    return jnp.where(live[:, None], unit * jnp.float32(0.5 / rank**0.5), 0.0)
+    rows at or past ``live_rows`` are zero. ``lanes`` (the slot's stride,
+    ``rank`` unsaid) is the width made, zero past ``rank``
+    (``kv.store.live_lanes``)."""
+    keep = live_lanes((rows > 0) & (rows < live_rows), rank, lanes)
+    unit = hashed_unit(seed, rows, lanes or rank) + jnp.float32(1.0)
+    return jnp.where(keep, unit * jnp.float32(0.5 / rank**0.5), 0.0)
 
 
 def mf_app(updater: Updater, rank: int, l2: float, init=None) -> StepApp:
     """The app's description for the shared parameter-server step: table
     ``mf`` (``vdim`` ``rank``) under ``updater``, squared error over
     real-valued labels, the identity as link, RMSE as the evaluator's
-    score. ``init(rows)`` makes the table's starting ``w`` (zeros without
-    it: a product of zeros has no gradient)."""
-    def init_slots(rows: int) -> State:
-        return {**updater.init(rows, rank), "w": init(rows)}
-
+    score. ``init(rows, lanes)`` makes the table's starting ``{"w": ...}``
+    as the store keeps it, ``lanes`` wide (zeros without it: a product of
+    zeros has no gradient)."""
     return StepApp(
-        tables=(Table(TABLE, updater, rank, init_slots if init else None),),
+        tables=(Table(TABLE, updater, rank, init),),
         grad=_grad(l2),
         logits=_logits,
         link=lambda x: x,
@@ -126,9 +129,9 @@ def app_from_config(cfg) -> StepApp:
         )
     return mf_app(
         make[m.algo](eta=m.eta), m.rank, m.l2,
-        init=lambda rows: init_factors(
-            cfg.seed, jnp.arange(rows, dtype=jnp.int32), m.rank, want
-        ),
+        init=lambda rows, lanes: {"w": init_factors(
+            cfg.seed, jnp.arange(rows, dtype=jnp.int32), m.rank, want, lanes
+        )},
     )
 
 

@@ -119,15 +119,13 @@ def wide_deep_app(
     (``parallel.spmd``): table ``wide`` (``vdim`` 1), table ``emb``
     (``vdim`` ``emb_dim``), both over the batch's one hashed key space, and
     the tower ``mlp`` as the replicated dense group under ``opt``.
-    ``mlp_init()`` makes the tower's parameters; ``emb_init(rows)`` the
-    embedding's starting ``w`` (zeros without it)."""
-    def init_emb(rows: int) -> State:
-        return {**emb_up.init(rows, emb_dim), "w": emb_init(rows)}
-
+    ``mlp_init()`` makes the tower's parameters; ``emb_init(rows,
+    lanes)`` the embedding's starting ``{"w": ...}`` as the store keeps it,
+    ``lanes`` wide (zeros without it)."""
     return StepApp(
         tables=(
             Table("wide", wide_up, 1),
-            Table("emb", emb_up, emb_dim, init_emb if emb_init else None),
+            Table("emb", emb_up, emb_dim, emb_init),
         ),
         grad=_grad,
         logits=_logits,
@@ -152,9 +150,9 @@ def app_from_config(cfg) -> StepApp:
         optax.adam(cfg.wd.mlp_lr),
         dim,
         mlp_init=lambda: init_mlp(dim, list(cfg.wd.hidden), seed=seed),
-        emb_init=lambda rows: hashed_uniform(
-            seed, jnp.arange(rows, dtype=jnp.int32), dim, EMB_INIT_SCALE, num_keys
-        ),
+        emb_init=lambda rows, lanes: {"w": hashed_uniform(
+            seed, jnp.arange(rows, dtype=jnp.int32), dim, EMB_INIT_SCALE, num_keys, lanes
+        )},
     )
 
 

@@ -40,10 +40,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from parameter_server_tpu.data.libsvm import SGNS
-from parameter_server_tpu.kv.store import State, hashed_unit
+from parameter_server_tpu.kv.store import hashed_unit, live_lanes
 from parameter_server_tpu.kv.updaters import Sgd, Updater
 from parameter_server_tpu.models.metrics import SGNS_SCORES
-from parameter_server_tpu.parallel.spmd import StepApp, Table, row_stride
+from parameter_server_tpu.parallel.spmd import StepApp, Table
 
 TABLE = "sgns"  # the table's name: state entry "sgns.w", scopes "ps.pull/sgns"
 
@@ -112,12 +112,10 @@ def init_vectors(
     1..V) uniform in [-0.5/dim, 0.5/dim) as a hash of (seed, row, lane)
     (``kv.store.hashed_unit`` times half the width: one rounding); the pad
     row, the output vectors and the rows past them zero. ``lanes`` (the
-    slot's stride, ``dim`` unsaid) is the width made, zero past ``dim``: one
-    elementwise pass, so that no second table is made to pad the first."""
-    lanes = dim if lanes is None else lanes
-    live = (rows > 0) & (rows <= vocab_size)
-    keep = live[:, None] & (jnp.arange(lanes) < dim)[None, :]
-    return jnp.where(keep, hashed_unit(seed, rows, lanes) * jnp.float32(0.5 / dim), 0.0)
+    slot's stride, ``dim`` unsaid) is the width made, zero past ``dim``
+    (``kv.store.live_lanes``)."""
+    keep = live_lanes((rows > 0) & (rows <= vocab_size), dim, lanes)
+    return jnp.where(keep, hashed_unit(seed, rows, lanes or dim) * jnp.float32(0.5 / dim), 0.0)
 
 
 def sgns_app(updater: Updater, dim: int, negatives: int, init=None) -> StepApp:
@@ -126,14 +124,10 @@ def sgns_app(updater: Updater, dim: int, negatives: int, init=None) -> StepApp:
     loss over examples of 2 + ``negatives`` entries, an example's
     log-likelihood as its prediction (the identity as link), the mean loss
     as the evaluator's score. ``init(rows, lanes)`` makes the table's
-    starting ``w`` as the store keeps it, ``lanes`` wide (zeros without
-    it: no gradient ever)."""
-    def init_slots(rows: int) -> State:
-        lanes = row_stride(dim)
-        return {**updater.init(rows, lanes), "w": init(rows, lanes)}
-
+    starting ``{"w": ...}`` as the store keeps it, ``lanes`` wide (zeros
+    without it: no gradient ever)."""
     return StepApp(
-        tables=(Table(TABLE, updater, dim, init_slots if init else None),),
+        tables=(Table(TABLE, updater, dim, init),),
         grad=_grad(negatives),
         logits=_logits(negatives),
         link=lambda x: x,
@@ -159,9 +153,9 @@ def app_from_config(cfg) -> StepApp:
         )
     return sgns_app(
         Sgd(eta=w.eta), w.dim, w.negatives,
-        init=lambda rows, lanes: init_vectors(
+        init=lambda rows, lanes: {"w": init_vectors(
             cfg.seed, jnp.arange(rows, dtype=jnp.int32), w.dim, w.vocab_size, lanes
-        ),
+        )},
     )
 
 

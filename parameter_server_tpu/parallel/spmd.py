@@ -57,6 +57,13 @@ Batch = dict[str, jax.Array]
 # "ps.push/scatter/emb"), so a reader of "ps.pull" sums over tables; its
 # dense group's forward and backward lie under "ps.grad/<group>" and the
 # group's ``psum`` and optimizer step under "ps.dense".
+# The step's two collectives have scopes of their own, innermost, beneath
+# the table's name: the pull's ``psum`` over "kv" under "ps.pull/<table>/psum"
+# and the push's ``all_gather`` over "data" under
+# "ps.push/<table>/all_gather" ("ps.pull/psum", "ps.push/all_gather" for an
+# unnamed table), so a reader of "ps.pull" or "ps.push" still holds them and
+# a reader of a table's ops ("ps.pull/<table>", "ps.push/<stage>/<table>")
+# does not.
 # The batch solver's call (``models.darlin``) pulls, takes its gradient and
 # pushes under the same names, by key RANGE (``pull_range`` / ``push_range``),
 # and has phases no online step has, beside them: the scatter of X_b d over
@@ -67,6 +74,7 @@ PHASE_SCOPES = (
     "darlin.xd", "darlin.linesearch", "darlin.refresh",
 )
 _PUSH_STAGES = ("gather", "update", "scatter")
+_COLLECTIVES = ("psum", "all_gather")
 
 
 def _sub_scope(name: str):
@@ -131,7 +139,8 @@ def hlo_scopes(hlo_text: str, names: frozenset = frozenset()) -> tuple[str, dict
     instruction's ``op_name`` metadata (a fusion carries its root's), with
     what the program nested under it after a slash: the push's stages
     (``ps.push/scatter``) and, of the app that ran it, the ``names`` of its
-    tables and dense group (``ps.pull/emb``; ``StepApp.scope_names``);
+    tables and dense group (``ps.pull/emb``; ``StepApp.scope_names``) and
+    the two collectives (``ps.pull/emb/psum``);
     ``""`` for an instruction that carries none (input copies, some
     custom calls)."""
     module = re.search(r"^HloModule\s+([^\s,]+)", hlo_text, re.M)
@@ -156,7 +165,7 @@ def _scope_of(op_name: str, names: frozenset = frozenset()) -> str:
             for sub in parts[i + 1 : -1]:
                 while (m := _TRANSFORMED.match(sub)) is not None:
                     sub = m.group(1)  # a scope inside jax.grad
-                if sub in _PUSH_STAGES or sub in names:
+                if sub in _PUSH_STAGES or sub in _COLLECTIVES or sub in names:
                     path.append(sub)
             return "/".join(path)
     return ""
@@ -193,18 +202,20 @@ class Table:
     key holds. ``name`` prefixes its entries in the flat state ("emb.w")
     and is the innermost scope of its pulls and pushes ("ps.pull/emb"); the
     one table of a single-table app goes unnamed ("z", "n"; "ps.pull").
-    ``init`` makes the slots of ``rows`` rows where the updater's zeros
-    will not do (an embedding's starting values): on the device, inside
-    ``Runtime.init_state``, never as a host array of table size. A slot is
-    stored ``row_stride(vdim)`` lanes wide, which is ``vdim`` for every
-    width but those the chip can neither gather nor scatter in place (300:
-    384, the lanes past ``vdim`` zero for ever); pulled rows and gradients
-    are ``vdim`` wide whatever the stride."""
+    ``init(rows, lanes)`` makes the slots of ``rows`` rows that the
+    updater's zeros will not do for (an embedding's starting ``w``), by the
+    updater's names: on the device, inside ``Runtime.init_state``, never as
+    a host array of table size. A slot is stored ``row_stride(vdim)`` lanes
+    wide, which is ``vdim`` for every width but those the chip can neither
+    gather nor scatter in place (100: 128, 300: 384, the lanes past
+    ``vdim`` zero for ever), and ``lanes`` is that width: ``init`` makes
+    its slots as they are stored. Pulled rows and gradients are ``vdim``
+    wide whatever the stride."""
 
     name: str
     updater: Updater
     vdim: int = 1
-    init: Callable[[int], State] | None = None
+    init: Callable[[int, int], State] | None = None
 
     def key(self, slot: str) -> str:
         return f"{self.name}.{slot}" if self.name else slot
@@ -213,18 +224,23 @@ class Table:
         return tuple(jax.eval_shape(lambda: self.updater.init(1, self.vdim)))
 
     def init_slots(self, rows: int) -> State:
-        slots = (
-            self.init(rows) if self.init is not None
-            else self.updater.init(rows, self.vdim)
-        )
-        # ``init`` may make its slots as stored, the pad lanes zero (XLA does
-        # not fuse a pad into what it pads: a chip-filling table's would be a
-        # second table); slots that come ``vdim`` wide are widened here
-        stride = row_stride(self.vdim)
-        slots = {
-            k: v if v.shape[1] == stride else jnp.pad(v, ((0, 0), (0, stride - self.vdim)))
-            for k, v in slots.items()
-        }
+        """The table's slots as the store keeps them, ``row_stride(vdim)``
+        lanes wide: the one place that applies the stride. The makers (the
+        updater's zeros, then the app's ``init`` over them) are handed that
+        width and make a slot at it in one pass, its lanes past ``vdim``
+        zero; nothing is padded here (XLA does not fuse a pad into what it
+        pads: a chip-filling table's would be a second table)."""
+        lanes = row_stride(self.vdim)
+        slots = self.updater.init(rows, lanes)
+        if self.init is not None:
+            slots = {**slots, **self.init(rows, lanes)}
+        for k, v in slots.items():
+            if v.shape != (rows, lanes):
+                raise ValueError(
+                    f"slot {self.key(k)!r} of {self.vdim}-lane rows is stored "
+                    f"{(rows, lanes)} (spmd.row_stride), not {v.shape}: its "
+                    "init makes it at the width it is handed"
+                )
         return {self.key(k): v for k, v in slots.items()}
 
     def of(self, state: State) -> State:
@@ -584,6 +600,17 @@ def _local_pull(
         return jnp.where(in_range[:, None], w, 0.0)
 
 
+def _pull(t: Table, state_l: State, idx: jax.Array, shard_size: int) -> jax.Array:
+    """Pull: slice + merge (ref kv_vector match). Table ``t``'s (U, vdim)
+    weights for global ids ``idx`` on every device: this shard's rows, the
+    others' zeros, and the ``psum`` over "kv" that merges them, under a
+    scope of its own beneath the table's. Inside ``shard_map``, under the
+    scope ``ps.pull``."""
+    mine = _local_pull(t.updater, t.of(state_l), idx, shard_size, t.name, t.vdim)
+    with _sub_scope(t.name), jax.named_scope("psum"):
+        return lax.psum(mine, "kv")
+
+
 def _range_owner(begin: jax.Array, shard_size: int, kv: int):
     """(this shard owns the range that starts at global row ``begin``, the
     range's first row in the owner's shard - 0 on the other shards, whose
@@ -878,9 +905,10 @@ def _local_push_quantized(
     q = floor + (jax.random.uniform(key, grad.shape) < (t - floor))
     q = jnp.clip(q, -127, 127).astype(jnp.int8)
     # the wire: indices + int8 payload + one scale per worker
-    all_idx = lax.all_gather(idx, "data")  # (D, U)
-    all_q = lax.all_gather(q, "data")  # (D, U, vdim) int8
-    all_scale = lax.all_gather(scale, "data")  # (D,)
+    with _sub_scope(table), jax.named_scope("all_gather"):
+        all_idx = lax.all_gather(idx, "data")  # (D, U)
+        all_q = lax.all_gather(q, "data")  # (D, U, vdim) int8
+        all_scale = lax.all_gather(scale, "data")  # (D,)
     all_grad = all_q.astype(grad.dtype) * all_scale[:, None, None]
     return _local_push(
         updater, state_l, all_idx, all_grad, shard_size, table, ascending, vdim
@@ -981,15 +1009,7 @@ def _microstep(
     with jax.named_scope("ps.row_ids"):
         row_ids = _row_ids_of(b)
     with jax.named_scope("ps.pull"):
-        pulled = {
-            t.name: lax.psum(
-                _local_pull(
-                    t.updater, t.of(state_l), idx, shard_size, t.name, t.vdim
-                ),
-                "kv",
-            )  # Pull: slice + merge (ref kv_vector match)
-            for t in app.tables
-        }
+        pulled = {t.name: _pull(t, state_l, idx, shard_size) for t in app.tables}
     with jax.named_scope("ps.grad"):
         loss, logits, grads, g_dense = app.grad(pulled, dense[0], b, row_ids)
         probs = app.link(logits)
@@ -1011,8 +1031,9 @@ def _microstep(
                 )
             else:
                 # Push: every data shard's (keys, grads) reach every kv shard.
-                all_idx = lax.all_gather(idx, "data")  # (D, U)
-                all_grad = lax.all_gather(g, "data")  # (D, U, vdim)
+                with _sub_scope(t.name), jax.named_scope("all_gather"):
+                    all_idx = lax.all_gather(idx, "data")  # (D, U)
+                    all_grad = lax.all_gather(g, "data")  # (D, U, vdim)
                 new = _local_push(
                     t.updater, tab, all_idx, all_grad, shard_size, t.name,
                     ascending=True, vdim=t.vdim,
@@ -1174,13 +1195,7 @@ def make_spmd_predict_step(app: "StepApp | Updater", mesh: Mesh, num_keys: int):
             row_ids = _row_ids_of(b)
         with jax.named_scope("ps.pull"):
             pulled = {
-                t.name: lax.psum(
-                    _local_pull(
-                        t.updater, t.of(state_l), b["unique_keys"],
-                        shard_size, t.name, t.vdim,
-                    ),
-                    "kv",
-                )
+                t.name: _pull(t, state_l, b["unique_keys"], shard_size)
                 for t in app.tables
             }
         with jax.named_scope("ps.grad"):

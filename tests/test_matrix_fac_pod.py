@@ -2,8 +2,10 @@
 (``models/matrix_fac.py``'s description over ``user item rating`` files)
 against the plain dense reference (``tests/mf_reference.py``): the step on
 three meshes, the evaluator's RMSE, the key layout, the batch shape, the
-``rating`` format's two parsers, the starting factors, and what a table of
-64 lanes asks of the store's gather. Small sizes, CPU, seeded."""
+``rating`` format's two parsers, the starting factors, what a table of
+64 lanes asks of the store's gather, and ranks the store keeps wider than
+themselves (100 and 36 in 128 lanes) against the benchmark's reference on
+three meshes. Small sizes, CPU, seeded."""
 
 import json
 
@@ -395,6 +397,103 @@ def test_take_rows_of_any_width_against_numpy(vdim, sliced):
     assert text.count("slice_sizes") == 1 and f"slice_sizes = array<i64: {sliced}>" in text, text
     if stride == vdim:  # the width unsaid is the slot's own
         np.testing.assert_array_equal(np.asarray(spmd._take_rows(jnp.asarray(table), jnp.asarray(rows))), table[rows])
+
+
+# -- ranks stored wider than themselves (100 and 36: 128 lanes) ------------------
+STRIDED_RANKS = [100, 36]  # neither has a block of 8k lanes: ``row_stride`` sends both to one whole tile
+
+
+@pytest.mark.parametrize("mesh_name", MESHES)
+@pytest.mark.parametrize("rank", STRIDED_RANKS)
+def test_a_strided_rank_against_the_benchmarks_reference_in_worker_order(tmp_path, rank, mesh_name):
+    """Three device calls of two microsteps, every worker a batch a
+    microstep, at a rank the store keeps 128 lanes wide, against the
+    benchmark's plain reference (``benchmark/harness/ref_mf.py``: its own
+    parse, its own starting factors, a step's workers applied in order).
+    The pad lanes never move: exactly zero after training."""
+    from benchmark.harness.ref_mf import RefMf, parse_ratings
+
+    mesh = MESHES[mesh_name]
+    d, calls, k = mesh[0], 3, 2
+    users, items, r = ratings(B * k * calls * d, seed=rank)
+    paths, per = write_files(tmp_path, users, items, r, d)
+    cfg = mf_config(mesh, rank=rank, steps_per_call=k)
+    tr = trainer_of(cfg)
+    assert spmd.row_stride(rank) == 128 and tr.state["mf.w"].shape[1] == 128
+    num_keys = cfg.data.num_keys
+    ref = RefMf(np.arange(num_keys), {"rank": rank, "eta": 0.05, "l2": 0.01}, cfg.seed, num_keys)
+    np.testing.assert_array_equal(np.asarray(tr.state["mf.w"])[:num_keys, :rank], ref.w0)  # the start, to the bit
+    losses = []
+    out = tr.train_files(paths)
+    files = [parse_ratings(p) for p in paths]  # the reference's own reading of the program's files
+    for s in range(per // B):
+        sl = slice(s * B, (s + 1) * B)
+        losses.append(ref.step([(1 + it[sl], 1 + N_ITEMS + us[sl], ra[sl]) for us, it, ra in files]))
+    table = np.asarray(tr.state["mf.w"])
+    got = table[:num_keys, :rank]
+    item_rows, user_rows = slice(1, 1 + N_ITEMS), slice(1 + N_ITEMS, num_keys)
+    assert np.abs(got - ref.w0).max() > 1e-3  # the steps moved something
+    # float32 sums in another order than NumPy's (segment_sum over a batch's 64 pairs
+    # against reduceat, the inner product over 100 lanes on the vector unit), six steps
+    # deep: an item's row collects about three gradients a batch of magnitude 0.1 at eta
+    # 0.05, a user's one in five batches; elements are about 0.05. 5e-7 is eight ulps of
+    # the largest element (2^-4: 6e-8 an ulp), and a bfloat16 table would miss by 1e-4
+    np.testing.assert_allclose(got[item_rows], ref.w[item_rows], rtol=0, atol=5e-7)
+    np.testing.assert_allclose(got[user_rows], ref.w[user_rows], rtol=0, atol=5e-7)
+    # the summed loss: float32 sums of 64 squared errors, six of them: relative 1e-6
+    assert out["objv"] == pytest.approx(sum(losses) / len(r), rel=1e-6)
+    assert not table[:, rank:].any()  # the pad lanes: exactly zero, every row, every shard
+    assert not table[0].any() and not table[num_keys:].any()  # and the pad rows
+
+
+@pytest.mark.parametrize("seed", [0, 2**31 + 23])
+@pytest.mark.parametrize("rank", STRIDED_RANKS)
+def test_a_slot_made_at_the_stride_is_the_narrow_one_in_its_first_lanes(rank, seed):
+    """``hashed_unit`` is a function of (seed, row, lane): the slot the store
+    asks for, ``row_stride(rank)`` lanes wide, equals the ``rank``-lane one
+    bit for bit in its first ``rank`` lanes and is zero past them; and the
+    store pads nothing (``Table.init_slots`` hands the app that width)."""
+    num_keys = 1 + 39_780 + 50_082_603
+    rows = jnp.asarray([0, 1, 2, 39_780, 39_781, num_keys - 1, num_keys, num_keys + 300], jnp.int32)
+    narrow = np.asarray(matrix_fac.init_factors(seed, rows, rank, num_keys))
+    wide = np.asarray(matrix_fac.init_factors(seed, rows, rank, num_keys, 128))
+    assert narrow.shape == (8, rank) and wide.shape == (8, 128)
+    np.testing.assert_array_equal(wide[:, :rank].view(np.uint32), narrow.view(np.uint32))
+    assert not wide[:, rank:].any() and narrow[1:6].all()
+    cfg = mf_config(rank=rank)
+    cfg.seed = seed
+    (table,) = app_from_config(cfg).tables
+    (slot,) = table.init_slots(1024).values()
+    want = matrix_fac.init_factors(seed, jnp.arange(1024, dtype=jnp.int32), rank, cfg.data.num_keys)
+    assert slot.shape == (1024, spmd.row_stride(rank)) == (1024, 128)
+    np.testing.assert_array_equal(np.asarray(slot)[:, :rank], np.asarray(want))
+    assert not np.asarray(slot)[:, rank:].any()
+    assert "pad" not in jax.jit(lambda: table.init_slots(1024)).lower().as_text()
+
+
+@pytest.mark.parametrize("rank", STRIDED_RANKS)
+def test_elastic_restore_of_a_strided_table(tmp_path, rank):
+    """A table stored 128 lanes wide, trained and saved on (2, 2), restored
+    on (1, 4): the same rows to the bit, pad lanes and all, and further
+    training from it moves the rows and leaves the pad lanes zero."""
+    users, items, r = ratings(B * 8, seed=9)
+    paths, _ = write_files(tmp_path, users, items, r, 2)
+    tr = trainer_of(mf_config((2, 2), rank=rank))
+    tr.train_files(paths)
+    tr.save(str(tmp_path / "ckpt"))
+    back = trainer_of(mf_config((1, 4), rank=rank))  # another mesh: another shard size, another pad tail
+    back.load(str(tmp_path / "ckpt"))
+    n = tr.cfg.data.num_keys
+    assert back.state["mf.w"].shape[1] == 128
+    np.testing.assert_array_equal(np.asarray(back.state["mf.w"])[:n], np.asarray(tr.state["mf.w"])[:n])
+    assert back.examples_seen == len(r)
+    (tmp_path / "more").mkdir()
+    more, _ = write_files(tmp_path / "more", *ratings(B * 4, seed=10), 1)
+    before = np.asarray(back.state["mf.w"]).copy()
+    back.train_files(more)
+    after = np.asarray(back.state["mf.w"])
+    assert np.abs(after - before)[:n, :rank].max() > 1e-3 and not after[:, rank:].any() and not after[n:].any()
+    np.testing.assert_array_equal(back.full_weights("mf"), after[:n, :rank])
 
 
 def test_cli_dump_holds_the_factors_by_id(tmp_path):

@@ -392,8 +392,9 @@ def test_table_scatters_take_ascending_rows_in_place(compiled_text, wd_text, app
         assert len(gathers) == slots, gathers
 
 
-# -- matrix factorization: one 64-lane table through the same step -------------
+# -- matrix factorization: one table at the configured rank through the same step
 MF_USERS, MF_ITEMS, MF_RANK = 50_082_603, 39_780, 64  # the cell mfhw.train
+MF_RANK_2X2 = 100  # the cell mfhw2x2.train: NOMAD's rank, stored 128 lanes wide, over kv 2
 MF_MINIBATCH, MF_SLOTS = 65_536, 131_072  # 2 entries a rating: one 2^17 bucket on both axes
 MF_CASES = [(1, 1, "multistep"), (1, 1, "predict")]
 MF_NAMES = frozenset({"mf"})
@@ -401,8 +402,10 @@ MF_NAMES = frozenset({"mf"})
 
 @pytest.fixture(scope="module")
 def mf_text(topo):
-    """(data, kv, program) -> optimised HLO text of the matrix-factorization
-    programs at the cell's size (5.0e7 rows x 64 under SGD), compiled once."""
+    """(data, kv, program[, rank]) -> optimised HLO text of the
+    matrix-factorization programs at the cells' sizes (5.0e7 rows under SGD:
+    x 64 on one chip, x 100 stored 128 wide over ``kv`` 2), compiled once;
+    ``program`` "init" is the table made on the devices."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding
@@ -413,19 +416,19 @@ def mf_text(topo):
 
     texts: dict = {}
 
-    def get(data: int, kv: int, program: str) -> str:
-        key = (data, kv, program)
+    def get(data: int, kv: int, program: str, rank: int = MF_RANK) -> str:
+        key = (data, kv, program) if rank == MF_RANK else (data, kv, program, rank)
         if key in texts:
             return texts[key]
         cfg = PSConfig()
-        cfg.mf.num_users, cfg.mf.num_items, cfg.mf.rank = MF_USERS, MF_ITEMS, MF_RANK
+        cfg.mf.num_users, cfg.mf.num_items, cfg.mf.rank = MF_USERS, MF_ITEMS, rank
         cfg.mf.algo, cfg.mf.batch_size = "sgd", MF_MINIBATCH
         cfg = matrix_fac.pod_config(cfg)
         app = matrix_fac.app_from_config(cfg)
         mesh = Mesh(np.array(topo.devices[: data * kv]).reshape(data, kv), ("data", "kv"))
         rows = spmd.padded_num_keys(cfg.data.num_keys, kv)
         table = NamedSharding(mesh, spmd.state_spec())
-        state = {"mf.w": jax.ShapeDtypeStruct((rows, MF_RANK), jnp.float32, sharding=table)}
+        state = {"mf.w": jax.ShapeDtypeStruct((rows, spmd.row_stride(rank)), jnp.float32, sharding=table)}
         feed = NamedSharding(mesh, spmd.batch_spec())
         lead = (K,) if program == "multistep" else ()
         fields = {
@@ -437,14 +440,17 @@ def mf_text(topo):
             k: jax.ShapeDtypeStruct((data, *lead, *shape), dt, sharding=feed)
             for k, (shape, dt) in fields.items()
         }
-        if program == "multistep":
-            fn, args = spmd.make_spmd_train_multistep(app, mesh, cfg.data.num_keys), (state, batch, 0)
+        if program == "init":
+            jitted, args = jax.jit(lambda: app.init_tables(rows), out_shardings=table), ()
         else:
-            fn, args = spmd.make_spmd_predict_step(app, mesh, cfg.data.num_keys), (state, batch)
-        (jitted,) = [
-            c.cell_contents for c in fn.__closure__
-            if callable(c.cell_contents) and hasattr(c.cell_contents, "lower")
-        ]
+            if program == "multistep":
+                fn, args = spmd.make_spmd_train_multistep(app, mesh, cfg.data.num_keys), (state, batch, 0)
+            else:
+                fn, args = spmd.make_spmd_predict_step(app, mesh, cfg.data.num_keys), (state, batch)
+            (jitted,) = [
+                c.cell_contents for c in fn.__closure__
+                if callable(c.cell_contents) and hasattr(c.cell_contents, "lower")
+            ]
         compiled = jitted.lower(*args).compile()
         texts[key] = compiled.as_text()
         texts[key, "memory"] = compiled.memory_analysis()
@@ -553,6 +559,81 @@ def test_mf_large_unscoped_instructions_are_the_known_kinds(mf_text, data, kv, p
         if opcode == "fusion" and not scopes[name]:
             # bookkeeping at batch size (a (U, 64) buffer at most), never a table op
             assert elements(shape) <= 2 * 64 * MF_SLOTS and all(elements(s) < MF_USERS for s in operand_shapes), name
+
+
+MF_2X2_CASES = [(2, 2, "multistep"), (2, 2, "predict"), (2, 2, "init")]
+
+
+@pytest.mark.parametrize("data,kv,program", MF_2X2_CASES)
+def test_mf_rank_100_over_kv_is_held_once_and_its_collectives_are_scoped(mf_text, data, kv, program):
+    """The cell ``mfhw2x2.train`` at its shapes: 50,122,752 rows of 100 lanes
+    stored 128 wide, 25,061,376 a ``kv`` shard (11.95 GiB a chip of 15.75).
+    Every executed instruction that reads or writes a shard sits under
+    ``ps.pull/mf`` or ``ps.push/<stage>/mf``; the shard is held once (the
+    step's two scatters, one a worker, take it in place, unhinted: 24,473
+    table elements a slot); the table's start is made at its stride in one
+    pass (a ``jnp.pad`` of a 100-lane slot held a second table: 23.9 GiB, no
+    fit, which is how the parent fails on the cell); and the step's two
+    collectives sit under scopes of their own at the shapes the chip moves:
+    the pull's ``psum`` over ``kv`` of every slot of the bucket (52 MB), the
+    push's ``all_gather`` over ``data`` of both workers' (105 MB out)."""
+    from parameter_server_tpu.parallel import spmd
+
+    text = mf_text(data, kv, program, MF_RANK_2X2)
+    mem = mf_text.texts[(data, kv, program, MF_RANK_2X2), "memory"]
+    rows = spmd.padded_num_keys(1 + MF_ITEMS + MF_USERS, kv) // kv
+    stride = spmd.row_stride(MF_RANK_2X2)
+    assert (rows, stride) == (25_061_376, 128)
+    table_bytes = 4 * rows * stride
+    assert table_bytes == 12_831_424_512  # a chip's shard: the one-chip cell's table to the byte
+    every = instructions(text)
+    assert not copies_of(every, rows * MF_RANK_2X2)
+    if program == "init":
+        assert mem.output_size_in_bytes == table_bytes
+        assert mem.temp_size_in_bytes < 512 << 20, mem.temp_size_in_bytes
+        assert not [name for _, name, shape, _, _, _ in every if re.search(rf"\[{rows},{MF_RANK_2X2}\]", shape)]
+        return
+    _, scopes = spmd.hlo_scopes(text, MF_NAMES)
+    table = re.compile(rf"\[{rows},{stride}\]")
+    touching = [
+        (name, scopes[name])
+        for name, shape, opcode, operand_shapes in executed(text)
+        if table.search(shape) or any(table.search(s) for s in operand_shapes)
+    ]
+    assert touching
+    assert all(re.match(r"^ps\.(pull|push/\w+)/mf$", scope) for _, scope in touching), touching
+    found = set(scopes.values())
+    assert {"ps.pull/mf", "ps.pull/mf/psum", "ps.grad"} <= found, found
+    # the table is row-major wherever it appears: whole tiles, nothing to pad
+    assert all("{1,0:" in shape for _, _, shape, _, _, _ in every if table.search(shape) and shape.startswith("f32")), "rows-minor"
+    collectives = {
+        (opcode, shape.split("{")[0]): scopes[name]
+        for _, name, shape, opcode, _, _ in every
+        if opcode in ("all-reduce", "all-gather", "all-reduce-start", "all-gather-start") and elements(shape) >= MF_SLOTS
+    }
+    pulled = ("all-reduce", f"f32[{MF_SLOTS},{MF_RANK_2X2}]")
+    assert collectives.get(pulled) == "ps.pull/mf/psum", collectives
+    assert mem.temp_size_in_bytes < 512 << 20, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < table_bytes + (512 << 20)
+    if program == "predict":
+        assert set(collectives) == {pulled}, collectives
+        return
+    assert {"ps.push/scatter/mf", "ps.push/update/mf", "ps.push/mf/all_gather"} <= found, found
+    assert collectives == {
+        pulled: "ps.pull/mf/psum",
+        ("all-gather", f"f32[{data * MF_SLOTS},{MF_RANK_2X2}]"): "ps.push/mf/all_gather",
+        ("all-gather", f"s32[{data},1,{MF_SLOTS}]"): "ps.push/mf/all_gather",
+    }, collectives
+    # a collective's scope is no table op's: what sums "ps.pull/mf" and "ps.push/*/mf" leaves both out
+    assert not any(re.match(r"^ps\.(pull|push/\w+)/mf$", scope) for scope in collectives.values())
+    scatters = [(comp, rest) for comp, _, shape, opcode, _, rest in every if opcode == "scatter" and table.search(shape)]
+    assert len(scatters) == 1, scatters  # one in the loop over the workers' pushes: two a microstep
+    ((home, told),) = scatters
+    assert sorted_hint(told) is spmd.scatter_rows_sorted(rows, stride, MF_SLOTS) is False, told
+    ((name, rest),) = fusions_calling(every, {home})
+    assert scopes[name] == "ps.push/scatter/mf", (name, scopes[name])
+    assert aliases_operand_0(rest), (name, rest[-300:])
+    assert mem.alias_size_in_bytes >= table_bytes  # the shard is donated through the call
 
 
 # -- skip-gram: one 300-lane table, stored 384 wide, through the same step -----

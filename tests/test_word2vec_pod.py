@@ -386,13 +386,18 @@ def test_a_slots_stride_is_read_off_the_rows_width(vdim, stride):
     from parameter_server_tpu.kv.updaters import Adagrad
 
     assert spmd.row_stride(vdim) == stride
-    t = spmd.Table("t", Adagrad(eta=0.1), vdim, lambda rows: {"w": jnp.ones((rows, vdim)), "n": jnp.ones((rows, vdim))})
+    ones = lambda rows, lanes: jnp.broadcast_to((jnp.arange(lanes) < vdim).astype(jnp.float32), (rows, lanes))  # noqa: E731
+    t = spmd.Table("t", Adagrad(eta=0.1), vdim, lambda rows, lanes: {"w": ones(rows, lanes), "n": ones(rows, lanes)})
     slots = t.init_slots(8)
     assert {k: v.shape for k, v in slots.items()} == {"t.w": (8, stride), "t.n": (8, stride)}
     assert np.asarray(slots["t.w"])[:, :vdim].all() and not np.asarray(slots["t.w"])[:, vdim:].any()
+    assert {k: v.shape for k, v in spmd.Table("t", Adagrad(eta=0.1), vdim).init_slots(8).items()} == {"t.w": (8, stride), "t.n": (8, stride)}
     if stride != vdim:
         with pytest.raises(ValueError, match="row_stride"):
             spmd._take_rows(jnp.zeros((8, vdim)), jnp.zeros((2,), jnp.int32), vdim)
+        narrow = spmd.Table("t", Adagrad(eta=0.1), vdim, lambda rows, lanes: {"w": jnp.ones((rows, vdim))})
+        with pytest.raises(ValueError, match="row_stride"):  # the store pads nothing: the maker makes the width it is handed
+            narrow.init_slots(8)
 
 
 def test_dynamic_window_keeps_the_pairs_within_each_centres_reach(tmp_path):

@@ -9,7 +9,8 @@
 # goes to chiprun_out/cells/<tag>_<side>_<workload>_<seed>_t<trace>.log; its
 # [gaps] and [timers] lines and its result line are echoed. A fifth field
 # keeps the traced run's .xplane.pb just long enough for tools/host_gaps.py
-# to read it into the log's .gaps.txt.
+# to read it into the log's .gaps.txt. CELL_TIMEOUT=<seconds> in the
+# environment ends a run that hangs (a parent tried on a cell it cannot run).
 tag=$1; shift
 root=$(cd "$(dirname "$0")/.." && pwd)
 out=$root/chiprun_out/cells; mkdir -p "$out"
@@ -17,7 +18,7 @@ for spec in "$@"; do
   IFS=: read -r side wl seed trace keep <<< "$spec"
   dir=$root; [ "$side" = P ] && dir=$root/_parent; [ "$side" = O ] && dir=$root/_parent_ov
   log=$out/${tag}_${side}_${wl}_${seed}_t${trace}.log
-  ( cd "$dir" && python3 benchmark/run.py --workload "$wl" --seed "$seed" --seconds 20 --trace "$trace" ${keep:+--keep-trace} ) > "$log" 2>&1
+  ( cd "$dir" && ${CELL_TIMEOUT:+timeout $CELL_TIMEOUT} python3 benchmark/run.py --workload "$wl" --seed "$seed" --seconds 20 --trace "$trace" ${keep:+--keep-trace} ) > "$log" 2>&1
   echo "== $side $wl seed=$seed trace=$trace rc=$?"
   grep -a "^\[gaps\]\|^\[timers\]" "$log" | tail -4 | cut -c1-600
   tail -n 1 "$log" | cut -c1-6000
