@@ -32,7 +32,7 @@ from parameter_server_tpu.data.batch import CSRBatch
 from parameter_server_tpu.kv.store import State, hashed_uniform
 from parameter_server_tpu.kv.updaters import Adagrad, Ftrl, Updater
 from parameter_server_tpu.models.metrics import BINARY_SCORES
-from parameter_server_tpu.ops.sparse import csr_logits, sum_by_example
+from parameter_server_tpu.ops.sparse import csr_logits, sum_by_example, take_by_slot
 from parameter_server_tpu.parallel.spmd import (
     DenseGroup,
     StepApp,
@@ -77,7 +77,7 @@ def _logits(pulled, mlp_params, b, row_ids):
     values, row_splits = _values_of(b), b["row_splits"]
     wide = csr_logits(pulled["wide"], values, b["local_ids"], row_ids, row_splits)
     # mean-pool the batch's unique-key embeddings per example
-    ent_emb = jnp.take(pulled["emb"], b["local_ids"], axis=0)  # (NNZ, d)
+    ent_emb = take_by_slot(pulled["emb"], b["local_ids"], row_splits)  # (NNZ, d)
     ones = (values != 0).astype(jnp.float32)
     num = sum_by_example(ent_emb * ones[:, None], row_ids, row_splits)
     cnt = sum_by_example(ones, row_ids, row_splits)

@@ -32,6 +32,7 @@ from parameter_server_tpu.data.batch import BatchBuilder, CSRBatch, inert_like
 from parameter_server_tpu.data.pipeline import PrefetchPipeline
 from parameter_server_tpu.data.reader import MinibatchReader, ingest_of
 from parameter_server_tpu.models.linear import updater_from_config
+from parameter_server_tpu.ops.sparse import walked_entries
 from parameter_server_tpu.parallel.mesh import make_mesh
 from parameter_server_tpu.parallel.runtime import Runtime
 from parameter_server_tpu.parallel.spmd import (
@@ -529,15 +530,21 @@ class PodTrainer:
         # the chip a batch out of order is undefined behaviour, not an error
         assert all(b.keys_in_order() for b in batches), "unique_keys out of order"
         padded = pad_group(batches)
-        if trace.enabled() and self.cfg.parallel.push_mode != "aggregate":
-            # beside pad_group's feed.unique_fill: what the push's scatters
-            # visit of the slots they are handed (spmd._add_rows' walk)
-            kv, slots = self.mesh.shape["kv"], len(padded[0].unique_keys)
+        if trace.enabled():
+            # beside pad_group's feed.unique_fill: what a sweep by key slot
+            # of ps.grad visits of the entry slots it is handed (ops.sparse's
+            # walk), and the push's scatters of their key slots (spmd._add_rows')
+            entries, slots = len(padded[0].values), len(padded[0].unique_keys)
+            kv = self.mesh.shape["kv"]
             for b in batches:
-                trace.counter("push.walk_share", push_walk_share(
-                    self.app.tables, b.unique_keys[: b.num_unique],
-                    self._table_rows // kv, kv, slots,
-                ))
+                trace.counter(
+                    "grad.walk_share", walked_entries(b.num_entries, entries) / entries
+                )
+                if self.cfg.parallel.push_mode != "aggregate":
+                    trace.counter("push.walk_share", push_walk_share(
+                        self.app.tables, b.unique_keys[: b.num_unique],
+                        self._table_rows // kv, kv, slots,
+                    ))
         stacked = stack_batches(
             padded, None, values_f16=self.cfg.data.wire_values == "f16",
         )

@@ -1,7 +1,8 @@
 """The two sweeps by example of ``ops.sparse`` (``sum_by_example`` /
 ``spread_by_example``) against ``segment_sum`` / ``take`` on batches from
-``BatchBuilder``, under both conventions for the pads' row ids, and the
-promise they rest on."""
+``BatchBuilder``, under both conventions for the pads' row ids, the
+promise they rest on, and the two sweeps by key slot (``take_by_slot`` /
+``sum_by_slot``) whole and walked on the same promise."""
 
 import jax
 import jax.numpy as jnp
@@ -9,11 +10,14 @@ import numpy as np
 import pytest
 
 from parameter_server_tpu.data.batch import BatchBuilder
+from parameter_server_tpu.ops import sparse
 from parameter_server_tpu.ops.sparse import (
     csr_grad,
     csr_logits,
     spread_by_example,
     sum_by_example,
+    sum_by_slot,
+    take_by_slot,
 )
 from parameter_server_tpu.parallel.spmd import _row_ids_of
 
@@ -139,10 +143,106 @@ def test_each_op_is_the_others_transpose(name, pads, lanes):
     np.testing.assert_allclose(vjp_spread(jnp.asarray(x))[0], want, rtol=1e-5, atol=1e-5)
 
 
+PIECE = 64
+SLOTS = 37
+# (entry slots, real entries): the inert batch, one entry, exactly one piece,
+# one piece + 1, a run that ends inside a later piece, the full axis; 200
+# entry slots are no whole number of pieces, 64 are a single piece: one op
+WALKS = [(e, r) for e in (256, 200) for r in (0, 1, PIECE, PIECE + 1, 150, e)] + [(PIECE, 40)]
+WALK_CASES = [(e, r, lanes) for e, r in WALKS for lanes in (1, 16)]
+
+
+@pytest.fixture
+def pieces(monkeypatch):
+    """The sweeps by key slot walk pieces of ``PIECE`` entries."""
+    monkeypatch.setattr(sparse, "_WALK_ENTRIES", PIECE)
+
+
+def _slot_case(entries, real, lanes):
+    """(w, x, local_ids, row_splits): per-slot values, per-entry terms with
+    NON-zero pads, slots drawn for every entry slot (a pad's too: neither
+    op may read one), and row splits that end at ``real``."""
+    rng = np.random.default_rng(entries + real)
+    shape = () if lanes == 1 else (lanes,)
+    w = rng.normal(size=(SLOTS, *shape)).astype(np.float32)
+    x = rng.normal(size=(entries, *shape)).astype(np.float32)
+    ids = rng.integers(0, SLOTS, entries).astype(np.int32)
+    return w, x, ids, jnp.asarray([0, real // 2, real], jnp.int32)
+
+
+@pytest.mark.parametrize("entries,real,lanes", WALK_CASES)
+def test_take_by_slot_is_the_take_on_real_entries(pieces, entries, real, lanes):
+    w, x, ids, splits = _slot_case(entries, real, lanes)
+    assert sparse.sweep_walks(entries) == (entries > PIECE)
+    got = np.asarray(jax.jit(take_by_slot)(w, ids, splits))
+    assert got.shape == x.shape
+    np.testing.assert_array_equal(got[:real], w[ids[:real]])  # copied: exact
+    assert not got[real:].any()
+
+
+@pytest.mark.parametrize("entries,real,lanes", WALK_CASES)
+def test_sum_by_slot_is_the_segment_sum_of_real_entries(pieces, entries, real, lanes):
+    w, x, ids, splits = _slot_case(entries, real, lanes)
+    want = jax.ops.segment_sum(jnp.asarray(x[:real]), jnp.asarray(ids[:real]), num_segments=SLOTS)
+    got = jax.jit(sum_by_slot, static_argnums=3)(x, ids, splits, SLOTS)
+    assert got.shape == w.shape
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("entries,real,lanes", WALK_CASES)
+def test_each_slot_op_is_the_others_transpose(pieces, entries, real, lanes):
+    """``jax.vjp`` of each (a loop with a trip count read off the batch has
+    no reverse of autodiff's: its ``custom_vjp`` names the other op)
+    against the transpose worked out in NumPy."""
+    w, x, ids, splits = _slot_case(entries, real, lanes)
+    _, vjp_take = jax.vjp(lambda t: take_by_slot(t, ids, splits), jnp.asarray(w))
+    want = np.zeros_like(w)
+    np.add.at(want, ids[:real], x[:real])
+    np.testing.assert_allclose(vjp_take(jnp.asarray(x))[0], want, rtol=1e-5, atol=1e-5)
+    _, vjp_sum = jax.vjp(lambda t: sum_by_slot(t, ids, splits, SLOTS), jnp.asarray(x))
+    want = np.zeros_like(x)
+    want[:real] = w[ids[:real]]
+    np.testing.assert_array_equal(vjp_sum(jnp.asarray(w))[0], want)
+
+
+@pytest.mark.parametrize("op", ["take", "sum"])
+@pytest.mark.parametrize("lanes", [1, 16])
+def test_a_single_piece_is_one_op_and_more_are_a_loop(pieces, op, lanes):
+    """The form is read off the static entry axis: one piece or less lowers
+    to the one gather / scatter-add the step had, more to a ``while`` that
+    holds it."""
+    def lowered(entries):
+        w, x, ids, splits = _slot_case(entries, entries // 2, lanes)
+        if op == "take":
+            return jax.jit(take_by_slot).lower(w, ids, splits).as_text()
+        return jax.jit(sum_by_slot, static_argnums=3).lower(x, ids, splits, SLOTS).as_text()
+
+    kind = '"stablehlo.gather"(' if op == "take" else '"stablehlo.scatter"('
+    one, looped = lowered(PIECE), lowered(4 * PIECE)
+    assert one.count(kind) == 1 and "stablehlo.while" not in one
+    assert looped.count(kind) == 1 and looped.count("stablehlo.while") == 1
+
+
+@pytest.mark.parametrize("entries,real", WALKS)
+def test_the_hosts_count_is_the_device_loops(pieces, entries, real):
+    """``walked_entries`` (the counter ``grad.walk_share``'s numerator, on
+    the host) against the turns ``_walk``'s loop takes on the same batch."""
+    splits = jnp.asarray([0, real], jnp.int32)
+    if not sparse.sweep_walks(entries):
+        assert sparse.walked_entries(real, entries) == entries
+        return
+    turns = jax.jit(lambda s: sparse._walk(s, entries, lambda at, real, fresh, n: n + 1, 0))(splits)
+    assert sparse.walked_entries(real, entries) == int(turns) * PIECE
+
+
+@pytest.mark.parametrize("walked", [False, True], ids=["whole", "walked"])
 @pytest.mark.parametrize("name", ["random", "long_row", "filled_to_the_last_entry"])
 @pytest.mark.parametrize("pads", ["host", "device"])
-def test_csr_ops_are_the_dense_matvec_and_its_transpose(name, pads):
+def test_csr_ops_are_the_dense_matvec_and_its_transpose(monkeypatch, name, pads, walked):
+    if walked:  # 16 entries a piece: every batch here is several
+        monkeypatch.setattr(sparse, "_WALK_ENTRIES", 16)
     b = _batch(name)
+    assert sparse.sweep_walks(len(b.values)) == walked
     rows, slots, n = len(b.labels), len(b.unique_keys), b.num_entries
     dense = np.zeros((rows, slots), np.float64)
     np.add.at(dense, (b.row_ids[:n], b.local_ids[:n]), b.values[:n])
@@ -175,15 +275,20 @@ def _parent_wd_loss(pulled, mlp_params, b, row_ids):
     return jnp.sum(m * (jax.nn.softplus(logits) - b["labels"] * logits)), logits
 
 
+@pytest.mark.parametrize("walked", [False, True], ids=["whole", "walked"])
 @pytest.mark.parametrize("name", ["random", "long_row", "filled_to_the_last_entry"])
-def test_wide_deep_gradients_are_the_parents(name):
-    """``jax.grad`` of Wide&Deep's loss through the two ops against the
-    same loss through ``segment_sum``: every pulled row's and every tower
-    parameter's gradient within 1e-6."""
+def test_wide_deep_gradients_are_the_parents(monkeypatch, name, walked):
+    """``jax.grad`` of Wide&Deep's loss through the four ops, under
+    ``jit``, against the same loss through ``take`` and ``segment_sum``:
+    every pulled row's and every tower parameter's gradient within 1e-6,
+    with the sweeps by key slot whole and walked."""
     from parameter_server_tpu.models import wide_deep as wd
     from parameter_server_tpu.parallel.spmd import CSR_FIELDS
 
+    if walked:
+        monkeypatch.setattr(sparse, "_WALK_ENTRIES", 16)
     cb = _batch(name)
+    assert sparse.sweep_walks(len(cb.values)) == walked
     b = {f: jnp.asarray(getattr(cb, f)) for f in CSR_FIELDS}
     rng = np.random.default_rng(3)
     slots = len(cb.unique_keys)
@@ -195,7 +300,7 @@ def test_wide_deep_gradients_are_the_parents(name):
     row_ids = _row_ids_of(b)
 
     def grad(loss):
-        return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(pulled, mlp, b, row_ids)
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True))(pulled, mlp, b, row_ids)
 
     (loss, logits), g = grad(wd._loss)
     (loss0, logits0), g0 = grad(_parent_wd_loss)

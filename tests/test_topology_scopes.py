@@ -172,8 +172,10 @@ def test_table_ops_and_the_row_id_loop_are_scoped(compiled_text, data, kv, progr
     assert len(summing) >= math.log2(NNZ) - 2, summing  # XLA may fold an add or two into a neighbour
     assert not strays, strays
     # and no loop: the only ``while``s are the scan over microsteps, across chips
-    # the push's loop over workers, and under ``ps.push/scatter`` the walk of each
-    # slot's scatter (z, n) over the live pieces of the key axis
+    # the push's loop over workers, under ``ps.push/scatter`` the walk of each
+    # slot's scatter (z, n) over the live pieces of the key axis, and under
+    # ``ps.grad`` the walks of the sweeps by key slot over the live pieces of the
+    # entry axis (the take of ``csr_logits``; a train step adds ``csr_grad``'s sum)
     whiles = {
         m.group(1): scopes[m.group(1)]
         for m in re.finditer(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*? while\(", text, re.M)
@@ -181,7 +183,10 @@ def test_table_ops_and_the_row_id_loop_are_scoped(compiled_text, data, kv, progr
     assert "ps.row_ids" not in whiles.values(), whiles
     walks = [name for name, scope in whiles.items() if scope == "ps.push/scatter"]
     assert len(walks) == {"multistep": 2, "predict": 0}[program], whiles
-    assert len(whiles) - len(walks) <= {"multistep": 1 + (data > 1), "predict": 0}[program], whiles
+    sweeps = [name for name, scope in whiles.items() if scope == "ps.grad"]
+    assert len(sweeps) == {"multistep": 2, "predict": 1}[program], whiles
+    rest = len(whiles) - len(walks) - len(sweeps)
+    assert rest <= {"multistep": 1 + (data > 1), "predict": 0}[program], whiles
 
 
 @pytest.mark.parametrize("data,kv,program", CASES)

@@ -303,6 +303,40 @@ def test_walk_share_counter(tmp_path, monkeypatch, kv):
     assert spmd.push_walk_share(t.app.tables, keys, 1 << 15, 2, 2048) == 1.0
 
 
+def test_grad_walk_share_counter(tmp_path, monkeypatch):
+    """``grad.walk_share``: the entry slots a sweep by key slot of
+    ``ps.grad`` visits over those it is handed, a sample a batch beside
+    ``push.walk_share``: the whole pieces up to the batch's last real entry
+    of the group's entry axis; 1.0 where that axis is one piece; nothing
+    with the tracer off."""
+    from parameter_server_tpu.ops import sparse
+
+    monkeypatch.setattr(sparse, "_WALK_ENTRIES", 1024)
+    builder = _builder(bucket_nnz=True)
+    # 1024 examples of 3 and of 8 entries: 3,072 real entries in a 4,096 bucket, 8,192 in 8,192
+    batches = [_batch(builder, 1023, per_example=3, seed=1), _batch(builder, 3071, seed=2)]
+    assert [(b.num_entries, len(b.values)) for b in batches] == [(3072, 4096), (8192, 8192)]
+    t = _trainer()
+    assert not trace.enabled()
+    t._prepare(batches[:1])  # tracer off: a no-op
+    tracer = trace.configure(str(tmp_path), process_name="walk")
+    try:
+        t._prepare(batches[:1])
+        t._prepare(batches)  # one group: the first batch is padded to the second's 8,192
+        monkeypatch.setattr(sparse, "_WALK_ENTRIES", 8192)
+        t._prepare(batches[:1])  # 4,096 entry slots are a single piece: one whole sweep
+        shares = [
+            e["args"]["value"] for e in tracer.events()
+            if e["ph"] == "C" and e["name"] == "grad.walk_share"
+        ]
+    finally:
+        trace.configure(None)
+    assert shares == [3072 / 4096, 3072 / 8192, 1.0, 1.0]
+    # a run that ends inside a piece pays for the whole piece
+    monkeypatch.setattr(sparse, "_WALK_ENTRIES", 1024)
+    assert sparse.walked_entries(3073, 8192) == 4096 and sparse.walked_entries(0, 8192) == 0
+
+
 @pytest.mark.parametrize("kv", [1, 2])
 def test_training_with_the_scatter_walked_is_training_to_the_bit(monkeypatch, kv):
     """The trainer's own step over batches of two key buckets, its push's

@@ -9,8 +9,9 @@ checked on the chip against the form it would replace (a pad's
 entry aside, which the new spread leaves 0).
 
 Forms, at each lane count:
-  take_by_slot, sum_by_slot        the two sweeps by ``local_ids`` (a true
-                                   element gather / ``segment_sum``: kept)
+  take_by_slot, sum_by_slot        the two sweeps by ``local_ids`` whole, as
+                                   one ``jnp.take`` / ``segment_sum`` (a true
+                                   element gather / scatter-add; see --walk)
   sum_by_example_today [_sorted]   ``segment_sum(x, row_ids)`` as the step
                                    had it, and with ``indices_are_sorted``
   take_by_example_today            ``jnp.take(v, row_ids)``, its transpose
@@ -28,7 +29,28 @@ calls them beside ``*_today``, the same with ``segment_sum`` / ``take`` by
 ``row_ids`` (``wd_grad_unpinned`` as above). One JSON line a form, also appended to
 chiprun_out/probe_csr_sweeps.jsonl.
 
-    chiprun --timeout 900 -- python3 tools/probe_csr_sweeps.py [SEED]
+``--walk [PIECE ...]`` (step 0 of ISSUE 47; PERF.md section 6, PR 47) reads
+the two sweeps by key slot alone instead, ``ops.sparse.take_by_slot`` /
+``sum_by_slot`` at 1 and 16 lanes: whole (``sparse.sweep_walks`` made to say
+no) against the walk over the pieces of the entry axis that hold a real
+entry, at pieces of 8,192 to 65,536 entry slots (or those named), at the
+bucket's own 319,488 real entries and, for the line through zero, at 0,
+131,072 and all 524,288 (one compiled program a piece: the trip count is
+read on the chip); each walked result is compared with the whole one on the
+chip and a pad's place must read 0. Then the whole of ``ps.grad`` both ways
+at each piece (``linear_grad_*``, ``wd_grad_*``). About 3 chip-minutes.
+Seed 2470000001, ms a call (the least of three sets of twenty), whole then
+pieces of 8,192 / 16,384 / 32,768 / 65,536: take 1 lane 3.784 -> 2.373 /
+2.400 / 2.401 / 2.398; sum 1 lane 3.949 -> 2.268 / 2.310 / 2.292 / 2.281;
+take 16 lanes 2.240 -> 1.152 / 1.139 / 1.139 / 1.142; sum 16 lanes 8.088
+-> 14.349 / 3.935 / 3.878 / 3.850 (at 8,192 XLA emits the unsorted scatter:
+the grid and the rule are in the comment above ``sparse._WALK_ENTRIES``);
+linear_grad 7.896 -> 4.803 / 4.879 / 4.864 / 4.849; wd_grad 20.006 ->
+22.889 / 12.539 / 12.459 / 12.597. A line through zero in the slots visited
+(take, 1 lane, 8,192: 0.19 / 0.99 / 2.37 / 3.88 at 0 / 131,072 / 319,488 /
+524,288 real), 1-2 us a loop turn.
+
+    chiprun --timeout 900 -- python3 tools/probe_csr_sweeps.py [SEED] [--walk [PIECE ...]]
 """
 import json, os, sys, time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -39,7 +61,7 @@ from parameter_server_tpu.models import wide_deep
 from parameter_server_tpu.ops import sparse
 from parameter_server_tpu.parallel import spmd
 
-SEED = int(sys.argv[1]) if len(sys.argv) > 1 else 2420000001
+SEED = int(sys.argv[1]) if len(sys.argv) > 1 and sys.argv[1].isdigit() else 2420000001
 B, NNZ, U, PER_ROW, EMB = 8192, 1 << 19, 1 << 16, 39, 16
 print("device", jax.devices()[0].platform, jax.devices()[0].device_kind, flush=True)
 assert jax.devices()[0].platform == "tpu"
@@ -112,10 +134,10 @@ def timed(fn, args, n=20, reps=3):
     return compile_s, ms, out
 
 
-def emit(form, lanes, fn, args, want=None, on=True):
+def emit(form, lanes, fn, args, want=None, on=True, **more):
     """Time ``fn``; ``want`` is today's result, compared where ``on``."""
     compile_s, ms, out = timed(jax.jit(fn), args)
-    res = {"form": form, "lanes": lanes, "seed": SEED, "ms": ms, "compile_s": compile_s}
+    res = {"form": form, "lanes": lanes, "seed": SEED, "ms": ms, "compile_s": compile_s, **more}
     if want is not None:
         gaps = jax.tree.map(lambda a, b: float(jnp.max(jnp.abs((a - b) * on))), out, want)
         res["max_gap_to_today"] = max(jax.tree.leaves(gaps))
@@ -157,8 +179,65 @@ def wd_grad_today(pulled, mlp, b, row_ids):
     return loss, logits, g, g_mlp
 
 
+WHOLE = 1 << 30  # a piece no entry axis reaches: ``sparse.sweep_walks`` says no, one whole sweep
+PIECES = tuple(int(a) for a in sys.argv[sys.argv.index("--walk") + 1:] if a.isdigit()) if "--walk" in sys.argv else ()
+
+
+def pieced(op, piece):
+    """``op`` traced with ``ops.sparse``'s sweeps by key slot walking pieces of ``piece`` entries."""
+    return patched(op, "_WALK_ENTRIES", piece)
+
+
+def walk_forms(b, row_ids, pieces=(8192, 16384, 32768, 65536)):
+    """Step 0 of ISSUE 47: ``sparse.take_by_slot`` / ``sum_by_slot`` whole and
+    walked at each piece, at the batch's own 319,488 real entries and, for
+    the line through zero, with the real count moved (the program is the
+    piece's; the trip count is read on the chip), then the whole of
+    ``ps.grad`` both ways at each piece."""
+    pieces = PIECES or pieces
+    local_ids, real_now = b["local_ids"], B * PER_ROW
+    rng = np.random.default_rng(SEED)
+    for lanes in (1, EMB):
+        shape = () if lanes == 1 else (lanes,)
+        x = jnp.asarray(rng.standard_normal((NNZ, *shape)).astype(np.float32))  # pads NOT 0: the op may read none
+        w = jnp.asarray(rng.standard_normal((U, *shape)).astype(np.float32))
+        for real in (real_now, 0, 131072, NNZ):
+            splits = jnp.minimum(b["row_splits"], real) if real < NNZ else b["row_splits"].at[-1].set(NNZ)
+            live = (np.arange(NNZ) < real).reshape(-1, *(1,) * len(shape))
+            taken = summed = None
+            for piece in (WHOLE, *pieces):
+                if real != real_now and piece not in (WHOLE, pieces[0], pieces[-1]):
+                    continue
+                tag = f"{'whole' if piece == WHOLE else f'walk{piece}'}_real{real}"
+                visited = int(pieced(sparse.walked_entries, piece)(real, NNZ))
+                got = emit(f"take_by_slot_{tag}", lanes, pieced(sparse.take_by_slot, piece), (w, local_ids, splits),
+                           taken, visited=visited)
+                taken = got if taken is None else taken
+                assert not bool(jnp.any(got * ~live)), "a pad's place must read 0"
+                got = emit(f"sum_by_slot_{tag}", lanes, pieced(lambda x, i, s: sparse.sum_by_slot(x, i, s, U), piece),
+                           (x, local_ids, splits), summed, visited=visited)
+                summed = got if summed is None else summed
+    pulled, mlp = grad_inputs(rng)
+    lin = wd = None
+    for piece in (WHOLE, *pieces):
+        tag = "whole" if piece == WHOLE else f"walk{piece}"
+        got = emit(f"linear_grad_{tag}", 1, pieced(spmd._linear_grad, piece), ({"": pulled["wide"]}, None, b, row_ids), lin)
+        lin = got if lin is None else lin
+        got = emit(f"wd_grad_{tag}", EMB, pieced(wide_deep._grad, piece), (pulled, mlp, b, row_ids), wd)
+        wd = got if wd is None else wd
+
+
+def grad_inputs(rng):
+    pulled = {"wide": jnp.asarray(rng.standard_normal((U, 1)).astype(np.float32) * 0.05),
+              "emb": jnp.asarray(rng.standard_normal((U, EMB)).astype(np.float32) * 0.05)}
+    return pulled, wide_deep.init_mlp(EMB, [1024, 512, 256], seed=SEED % 1000)
+
+
 b = bucket()
 row_ids = jax.jit(spmd._row_ids_of)(b)
+if "--walk" in sys.argv:
+    walk_forms(b, row_ids)
+    sys.exit(0)
 splits, local_ids = b["row_splits"], b["local_ids"]
 rng = np.random.default_rng(SEED)
 for lanes in (1, EMB):
@@ -182,11 +261,9 @@ for lanes in (1, EMB):
     if lanes > 1:
         emit("sum_by_example_unpinned", lanes, unpinned(sparse.sum_by_example), (x, row_ids, splits), summed)
 
-pulled = {"": jnp.asarray(rng.standard_normal((U, 1)).astype(np.float32) * 0.05)}
-today = emit("linear_grad_today", 1, linear_grad_today, (pulled, None, b, row_ids))
-emit("linear_grad", 1, spmd._linear_grad, (pulled, None, b, row_ids), today)
-pulled = {"wide": pulled[""], "emb": jnp.asarray(rng.standard_normal((U, EMB)).astype(np.float32) * 0.05)}
-mlp = wide_deep.init_mlp(EMB, [1024, 512, 256], seed=SEED % 1000)
+pulled, mlp = grad_inputs(rng)
+today = emit("linear_grad_today", 1, linear_grad_today, ({"": pulled["wide"]}, None, b, row_ids))
+emit("linear_grad", 1, spmd._linear_grad, ({"": pulled["wide"]}, None, b, row_ids), today)
 today = emit("wd_grad_today", EMB, wd_grad_today, (pulled, mlp, b, row_ids))
 emit("wd_grad", EMB, wide_deep._grad, (pulled, mlp, b, row_ids), today)
 emit("wd_grad_unpinned", EMB, unpinned(wide_deep._grad), (pulled, mlp, b, row_ids), today)
