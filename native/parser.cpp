@@ -787,15 +787,32 @@ static const float* const log1p_small = [] {
   return t;
 }();
 
+}  // extern "C": the criteo parser below is a template, which has no C linkage
+
 // criteo TSV: label \t 13 ints \t 26 hex cats. Missing fields skipped.
 // Integer column j -> key j, slot j+1, value sign*log1p(|x|);
-// categorical column j -> key hex id, slot j+14, value 1.0.
-int ps_parse_criteo(const char* buf, int64_t len,
-                    int64_t max_rows, int64_t max_nnz,
-                    float* labels, int64_t* row_splits,
-                    uint64_t* keys, float* vals, uint64_t* slots,
-                    int64_t* out_rows, int64_t* out_nnz, int64_t* err_line) {
-  (void)err_line;  // criteo skips malformed lines instead of erroring
+// categorical column j -> key hex id, slot j+14, value 1.0. With kFields
+// (``ps_parse_criteo_fields``) the 26 columns are tables of their own, one
+// behind the other in one id space behind the 13 integer columns' keys:
+// column j's key is 13 + off_j + id % field_rows[j], off_j the rows of the
+// columns before it (identity keying, +1 for the pad row, makes it a table
+// row); the hashed layout's loop is compiled without a word of this.
+template <bool kFields>
+static int parse_criteo(const char* buf, int64_t len,
+                        int64_t max_rows, int64_t max_nnz,
+                        float* labels, int64_t* row_splits,
+                        uint64_t* keys, float* vals, uint64_t* slots,
+                        int64_t* out_rows, int64_t* out_nnz,
+                        const uint64_t* field_rows) {
+  uint64_t field_first[26];  // key of column j's id 0
+  if (kFields) {
+    uint64_t first = 13;
+    for (int j = 0; j < 26; ++j) {
+      if (field_rows[j] == 0) return -2;
+      field_first[j] = first;
+      first += field_rows[j];
+    }
+  }
   const char* p = buf;
   const char* end = buf + len;
   const bool any_cr = chunk_has_cr(buf, len);
@@ -866,7 +883,9 @@ int ps_parse_criteo(const char* buf, int64_t len,
             ok = parse_hex64(fp, field_end, h) && fp == field_end;
           }
           if (ok) {
-            keys[nnz] = h;
+            keys[nnz] = kFields
+                ? field_first[col - 13] + h % field_rows[col - 13]
+                : h;
             vals[nnz] = 1.0f;
             slots[nnz] = static_cast<uint64_t>(col - 13 + 14);
             ++nnz;
@@ -888,6 +907,31 @@ int ps_parse_criteo(const char* buf, int64_t len,
   *out_rows = rows;
   *out_nnz = nnz;
   return 0;
+}
+
+extern "C" {
+
+int ps_parse_criteo(const char* buf, int64_t len,
+                    int64_t max_rows, int64_t max_nnz,
+                    float* labels, int64_t* row_splits,
+                    uint64_t* keys, float* vals, uint64_t* slots,
+                    int64_t* out_rows, int64_t* out_nnz, int64_t* err_line) {
+  (void)err_line;  // criteo skips malformed lines instead of erroring
+  return parse_criteo<false>(buf, len, max_rows, max_nnz, labels, row_splits,
+                             keys, vals, slots, out_rows, out_nnz, nullptr);
+}
+
+// "criteo:<26 table sizes>" (data.libsvm.split_format): the per-field
+// identity layout; a size of 0 is the only error (-2)
+int ps_parse_criteo_fields(const char* buf, int64_t len,
+                           int64_t max_rows, int64_t max_nnz,
+                           float* labels, int64_t* row_splits,
+                           uint64_t* keys, float* vals, uint64_t* slots,
+                           int64_t* out_rows, int64_t* out_nnz,
+                           int64_t* err_line, const uint64_t* field_rows) {
+  (void)err_line;
+  return parse_criteo<true>(buf, len, max_rows, max_nnz, labels, row_splits,
+                            keys, vals, slots, out_rows, out_nnz, field_rows);
 }
 
 // Hash + localize kernel (ref: src/app/linear_method/localizer.h — remap

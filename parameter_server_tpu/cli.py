@@ -469,7 +469,7 @@ def _build_parser() -> argparse.ArgumentParser:
 
 _KNOWN_APPS = (
     "linear_method", "graph_partition", "sketch", "matrix_fac", "word2vec",
-    "wide_deep",
+    "wide_deep", "dlrm",
 )
 
 
@@ -515,6 +515,8 @@ def run_train(cfg: PSConfig, args: argparse.Namespace) -> dict:
         return _run_train_w2v(cfg, args)
     if cfg.app == "wide_deep":
         return _run_train_wd(cfg, args)
+    if cfg.app == "dlrm":
+        return _run_train_dlrm(cfg, args)
     if cfg.solver.algo == "darlin":
         from parameter_server_tpu.data.blockcache import cached_column_blocks
         from parameter_server_tpu.models.darlin import Darlin
@@ -728,6 +730,34 @@ def _run_train_w2v(cfg: PSConfig, args: argparse.Namespace) -> dict:
         in_v, out_v = word2vec.vectors(trainer)
         np.savez(args.model_out, in_vectors=in_v, out_vectors=out_v)
         out["model_out"] = args.model_out
+    return out
+
+
+def _run_train_dlrm(cfg: PSConfig, args: argparse.Namespace) -> dict:
+    """dlrm app dispatch: the ``PodTrainer`` every app runs through, over
+    DLRM's description and ``criteo`` files in the per-field layout
+    (``dlrm.pod_config`` puts [dlrm]'s settings where the shared loop reads
+    them); checkpoint of the table and of both MLPs."""
+    from parameter_server_tpu.models import dlrm
+    from parameter_server_tpu.parallel.trainer import PodTrainer
+
+    trainer = PodTrainer(dlrm.pod_config(cfg))
+    if args.resume:
+        if not args.ckpt_dir:
+            raise SystemExit("--resume requires --ckpt_dir")
+        trainer.load(args.ckpt_dir)
+    out = dict(
+        trainer.train_files(cfg.data.files, report_every=args.report_interval)
+        or {}
+    )
+    out.update({"emb_dim": cfg.dlrm.emb_dim, "tables": len(cfg.dlrm.field_rows)})
+    if args.ckpt_dir:
+        trainer.save(args.ckpt_dir)
+    if cfg.data.val_files:
+        ev = trainer.evaluate_files(cfg.data.val_files)
+        out.update({f"val_{k}": v for k, v in ev.items()})
+    if args.model_out:
+        out["model_out"] = dlrm.dump_model(trainer, args.model_out)
     return out
 
 

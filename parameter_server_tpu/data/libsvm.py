@@ -46,14 +46,30 @@ def iter_libsvm(path: str | Path) -> Iterator[Row]:
             yield label, keys, vals, np.zeros(n, dtype=np.uint64)
 
 
-def iter_criteo(path: str | Path) -> Iterator[Row]:
+N_INT, N_CAT = 13, 26  # the criteo format's integer and categorical columns
+
+
+def iter_criteo(
+    path: str | Path, field_rows: tuple[int, ...] | None = None
+) -> Iterator[Row]:
     """Parse Criteo CTR TSV: label, 13 integer slots, 26 categorical slots.
 
     Ref: ParseCriteo in src/data/text_parser.cc. Integer slot j becomes key
     ``raw value`` in slot j+1; categorical slot j becomes its hex id in slot
     j+14 — the slot salt keeps columns decorrelated in the hashed space.
     Missing fields are skipped (reference behavior).
+
+    ``field_rows`` (the format "criteo:<26 sizes>", ``criteo_format``)
+    keeps a table a categorical column instead, the 26 one behind the
+    other in one id space behind the integer columns' 13 keys: column j's
+    key is ``13 + off_j + id mod field_rows[j]``, ``off_j`` the rows of the
+    columns before it, so that identity keying (+1 for the pad row) puts
+    integer column j at table row 1 + j and column j's value at row
+    ``14 + off_j + id mod field_rows[j]``: no two columns share a row.
     """
+    first = None
+    if field_rows is not None:
+        first = [N_INT + sum(field_rows[:j]) for j in range(N_CAT)]
     with _open(path) as f:
         for line in f:
             cols = line.rstrip("\n").split("\t")
@@ -78,10 +94,11 @@ def iter_criteo(path: str | Path) -> Iterator[Row]:
                     k = int(c, 16)
                 except ValueError:
                     continue
+                if first is not None:
+                    k = first[j] + k % field_rows[j]
                 keys.append(k)
                 vals.append(1.0)
                 slots.append(j + 14)
-            n = len(keys)
             yield (
                 label,
                 np.array(keys, dtype=np.uint64),
@@ -186,9 +203,13 @@ FORMATS = {"libsvm": iter_libsvm, "criteo": iter_criteo, "adfea": iter_adfea}
 # first id range behind the name, because the second range's keys lie
 # behind it: ``user item rating`` lines as "rating:<num_items>"
 # (``rating_format``), ``centre context negatives...`` lines as
-# "sgns:<vocab_size>" (``sgns_format``).
+# "sgns:<vocab_size>" (``sgns_format``). The criteo format becomes a third
+# when it is read with the 26 columns' table sizes behind its name,
+# "criteo:<rows of column 1>,...,<rows of column 26>" (``criteo_format``;
+# ``iter_criteo``'s per-field layout); bare, it is hashed as ever.
 RATING = "rating"
 SGNS = "sgns"
+CRITEO = "criteo"
 _SIZED = {RATING: ("num_items", iter_rating), SGNS: ("vocab_size", iter_sgns)}
 
 
@@ -200,10 +221,24 @@ def sgns_format(vocab_size: int) -> str:
     return f"{SGNS}:{int(vocab_size)}"
 
 
-def split_format(fmt: str) -> tuple[str, int | None]:
+def criteo_format(field_rows) -> str:
+    return f"{CRITEO}:" + ",".join(str(int(r)) for r in field_rows)
+
+
+def split_format(fmt: str) -> tuple[str, "int | tuple[int, ...] | None"]:
     """(format name, its parameter or None): ("rating", 39780) of
-    "rating:39780", ("criteo", None) of "criteo"."""
+    "rating:39780", ("criteo", None) of "criteo", ("criteo", (r_1, ...,
+    r_26)) of "criteo:r_1,...,r_26"."""
     name, _, arg = fmt.partition(":")
+    if name == CRITEO and arg:
+        sizes = arg.split(",")
+        if len(sizes) != N_CAT or not all(r.isdigit() and int(r) > 0 for r in sizes):
+            raise ValueError(
+                f"the per-field criteo format is read as '{CRITEO}:<{N_CAT} "
+                f"table sizes, comma-separated>' (data.libsvm.criteo_format), "
+                f"got {fmt!r}"
+            )
+        return name, tuple(int(r) for r in sizes)
     if name in _SIZED:
         if not arg.isdigit():
             raise ValueError(
@@ -218,6 +253,8 @@ def iter_format(fmt: str, path: str | Path) -> Iterator[Row]:
     name, arg = split_format(fmt)
     if name in _SIZED:
         return _SIZED[name][1](path, arg)
+    if name == CRITEO and arg is not None:
+        return iter_criteo(path, arg)
     if name not in FORMATS:
         raise ValueError(
             f"unknown data format {fmt!r}; known: {sorted([*FORMATS, *_SIZED])}"

@@ -49,6 +49,9 @@ NATIVE_FORMATS = {
     # "sgns:<vocab_size>", the same way
     "sgns": "ps_parse_sgns",
 }
+# "criteo:<26 table sizes>" (the per-field layout): the sizes as a
+# thirteenth argument, a pointer to 26 uint64
+_CRITEO_FIELDS = "ps_parse_criteo_fields"
 
 
 def has_native(fmt: str) -> bool:
@@ -136,7 +139,13 @@ def _load_native_locked() -> ctypes.CDLL | None:
         return None
     i64, u64p = ctypes.c_int64, ctypes.POINTER(ctypes.c_uint64)
     f32p, i64p = ctypes.POINTER(ctypes.c_float), ctypes.POINTER(ctypes.c_int64)
-    for name, fn in NATIVE_FORMATS.items():
+    # what a parser takes behind the twelve arguments they all share
+    sized = {
+        NATIVE_FORMATS["rating"]: ctypes.c_uint64,  # num_items
+        NATIVE_FORMATS["sgns"]: ctypes.c_uint64,  # vocab_size
+        _CRITEO_FIELDS: u64p,  # the 26 table sizes
+    }
+    for fn in [*NATIVE_FORMATS.values(), _CRITEO_FIELDS]:
         f = getattr(lib, fn, None)
         if f is None:
             continue  # older prebuilt artifact: _parse_region says so
@@ -147,9 +156,8 @@ def _load_native_locked() -> ctypes.CDLL | None:
             f32p, i64p,  # labels, row_splits
             u64p, f32p, u64p,  # keys, vals, slots
             i64p, i64p, i64p,  # out_rows, out_nnz, err_line
+            *([sized[fn]] if fn in sized else []),
         ]
-        if name in ("rating", "sgns"):
-            f.argtypes = [*f.argtypes, ctypes.c_uint64]  # num_items / vocab_size
     try:
         c4 = lib.ps_count4
         c4.restype = None
@@ -295,10 +303,16 @@ def _parse_region(fmt: str, ba: bytearray, length: int) -> FlatRows:
     fmt, arg = split_format(fmt)
     if fmt not in NATIVE_FORMATS:
         raise ValueError(f"native parser: unknown format {fmt!r}")
-    fn = getattr(lib, NATIVE_FORMATS[fmt], None)
+    fn_name = _CRITEO_FIELDS if isinstance(arg, tuple) else NATIVE_FORMATS[fmt]
+    fn = getattr(lib, fn_name, None)
     if fn is None:
-        raise RuntimeError(f"the native library has no {NATIVE_FORMATS[fmt]}")
-    extra = () if arg is None else (ctypes.c_uint64(arg),)
+        raise RuntimeError(f"the native library has no {fn_name}")
+    if arg is None:
+        extra = ()
+    elif isinstance(arg, tuple):
+        extra = ((ctypes.c_uint64 * len(arg))(*arg),)
+    else:
+        extra = (ctypes.c_uint64(arg),)
     rows_cap, nnz_cap = _counts(lib, fmt, ba, length)
     want_slots = fmt not in SLOTLESS_FORMATS
     buf_p = (ctypes.c_char * len(ba)).from_buffer(ba)

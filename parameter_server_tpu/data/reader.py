@@ -266,16 +266,22 @@ def ingest_of(cfg) -> tuple[str, str]:
     PSConfig's files. ``data.format`` decides both: ``rating`` and ``sgns``
     lines carry ids of one dense space in two ranges (items then users,
     ``[mf].num_items`` saying where the users' begin; input then output
-    vectors, ``[w2v].vocab_size`` apart), and are keyed by identity; every
-    other format carries features, hashed into ``data.num_keys``."""
+    vectors, ``[w2v].vocab_size`` apart), and are keyed by identity; so are
+    the lines of "criteo:<26 sizes>" (``criteo_format``), whose 26
+    categorical columns are 26 tables of those sizes in one id space; every
+    other format, the bare "criteo" among them, carries features, hashed
+    into ``data.num_keys``."""
     from parameter_server_tpu.data.libsvm import (
-        RATING, SGNS, rating_format, sgns_format,
+        CRITEO, RATING, SGNS, rating_format, sgns_format, split_format,
     )
 
     if cfg.data.format == RATING:
         return rating_format(cfg.mf.num_items), "identity"
     if cfg.data.format == SGNS:
         return sgns_format(cfg.w2v.vocab_size), "identity"
+    name, sizes = split_format(cfg.data.format)
+    if name == CRITEO and sizes is not None:
+        return cfg.data.format, "identity"
     return cfg.data.format, "hash"
 
 

@@ -1,0 +1,260 @@
+"""DLRM: per-field embedding tables in the KV store, two MLPs and the
+pairwise-dot interaction between them (Naumov et al., "Deep Learning
+Recommendation Model for Personalization and Recommendation Systems",
+arXiv:1906.00091, sections 2-3; the sizes MLPerf Training's recommendation
+benchmark ran it at on the Criteo 1TB click logs are the [dlrm] defaults).
+
+One example is a ``criteo`` line: 13 integer columns, the dense input
+``x`` (``sign(v) log(1 + |v|)``, which the parsers put in ``values``), and
+26 categorical columns, each with a table of its own:
+
+    z0 = MLP_bot(x)                     13 -> bot..., ReLU after every layer
+    e_f = E[off_f + c_f mod R_f]        one emb_dim-wide row a column f
+    T = [z0; e_1; ...; e_26]            (27, emb_dim)
+    p = (T T^t)[i, j] for i > j         the 351 pairs, row by row
+    logit = MLP_top([z0; p])            ReLU after every layer but the last
+
+under the logistic loss, summed over the minibatch, and plain SGD on both
+halves: the touched rows take the sum of the minibatch's gradients once
+(the store's push) and the MLPs one ``optax.sgd`` step on theirs.
+
+TPU re-expression: ONE table ``emb`` of ``vdim = emb_dim`` over one key
+space, as the reference's KV layer has one: row 0 is the pad, rows 1..13
+are the integer columns' (their entries carry the dense input; the rows are
+pulled with the rest, read by nothing and never move), and column f's
+table starts at row ``14 + off_f`` (``data.libsvm.iter_criteo``'s per-field
+layout, keyed by identity). An example's 39 entries carry their ROLES IN
+THEIR ORDER, as ``models.word2vec``'s do: entries 0..12 give ``x`` from
+``values``, entries 13..38 the 26 rows from ``local_ids``. An example with
+a field missing has fewer entries and no such order: the app's host check
+(``StepApp.check_batch``) refuses its batch.
+
+This module holds the model and its description (``dlrm_app``); the step
+is ``parallel.spmd``'s and the training loop ``PodTrainer``'s."""
+
+from __future__ import annotations
+
+import copy
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+
+from parameter_server_tpu.data.batch import CSRBatch
+from parameter_server_tpu.data.libsvm import N_CAT, N_INT, criteo_format
+from parameter_server_tpu.kv.store import hashed_unit, live_lanes
+from parameter_server_tpu.kv.updaters import Sgd, Updater
+from parameter_server_tpu.models import mlp
+from parameter_server_tpu.models.metrics import BINARY_SCORES
+from parameter_server_tpu.parallel.spmd import (
+    DenseGroup,
+    StepApp,
+    Table,
+    _sub_scope,
+    _values_of,
+)
+
+TABLE = "emb"  # state entry "emb.w", scopes "ps.pull/emb", "ps.push/*/emb"
+DENSE = "mlp"  # state entries "mlp.bot.0.W", ...; scopes "ps.grad/mlp/*"
+ENTRIES = N_INT + N_CAT  # an example's entries, the dense columns' first
+FIRST_FIELD_ROW = 1 + N_INT  # behind the pad row and the dense columns' rows
+# the phases of the dense half, nested under "ps.grad/mlp"
+MLP_SCOPES = ("bot", "interact", "top")
+
+
+def num_keys_of(field_rows) -> int:
+    """Rows of the one key space: the pad row, the 13 dense columns' rows
+    and the 26 tables."""
+    return FIRST_FIELD_ROW + int(sum(field_rows))
+
+
+def interaction_width(emb_dim: int) -> int:
+    """The top MLP's input: z0 and the pairs under the diagonal."""
+    vectors = 1 + N_CAT
+    return emb_dim + vectors * (vectors - 1) // 2
+
+
+def interact(z0: jax.Array, e: jax.Array) -> jax.Array:
+    """(B, d) and (B, F, d) -> (B, d + (F + 1) F / 2): ``z0`` beside the
+    dots of every pair of the F + 1 vectors ``[z0; e]``, the entries of
+    ``T T^t`` strictly under the diagonal, row by row. A row's entries are
+    a static slice: no gather, and the backward pass a pad."""
+    t = jnp.concatenate([z0[:, None, :], e], axis=1)
+    z = jnp.einsum("bid,bjd->bij", t, t, precision=jax.lax.Precision.HIGHEST)
+    pairs = [z[:, i, :i] for i in range(1, t.shape[1])]
+    return jnp.concatenate([z0, *pairs], axis=1)
+
+
+def _by_position(flat: jax.Array, examples: int) -> jax.Array:
+    """(NNZ,) -> (B, ENTRIES): entry j of example i. Every example carries
+    exactly ``ENTRIES`` entries (``check_batch`` holds the host to it) and
+    the real entries are the head of the entry axis, so example i's begin
+    at ``ENTRIES * i``: a slice and a reshape, where a take by
+    ``row_splits`` would gather 320,000 elements one by one (2.4 ms of the
+    step on the chip). A short bucket (a file's last, partial batch) is
+    zero-extended: its missing examples are masked."""
+    need = examples * ENTRIES
+    if flat.shape[0] < need:
+        flat = jnp.pad(flat, (0, need - flat.shape[0]))
+    return flat[:need].reshape(examples, ENTRIES)
+
+
+def _logits(pulled, params, b, row_ids):
+    """(B,) logits: the examples' entries by position. A padded example's
+    entries are zeros (the pad row, a dense input of 0); its loss is
+    masked."""
+    examples = b["labels"].shape[0]
+    x = _by_position(_values_of(b), examples)[:, :N_INT]
+    slots = _by_position(b["local_ids"], examples)[:, N_INT:]
+    e = jnp.take(pulled[TABLE], slots, axis=0)  # (B, 26, d)
+    with _sub_scope(DENSE):
+        with jax.named_scope("bot"):
+            z0 = mlp.mlp_apply(params["bot"], x, last=jax.nn.relu)
+        with jax.named_scope("interact"):
+            r = interact(z0, e)
+        with jax.named_scope("top"):
+            return mlp.mlp_apply(params["top"], r)[:, 0]
+
+
+def _loss(pulled, params, b, row_ids):
+    logits = _logits(pulled, params, b, row_ids)
+    m = b["example_mask"].astype(jnp.float32)
+    loss = jnp.sum(m * (jax.nn.softplus(logits) - b["labels"] * logits))
+    return loss, logits
+
+
+def _grad(pulled, params, b, row_ids):
+    """One differentiable forward; ``jax.grad`` gives the pulled rows'
+    gradient (the transpose of the take by ``local_ids``: a row's gradient
+    summed over the minibatch, zero for the rows no entry reads: the pad's
+    and the dense columns') and the MLPs'."""
+    (loss, logits), (g_pulled, g_mlp) = jax.value_and_grad(
+        _loss, argnums=(0, 1), has_aux=True
+    )(pulled, params, b, row_ids)
+    return loss, logits, g_pulled, g_mlp
+
+
+def check_batch(b: CSRBatch) -> None:
+    """On the host: every example of the batch carries its 39 entries, so
+    that an entry's position says its field. The criteo parsers skip an
+    empty or malformed field, and the example is then shorter."""
+    counts = np.diff(b.row_splits[: b.num_examples + 1])
+    if (counts != ENTRIES).any():
+        i = int(np.flatnonzero(counts != ENTRIES)[0])
+        raise ValueError(
+            f"app dlrm reads an example's {ENTRIES} entries by position "
+            f"({N_INT} dense columns, then {N_CAT} categorical fields): example "
+            f"{i} of the batch carries {int(counts[i])}, so a field of its line "
+            "is empty or malformed"
+        )
+
+
+def init_rows(seed: int, rows: jax.Array, emb_dim: int, field_rows, lanes: int | None = None):
+    """Starting rows of the table: column f's uniform in
+    +-sqrt(1 / R_f) as a hash of (seed, row, lane)
+    (``kv.store.hashed_unit`` times the column's bound: one rounding); the
+    pad row, the dense columns' rows and the rows past the last table
+    zero. The bound of a row is found by its table's first row: 26
+    compare-and-selects, fused into the one pass that makes the slot."""
+    bound = jnp.zeros(rows.shape, jnp.float32)
+    first = FIRST_FIELD_ROW
+    for r in field_rows:
+        bound = jnp.where(rows >= first, jnp.float32(np.sqrt(1.0 / r)), bound)
+        first += int(r)
+    bound = jnp.where(rows >= first, 0.0, bound)
+    keep = live_lanes(bound > 0, emb_dim, lanes)
+    return jnp.where(keep, hashed_unit(seed, rows, lanes or emb_dim) * bound[:, None], 0.0)
+
+
+def init_mlps(seed: int, emb_dim: int, bot: list[int], top: list[int]) -> dict:
+    """{"bot": layers 13 -> bot..., "top": layers (emb_dim + 351) ->
+    top...}, both drawn from one generator of ``seed``, bottom first."""
+    rng = np.random.default_rng(seed)
+    return {
+        "bot": mlp.init_mlp([N_INT, *bot], rng, mlp.xavier_normal),
+        "top": mlp.init_mlp([interaction_width(emb_dim), *top], rng, mlp.xavier_normal),
+    }
+
+
+def dlrm_app(updater: Updater, opt, emb_dim: int, mlp_init, emb_init=None) -> StepApp:
+    """The app's description for the shared parameter-server step: table
+    ``emb`` (``vdim`` ``emb_dim``) under ``updater``, the two MLPs as the
+    replicated dense group ``mlp`` under ``opt``. ``mlp_init()`` makes
+    ``{"bot": layers, "top": layers}``; ``emb_init(rows, lanes)`` the
+    table's starting ``{"w": ...}`` as the store keeps it (zeros without
+    it)."""
+    return StepApp(
+        tables=(Table(TABLE, updater, emb_dim, emb_init),),
+        grad=_grad,
+        logits=_logits,
+        dense=DenseGroup(DENSE, mlp_init, opt),
+        link=jax.nn.sigmoid,
+        score=BINARY_SCORES,
+        scopes=MLP_SCOPES,
+        check_batch=check_batch,
+    )
+
+
+def app_from_config(cfg) -> StepApp:
+    """The description from a PSConfig's [dlrm] section: plain SGD at
+    ``eta`` on the summed gradient for the table and for the MLPs, the rows
+    and the MLPs started from ``cfg.seed``. The files are ``criteo`` lines
+    in the per-field layout and the key space is the pad row, the dense
+    columns' rows and the 26 tables (``pod_config`` sets both)."""
+    d = cfg.dlrm
+    rows_of = tuple(int(r) for r in d.field_rows)
+    if len(rows_of) != N_CAT or min(rows_of, default=0) < 1:
+        raise ValueError(
+            f"app dlrm keeps a table a categorical column: dlrm.field_rows "
+            f"names {N_CAT} sizes of at least 1, got {list(d.field_rows)}"
+        )
+    if cfg.data.format != criteo_format(rows_of) or cfg.data.num_keys != num_keys_of(rows_of):
+        raise ValueError(
+            f"app dlrm reads data.format {criteo_format(rows_of)!r} into data.num_keys = 1 + "
+            f"{N_INT} + sum(dlrm.field_rows) = {num_keys_of(rows_of)} rows; the "
+            f"config says {cfg.data.format!r} and {cfg.data.num_keys} "
+            "(models.dlrm.pod_config fills both in)"
+        )
+    if cfg.data.max_nnz_per_example < ENTRIES:
+        raise ValueError(
+            f"an example carries {ENTRIES} entries; data.max_nnz_per_example "
+            f"is {cfg.data.max_nnz_per_example}"
+        )
+    if not d.bot or d.bot[-1] != d.emb_dim or not d.top or d.top[-1] != 1:
+        raise ValueError(
+            f"the bottom MLP ends {d.emb_dim} wide (dlrm.emb_dim: its output "
+            f"is one of the interaction's vectors) and the top MLP in one "
+            f"logit; got bot {list(d.bot)}, top {list(d.top)}"
+        )
+    return dlrm_app(
+        Sgd(eta=d.eta), optax.sgd(d.eta), d.emb_dim,
+        mlp_init=lambda: init_mlps(cfg.seed, d.emb_dim, list(d.bot), list(d.top)),
+        emb_init=lambda rows, lanes: {"w": init_rows(
+            cfg.seed, jnp.arange(rows, dtype=jnp.int32), d.emb_dim, rows_of, lanes
+        )},
+    )
+
+
+def pod_config(cfg):
+    """A copy of ``cfg`` with [dlrm]'s settings where the shared loop reads
+    them: ``criteo`` files in the per-field layout (the format names the
+    26 sizes, and ``data.reader.ingest_of`` keys it by identity), the key
+    space's size."""
+    cfg = copy.deepcopy(cfg)
+    cfg.app = "dlrm"
+    cfg.data.format = criteo_format(cfg.dlrm.field_rows)
+    cfg.data.num_keys = num_keys_of(cfg.dlrm.field_rows)
+    return cfg
+
+
+def dump_model(trainer, path: str) -> str:
+    """Inference weights as one npz: the table (``emb_w``, by table row)
+    and the MLPs' layers (``bot_W0``, ``bot_b0``, ..., ``top_W0``, ...)."""
+    host = {"emb_w": trainer.full_weights(TABLE)}
+    for name, layers in trainer.dense()[0].items():
+        for i, layer in enumerate(layers):
+            host[f"{name}_W{i}"] = np.asarray(layer["W"])
+            host[f"{name}_b{i}"] = np.asarray(layer["b"])
+    np.savez(path, **host)
+    return path

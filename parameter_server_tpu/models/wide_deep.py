@@ -31,6 +31,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 from parameter_server_tpu.data.batch import CSRBatch
 from parameter_server_tpu.kv.store import State, hashed_uniform
 from parameter_server_tpu.kv.updaters import Adagrad, Ftrl, Updater
+from parameter_server_tpu.models import mlp
 from parameter_server_tpu.models.metrics import BINARY_SCORES
 from parameter_server_tpu.ops.sparse import csr_logits, sum_by_example, take_by_slot
 from parameter_server_tpu.parallel.spmd import (
@@ -44,31 +45,13 @@ from parameter_server_tpu.utils.metrics import ProgressReporter
 
 
 def init_mlp(dim: int, hidden: list[int], seed: int = 0) -> list[dict[str, Any]]:
-    rng = np.random.default_rng(seed)
-    sizes = [dim, *hidden, 1]
-    params = []
-    for fan_in, fan_out in zip(sizes, sizes[1:]):
-        params.append(
-            {
-                "W": jnp.asarray(
-                    rng.normal(scale=np.sqrt(2.0 / fan_in), size=(fan_in, fan_out)),
-                    dtype=jnp.float32,
-                ),
-                "b": jnp.zeros(fan_out, dtype=jnp.float32),
-            }
-        )
-    return params
+    """The tower ``dim -> hidden... -> 1``, He-normal from ``seed``."""
+    return mlp.init_mlp([dim, *hidden, 1], seed)
 
 
 def _mlp_apply(params, x):
-    """The tower: ReLU layers, one logit out. Its matmuls run at float32
-    (``Precision.HIGHEST``): the TPU's default would round the operands to
-    bfloat16, and the app states float32 throughout."""
-    hi = jax.lax.Precision.HIGHEST
-    for layer in params[:-1]:
-        x = jax.nn.relu(jnp.dot(x, layer["W"], precision=hi) + layer["b"])
-    last = params[-1]
-    return (jnp.dot(x, last["W"], precision=hi) + last["b"])[:, 0]
+    """The tower: ReLU layers, one logit out (``models.mlp``)."""
+    return mlp.mlp_apply(params, x)[:, 0]
 
 
 def _logits(pulled, mlp_params, b, row_ids):

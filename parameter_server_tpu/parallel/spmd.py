@@ -66,8 +66,10 @@ Batch = dict[str, jax.Array]
 # updater), "update" (``updater.delta``), "scatter" (the scatter-add).
 # An app of several tables names the table innermost ("ps.pull/emb",
 # "ps.push/scatter/emb"), so a reader of "ps.pull" sums over tables; its
-# dense group's forward and backward lie under "ps.grad/<group>" and the
-# group's ``psum`` and optimizer step under "ps.dense".
+# dense group's forward and backward lie under "ps.grad/<group>" (and, of
+# an app that names them, its phases beneath: "ps.grad/mlp/interact";
+# ``StepApp.scopes``) and the group's ``psum`` and optimizer step under
+# "ps.dense".
 # The step's two collectives have scopes of their own, innermost, beneath
 # the table's name: the pull's ``psum`` over "kv" under "ps.pull/<table>/psum"
 # and the push's ``all_gather`` over "data" under
@@ -339,7 +341,13 @@ class StepApp:
 
     The state of an app is one flat ``{name: array}`` dict: each table's
     slots (range-sharded over "kv") and the dense group's leaves
-    (replicated)."""
+    (replicated).
+
+    ``scopes``: names of ``jax.named_scope``s that ``grad`` and ``logits``
+    nest under the dense group's, the phases of a dense half that is more
+    than one ("ps.grad/mlp/interact"). ``check_batch(batch)``: the app's
+    check of a host ``CSRBatch`` before it is stacked, raising on one its
+    model cannot read (the device cannot refuse a batch)."""
 
     tables: tuple[Table, ...]
     grad: Callable
@@ -347,6 +355,8 @@ class StepApp:
     dense: DenseGroup | None = None
     link: Callable = dataclasses.field(kw_only=True)
     score: tuple[tuple[str, Callable], ...] = dataclasses.field(kw_only=True)
+    scopes: tuple[str, ...] = dataclasses.field(default=(), kw_only=True)
+    check_batch: Callable | None = dataclasses.field(default=None, kw_only=True)
 
     def table(self, name: str) -> Table:
         (t,) = [t for t in self.tables if t.name == name]
@@ -354,9 +364,9 @@ class StepApp:
 
     def scope_names(self) -> frozenset:
         """The names this app's programs nest under a phase scope: its
-        named tables and its dense group (what ``hlo_scopes`` keeps of an
-        op_name besides the push's stages)."""
-        names = {t.name for t in self.tables if t.name}
+        named tables, its dense group and ``scopes`` (what ``hlo_scopes``
+        keeps of an op_name besides the push's stages)."""
+        names = {t.name for t in self.tables if t.name} | set(self.scopes)
         if self.dense is not None:
             names.add(self.dense.name)
         return frozenset(names)

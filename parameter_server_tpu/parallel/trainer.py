@@ -165,6 +165,10 @@ def app_from_config(cfg: PSConfig) -> StepApp:
         from parameter_server_tpu.models import word2vec
 
         return word2vec.app_from_config(cfg)
+    if cfg.app == "dlrm":
+        from parameter_server_tpu.models import dlrm
+
+        return dlrm.app_from_config(cfg)
     return linear_app(updater_from_config(cfg))
 
 
@@ -192,9 +196,8 @@ class _EpochStream(_WorkerStream):
 
 class PodTrainer:
     """Train ``cfg.app`` (the flagship sparse-LR app, Wide&Deep, matrix
-    factorization or skip-gram) across a data x kv device mesh: state, step,
-    predict,
-    scores and checkpoint all come from the app's description
+    factorization, skip-gram or DLRM) across a data x kv device mesh: state,
+    step, predict, scores and checkpoint all come from the app's description
     (``app_from_config``; ``app`` overrides it), the files' format and key
     mode from ``cfg.data.format`` (``data.reader.ingest_of``)."""
 
@@ -529,6 +532,7 @@ class PodTrainer:
         # the step's push tells XLA its rows ascend (spmd._local_push): on
         # the chip a batch out of order is undefined behaviour, not an error
         assert all(b.keys_in_order() for b in batches), "unique_keys out of order"
+        self._check(batches)
         padded = pad_group(batches)
         if trace.enabled():
             # beside pad_group's feed.unique_fill: what a sweep by key slot
@@ -552,6 +556,13 @@ class PodTrainer:
         labels = np.concatenate([b.labels[: b.num_examples] for b in batches])
         counts = [b.num_examples for b in batches]
         return stacked, n, labels, counts
+
+    def _check(self, batches: list[CSRBatch]) -> None:
+        """The app's own check of host batches it is about to be handed
+        (``StepApp.check_batch``), on the thread that stacks them."""
+        if self.app.check_batch is not None:
+            for b in batches:
+                self.app.check_batch(b)
 
     def _agree_bucket(self, stacked: dict, tag: str) -> dict:
         """Pod-wide bucket agreement for bucketed batches: max-reduce
@@ -972,6 +983,7 @@ class PodTrainer:
             # fill every data shard with real batches (D at a time); only
             # the tail group pads with inert batches
             with trace.phase("eval.stack"):
+                self._check(group)
                 batches = pad_group(
                     group + [pad() for _ in range(self.data_shards - len(group))]
                 )

@@ -155,6 +155,30 @@ class WDConfig:
 
 
 @dataclass
+class DLRMConfig:
+    """dlrm app settings (Naumov et al., arXiv:1906.00091; the MLPerf
+    recommendation benchmark's flags where they have one:
+    --arch-sparse-feature-size, --arch-mlp-bot, --arch-mlp-top,
+    --learning-rate a summed example). data.files = criteo text, read in the
+    per-field layout (``models.dlrm.pod_config`` sets data.format and
+    data.num_keys = 1 + 13 + sum(field_rows) for PodTrainer)."""
+
+    emb_dim: int = 128
+    # the bottom MLP's layers behind the 13 dense columns; its last is emb_dim
+    bot: list[int] = field(default_factory=lambda: [512, 256, 128])
+    # the top MLP's layers behind the interaction's output; its last is 1
+    top: list[int] = field(default_factory=lambda: [1024, 1024, 512, 256, 1])
+    # plain SGD on the summed gradient, both halves, constant. MLPerf's 24.0
+    # on the mean of 55,296 is 4.34e-4 a summed example BEHIND a warm-up:
+    # held constant from the first step it diverges at these widths inside
+    # the first call, and 1e-4 wrecked one seed of thirty-one within 1,200
+    # steps; 5e-5 held there, and the default is half of that
+    eta: float = 2.5e-5
+    # rows of each categorical column's table: min(cardinality, max_ind_range)
+    field_rows: list[int] = field(default_factory=list)
+
+
+@dataclass
 class SketchConfig:
     """sketch app settings (ref: the sketch App — distributed count-min)."""
 
@@ -557,6 +581,7 @@ class PSConfig:
     mf: MFConfig = field(default_factory=MFConfig)
     w2v: W2VConfig = field(default_factory=W2VConfig)
     wd: WDConfig = field(default_factory=WDConfig)
+    dlrm: DLRMConfig = field(default_factory=DLRMConfig)
     parallel: ParallelConfig = field(default_factory=ParallelConfig)
     mesh: MeshConfig = field(default_factory=MeshConfig)
     wire: WireConfig = field(default_factory=WireConfig)
@@ -608,6 +633,7 @@ _NESTED = {
     "mf": MFConfig,
     "w2v": W2VConfig,
     "wd": WDConfig,
+    "dlrm": DLRMConfig,
     "parallel": ParallelConfig,
     "mesh": MeshConfig,
     "wire": WireConfig,

@@ -887,3 +887,161 @@ def test_darlin_programs_hold_the_entries_once(darlin_compiled):
     for name, (_, mem) in darlin_compiled.items():
         assert mem.temp_size_in_bytes < 1 << 30, (name, mem.temp_size_in_bytes)
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8 << 30, name
+
+
+# -- DLRM: 26 per-field tables as one 128-lane table, and a dense half that costs --------
+DLRM_CARDS = [
+    39884406, 39043, 17289, 7420, 20263, 3, 7120, 1543, 63, 38532951, 2953546, 403346, 10,
+    2208, 11938, 155, 4, 976, 14, 39979771, 25641295, 39664984, 585935, 12972, 108, 36,
+]
+DLRM_FIELD_ROWS = [min(c, 4_000_000) for c in DLRM_CARDS]  # the cell dlrm1tb.train: max_ind_range 4,000,000
+DLRM_DIM = 128
+DLRM_CASES = [(1, 1, "multistep"), (1, 1, "predict")]
+DLRM_NAMES = frozenset({"emb", "mlp", "bot", "interact", "top"})  # the app's StepApp.scope_names()
+
+
+@pytest.fixture(scope="module")
+def dlrm_text(topo):
+    """(data, kv, program) -> optimised HLO text of the DLRM programs at the
+    cell's size (24,065,024 rows x 128 lanes under SGD, MLPs 13-512-256-128
+    and 479-1024-1024-512-256-1, ``ctr1.train``'s batch shapes), compiled
+    once; the 2x2 and 1x4 programs are the small tests'
+    (``tests/test_dlrm_pod.py``)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding
+
+    from parameter_server_tpu.models import dlrm
+    from parameter_server_tpu.parallel import spmd
+    from parameter_server_tpu.utils.config import PSConfig
+
+    texts: dict = {}
+
+    def get(data: int, kv: int, program: str) -> str:
+        key = (data, kv, program)
+        if key in texts:
+            return texts[key]
+        cfg = PSConfig()
+        cfg.dlrm.field_rows = DLRM_FIELD_ROWS
+        cfg = dlrm.pod_config(cfg)
+        app = dlrm.app_from_config(cfg)
+        assert app.scope_names() == DLRM_NAMES
+        mesh = Mesh(np.array(topo.devices[: data * kv]).reshape(data, kv), ("data", "kv"))
+        specs = app.specs()
+        rows = spmd.padded_num_keys(cfg.data.num_keys, kv)
+        shapes = jax.eval_shape(lambda: {**app.init_tables(rows), **app.dense.init_state()})
+        state = {
+            k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=NamedSharding(mesh, specs[k]))
+            for k, v in shapes.items()
+        }
+        feed = NamedSharding(mesh, spmd.batch_spec())
+        lead = (K,) if program == "multistep" else ()
+        fields = {
+            "unique_keys": ((UNIQUE,), jnp.int32), "local_ids": ((NNZ,), jnp.int32),
+            "row_splits": ((MINIBATCH + 1,), jnp.int32), "values": ((NNZ,), jnp.float32),
+            "labels": ((MINIBATCH,), jnp.float32), "example_mask": ((MINIBATCH,), jnp.bool_),
+        }
+        batch = {
+            k: jax.ShapeDtypeStruct((data, *lead, *shape), dt, sharding=feed)
+            for k, (shape, dt) in fields.items()
+        }
+        if program == "multistep":
+            fn, args = spmd.make_spmd_train_multistep(app, mesh, cfg.data.num_keys), (state, batch, 0)
+        else:
+            fn, args = spmd.make_spmd_predict_step(app, mesh, cfg.data.num_keys), (state, batch)
+        (jitted,) = [
+            c.cell_contents for c in fn.__closure__
+            if callable(c.cell_contents) and hasattr(c.cell_contents, "lower")
+        ]
+        compiled = jitted.lower(*args).compile()
+        texts[key] = compiled.as_text()
+        texts[key, "memory"] = compiled.memory_analysis()
+        if program == "multistep":  # the table made on the device in one pass
+            table = NamedSharding(mesh, spmd.state_spec())
+            made = jax.jit(lambda: app.init_tables(rows), out_shardings=table).lower().compile()
+            texts["init", "memory"] = made.memory_analysis()
+        return texts[key]
+
+    get.texts = texts
+    return get
+
+
+@pytest.mark.parametrize("data,kv,program", DLRM_CASES)
+def test_dlrm_holds_one_table_and_names_its_dense_phases(dlrm_text, data, kv, program):
+    """The cell ``dlrm1tb.train`` at its shapes: 24,065,024 rows of 128
+    lanes, 11.475 GiB of the chip's 15.75. Every executed instruction that
+    reads or writes the table sits under ``ps.pull/emb`` or
+    ``ps.push/<stage>/emb``; the table is held once, row-major, with no copy
+    of it among the temporaries (the take by ``local_ids``, its transpose
+    and the interaction's (8192, 27, 27) are batch-sized: bounded below);
+    the scatter is unhinted and in place (46,998 table elements a slot:
+    ``store.scatter_rows_sorted`` says taking the slots in turn is the
+    cheaper) and walked (``store.scatter_walks``); and the dense half's
+    three phases carry their names under ``ps.grad/mlp``, its step
+    ``ps.dense``."""
+    from parameter_server_tpu.kv import store
+    from parameter_server_tpu.parallel import spmd
+
+    text = dlrm_text(data, kv, program)
+    mem = dlrm_text.texts[(data, kv, program), "memory"]
+    _, scopes = spmd.hlo_scopes(text, DLRM_NAMES)
+    rows = spmd.padded_num_keys(14 + sum(DLRM_FIELD_ROWS), kv) // kv
+    assert (14 + sum(DLRM_FIELD_ROWS), rows) == (24_064_006, 24_065_024)
+    table_bytes = 4 * rows * DLRM_DIM
+    assert table_bytes == 12_321_292_288  # 11.475 GiB
+    table = re.compile(rf"\[{rows},{DLRM_DIM}\]")
+    touching = [
+        (name, scopes[name])
+        for name, shape, opcode, operand_shapes in executed(text)
+        if table.search(shape) or any(table.search(s) for s in operand_shapes)
+    ]
+    assert touching
+    assert all(re.match(r"^ps\.(pull|push/\w+)/emb$", scope) for _, scope in touching), touching
+    found = set(scopes.values())
+    assert {"ps.pull/emb", "ps.grad", "ps.grad/mlp/bot", "ps.grad/mlp/interact", "ps.grad/mlp/top"} <= found, found
+    every = instructions(text)
+    assert not copies_of(every, rows * DLRM_DIM)
+    assert all("{1,0:" in shape for _, _, shape, _, _, _ in every if table.search(shape) and shape.startswith("f32")), "rows-minor"
+    # the step's temporaries are the batch's: 8192 x 26 rows of 128 lanes each way (109 MB), the
+    # pulled rows and their gradient (2 x 34 MB), the MLPs' activations; never a second table
+    assert mem.temp_size_in_bytes < (1 << 30), mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < table_bytes + (1280 << 20)
+    if program == "predict":
+        return
+    assert {"ps.push/scatter/emb", "ps.push/update/emb", "ps.dense"} <= found, found
+    scatters = [(comp, rest) for comp, _, shape, opcode, _, rest in every if opcode == "scatter" and table.search(shape)]
+    assert len(scatters) == 1, scatters
+    ((home, told),) = scatters
+    assert store.scatter_walks(rows, DLRM_DIM, UNIQUE)
+    assert sorted_hint(told) is store.scatter_rows_sorted(rows, DLRM_DIM, store._WALK_SLOTS) is False, told
+    ((name, rest),) = fusions_calling(every, {home})
+    assert scopes[name] == "ps.push/scatter/emb", (name, scopes[name])
+    assert aliases_operand_0(rest), (name, rest[-300:])
+    assert mem.alias_size_in_bytes >= table_bytes  # the table is donated through the call
+    made = dlrm_text.texts["init", "memory"]
+    assert made.output_size_in_bytes == table_bytes
+    assert made.temp_size_in_bytes < 512 << 20, made.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("data,kv,program", DLRM_CASES)
+def test_dlrm_every_large_op_is_under_a_scope(dlrm_text, data, kv, program):
+    """The list of unscoped kinds holds for the sixth app, and nothing the
+    size of a batch's gathered rows runs outside a ``ps.*`` scope."""
+    from parameter_server_tpu.parallel import spmd
+
+    text = dlrm_text(data, kv, program)
+    _, scopes = spmd.hlo_scopes(text, DLRM_NAMES)
+    known = re.compile(
+        r"^(copy|copy-start|copy-done|custom-call|slice-start|slice-done|async-start|async-done"
+        r"|reduce|broadcast|dynamic-update-slice|all-reduce|fusion)$"
+    )
+    strays = [
+        (name, opcode, shape)
+        for name, shape, opcode, _ in executed(text)
+        if not scopes[name] and elements(shape) >= UNIQUE and not known.match(opcode)
+    ]
+    assert not strays, strays
+    for name, shape, opcode, operand_shapes in executed(text):
+        if opcode == "fusion" and not scopes[name]:
+            # bookkeeping at batch size (a (U, 128) buffer at most), never a table op
+            assert elements(shape) <= DLRM_DIM * UNIQUE and all(elements(s) < 10**7 for s in operand_shapes), name

@@ -11,6 +11,9 @@
 # keeps the traced run's .xplane.pb just long enough for tools/host_gaps.py
 # to read it into the log's .gaps.txt. CELL_TIMEOUT=<seconds> in the
 # environment ends a run that hangs (a parent tried on a cell it cannot run).
+# A parent that cannot run a new cell is shown to fail cleanly by side O:
+# O:dlrm1tb.train:<seed>:0 on PR 49's parent exits 1 in under a second of its
+# own clock (the app finds no models.dlrm), with no table made.
 tag=$1; shift
 root=$(cd "$(dirname "$0")/.." && pwd)
 out=$root/chiprun_out/cells; mkdir -p "$out"
