@@ -3,28 +3,39 @@
 Reference analog: learner/sgd.h MinibatchReader (parser thread feeding a
 threadsafe queue) + data/stream_reader.h (multi-file, gz-aware streaming).
 
-Every iteration of a ``MinibatchReader`` starts one producer thread, the
-reader's thread, which parses. Under ``iter(reader)`` it also runs the
-``BatchBuilder``, and whoever iterates only takes finished batches off a
-queue (evaluation). Under ``reader.parsed()`` it hands over each batch as
-parsed and the caller finishes it with ``reader.build(piece)`` on its own
-thread (training: ``_WorkerStream.next_batch`` on the pipeline's producer
-thread), so that a stream's parse and build run side by side. Named phases
-(``trace.phase``; one a chunk or a batch, never one a row):
-``reader.parse`` one step of ``iter_chunks`` (read + native parse of a 2 MiB
-chunk, and its merge with the rows the chunk before left over; count:
-chunks), ``reader.build`` one ``BatchBuilder.build_flat`` / ``build`` (hash,
-unique, localize, bucket; count: batches; on whichever thread builds),
-``reader.put_wait`` the reader's thread blocked on its full queue (its
-slack: near 0 means this thread sets the pace). The Python parsers yield a
-row at a time, so that path has no ``reader.parse``.
+A batch has two stages, the parse and the ``BatchBuilder`` call, and every
+iteration of a ``MinibatchReader`` runs them side by side on two threads.
+Its own parse thread (``ps-reader-parse``) parses, up to ``prefetch``
+batches ahead. Under ``iter(reader)`` a second thread of the reader's own
+(``ps-reader-build``) takes the parsed pieces in the order the parse made
+them, builds them and runs up to ``prefetch`` batches ahead in its turn;
+whoever iterates only takes finished batches off a queue (evaluation,
+validation, the apps' reference batches). Under ``reader.parsed()`` the
+caller is the build stage: it takes each piece as parsed and finishes it
+with ``reader.build(piece)`` on its own thread (training:
+``_WorkerStream.next_batch`` on the pipeline's producer thread). Both
+threads end with the iteration, exhausted or abandoned.
+
+Named phases (``trace.phase``; one a chunk or a batch, never one a row), each
+on the thread that does the work: ``reader.parse`` one step of
+``iter_chunks`` (read + native parse of a 2 MiB chunk, and its merge with
+the rows the chunk before left over; count: chunks), ``reader.build`` one
+``BatchBuilder.build_flat`` / ``build`` (hash, unique, localize, bucket;
+count: batches), ``reader.parsed_wait`` the build thread's wait for a
+parsed piece (count: pieces), ``reader.put_wait`` a stage blocked on its
+full queue (its slack). Which stage paces an ``iter(reader)``: a build
+thread in ``reader.parsed_wait`` says the parse, a parse thread in
+``reader.put_wait`` the build, a build thread in ``reader.put_wait`` the
+caller. The Python parsers yield a row at a time, so that path has no
+``reader.parse``.
 """
 
 from __future__ import annotations
 
 import queue
+import sys
 import threading
-from collections.abc import Iterator
+from collections.abc import Generator, Iterator
 from pathlib import Path
 
 import numpy as np
@@ -35,12 +46,18 @@ from parameter_server_tpu.utils import trace
 
 
 class MinibatchReader:
-    """Streams CSRBatches from text files through a prefetch thread: the
-    reader's thread runs up to ``prefetch`` batches ahead of whoever
-    iterates, parsing and building them (``iter(reader)``: the caller's
-    thread in ``evaluate_files``) or parsing alone (``reader.parsed()``, the
-    caller building: a training stream's ``next_batch``), and carries the
-    ``reader.*`` phases of the module's docstring.
+    """Streams CSRBatches from text files through two prefetch stages: the
+    parse thread runs up to ``prefetch`` parsed batches ahead of the build,
+    which under ``iter(reader)`` is a second thread of the reader's, itself
+    up to ``prefetch`` finished batches ahead of whoever iterates (the
+    caller's thread in ``evaluate_files`` only waits), and under
+    ``reader.parsed()`` the caller (a training stream's ``next_batch``).
+    Both carry the ``reader.*`` phases of the module's docstring.
+
+    The two depths are the same ``prefetch``, one queue each: the parse can
+    run a file to its last batch while the build holds a piece and the
+    caller a group of ``data_shards`` batches. At most ``2 * prefetch + 2``
+    batches are alive between the stages, a few MB each at 8192 x 39 entries.
 
     ``epochs`` and ``drop_remainder`` control the stream; a worker id /
     num_workers pair shards *files* across workers the way the reference's
@@ -81,7 +98,7 @@ class MinibatchReader:
 
     def build(self, piece: tuple) -> CSRBatch:
         """One of ``_pieces``' parsed batches through its ``BatchBuilder``
-        call, timed where it happens: the reader's thread under
+        call, timed where it happens: the reader's build thread under
         ``__iter__``, the caller's under ``parsed``."""
         build, rows = piece
         with trace.phase("reader.build", examples=len(rows[0])):
@@ -202,51 +219,67 @@ class MinibatchReader:
                 yield self.builder.build, (np.array(labels), keys, vals, slots)
 
     def __iter__(self) -> Iterator[CSRBatch]:
-        """Finished batches: parse and build both on the reader's thread."""
-        return self._ahead(map(self.build, self._pieces()))
+        """Finished batches in file order: the pieces of ``parsed()`` built
+        one after the other on the reader's build thread."""
+        return self._ahead(self._built(), "ps-reader-build")
+
+    def _built(self) -> Iterator[CSRBatch]:
+        pieces = self.parsed()
+        try:
+            while True:
+                with trace.phase("reader.parsed_wait") as wait:
+                    piece = next(pieces, None)
+                    if piece is None:
+                        wait.count = 0  # the probe that finds the stream at its end
+                        return
+                yield self.build(piece)
+        finally:
+            pieces.close()  # a build that leaves, or fails, takes the parse with it
 
     def parsed(self) -> Iterator[tuple]:
-        """The batches as the reader's thread parsed them, for the caller
-        to ``build`` on its own thread, in the order it takes them (a
+        """The batches as the parse thread parsed them, for the caller to
+        ``build`` on its own thread, in the order it takes them (a
         ``BatchBuilder`` with a frequency filter counts in that order): a
         stream's parse and build then run side by side."""
-        return self._ahead(self._pieces())
+        return self._ahead(self._pieces(), "ps-reader-parse")
 
-    def _ahead(self, source: Iterator) -> Iterator:
-        """``source`` run on a new thread, the reader's, up to ``prefetch``
-        items ahead of whoever iterates."""
+    def _ahead(self, source: Generator, name: str) -> Iterator:
+        """``source`` run on a new thread ``name``, up to ``prefetch`` items
+        ahead of whoever iterates. An exception of ``source`` is raised
+        here, behind the items made before it. The thread ends with the
+        iteration: closing this generator (or dropping it) lets a blocked
+        thread go, has it close ``source`` and joins it."""
         q: queue.Queue = queue.Queue(maxsize=self.prefetch)
         _END = object()
         err: list[BaseException] = []
         stop = threading.Event()
 
         def _put(item) -> bool:
+            """False once whoever iterates has left."""
             try:
                 q.put_nowait(item)
-                return True
             except queue.Full:
-                pass
-            # the reader's slack: this thread is ahead of whoever iterates
-            with trace.phase("reader.put_wait"):
-                while not stop.is_set():
-                    try:
-                        q.put(item, timeout=0.1)
-                        return True
-                    except queue.Full:
-                        continue
-            return False
+                if stop.is_set():
+                    return False
+                # this stage's slack: it is ahead of whoever takes its items
+                with trace.phase("reader.put_wait"):
+                    q.put(item)  # whoever sets ``stop`` empties the queue behind it
+            return not stop.is_set()
 
         def produce() -> None:
             try:
-                for b in source:
-                    if not _put(b):
-                        return  # consumer abandoned iteration
+                try:
+                    for item in source:
+                        if not _put(item):
+                            return
+                finally:
+                    source.close()  # on this thread, the one that ran it
             except BaseException as e:  # surfaced on the consumer side
                 err.append(e)
             finally:
                 _put(_END)
 
-        t = threading.Thread(target=produce, daemon=True)
+        t = threading.Thread(target=produce, name=name, daemon=True)
         t.start()
         try:
             while True:
@@ -257,8 +290,13 @@ class MinibatchReader:
                     return
                 yield item
         finally:
-            # unstick the producer if the consumer broke out early
             stop.set()
+            while not q.empty():  # room for a put that is blocked
+                q.get_nowait()
+            # a generator dropped at interpreter exit finds its daemon
+            # thread frozen: nothing to wait for
+            if not sys.is_finalizing():
+                t.join()
 
 
 def ingest_of(cfg) -> tuple[str, str]:

@@ -881,17 +881,18 @@ class PodTrainer:
         """Pod-wide batch evaluation using the predict step on shard 0's
         stream layout (eval is read-only; one worker suffices).
 
-        Two threads: the ``MinibatchReader``'s own thread parses and builds
-        up to four batches ahead (``reader.parse`` / ``reader.build`` /
+        Three threads: the ``MinibatchReader``'s parse thread and its build
+        thread run side by side, each up to four batches ahead of the next
+        (``reader.parse`` / ``reader.build`` / ``reader.parsed_wait`` /
         ``reader.put_wait``, see ``data/reader.py``); the caller's thread
-        takes them off its queue, pads, stacks, ships and enqueues them,
-        retires results and scores.
+        takes finished batches off the build's queue, pads, stacks, ships
+        and enqueues them, retires results and scores.
 
         Host phases of the caller's thread (``trace.phase``). ``eval.pass``
         is the whole pass, and six leaves add up to it but for loop
         overhead: ``eval.open_reader`` (builder and reader), ``eval.read``
         (one group of ``data_shards`` batches taken off the reader's queue:
-        the wait for the reader's thread, which the pass's first read
+        the wait for the reader's two threads, which the pass's first read
         starts; count: groups), ``eval.stack`` (``pad_group`` +
         ``stack_batches``: host stack + H2D), ``eval.enqueue`` (the predict
         call), ``eval.retire`` (the blocking read of the oldest call's
@@ -998,7 +999,8 @@ class PodTrainer:
             )
 
         def _read() -> list[CSRBatch]:
-            # the wait for whoever makes the batches (a reader's thread)
+            # a pure wait for whoever makes the batches: under iter(reader) the
+            # reader's parse thread and, ahead of this one, its build thread
             with trace.phase("eval.read") as read:
                 group = list(itertools.islice(reader, self.data_shards))
                 if not group:

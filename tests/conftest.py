@@ -37,6 +37,20 @@ import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 
+def pytest_configure(config):
+    """Build ``native/libpsdata.so`` once, before a test imports it: here
+    on the ``xdist`` controller, which configures before it starts a worker
+    (or in the one process of a run without workers). ``make`` writes the
+    library in place and ``data/native.py`` trusts any file not older than
+    the source, so workers that each found none on a fresh checkout built
+    it over one another, and one that loaded a half-written file parsed in
+    Python for the rest of its life: its ``[native]`` tests failed."""
+    if not hasattr(config, "workerinput"):
+        from parameter_server_tpu.data import native
+
+        native.native_available()
+
+
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)

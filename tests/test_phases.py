@@ -5,6 +5,7 @@ leave behind."""
 
 import glob
 import os
+import sys
 import time
 
 import jax
@@ -233,7 +234,7 @@ class TestFeedTimers:
         assert w1["count"] > w0["count"] and w1["total_s"] - w0["total_s"] > 0.1
 
 
-READER_PHASES = ("reader.parse", "reader.build", "reader.put_wait")
+READER_PHASES = ("reader.parse", "reader.build", "reader.parsed_wait", "reader.put_wait")
 
 
 def _reader(tmp_path, backend: str = "auto", prefetch: int = 4) -> MinibatchReader:
@@ -249,7 +250,8 @@ class TestReaderTimers:
         batches = list(_reader(tmp_path, backend))
         grew = {n: _count(n) - before[n] for n in before}
         assert sum(b.num_examples for b in batches) == 512 and len(batches) == 8
-        assert grew["reader.build"] == 8
+        # a batch is one build on the build thread and one wait for its parsed piece
+        assert grew["reader.build"] == grew["reader.parsed_wait"] == 8
         # the file is one chunk (the step that finds its end counts nothing);
         # the Python parsers hand over a row at a time: no phase a row
         assert grew["reader.parse"] == (1 if backend == "native" else 0)
@@ -284,7 +286,11 @@ class TestReaderTimers:
         # the step that finds the file at its end is a span too, and says nothing
         assert [e["args"].get("examples") for e in evs if e["name"] == "reader.parse"] == [512, None]
         assert [e["args"]["examples"] for e in evs if e["name"] == "reader.build"] == [64] * 8
-        assert {e["cat"] for e in evs} == {"reader"} and len({e["tid"] for e in evs}) == 1
+        assert {e["cat"] for e in evs} == {"reader"}
+        # two threads of the reader's own, a stage each
+        tids = {name: {e["tid"] for e in evs if e["name"] == name} for name in READER_PHASES[:3]}
+        assert all(len(v) == 1 for v in tids.values())
+        assert tids["reader.build"] == tids["reader.parsed_wait"] != tids["reader.parse"]
 
     @pytest.mark.parametrize("backend", ["native", "python"])
     @pytest.mark.parametrize("min_count", [0, 2])
@@ -333,7 +339,7 @@ class TestReaderTimers:
         assert all(len(v) == 1 for v in tids.values())
         assert tids["reader.build"] == tids["feed.build"] != tids["reader.parse"]
 
-    def test_names_are_host_events_of_the_readers_thread(self, tmp_path):
+    def test_names_are_host_events_of_the_readers_two_threads(self, tmp_path):
         reader = _reader(tmp_path, prefetch=1)
         with jax.profiler.trace(str(tmp_path / "prof")):
             with trace.phase("test.reader_caller"):
@@ -347,9 +353,21 @@ class TestReaderTimers:
             {ev.name for ev in ln.events}
             for p in prof.planes if not p.name.startswith("/device:") for ln in p.lines
         ]
-        (readers,) = [names for names in threads if "reader.build" in names]
-        assert set(READER_PHASES) <= readers
-        assert "test.reader_caller" not in readers  # not the thread that iterates
+        (parses,) = [names for names in threads if "reader.parse" in names]
+        (builds,) = [names for names in threads if "reader.build" in names]
+        # each stage waits on its own full queue; the build alone waits for a piece
+        assert {"reader.parse", "reader.put_wait"} <= parses and "reader.parsed_wait" not in parses
+        assert {"reader.build", "reader.parsed_wait", "reader.put_wait"} <= builds
+        assert "test.reader_caller" not in parses | builds  # neither is the thread that iterates
+        # tools/host_gaps.py tells the stages apart by these names
+        tools = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tools")
+        sys.path.insert(0, tools)
+        try:
+            import host_gaps
+        finally:
+            sys.path.remove(tools)
+        roles = sorted(th.role for th in host_gaps.host_threads(prof, 0.0, float("inf")))
+        assert roles == ["reader/build", "reader/parse", "test"]
 
 
 def _files(tmp_path, nnz_per_example: int, tag: str, examples: int = 512) -> list:

@@ -12,12 +12,16 @@ the window between the ``bench.window_open`` and ``bench.window_close`` marks
 this prints
 
 (a) seconds by phase name and thread role. A role is the set of layers whose
-    phases a thread carries (``reader``: the threads that parse, and in
-    evaluation build, one a file, merged into one row; ``feed+reader``: a
-    training stream's producer thread, which builds; ``bench+eval``: the
-    evaluator's caller), so
-    the reader threads' busy share of a ``ctr1.eval`` window is read here,
-    where the ``eval`` kind takes no timer snapshot;
+    phases a thread carries (``bench+eval``: the evaluator's caller;
+    ``feed+reader``: a training stream's producer thread, which builds), and
+    a ``MinibatchReader``'s own threads, a new pair (evaluation) or one
+    (training) every file set or pass, go by their stage, merged into one
+    row a stage: ``reader/parse`` and, under ``iter(reader)``,
+    ``reader/build``. So the two stages' busy shares of a ``ctr1.eval``
+    window are read here, where the ``eval`` kind takes no timer snapshot,
+    and which of them paces a pass: ``reader.parsed_wait`` (the build
+    thread's alone) says the parse, ``reader.put_wait`` under
+    ``reader/parse`` the build, under ``reader/build`` the caller;
 (b) for the N longest idle gaps of chip 0, every such thread's phases that
     overlap the gap, by seconds of overlap, the share of the gap they cover
     (under 90%: the thread was in no span there), and the events of any kind
@@ -67,7 +71,9 @@ class Thread:
 def host_threads(profile, t0: float, t1: float) -> list:
     """The host threads that carry a phase inside [t0, t1), their events
     clipped to it, labelled ``<role>#<k>``: a role is the layers of the
-    thread's phases (``bench+eval``, ``reader``, ``feed``, ...)."""
+    thread's phases (``bench+eval``, ``feed``, ...), a reader's own thread
+    by its stage (``reader/parse``, ``reader/build``; plain ``reader`` where
+    one thread carries both or neither)."""
     out: list = []
     for plane in profile.planes:
         if plane.name.startswith("/device:"):
@@ -85,7 +91,11 @@ def host_threads(profile, t0: float, t1: float) -> list:
             phase = np.array([bool(PHASE.match(n)) for n in names], dtype=bool)
             layers = sorted({n.split(".")[0] for n, is_phase in zip(names, phase) if is_phase})
             if layers:
-                out.append(Thread(line.name, names, np.array(start), np.array(end), phase, "+".join(layers)))
+                role = "+".join(layers)
+                stages = {"reader.parse", "reader.build"}.intersection(names)
+                if role == "reader" and len(stages) == 1:
+                    role = stages.pop().replace(".", "/")
+                out.append(Thread(line.name, names, np.array(start), np.array(end), phase, role))
     seen: dict = {}
     for th in sorted(out, key=lambda th: float(th.start.min())):
         k = seen[th.role] = seen.get(th.role, -1) + 1
