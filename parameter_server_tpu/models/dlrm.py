@@ -35,6 +35,7 @@ is ``parallel.spmd``'s and the training loop ``PodTrainer``'s."""
 from __future__ import annotations
 
 import copy
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +62,8 @@ ENTRIES = N_INT + N_CAT  # an example's entries, the dense columns' first
 FIRST_FIELD_ROW = 1 + N_INT  # behind the pad row and the dense columns' rows
 # the phases of the dense half, nested under "ps.grad/mlp"
 MLP_SCOPES = ("bot", "interact", "top")
+# every product of the interaction: the configuration states float32 sums of float32 products
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def num_keys_of(field_rows) -> int:
@@ -75,15 +78,76 @@ def interaction_width(emb_dim: int) -> int:
     return emb_dim + vectors * (vectors - 1) // 2
 
 
+@functools.lru_cache(maxsize=None)
+def _pair_selectors(vectors: int) -> tuple[np.ndarray, np.ndarray]:
+    """The 0/1 matrices that cut the pairs out of ``T T^t`` seen as
+    ``(B, vectors^2)`` and put their cotangent back. ``cut`` is
+    ``(vectors^2, pairs)``: column p holds its one at ``i * vectors + j``
+    for the p-th pair ``i > j``, row by row. ``back`` is ``(pairs,
+    vectors^2)`` with row p's ones at ``(i, j)`` and at ``(j, i)``: a
+    cotangent times it is ``dZ + dZ^t`` at once (the two have disjoint
+    supports and the diagonal stays zero). The vectors are numbered as
+    ``_pairs`` holds them, ``z0`` LAST (vector 0 of the model at place
+    ``vectors - 1``, e_k at ``k - 1``): the order of the pairs is the
+    model's."""
+    i, j = np.tril_indices(vectors, -1)  # the model's numbering, row by row
+    at_i, at_j = (i - 1) % vectors, (j - 1) % vectors
+    p = np.arange(len(i))
+    cut = np.zeros((vectors * vectors, len(i)), np.float32)
+    cut[at_i * vectors + at_j, p] = 1.0
+    back = np.zeros((len(i), vectors * vectors), np.float32)
+    back[p, at_i * vectors + at_j] = 1.0
+    back[p, at_j * vectors + at_i] = 1.0
+    return cut, back
+
+
+def _vectors(z0: jax.Array, e: jax.Array) -> jax.Array:
+    """(B, F + 1, d): the interaction's vectors as ``_pairs`` holds them,
+    ``e``'s first and ``z0`` last (``_pair_selectors`` numbers them so)."""
+    return jnp.concatenate([e, z0[:, None, :]], axis=1)
+
+
+@jax.custom_vjp
+def _pairs(z0: jax.Array, e: jax.Array) -> jax.Array:
+    """(B, d) and (B, F, d) -> (B, (F + 1) F / 2): the entries of ``T T^t``
+    strictly under the diagonal, cut by ONE selection product where 26
+    slices and a concatenate each read the lane-padded ``(B, 27, 27)``
+    again. Exact: a 0/1 entry is a bfloat16 number, and at ``HIGHEST`` the
+    three bfloat16 pieces of a float32 sum back to it."""
+    t = _vectors(z0, e)
+    vectors = t.shape[1]
+    cut, _ = _pair_selectors(vectors)
+    z = jnp.einsum("bid,bjd->bij", t, t, precision=_HIGHEST)
+    return jnp.dot(z.reshape(-1, vectors * vectors), cut, precision=_HIGHEST)
+
+
+def _pairs_fwd(z0, e):
+    return _pairs(z0, e), (z0, e)
+
+
+def _pairs_bwd(residual, g):
+    """``dT = (dZ + dZ^t) T``: one selection product builds the symmetrised
+    cotangent from the pairs' (no pad, no transpose, no add), one batched
+    product applies it, where ``jax.grad`` emits ``dZ T`` and ``dZ^t T``
+    and adds them. With ``z0`` the last vector, ``e``'s share is the head
+    of ``dT``: the same tiles, no copy."""
+    t = _vectors(*residual)
+    vectors = t.shape[1]
+    _, back = _pair_selectors(vectors)
+    s = jnp.dot(g, back, precision=_HIGHEST).reshape(-1, vectors, vectors)
+    dt = jnp.einsum("bij,bjd->bid", s, t, precision=_HIGHEST)
+    return dt[:, -1], dt[:, :-1]
+
+
+_pairs.defvjp(_pairs_fwd, _pairs_bwd)
+
+
 def interact(z0: jax.Array, e: jax.Array) -> jax.Array:
     """(B, d) and (B, F, d) -> (B, d + (F + 1) F / 2): ``z0`` beside the
     dots of every pair of the F + 1 vectors ``[z0; e]``, the entries of
-    ``T T^t`` strictly under the diagonal, row by row. A row's entries are
-    a static slice: no gather, and the backward pass a pad."""
-    t = jnp.concatenate([z0[:, None, :], e], axis=1)
-    z = jnp.einsum("bid,bjd->bij", t, t, precision=jax.lax.Precision.HIGHEST)
-    pairs = [z[:, i, :i] for i in range(1, t.shape[1])]
-    return jnp.concatenate([z0, *pairs], axis=1)
+    ``T T^t`` strictly under the diagonal, row by row (``_pairs``: cut by a
+    selection product, differentiated by hand)."""
+    return jnp.concatenate([z0, _pairs(z0, e)], axis=1)
 
 
 def _by_position(flat: jax.Array, examples: int) -> jax.Array:

@@ -183,6 +183,73 @@ def test_interaction_against_a_double_loop_over_pairs():
         np.testing.assert_allclose(got[b, DIM:], want, rtol=1e-5, atol=1e-6)
 
 
+def plain_interact(z0, e):
+    """The interaction as ``jax.grad`` alone differentiates it: ``T T^t``,
+    a row's entries under the diagonal by a static slice, one concatenate
+    (``models.dlrm.interact`` until PR 51: the reference of its selection
+    products and of its hand-written backward pass)."""
+    t = jnp.concatenate([z0[:, None, :], e], axis=1)
+    z = jnp.einsum("bid,bjd->bij", t, t, precision=jax.lax.Precision.HIGHEST)
+    return jnp.concatenate([z0, *(z[:, i, :i] for i in range(1, t.shape[1]))], axis=1)
+
+
+def interaction_case(vectors, d, examples=6, seed=51):
+    rng = np.random.default_rng(seed)
+    z0 = jnp.asarray(rng.normal(size=(examples, d)).astype(np.float32))
+    e = jnp.asarray(rng.normal(size=(examples, vectors - 1, d)).astype(np.float32))
+    w = jnp.asarray(rng.normal(size=(d + vectors * (vectors - 1) // 2, 3)).astype(np.float32))
+    return z0, e, w
+
+
+def loss_through(interact):
+    """A loss that weighs every output of the interaction differently, with
+    the outputs as aux: the shape of ``models.dlrm._loss``."""
+
+    def loss(z0, e, w):
+        r = interact(z0, e)
+        return jnp.sum(jnp.tanh(r @ w)), r
+
+    return loss
+
+
+def assert_relative(got, want, rel):
+    np.testing.assert_allclose(got, want, rtol=0, atol=rel * float(np.abs(want).max()))
+
+
+@pytest.mark.parametrize("d", [8, 128])
+@pytest.mark.parametrize("vectors", [3, 5, 27])
+def test_interaction_and_its_gradient_against_the_plain_form(vectors, d):
+    """The pairs cut by a selection product are the sliced ones to the bit
+    (a 0/1 selector at ``HIGHEST`` moves a float32 whole), and the
+    ``custom_vjp``'s one product of the symmetrised cotangent is
+    ``jax.grad`` of the plain form (the same terms in another order:
+    1e-6 of the largest entry) for ``z0`` and for ``e``."""
+    z0, e, w = interaction_case(vectors, d)
+    np.testing.assert_array_equal(dlrm.interact(z0, e), plain_interact(z0, e))
+    got, _ = jax.grad(loss_through(dlrm.interact), argnums=(0, 1), has_aux=True)(z0, e, w)
+    want, _ = jax.grad(loss_through(plain_interact), argnums=(0, 1), has_aux=True)(z0, e, w)
+    for g, ref, like in zip(got, want, (z0, e)):
+        assert g.shape == like.shape and float(np.abs(ref).max()) > 0.1
+        assert_relative(g, ref, 1e-6)
+
+
+def test_interaction_under_jit_and_value_and_grad_with_aux():
+    """As ``models.dlrm._grad`` calls it: jitted, ``value_and_grad`` over
+    two arguments with the forward values as aux."""
+    z0, e, w = interaction_case(27, DIM, examples=BATCH)
+
+    def run(interact):
+        return jax.jit(jax.value_and_grad(loss_through(interact), argnums=(0, 1), has_aux=True))(z0, e, w)
+
+    (loss, r), (g_z0, g_e) = run(dlrm.interact)
+    (want_loss, want_r), (want_z0, want_e) = run(plain_interact)
+    assert r.shape == (BATCH, dlrm.interaction_width(DIM))
+    assert_relative(r, want_r, 1e-6)  # two jitted programs: XLA may order a dot's sum its own way
+    assert_relative(loss, want_loss, 1e-6)
+    assert_relative(g_z0, want_z0, 1e-6)
+    assert_relative(g_e, want_e, 1e-6)
+
+
 @pytest.mark.parametrize("mesh_name", ["1x1", "2x2"])
 def test_predict_is_the_references_forward_pass(tmp_path, mesh_name):
     """``evaluate_files`` after some training: the evaluator's AUC and

@@ -130,6 +130,20 @@ def elements(shape: str) -> int:
     )
 
 
+def bare_op_name(op_name: str) -> str:
+    """An ``op_name`` with the transformations' wrappers taken off every
+    part: ``ps.grad/transpose(jvp(mlp))/interact/dot_general`` ->
+    ``ps.grad/mlp/interact/dot_general``."""
+    from parameter_server_tpu.parallel import spmd
+
+    parts = []
+    for part in op_name.split("/"):
+        while (m := spmd._TRANSFORMED.match(part)) is not None:
+            part = m.group(1)
+        parts.append(part)
+    return "/".join(parts)
+
+
 def copies_of(every: list, n: int) -> list:
     """(name, shape) of the module's ``copy`` instructions of ``n`` elements
     or more: a table relaid."""
@@ -1000,6 +1014,9 @@ def test_dlrm_holds_one_table_and_names_its_dense_phases(dlrm_text, data, kv, pr
     found = set(scopes.values())
     assert {"ps.pull/emb", "ps.grad", "ps.grad/mlp/bot", "ps.grad/mlp/interact", "ps.grad/mlp/top"} <= found, found
     every = instructions(text)
+    op_name_of = {
+        name: (re.search(r'op_name="([^"]*)"', rest) or [None, ""])[1] for _, name, _, _, _, rest in every
+    }
     assert not copies_of(every, rows * DLRM_DIM)
     assert all("{1,0:" in shape for _, _, shape, _, _, _ in every if table.search(shape) and shape.startswith("f32")), "rows-minor"
     # the step's temporaries are the batch's: 8192 x 26 rows of 128 lanes each way (109 MB), the
@@ -1021,6 +1038,28 @@ def test_dlrm_holds_one_table_and_names_its_dense_phases(dlrm_text, data, kv, pr
     made = dlrm_text.texts["init", "memory"]
     assert made.output_size_in_bytes == table_bytes
     assert made.temp_size_in_bytes < 512 << 20, made.temp_size_in_bytes
+    # the interaction (PR 51): its instructions, the forward's and the hand-written backward
+    # pass's, are all found by step.interact_ms; the pairs are cut by a selection product,
+    # not by a slice a row; and neither T nor dT is relaid
+    inside = [
+        (name, shape, opcode)
+        for name, shape, opcode, _ in executed(text)
+        if "mlp/interact/" in bare_op_name(op_name_of[name])
+    ]
+    assert len(inside) >= 8, inside  # two products and a selection product each way
+    assert all(scopes[name] == "ps.grad/mlp/interact" for name, _, _ in inside), inside
+    assert any("transpose(" in op_name_of[name] for name, _, _ in inside), "the backward pass carries the scope"
+    # 25 executed slices f32[8192,1,k] of the lane-padded (8192, 27, 27) until PR 51; the one
+    # slice of a row left is z0's share of dT, f32[8192,1,128]
+    row_slices = [(name, shape) for name, shape, opcode in inside if opcode == "slice" and re.match(r"f32\[8192,1,\d+\]", shape)]
+    assert all(shape.startswith("f32[8192,1,128]") for _, shape in row_slices) and len(row_slices) <= 1, row_slices
+    # copies of T's or dT's size, (8192, 27, 128) or (8192, 26, 128): 3 in PR 51's parent (the
+    # two backward products' results to batch-minor, e's share back to row-major), none now
+    relaid = [
+        (name, shape) for name, shape, opcode, _ in executed(text)
+        if opcode == "copy" and elements(shape) in (MINIBATCH * 27 * DLRM_DIM, MINIBATCH * 26 * DLRM_DIM)
+    ]
+    assert not relaid, relaid
 
 
 @pytest.mark.parametrize("data,kv,program", DLRM_CASES)
