@@ -796,21 +796,42 @@ static const float* const log1p_small = [] {
 // behind the other in one id space behind the 13 integer columns' keys:
 // column j's key is 13 + off_j + id % field_rows[j], off_j the rows of the
 // columns before it (identity keying, +1 for the pad row, makes it a table
-// row); the hashed layout's loop is compiled without a word of this.
-template <bool kFields>
+// row); the hashed layout's loop is compiled without a word of this. With
+// kBags too (``ps_parse_criteo_bags``) column j's id stands for a bag of
+// hot[j] rows of its table: r = id % field_rows[j] itself, then
+// bag_draw(seed, j, r, k) % field_rows[j] for k = 1..hot[j]-1
+// (data/libsvm.py ``bag_draw``), one entry each, in that order; the one-hot
+// loop is compiled without a word of that.
+static inline uint64_t sm64_mix(uint64_t x) {
+  // identical constants/steps to utils/hashing.splitmix64 (which adds C1
+  // as its first step)
+  uint64_t z = x + 0x9E3779B97F4A7C15ull;
+  z = (z ^ (z >> 30)) * 0xBF58476D1CE4E5B9ull;
+  z = (z ^ (z >> 27)) * 0x94D049BB133111EBull;
+  return z ^ (z >> 31);
+}
+
+template <bool kFields, bool kBags = false>
 static int parse_criteo(const char* buf, int64_t len,
                         int64_t max_rows, int64_t max_nnz,
                         float* labels, int64_t* row_splits,
                         uint64_t* keys, float* vals, uint64_t* slots,
                         int64_t* out_rows, int64_t* out_nnz,
-                        const uint64_t* field_rows) {
+                        const uint64_t* field_rows,
+                        const uint64_t* hot = nullptr, uint64_t seed = 0) {
+  static_assert(kFields || !kBags, "bags are rows of per-field tables");
   uint64_t field_first[26];  // key of column j's id 0
+  uint64_t field_salt[26];   // splitmix64(seed + j * 2^32): the bags' draws
   if (kFields) {
     uint64_t first = 13;
     for (int j = 0; j < 26; ++j) {
       if (field_rows[j] == 0) return -2;
       field_first[j] = first;
       first += field_rows[j];
+      if (kBags) {
+        if (hot[j] == 0) return -2;
+        field_salt[j] = sm64_mix(seed + (static_cast<uint64_t>(j) << 32));
+      }
     }
   }
   const char* p = buf;
@@ -883,12 +904,24 @@ static int parse_criteo(const char* buf, int64_t len,
             ok = parse_hex64(fp, field_end, h) && fp == field_end;
           }
           if (ok) {
-            keys[nnz] = kFields
-                ? field_first[col - 13] + h % field_rows[col - 13]
-                : h;
+            const uint64_t r = kFields ? h % field_rows[col - 13] : h;
+            keys[nnz] = kFields ? field_first[col - 13] + r : h;
             vals[nnz] = 1.0f;
             slots[nnz] = static_cast<uint64_t>(col - 13 + 14);
             ++nnz;
+            if (kBags) {
+              const int j = col - 13;
+              const int64_t more = static_cast<int64_t>(hot[j]) - 1;
+              if (nnz + more > max_nnz) return -1;
+              const uint64_t of_id = sm64_mix(field_salt[j] ^ r);
+              for (int64_t k = 1; k <= more; ++k) {
+                keys[nnz] = field_first[j] +
+                    sm64_mix(of_id + static_cast<uint64_t>(k)) % field_rows[j];
+                vals[nnz] = 1.0f;
+                slots[nnz] = static_cast<uint64_t>(j + 14);
+                ++nnz;
+              }
+            }
           }
         }
       }
@@ -934,6 +967,21 @@ int ps_parse_criteo_fields(const char* buf, int64_t len,
                             keys, vals, slots, out_rows, out_nnz, field_rows);
 }
 
+// "criteo:<26 table sizes>:<26 bag sizes>:<seed>": the per-field layout
+// with every id a bag of rows; a size of 0 is the only error (-2)
+int ps_parse_criteo_bags(const char* buf, int64_t len,
+                         int64_t max_rows, int64_t max_nnz,
+                         float* labels, int64_t* row_splits,
+                         uint64_t* keys, float* vals, uint64_t* slots,
+                         int64_t* out_rows, int64_t* out_nnz,
+                         int64_t* err_line, const uint64_t* field_rows,
+                         const uint64_t* hot, uint64_t seed) {
+  (void)err_line;
+  return parse_criteo<true, true>(buf, len, max_rows, max_nnz, labels,
+                                  row_splits, keys, vals, slots, out_rows,
+                                  out_nnz, field_rows, hot, seed);
+}
+
 // Hash + localize kernel (ref: src/app/linear_method/localizer.h — remap
 // touched keys to dense local ids; the per-batch hot loop after parsing).
 // Reproduces utils/hashing.hash_keys + np.unique(return_inverse) exactly:
@@ -949,15 +997,6 @@ int ps_parse_criteo_fields(const char* buf, int64_t len,
 // [1, num_keys); -4 alloc failure; -5 num_keys > 2^32. On -3/-5 the
 // caller falls back to the numpy path (which owns the error text for -3
 // and handles arbitrarily large key spaces for -5).
-
-static inline uint64_t sm64_mix(uint64_t x) {
-  // identical constants/steps to utils/hashing.splitmix64 (which adds C1
-  // as its first step)
-  uint64_t z = x + 0x9E3779B97F4A7C15ull;
-  z = (z ^ (z >> 30)) * 0xBF58476D1CE4E5B9ull;
-  z = (z ^ (z >> 27)) * 0x94D049BB133111EBull;
-  return z ^ (z >> 31);
-}
 
 int ps_hash_localize(const uint64_t* raw, const uint64_t* slots, int64_t n,
                      uint64_t num_keys, int identity,

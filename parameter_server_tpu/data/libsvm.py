@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import gzip
 from collections.abc import Iterator
+from dataclasses import dataclass
 from pathlib import Path
 
 import numpy as np
@@ -48,9 +49,50 @@ def iter_libsvm(path: str | Path) -> Iterator[Row]:
 
 N_INT, N_CAT = 13, 26  # the criteo format's integer and categorical columns
 
+_U64 = (1 << 64) - 1
+
+
+def _splitmix64(x: int) -> int:
+    """``utils.hashing.splitmix64`` of one Python integer, modulo 2^64."""
+    z = (x + 0x9E3779B97F4A7C15) & _U64
+    z = ((z ^ (z >> 30)) * 0xBF58476D1CE4E5B9) & _U64
+    z = ((z ^ (z >> 27)) * 0x94D049BB133111EB) & _U64
+    return z ^ (z >> 31)
+
+
+def bag_draw(seed: int, f: int, r: int, j: int) -> int:
+    """``u(seed, f, r, j)``: the 64-bit draw behind place ``j >= 1`` of the
+    bag that id ``r`` of categorical column ``f`` (0-based) stands for in
+    the multi-hot criteo format:
+
+        u = splitmix64(splitmix64(splitmix64(seed + f * 2^32) ^ r) + j)
+
+    every sum modulo 2^64, ``splitmix64`` the finalizer of
+    ``utils.hashing`` (it adds 0x9E3779B97F4A7C15 first). The bag of
+    ``r`` is ``[r, u(.., 1) mod R_f, ..., u(.., h_f - 1) mod R_f]``: a
+    fixed, uniform bag an id, a function of ``(seed, f, r)`` alone."""
+    return _splitmix64((_splitmix64(_splitmix64((seed + (f << 32)) & _U64) ^ r) + j) & _U64)
+
+
+@dataclass(frozen=True)
+class CriteoBags:
+    """What "criteo:<26 sizes>:<26 bag sizes>:<seed>" names: the per-field
+    layout's table sizes, each column's bag size and the seed of the
+    bags' draws (``bag_draw``)."""
+
+    rows: tuple[int, ...]
+    hot: tuple[int, ...]
+    seed: int
+
+    @property
+    def entries(self) -> int:
+        """Entries an example with every field present carries."""
+        return N_INT + sum(self.hot)
+
 
 def iter_criteo(
-    path: str | Path, field_rows: tuple[int, ...] | None = None
+    path: str | Path,
+    field_rows: "tuple[int, ...] | CriteoBags | None" = None,
 ) -> Iterator[Row]:
     """Parse Criteo CTR TSV: label, 13 integer slots, 26 categorical slots.
 
@@ -66,8 +108,18 @@ def iter_criteo(
     columns before it, so that identity keying (+1 for the pad row) puts
     integer column j at table row 1 + j and column j's value at row
     ``14 + off_j + id mod field_rows[j]``: no two columns share a row.
+
+    A ``CriteoBags`` (the multi-hot format, ``criteo_format`` with bag
+    sizes) turns column j's id into a bag of ``hot[j]`` entries of that
+    column, in this order: ``r = id mod R_j`` itself, then ``bag_draw(seed,
+    j, r, k) mod R_j`` for k = 1..hot[j] - 1, each keyed as above. A row may
+    come twice in a bag; it is then two entries. An example with every
+    field present carries 13 + sum(hot) entries, and an entry's position
+    says its column and its place in the bag.
     """
-    first = None
+    first, bags = None, None
+    if isinstance(field_rows, CriteoBags):
+        bags, field_rows = field_rows, field_rows.rows
     if field_rows is not None:
         first = [N_INT + sum(field_rows[:j]) for j in range(N_CAT)]
     with _open(path) as f:
@@ -95,10 +147,16 @@ def iter_criteo(
                 except ValueError:
                     continue
                 if first is not None:
-                    k = first[j] + k % field_rows[j]
+                    r = k % field_rows[j]
+                    k = first[j] + r
                 keys.append(k)
                 vals.append(1.0)
                 slots.append(j + 14)
+                if bags is not None:
+                    for place in range(1, bags.hot[j]):
+                        keys.append(first[j] + bag_draw(bags.seed, j, r, place) % field_rows[j])
+                        vals.append(1.0)
+                        slots.append(j + 14)
             yield (
                 label,
                 np.array(keys, dtype=np.uint64),
@@ -206,7 +264,10 @@ FORMATS = {"libsvm": iter_libsvm, "criteo": iter_criteo, "adfea": iter_adfea}
 # "sgns:<vocab_size>" (``sgns_format``). The criteo format becomes a third
 # when it is read with the 26 columns' table sizes behind its name,
 # "criteo:<rows of column 1>,...,<rows of column 26>" (``criteo_format``;
-# ``iter_criteo``'s per-field layout); bare, it is hashed as ever.
+# ``iter_criteo``'s per-field layout); bare, it is hashed as ever. With the
+# 26 columns' bag sizes and a seed behind the sizes,
+# "criteo:<26 sizes>:<26 bag sizes>:<seed>", every id stands for a fixed
+# bag of rows of its column's table (``CriteoBags``; the multi-hot form).
 RATING = "rating"
 SGNS = "sgns"
 CRITEO = "criteo"
@@ -221,24 +282,47 @@ def sgns_format(vocab_size: int) -> str:
     return f"{SGNS}:{int(vocab_size)}"
 
 
-def criteo_format(field_rows) -> str:
-    return f"{CRITEO}:" + ",".join(str(int(r)) for r in field_rows)
+def criteo_format(field_rows, hot=None, seed: int = 0) -> str:
+    """The per-field format's name: the one-hot form where ``hot`` is
+    unsaid or 1 for every column, else the multi-hot form."""
+    fmt = f"{CRITEO}:" + ",".join(str(int(r)) for r in field_rows)
+    if hot is None or all(int(h) == 1 for h in hot):
+        return fmt
+    return fmt + ":" + ",".join(str(int(h)) for h in hot) + f":{int(seed)}"
 
 
-def split_format(fmt: str) -> tuple[str, "int | tuple[int, ...] | None"]:
+def _sizes(text: str) -> "tuple[int, ...] | None":
+    sizes = text.split(",")
+    if len(sizes) != N_CAT or not all(r.isdigit() and int(r) > 0 for r in sizes):
+        return None
+    return tuple(int(r) for r in sizes)
+
+
+def split_format(fmt: str) -> tuple[str, "int | tuple[int, ...] | CriteoBags | None"]:
     """(format name, its parameter or None): ("rating", 39780) of
     "rating:39780", ("criteo", None) of "criteo", ("criteo", (r_1, ...,
-    r_26)) of "criteo:r_1,...,r_26"."""
+    r_26)) of "criteo:r_1,...,r_26", ("criteo", CriteoBags) of
+    "criteo:r_1,...,r_26:h_1,...,h_26:seed"."""
     name, _, arg = fmt.partition(":")
     if name == CRITEO and arg:
-        sizes = arg.split(",")
-        if len(sizes) != N_CAT or not all(r.isdigit() and int(r) > 0 for r in sizes):
+        rows, colon, bags = arg.partition(":")
+        sizes = _sizes(rows)
+        if sizes is None:
             raise ValueError(
                 f"the per-field criteo format is read as '{CRITEO}:<{N_CAT} "
                 f"table sizes, comma-separated>' (data.libsvm.criteo_format), "
                 f"got {fmt!r}"
             )
-        return name, tuple(int(r) for r in sizes)
+        if not colon:
+            return name, sizes
+        hot, _, seed = bags.partition(":")
+        if _sizes(hot) is None or not seed.isdigit() or int(seed) > _U64:
+            raise ValueError(
+                f"the multi-hot criteo format is read as '{CRITEO}:<{N_CAT} table "
+                f"sizes>:<{N_CAT} bag sizes>:<seed>' (data.libsvm.criteo_format), "
+                f"got {fmt!r}"
+            )
+        return name, CriteoBags(sizes, _sizes(hot), int(seed))
     if name in _SIZED:
         if not arg.isdigit():
             raise ValueError(

@@ -28,7 +28,7 @@ from pathlib import Path
 
 import numpy as np
 
-from parameter_server_tpu.data.libsvm import split_format
+from parameter_server_tpu.data.libsvm import N_CAT, N_INT, CriteoBags, split_format
 
 _NATIVE_DIR = Path(__file__).resolve().parent.parent.parent / "native"
 _LIB_ENV = "PS_TPU_NATIVE_LIB"
@@ -52,6 +52,9 @@ NATIVE_FORMATS = {
 # "criteo:<26 table sizes>" (the per-field layout): the sizes as a
 # thirteenth argument, a pointer to 26 uint64
 _CRITEO_FIELDS = "ps_parse_criteo_fields"
+# "criteo:<26 table sizes>:<26 bag sizes>:<seed>" (the multi-hot form): the
+# bag sizes and the seed behind the table sizes
+_CRITEO_BAGS = "ps_parse_criteo_bags"
 
 
 def has_native(fmt: str) -> bool:
@@ -141,11 +144,12 @@ def _load_native_locked() -> ctypes.CDLL | None:
     f32p, i64p = ctypes.POINTER(ctypes.c_float), ctypes.POINTER(ctypes.c_int64)
     # what a parser takes behind the twelve arguments they all share
     sized = {
-        NATIVE_FORMATS["rating"]: ctypes.c_uint64,  # num_items
-        NATIVE_FORMATS["sgns"]: ctypes.c_uint64,  # vocab_size
-        _CRITEO_FIELDS: u64p,  # the 26 table sizes
+        NATIVE_FORMATS["rating"]: [ctypes.c_uint64],  # num_items
+        NATIVE_FORMATS["sgns"]: [ctypes.c_uint64],  # vocab_size
+        _CRITEO_FIELDS: [u64p],  # the 26 table sizes
+        _CRITEO_BAGS: [u64p, u64p, ctypes.c_uint64],  # ..., the 26 bag sizes, the seed
     }
-    for fn in [*NATIVE_FORMATS.values(), _CRITEO_FIELDS]:
+    for fn in [*NATIVE_FORMATS.values(), _CRITEO_FIELDS, _CRITEO_BAGS]:
         f = getattr(lib, fn, None)
         if f is None:
             continue  # older prebuilt artifact: _parse_region says so
@@ -156,7 +160,7 @@ def _load_native_locked() -> ctypes.CDLL | None:
             f32p, i64p,  # labels, row_splits
             u64p, f32p, u64p,  # keys, vals, slots
             i64p, i64p, i64p,  # out_rows, out_nnz, err_line
-            *([sized[fn]] if fn in sized else []),
+            *sized.get(fn, []),
         ]
     try:
         c4 = lib.ps_count4
@@ -252,7 +256,7 @@ _COUNT_NEEDLES = {
 }
 
 
-def _counts(lib, fmt: str, ba: bytearray, length: int) -> tuple[int, int]:
+def _counts(lib, fmt: str, ba: bytearray, length: int, entries: int = N_INT + N_CAT) -> tuple[int, int]:
     """(rows_cap, nnz_cap): exact row bound from the line-terminator
     count, entry bound from format-specific marker counts — one AVX2
     pass in C (python's bytes.count pays per-occurrence overhead that at
@@ -281,7 +285,8 @@ def _counts(lib, fmt: str, ba: bytearray, length: int) -> tuple[int, int]:
         # undershoot and take the retry, whose jump below is linear)
         nnz_cap = max(out[2], out[3]) + 1
     elif fmt == "criteo":
-        nnz_cap = 39 * rows_cap + 1  # hard bound: <= 39 features per row
+        # hard bound: <= 39 features a row, <= ``entries`` with bags
+        nnz_cap = entries * rows_cap + 1
     elif fmt == "rating":
         nnz_cap = 2 * rows_cap  # exactly two entries a row
     elif fmt == "sgns":
@@ -303,17 +308,23 @@ def _parse_region(fmt: str, ba: bytearray, length: int) -> FlatRows:
     fmt, arg = split_format(fmt)
     if fmt not in NATIVE_FORMATS:
         raise ValueError(f"native parser: unknown format {fmt!r}")
-    fn_name = _CRITEO_FIELDS if isinstance(arg, tuple) else NATIVE_FORMATS[fmt]
+    def u64s(xs):
+        return (ctypes.c_uint64 * len(xs))(*xs)
+
+    entries = N_INT + N_CAT
+    if arg is None:
+        fn_name, extra = NATIVE_FORMATS[fmt], ()
+    elif isinstance(arg, CriteoBags):
+        fn_name, entries = _CRITEO_BAGS, arg.entries
+        extra = (u64s(arg.rows), u64s(arg.hot), ctypes.c_uint64(arg.seed))
+    elif isinstance(arg, tuple):
+        fn_name, extra = _CRITEO_FIELDS, (u64s(arg),)
+    else:
+        fn_name, extra = NATIVE_FORMATS[fmt], (ctypes.c_uint64(arg),)
     fn = getattr(lib, fn_name, None)
     if fn is None:
         raise RuntimeError(f"the native library has no {fn_name}")
-    if arg is None:
-        extra = ()
-    elif isinstance(arg, tuple):
-        extra = ((ctypes.c_uint64 * len(arg))(*arg),)
-    else:
-        extra = (ctypes.c_uint64(arg),)
-    rows_cap, nnz_cap = _counts(lib, fmt, ba, length)
+    rows_cap, nnz_cap = _counts(lib, fmt, ba, length, entries)
     want_slots = fmt not in SLOTLESS_FORMATS
     buf_p = (ctypes.c_char * len(ba)).from_buffer(ba)
     while True:

@@ -216,6 +216,25 @@ class ProxNewton:
         ).sum(axis=-1)
 
 
+def dense_adagrad(eta: float, eps: float = 1e-8):
+    """``Adagrad``'s rule (no L2) as an optax transformation, for an app's
+    dense group: ``n += g^2; w -= eta g / (sqrt(n) + eps)``, ``n`` from 0
+    and ``eps`` outside the root (``optax.adagrad`` puts it inside and
+    starts ``n`` at 0.1: another rule). A zero gradient moves nothing."""
+    import jax
+    import optax
+
+    def init(params):
+        return jax.tree.map(jnp.zeros_like, params)
+
+    def update(grads, n, params=None):
+        del params
+        n = jax.tree.map(lambda n_, g: n_ + g * g, n, grads)
+        return jax.tree.map(lambda g, n_: -eta * g / (jnp.sqrt(n_) + eps), grads, n), n
+
+    return optax.GradientTransformation(init, update)
+
+
 def make_updater(algo: str, **kw: Any) -> Updater:
     """Factory by config name (ref: solver/penalty fields of the app proto)."""
     table = {"sgd": Sgd, "adagrad": Adagrad, "ftrl": Ftrl, "prox_newton": ProxNewton}

@@ -1,7 +1,8 @@
 """ReLU multilayer perceptrons, for the apps whose dense group is one or
 more of them (Wide&Deep's tower; DLRM's bottom and top MLPs): the
 parameters as a list of ``{"W", "b"}`` layers made on the host from a
-seeded generator, and the forward pass.
+seeded generator, and the forward pass. And the low-rank cross network
+that DLRM-DCNv2 puts between the two (``init_cross``, ``cross_apply``).
 
 Every product runs at float32 (``Precision.HIGHEST``): the TPU's default
 would round the operands to bfloat16, and the apps state float32
@@ -57,3 +58,29 @@ def mlp_apply(params: list[Layer], x: jax.Array, last: Callable | None = None) -
         x = jax.nn.relu(jnp.dot(x, layer["W"], precision=hi) + layer["b"])
     out = jnp.dot(x, params[-1]["W"], precision=hi) + params[-1]["b"]
     return out if last is None else last(out)
+
+
+def init_cross(width: int, rank: int, layers: int, rng: "np.random.Generator | int") -> list[Layer]:
+    """``layers`` low-rank cross layers ``{"V": (width, rank), "W": (rank,
+    width), "b": (width,)}`` in float32, drawn layer by layer from ``rng``:
+    V normal with variance 2 / (width + rank), then W and b as
+    ``xavier_normal`` draws a layer's."""
+    rng = np.random.default_rng(rng)
+    params = []
+    for _ in range(layers):
+        v = rng.normal(scale=np.sqrt(2.0 / (width + rank)), size=(width, rank))
+        w, b = xavier_normal(rng, rank, width)
+        params.append({k: jnp.asarray(a, dtype=jnp.float32) for k, a in (("V", v), ("W", w), ("b", b))})
+    return params
+
+
+def cross_apply(params: list[Layer], x0: jax.Array) -> jax.Array:
+    """(B, width) -> (B, width): the cross network of DCN V2 in its
+    low-rank form (Wang et al., arXiv:2008.13535, sections 3-4),
+    ``x_{l+1} = x_0 * ((x_l V_l) W_l + b_l) + x_l``, ``*`` elementwise."""
+    hi = jax.lax.Precision.HIGHEST
+    x = x0
+    for layer in params:
+        low = jnp.dot(x, layer["V"], precision=hi)
+        x = x0 * (jnp.dot(low, layer["W"], precision=hi) + layer["b"]) + x
+    return x

@@ -176,6 +176,20 @@ class DLRMConfig:
     eta: float = 2.5e-5
     # rows of each categorical column's table: min(cardinality, max_ind_range)
     field_rows: list[int] = field(default_factory=list)
+    # What the multi-hot form differs by (MLPerf Training's DLRM-DCNv2 since
+    # v3.0: --multi_hot_sizes, --dcn_num_layers, --dcn_low_rank_dim, --adagrad).
+    # ids an example carries in each column: an id stands for a fixed bag of
+    # that many rows of its table, summed (data.libsvm.CriteoBags); all 1 is
+    # the one-hot form
+    hot: list[int] = field(default_factory=lambda: [1] * 26)
+    # layers of the low-rank cross network (Wang et al., arXiv:2008.13535) in
+    # the pairwise dots' place; 0 keeps the dots
+    cross_layers: int = 0
+    cross_rank: int = 512
+    # both halves' rule, at ``eta``: "sgd" | "adagrad" (n from 0, eps outside
+    # the root: kv.updaters.Adagrad)
+    updater: str = "sgd"
+    eps: float = 1e-8
 
 
 @dataclass

@@ -424,24 +424,34 @@ class TestTrainerAndEvaluatorTimers:
             evaluate = lambda: t.evaluate_batches(batches)  # noqa: E731
         evaluate()  # the pass that compiles
         whole = ("eval.pass", "eval.open", "eval.dispatch", "eval.new_shapes")
-        before = {n: (_count(n), _total(n)) for n in EVAL_LEAVES + whole}
-        ev = evaluate()
-        count = {n: _count(n) - before[n][0] for n in before}
-        spent = {n: _total(n) - before[n][1] for n in before}
-        batches_read = -(-ev["examples"] // 1024)  # 17: the last one is short
-        calls = -(-batches_read // 2)  # two data shards a call: 9, the last one half inert
-        assert ev["examples"] == 16 * 1024 + 512 and calls == 9
-        assert count == {
-            "eval.pass": 1, "eval.open_reader": 1, "eval.score": 1,
-            "eval.read": calls,  # a group a call; not the probe that finds the stream at its end
-            "eval.stack": calls, "eval.enqueue": calls, "eval.retire": calls,
-            "eval.open": 1, "eval.dispatch": calls - 1, "eval.new_shapes": 0,
-        }
-        leaves = sum(spent[n] for n in EVAL_LEAVES)
-        assert 0.9 * spent["eval.pass"] <= leaves <= spent["eval.pass"], spent
-        # the two enclosing phases hold their calls' leaves
-        assert spent["eval.open"] + spent["eval.dispatch"] >= spent["eval.stack"] + spent["eval.enqueue"]
-        assert spent["eval.open"] >= spent["eval.open_reader"]
+        # The leaves are wall-clock timers and the pass is 20-60 ms: where the test's
+        # process is descheduled between two leaves (six xdist workers on as many
+        # cores) the unnamed remainder takes the whole stall and passes a tenth of
+        # the pass. Every pass is held to its counts and its order; the share is
+        # held over up to five passes, of which a stall would have to hit every one
+        # between two leaves.
+        for attempt in range(5):
+            before = {n: (_count(n), _total(n)) for n in EVAL_LEAVES + whole}
+            ev = evaluate()
+            count = {n: _count(n) - before[n][0] for n in before}
+            spent = {n: _total(n) - before[n][1] for n in before}
+            batches_read = -(-ev["examples"] // 1024)  # 17: the last one is short
+            calls = -(-batches_read // 2)  # two data shards a call: 9, the last one half inert
+            assert ev["examples"] == 16 * 1024 + 512 and calls == 9
+            assert count == {
+                "eval.pass": 1, "eval.open_reader": 1, "eval.score": 1,
+                "eval.read": calls,  # a group a call; not the probe that finds the stream at its end
+                "eval.stack": calls, "eval.enqueue": calls, "eval.retire": calls,
+                "eval.open": 1, "eval.dispatch": calls - 1, "eval.new_shapes": 0,
+            }
+            leaves = sum(spent[n] for n in EVAL_LEAVES)
+            assert leaves <= spent["eval.pass"], spent
+            # the two enclosing phases hold their calls' leaves
+            assert spent["eval.open"] + spent["eval.dispatch"] >= spent["eval.stack"] + spent["eval.enqueue"]
+            assert spent["eval.open"] >= spent["eval.open_reader"]
+            if 0.9 * spent["eval.pass"] <= leaves:
+                break
+        assert 0.9 * spent["eval.pass"] <= leaves, (attempt, spent)
 
     def test_the_three_trainer_phases_keep_their_timers(self, tmp_path):
         before = {n: _count(n) for n in ("trainer.fetch", "trainer.dispatch", "trainer.retire")}

@@ -914,6 +914,52 @@ DLRM_CASES = [(1, 1, "multistep"), (1, 1, "predict")]
 DLRM_NAMES = frozenset({"emb", "mlp", "bot", "interact", "top"})  # the app's StepApp.scope_names()
 
 
+def compile_dlrm(topo, cfg, names, data, kv, program, steps, nnz, unique):
+    """(compiled program, app, mesh, rows a chip) of app ``dlrm`` under
+    ``cfg``'s [dlrm] settings: the scanned step of ``steps`` microsteps
+    (``program`` "multistep") or the predict program, on the first ``data``
+    x ``kv`` described devices, at a batch of ``nnz`` entry slots and
+    ``unique`` key slots."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding
+
+    from parameter_server_tpu.models import dlrm
+    from parameter_server_tpu.parallel import spmd
+
+    cfg = dlrm.pod_config(cfg)
+    app = dlrm.app_from_config(cfg)
+    assert app.scope_names() == names
+    mesh = Mesh(np.array(topo.devices[: data * kv]).reshape(data, kv), ("data", "kv"))
+    specs = app.specs()
+    rows = spmd.padded_num_keys(cfg.data.num_keys, kv)
+    shapes = jax.eval_shape(lambda: {**app.init_tables(rows), **app.dense.init_state()})
+    state = {
+        k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=NamedSharding(mesh, specs[k]))
+        for k, v in shapes.items()
+    }
+    feed = NamedSharding(mesh, spmd.batch_spec())
+    lead = (steps,) if program == "multistep" else ()
+    fields = {
+        "unique_keys": ((unique,), jnp.int32), "local_ids": ((nnz,), jnp.int32),
+        "row_splits": ((MINIBATCH + 1,), jnp.int32), "values": ((nnz,), jnp.float32),
+        "labels": ((MINIBATCH,), jnp.float32), "example_mask": ((MINIBATCH,), jnp.bool_),
+    }
+    batch = {
+        k: jax.ShapeDtypeStruct((data, *lead, *shape), dt, sharding=feed)
+        for k, (shape, dt) in fields.items()
+    }
+    if program == "multistep":
+        fn, args = spmd.make_spmd_train_multistep(app, mesh, cfg.data.num_keys), (state, batch, 0)
+    else:
+        fn, args = spmd.make_spmd_predict_step(app, mesh, cfg.data.num_keys), (state, batch)
+    (jitted,) = [
+        c.cell_contents for c in fn.__closure__
+        if callable(c.cell_contents) and hasattr(c.cell_contents, "lower")
+    ]
+    return jitted.lower(*args).compile(), app, mesh, rows
+
+
 @pytest.fixture(scope="module")
 def dlrm_text(topo):
     """(data, kv, program) -> optimised HLO text of the DLRM programs at the
@@ -922,10 +968,8 @@ def dlrm_text(topo):
     once; the 2x2 and 1x4 programs are the small tests'
     (``tests/test_dlrm_pod.py``)."""
     import jax
-    import jax.numpy as jnp
-    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import NamedSharding
 
-    from parameter_server_tpu.models import dlrm
     from parameter_server_tpu.parallel import spmd
     from parameter_server_tpu.utils.config import PSConfig
 
@@ -937,37 +981,7 @@ def dlrm_text(topo):
             return texts[key]
         cfg = PSConfig()
         cfg.dlrm.field_rows = DLRM_FIELD_ROWS
-        cfg = dlrm.pod_config(cfg)
-        app = dlrm.app_from_config(cfg)
-        assert app.scope_names() == DLRM_NAMES
-        mesh = Mesh(np.array(topo.devices[: data * kv]).reshape(data, kv), ("data", "kv"))
-        specs = app.specs()
-        rows = spmd.padded_num_keys(cfg.data.num_keys, kv)
-        shapes = jax.eval_shape(lambda: {**app.init_tables(rows), **app.dense.init_state()})
-        state = {
-            k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=NamedSharding(mesh, specs[k]))
-            for k, v in shapes.items()
-        }
-        feed = NamedSharding(mesh, spmd.batch_spec())
-        lead = (K,) if program == "multistep" else ()
-        fields = {
-            "unique_keys": ((UNIQUE,), jnp.int32), "local_ids": ((NNZ,), jnp.int32),
-            "row_splits": ((MINIBATCH + 1,), jnp.int32), "values": ((NNZ,), jnp.float32),
-            "labels": ((MINIBATCH,), jnp.float32), "example_mask": ((MINIBATCH,), jnp.bool_),
-        }
-        batch = {
-            k: jax.ShapeDtypeStruct((data, *lead, *shape), dt, sharding=feed)
-            for k, (shape, dt) in fields.items()
-        }
-        if program == "multistep":
-            fn, args = spmd.make_spmd_train_multistep(app, mesh, cfg.data.num_keys), (state, batch, 0)
-        else:
-            fn, args = spmd.make_spmd_predict_step(app, mesh, cfg.data.num_keys), (state, batch)
-        (jitted,) = [
-            c.cell_contents for c in fn.__closure__
-            if callable(c.cell_contents) and hasattr(c.cell_contents, "lower")
-        ]
-        compiled = jitted.lower(*args).compile()
+        compiled, app, mesh, rows = compile_dlrm(topo, cfg, DLRM_NAMES, data, kv, program, K, NNZ, UNIQUE)
         texts[key] = compiled.as_text()
         texts[key, "memory"] = compiled.memory_analysis()
         if program == "multistep":  # the table made on the device in one pass
@@ -1084,3 +1098,95 @@ def test_dlrm_every_large_op_is_under_a_scope(dlrm_text, data, kv, program):
         if opcode == "fusion" and not scopes[name]:
             # bookkeeping at batch size (a (U, 128) buffer at most), never a table op
             assert elements(shape) <= DLRM_DIM * UNIQUE and all(elements(s) < 10**7 for s in operand_shapes), name
+
+
+# -- the multi-hot form: bags summed a field, the cross network, AdaGrad; the streamed scatter --------
+DCN_VOCAB = [
+    40000000, 39060, 17295, 7424, 20265, 3, 7122, 1543, 63, 40000000, 3067956, 405282, 10,
+    2209, 11938, 155, 4, 976, 14, 40000000, 40000000, 40000000, 590152, 12973, 108, 36,
+]
+DCN_FIELD_ROWS = [min(c, 1_500_000) for c in DCN_VOCAB]  # the cell dcn1tb.train: max_ind_range 1,500,000
+DCN_HOT = [3, 2, 1, 2, 6, 1, 1, 1, 1, 7, 3, 8, 1, 6, 9, 5, 1, 1, 1, 12, 100, 27, 10, 3, 1, 1]
+DCN_K, DCN_NNZ, DCN_UNIQUE = 4, 1 << 21, 1 << 20  # 4 microsteps a call; 227 x 8192 entries; about 627,000 keys
+DCN_NAMES = frozenset({"emb", "mlp", "bot", "cross", "top", "pool"})
+
+
+@pytest.fixture(scope="module")
+def dcn_compiled(topo):
+    """(optimised HLO text, memory analysis) of ``dcn1tb.train``'s scanned
+    step at the cell's sizes: 10,117,120 rows x 128 lanes of ``w`` and
+    ``n`` under AdaGrad, bags of 1 to 100 ids, three cross layers of rank
+    512, a 2^21-slot entry axis and a 2^20-slot key axis. One compile,
+    about half a minute."""
+    from parameter_server_tpu.utils.config import PSConfig
+
+    cfg = PSConfig()
+    d = cfg.dlrm
+    d.field_rows, d.hot, d.cross_layers, d.cross_rank, d.updater, d.eta = DCN_FIELD_ROWS, DCN_HOT, 3, 512, "adagrad", 0.004
+    cfg.data.max_nnz_per_example = 256
+    compiled, *_ = compile_dlrm(topo, cfg, DCN_NAMES, 1, 1, "multistep", DCN_K, DCN_NNZ, DCN_UNIQUE)
+    return compiled.as_text(), compiled.memory_analysis()
+
+
+def test_dcn_step_names_its_phases_streams_both_scatters_and_holds_no_second_table(dcn_compiled):
+    """The cell ``dcn1tb.train`` at its shapes. The step names
+    ``ps.grad/emb/pool`` (the take by position, the sum a field, their
+    backward pass) and ``ps.grad/mlp/cross`` beside ``bot`` and ``top``; both
+    of ``emb``'s scatters (``w`` and ``n``) carry ``indices_are_sorted``:
+    1,235 table elements a key slot, under ``_STREAM_ELEMENTS_A_SLOT``, so
+    ``store.scatter_rows_sorted`` says the streamed emitter is the cheaper,
+    the scatter is one whole scatter and is not walked (the first cell on
+    this side of the rule), in place; and the step's temporaries (the pulled
+    rows, the take of 214 rows an example and its cotangent, the push's
+    gathered rows and deltas) stay under 3 GiB, which a copy of either slot
+    (4.82 GiB) would break."""
+    from parameter_server_tpu.kv import store
+    from parameter_server_tpu.parallel import spmd
+
+    text, mem = dcn_compiled
+    _, scopes = spmd.hlo_scopes(text, DCN_NAMES)
+    rows = spmd.padded_num_keys(14 + sum(DCN_FIELD_ROWS), 1)
+    assert (14 + sum(DCN_FIELD_ROWS), rows) == (10_116_646, 10_117_120)
+    slot_bytes = 4 * rows * DLRM_DIM
+    assert 2 * slot_bytes == 10_359_930_880  # w + n: 9.65 GiB
+    found = set(scopes.values())
+    assert {
+        "ps.pull/emb", "ps.grad/emb/pool", "ps.grad/mlp/bot", "ps.grad/mlp/cross", "ps.grad/mlp/top",
+        "ps.push/gather/emb", "ps.push/update/emb", "ps.push/scatter/emb", "ps.dense",
+    } <= found, found
+    assert "ps.grad/mlp/interact" not in found
+    every = instructions(text)
+    op_name_of = {
+        name: (re.search(r'op_name="([^"]*)"', rest) or [None, ""])[1] for _, name, _, _, _, rest in every
+    }
+    pooled = [name for name, _, _, _ in executed(text) if scopes[name] == "ps.grad/emb/pool"]
+    assert any("transpose(" in op_name_of[name] for name in pooled), "the backward pass carries the scope"
+    table = re.compile(rf"\[{rows},{DLRM_DIM}\]")
+    touching = [
+        (name, scopes[name])
+        for name, shape, opcode, operand_shapes in executed(text)
+        if table.search(shape) or any(table.search(s) for s in operand_shapes)
+    ]
+    assert touching
+    assert all(re.match(r"^ps\.(pull|push/\w+)/emb$", scope) for _, scope in touching), touching
+    assert store.scatter_rows_sorted(rows, DLRM_DIM, DCN_UNIQUE) and not store.scatter_walks(rows, DLRM_DIM, DCN_UNIQUE)
+    assert rows * DLRM_DIM // DCN_UNIQUE == 1235 < store._STREAM_ELEMENTS_A_SLOT
+    scatters = [(comp, rest) for comp, _, shape, opcode, _, rest in every if opcode == "scatter" and table.search(shape)]
+    assert len(scatters) == 2, scatters  # w and n
+    assert all(sorted_hint(told) for _, told in scatters), scatters
+    # the streamed emitter's scatter sits in a fusion inside the executed fusion: climb to that one
+    homes, outer = {home for home, _ in scatters}, []
+    comp_of = {name: comp for comp, name, _, _, _, _ in every}
+    run = {name for name, _, _, _ in executed(text)}
+    while homes:
+        calling = fusions_calling(every, homes)
+        outer += [(name, rest) for name, rest in calling if name in run]
+        homes = {comp_of[name] for name, _ in calling if name not in run}
+    assert len(outer) == 2, outer
+    for name, rest in outer:
+        assert scopes[name] == "ps.push/scatter/emb", (name, scopes[name])
+        assert aliases_operand_0(rest), (name, rest[-300:])
+    assert not copies_of(every, rows * DLRM_DIM)
+    assert mem.alias_size_in_bytes >= 2 * slot_bytes  # both slots donated through the call
+    assert mem.temp_size_in_bytes < (3 << 30), mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 2 * slot_bytes + (3 << 30)
