@@ -44,7 +44,14 @@ vector (a row twice in a bag counts twice):
 the low-rank cross network of DCN V2 (Wang et al., arXiv:2008.13535) in the
 pairwise dots' place, its layers under the dense group's ``"cross"``; and
 both halves step by AdaGrad (``kv.updaters.Adagrad`` / ``dense_adagrad``).
-Bags of VARIABLE length would need a field id an entry: not here.
+Bags of VARIABLE length would need a field id an entry: not here. The
+bags' rows are read POSITION-MAJOR (``read_bags``; since PR 53): the take
+by the turned slots writes ``(sum(h_f), B, emb_dim)``, a bag is a run of
+its leading axis, and ``pool_bags``' hand-written backward pass turns the
+``(B, 26, emb_dim)`` cotangent and writes its planes out once, so that the
+two arrays of ``sum(h_f) B emb_dim`` elements (898 MB each at the
+benchmark's sizes) are written by the take and read by its transpose, the
+scatter-add, and never relaid.
 
 This module holds the model and its description (``dlrm_app``); the step
 is ``parallel.spmd``'s and the training loop ``PodTrainer``'s."""
@@ -193,15 +200,54 @@ def _by_position(flat: jax.Array, examples: int, entries: int = ENTRIES) -> jax.
     return flat[:need].reshape(examples, entries)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
 def pool_bags(rows: jax.Array, hot: tuple[int, ...]) -> jax.Array:
     """(B, sum(hot), d) -> (B, F, d): column f's vector, the sum of its
     bag's ``hot[f]`` rows, which lie one behind the other in the entry
-    axis: a static slice and a sum a column."""
-    out, at = [], 0
+    axis. Summed POSITION-MAJOR, as runs of the leading axis of ``(sum(hot),
+    B, d)``: that is how the step's take writes the rows (the swap here folds
+    with the caller's), so the one array of ``sum(hot) B d`` elements is never
+    relaid; the backward pass is written by hand for the same reason."""
+    planes, out, at = jnp.swapaxes(rows, 0, 1), [], 0
     for h in hot:
-        out.append(rows[:, at] if h == 1 else jnp.sum(rows[:, at : at + h], axis=1))
+        out.append(planes[at] if h == 1 else jnp.sum(planes[at : at + h], axis=0))
         at += h
     return jnp.stack(out, axis=1)
+
+
+def _pool_bags_fwd(rows, hot):
+    return pool_bags(rows, hot), None
+
+
+def _pool_bags_bwd(hot, _, g):
+    """A bag's rows each take their column's cotangent. The SMALL array
+    turns, ``(B, F, d)`` to ``(F, B, d)``, and its planes are written out
+    once, position-major, as the scatter-add behind reads them: ``jax.grad``
+    of the sums builds the ``(sum(hot), B, d)`` cotangent batch-minor (the
+    dense half's layout carried backward) and copies it row-major."""
+    gp = jnp.swapaxes(g, 0, 1)
+    planes = jnp.concatenate(
+        [jnp.broadcast_to(gp[f][None], (h, *gp.shape[1:])) for f, h in enumerate(hot)], axis=0
+    )
+    return (jnp.swapaxes(planes, 0, 1),)
+
+
+pool_bags.defvjp(_pool_bags_fwd, _pool_bags_bwd)
+
+
+def read_bags(pulled: jax.Array, slots: jax.Array, hot: tuple[int, ...]) -> jax.Array:
+    """(U, d) pulled rows and (B, sum(hot)) slots -> (B, F, d): every
+    column's bag taken and summed. The SLOTS turn (int32, ``d`` times
+    smaller than the rows), so the take writes ``(sum(hot), B, d)``
+    row-major, which is its natural ``(sum(hot) B, d)`` with no copy where
+    ``B`` is whole 8-row tiles, and its transpose, the scatter-add, reads
+    the cotangent the same way; ``(B, sum(hot), d)`` exists only as the
+    seam to ``pool_bags`` (looked up in the module when called: a run with
+    another pooling in its place is differentiated by ``jax.grad``). A
+    batch's ``local_ids`` lie inside its own key axis, so the take need
+    select no fill in (``mode="clip"``)."""
+    planes = jnp.take(pulled, slots.T, axis=0, mode="clip")
+    return pool_bags(jnp.swapaxes(planes, 0, 1), hot)
 
 
 def _logits(pulled, params, b, row_ids, hot: tuple[int, ...] | None = None):
@@ -217,7 +263,7 @@ def _logits(pulled, params, b, row_ids, hot: tuple[int, ...] | None = None):
         e = jnp.take(pulled[TABLE], slots, axis=0)  # (B, 26, d)
     else:
         with _sub_scope(TABLE), jax.named_scope("pool"):
-            e = pool_bags(jnp.take(pulled[TABLE], slots, axis=0), hot)
+            e = read_bags(pulled[TABLE], slots, hot)
     with _sub_scope(DENSE):
         with jax.named_scope("bot"):
             z0 = mlp.mlp_apply(params["bot"], x, last=jax.nn.relu)

@@ -454,6 +454,86 @@ def test_pool_bags_sums_each_fields_run():
     assert got.shape == (5, 26, DIM)
 
 
+def _plain_read(pulled, slots, hot):
+    """The pooled read with nothing written by hand: one take of the
+    ``(B, sum(hot))`` slots, a slice and a sum a field."""
+    rows, out, at = jnp.take(pulled, slots, axis=0), [], 0
+    for h in hot:
+        out.append(rows[:, at : at + h].sum(axis=1))
+        at += h
+    return jnp.stack(out, axis=1)
+
+
+def _first_row_of_each_bag(rows, hot):
+    """``benchmark/tests/test_controls_dcn.py``'s broken pooling, to the
+    letter: every field's vector its bag's first row."""
+    at = [sum(hot[:f]) for f in range(len(hot))]
+    return rows[:, jnp.asarray(at)]
+
+
+def _step_inputs(examples, hot, rng):
+    """(pulled, params, batch, slots) for ``dlrm._logits``: every bag
+    position of every example reads a row of its own."""
+    entries = 13 + sum(hot)
+    ids = np.zeros((examples, entries), np.int32)
+    slots = 1 + np.arange(examples * sum(hot), dtype=np.int32).reshape(examples, sum(hot))
+    ids[:, 13:] = slots
+    values = np.ones((examples, entries), np.float32)
+    values[:, :13] = rng.normal(size=(examples, 13))
+    batch = {
+        "local_ids": jnp.asarray(ids.reshape(-1)), "values": jnp.asarray(values.reshape(-1)),
+        "labels": jnp.asarray(rng.integers(0, 2, examples).astype(np.float32)),
+        "example_mask": jnp.ones(examples, bool),
+    }
+    pulled = {dlrm.TABLE: jnp.asarray(rng.normal(size=(1 + slots.size, DIM)).astype(np.float32))}
+    params = jax.tree_util.tree_map(jnp.asarray, dlrm.init_mlps(3, DIM, BOT, TOP, LAYERS, RANK))
+    return pulled, params, batch, slots
+
+
+@pytest.mark.parametrize("kind,examples", [
+    ("runs_of_one", 8), ("runs_of_one", 13), ("no_run_of_one", 16), ("no_run_of_one", 5), ("cell_bags", 8),
+    ("first_row_seam", 8), ("first_row_seam", 13),
+])
+def test_pooled_read_differentiates_as_the_plain_form_and_leaves_the_pooling_to_jax_grad(kind, examples, monkeypatch):
+    """``read_bags`` takes position-major and ``pool_bags`` carries a
+    hand-written backward pass: the gradient with respect to the pulled rows
+    is the plain form's (``jax.grad`` of a take, a slice and a sum) on slots
+    that repeat inside a bag, across bags and across examples, with and
+    without runs of one-id bags, at a batch of whole 8-row tiles and not.
+    And the hand-written pass is ``pool_bags``' alone: with the module's
+    ``pool_bags`` replaced by a function that keeps each bag's first row (the
+    benchmark's control, which tier 1 does not run), the step's logits change
+    and every other bag row's gradient is exactly zero, so the control still
+    fails a wrong program."""
+    rng = np.random.default_rng(examples)
+    if kind == "first_row_seam":
+        hot = tuple(HOT)
+        pulled, params, batch, slots = _step_inputs(examples, hot, rng)
+        sound = dlrm._logits(pulled, params, batch, None, hot)
+        monkeypatch.setattr(dlrm, "pool_bags", _first_row_of_each_bag)
+        broken = dlrm._logits(pulled, params, batch, None, hot)
+        assert np.abs(np.asarray(broken) - np.asarray(sound)).max() > 1e-3
+        _, _, g_pulled, _ = dlrm._grad(pulled, params, batch, None, hot)
+        g = np.asarray(g_pulled[dlrm.TABLE])
+        first = np.zeros(sum(hot), bool)
+        first[np.cumsum((0, *hot[:-1]))] = True
+        assert (g[slots[:, ~first]] == 0.0).all() and (np.abs(g[slots[:, first]]).max(axis=-1) > 0).all()
+        return
+    hot = {"runs_of_one": tuple(HOT), "no_run_of_one": (3, 2, 7, 2, 4), "cell_bags": (1, 1, 12, 100, 27, 1)}[kind]
+    slots = rng.integers(0, 9, size=(examples, sum(hot))).astype(np.int32)  # nine rows: repeats everywhere
+    slots[0, :3] = 4  # a row three times in one bag,
+    slots[1] = slots[0]  # every bag of an example in another's,
+    slots[2, -2:] = slots[2, 0]  # a row in two bags of one example
+    pulled = jnp.asarray(rng.normal(size=(12, DIM)).astype(np.float32))
+    ct = jnp.asarray(rng.normal(size=(examples, len(hot), DIM)).astype(np.float32))
+    slots = jnp.asarray(slots)
+    np.testing.assert_allclose(dlrm.read_bags(pulled, slots, hot), _plain_read(pulled, slots, hot), rtol=1e-6, atol=1e-5)
+    got = jax.grad(lambda p: jnp.sum(ct * dlrm.read_bags(p, slots, hot)))(pulled)
+    want = jax.grad(lambda p: jnp.sum(ct * _plain_read(p, slots, hot)))(pulled)
+    assert np.asarray(want)[9:].max() == 0.0 and np.abs(np.asarray(want)[:9]).min() > 0
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
+
+
 @pytest.mark.parametrize("mesh_name", ["1x1", "2x2"])
 def test_predict_is_the_references_forward_pass(tmp_path, mesh_name):
     data = MESHES[mesh_name][0]

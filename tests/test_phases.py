@@ -269,6 +269,7 @@ class TestReaderTimers:
         n0 = _count("reader.put_wait")
         it = iter(_reader(tmp_path, prefetch=1))
         next(it)
+        time.sleep(0.3)  # until a stage has filled its queue and blocks: one that had not yet is let go unblocked
         it.close()  # the caller leaves: the thread blocked on its full queue is let go
         deadline = time.time() + 5
         while _count("reader.put_wait") == n0 and time.time() < deadline:
