@@ -33,7 +33,11 @@ batch (plus, once a file, the start of the next reader and its thread);
 ``feed.put_wait`` a producer blocked on its full queue (the feed's slack);
 ``feed.stack`` ``prepare`` + ``assemble`` in the stacker thread (count:
 emitted items; one thread serves every stream, so its busy share has a
-wall at 100%).
+wall at 100%). While a tracing plane is on, ``feed.stack`` also feeds a
+twin ``feed.stack.cpu``, the CPU seconds of the stacker inside the phase:
+wall less CPU is what it stood waiting for the interpreter lock, the
+runtime or the machine. The other two, a phase around the reader's own and
+a wait, keep to the wall.
 
 Draining contract: ``get()`` returns ``None`` once every stream is
 exhausted (and forever after). Callers that must keep issuing collectives

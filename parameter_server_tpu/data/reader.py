@@ -27,7 +27,11 @@ full queue (its slack). Which stage paces an ``iter(reader)``: a build
 thread in ``reader.parsed_wait`` says the parse, a parse thread in
 ``reader.put_wait`` the build, a build thread in ``reader.put_wait`` the
 caller. The Python parsers yield a row at a time, so that path has no
-``reader.parse``.
+``reader.parse``. While a tracing plane is on, the two working phases also
+feed a twin ``<name>.cpu``, the CPU seconds of their own thread inside
+them: ``reader.parse`` less ``reader.parse.cpu`` is what the parse thread
+waited (for the interpreter lock between its native calls, the machine's
+run queue, the disk) and did not work. The two waits keep to the wall.
 """
 
 from __future__ import annotations

@@ -15,7 +15,7 @@ import sys
 import threading
 import time
 from pathlib import Path
-from typing import Any
+from typing import Any, Callable
 
 import numpy as np
 
@@ -482,23 +482,28 @@ class Timer:
 
     Thread-safe: the live ``t0`` is thread-local (the checkpoint thread
     and serve threads tic/toc concurrently without racing each other's
-    start marks) and the totals are lock-protected."""
+    start marks) and the totals are lock-protected. ``clock`` is the wall
+    (``time.perf_counter``) unless the timer is a phase's ``.cpu`` twin,
+    which reads ``time.thread_time``: the seconds the calling thread was
+    on a CPU, not those it waited for the interpreter lock, the run queue
+    or the disk."""
 
-    def __init__(self) -> None:
+    def __init__(self, clock: Callable[[], float] = time.perf_counter) -> None:
+        self._clock = clock
         self._local = threading.local()
         self._lock = threading.Lock()
         self.total = 0.0
         self.count = 0
 
     def tic(self) -> None:
-        self._local.t0 = time.perf_counter()
+        self._local.t0 = self._clock()
 
     def toc(self, count: int = 1) -> float:
         """Add the time since ``tic`` and ``count`` finished units (0 for
         time that belongs here but completes no unit of its own)."""
         t0 = getattr(self._local, "t0", None)
         assert t0 is not None, "toc without tic"
-        dt = time.perf_counter() - t0
+        dt = self._clock() - t0
         self._local.t0 = None
         with self._lock:
             self.total += dt
@@ -520,23 +525,34 @@ class Timer:
 class TimerRegistry:
     """Process-global named timers (ref: resource_usage.h's named tic/toc
     tables): ``timers.timer("trainer.dispatch")`` returns one shared
-    Timer per name, and ``snapshot()`` rides the telemetry plane."""
+    Timer per name, and ``snapshot()`` rides the telemetry plane. Beside
+    the named timers a snapshot holds ``process.cpu``: ``total_s`` the CPU
+    seconds of every thread of the process so far (``time.process_time``,
+    the runtime's and a profiler's threads included), ``count`` the
+    snapshots taken, so that the difference of two snapshots is what the
+    whole process burned between them."""
 
     def __init__(self) -> None:
         self._d: dict[str, Timer] = {}
         self._lock = threading.Lock()
+        self._snapshots = 0
 
-    def timer(self, name: str) -> Timer:
+    def timer(self, name: str, clock: Callable[[], float] = time.perf_counter) -> Timer:
+        """The timer ``name``, made on ``clock`` where it is new."""
         t = self._d.get(name)
         if t is None:
             with self._lock:
-                t = self._d.setdefault(name, Timer())
+                t = self._d.setdefault(name, Timer(clock))
         return t
 
     def snapshot(self) -> dict[str, dict[str, float]]:
         with self._lock:
             ts = dict(self._d)
-        return {k: t.snapshot() for k, t in ts.items()}
+            self._snapshots += 1
+            taken = self._snapshots
+        out = {k: t.snapshot() for k, t in ts.items()}
+        out["process.cpu"] = {"total_s": time.process_time(), "count": taken}
+        return out
 
     def reset(self) -> None:
         """Tests/benchmarks only."""

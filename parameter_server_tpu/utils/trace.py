@@ -961,10 +961,26 @@ def enabled() -> bool:
     return tracer.enabled
 
 
+#: the phases that keep a twin timer ``<name>.cpu`` on the thread's CPU
+#: clock while a tracing plane is on (a profiler session, or the tracer
+#: armed): the working leaves of the threads that make a batch and hand
+#: it to the chip, which are the ones a metric reads (``benchmark/
+#: layer_metrics_cpu.py``). Never with tracing off, nor on waits and the
+#: phases around other phases: the clock is a real system call with the
+#: interpreter lock held (12 us under the chip machine's sandboxed kernel,
+#: 0.4-0.6 on Linux), and four twins a batch cost the one host-paced cell
+#: 1% of its rate
+_CPU_TWINS = frozenset({
+    "reader.parse", "reader.build", "feed.stack", "trainer.dispatch",
+    "eval.open_reader", "eval.stack", "eval.enqueue", "eval.score",
+    "eval.new_shapes",
+})
+
+
 class _Phase:
     """One host phase, entered in three planes at once (see ``phase``)."""
 
-    __slots__ = ("_timer", "_annotation", "_span", "count")
+    __slots__ = ("_timer", "_cpu", "_annotation", "_span", "count")
 
     def __init__(self, name: str, args: dict[str, Any]):
         self._timer = timers.timer(name)
@@ -974,10 +990,16 @@ class _Phase:
         self._annotation = (
             profiler.TraceAnnotation(name, **args) if profiler else _NOOP
         )
+        self._cpu = None
+        if name in _CPU_TWINS and (
+            tracer.enabled
+            or (profiler is not None and profiler.TraceAnnotation.is_enabled())
+        ):
+            self._cpu = timers.timer(name + ".cpu", time.thread_time)
         self._span = tracer.span(name, name.partition(".")[0], **args)
-        #: units of work this phase completes: what the timer's count
-        #: grows by at exit. Set it to 0 inside the block for time that
-        #: belongs to the phase but finishes no unit of its own.
+        #: units of work this phase completes: what the timer's count, and
+        #: its twin's, grows by at exit. Set it to 0 inside the block for
+        #: time that belongs to the phase but finishes no unit of its own.
         self.count = 1
 
     def set(self, **args: Any) -> None:
@@ -989,9 +1011,13 @@ class _Phase:
         self._span.__enter__()
         self._annotation.__enter__()
         self._timer.tic()
+        if self._cpu is not None:
+            self._cpu.tic()
         return self
 
     def __exit__(self, et, ev, tb) -> bool:
+        if self._cpu is not None:
+            self._cpu.toc(self.count)
         self._timer.toc(self.count)
         self._annotation.__exit__(et, ev, tb)
         self._span.__exit__(et, ev, tb)
@@ -1004,9 +1030,19 @@ def phase(name: str, **args: Any) -> _Phase:
     ``utils.metrics.timers`` (what telemetry and the benchmark read), a
     ``jax.profiler.TraceAnnotation`` (on the device trace's own clock
     whenever a profiler session is running, a no-op otherwise), and a
-    ``Tracer`` span when the tracer is armed, and only then. About a
-    microsecond with both off. ``args`` label the span and the
-    annotation."""
+    ``Tracer`` span when the tracer is armed, and only then. While either
+    of those two is on, a phase named in ``_CPU_TWINS`` (the working
+    leaves a metric reads) also feeds a twin timer on a second clock,
+    ``<name>.cpu``: the CPU seconds of the calling thread inside the phase
+    (``time.thread_time()`` at both ends), its count growing by what the
+    phase's does, so that the wall seconds a unit less the CPU seconds a
+    unit are what the thread stood in the phase without running: waiting
+    for the interpreter lock, the machine's run queue, the disk, or
+    whatever the phase blocks on. Nested phases each count their own
+    interval on both clocks. With both planes off a phase costs 2 to 3 us
+    and reads no clock but the wall; a twin adds 2 us (its two clock reads
+    and its timer), and 25 under the chip machine's kernel, where a read
+    is a slow system call. ``args`` label the span and the annotation."""
     return _Phase(name, args)
 
 

@@ -3,9 +3,13 @@
 the timers the reader's thread, the feed, the trainer and the evaluator
 leave behind."""
 
+import contextlib
 import glob
+import hashlib
 import os
+import resource
 import sys
+import threading
 import time
 
 import jax
@@ -23,7 +27,7 @@ from parameter_server_tpu.parallel.mesh import make_mesh
 from parameter_server_tpu.parallel.trainer import PodTrainer
 from parameter_server_tpu.utils import trace
 from parameter_server_tpu.utils.config import PSConfig
-from parameter_server_tpu.utils.metrics import ProgressReporter, timers
+from parameter_server_tpu.utils.metrics import ProgressReporter, Timer, timers
 
 PHASES = ("ps.row_ids", "ps.pull", "ps.grad", "ps.push/scatter")
 NUM_KEYS, B, NNZ, U = 1 << 10, 16, 64, 65
@@ -186,6 +190,210 @@ class TestPhase:
         hosts = [p for p in prof.planes if not p.name.startswith("/device:")]
         names = {ev.name for p in hosts for ln in p.lines for ev in ln.events}
         assert "test.phase_profiled" in names
+
+
+def _spin(seconds: float) -> None:
+    """Python that holds a CPU (and the interpreter lock) for ``seconds``."""
+    until = time.perf_counter() + seconds
+    while time.perf_counter() < until:
+        sum(range(200))
+
+
+@contextlib.contextmanager
+def _spinning_threads(n: int):
+    """``n`` threads that spin in Python until the block ends."""
+    stop = threading.Event()
+    threads = [threading.Thread(target=lambda: [_spin(0.01) for _ in iter(stop.is_set, True)]) for _ in range(n)]
+    for t in threads:
+        t.start()
+    try:
+        yield
+    finally:
+        stop.set()
+        for t in threads:
+            t.join()
+
+
+class TestPhaseCpu:
+    """``<name>.cpu``: the CPU seconds of the phase's own thread, beside the
+    wall seconds of ``<name>``, for the phases ``trace._CPU_TWINS`` names
+    while a tracing plane is on (here the tracer, armed)."""
+
+    @pytest.fixture(autouse=True)
+    def _twins_for_the_tests_names_and_the_tracer_armed(self, monkeypatch, tmp_path):
+        names = "sleep spin alone beside counts own ends short outer inner plane keys".split()
+        monkeypatch.setattr(trace, "_CPU_TWINS", trace._CPU_TWINS | {f"test.cpu_{n}" for n in names})
+        trace.configure(str(tmp_path), process_name="phase-cpu-test")
+        yield
+        trace.configure(None)
+
+    def test_a_sleep_is_wall_and_no_cpu(self):
+        w0, c0 = _total("test.cpu_sleep"), _total("test.cpu_sleep.cpu")
+        with trace.phase("test.cpu_sleep"):
+            time.sleep(0.05)
+        assert _total("test.cpu_sleep") - w0 >= 0.05
+        assert _total("test.cpu_sleep.cpu") - c0 < 0.01
+
+    def test_a_spin_is_cpu(self):
+        """Held to another clock of the kernel's, the thread's resource usage: a
+        spin that lasts until that clock has counted 50 ms leaves them in the
+        twin, however long a busy machine (six xdist workers) makes it take."""
+        def used() -> float:
+            ru = resource.getrusage(resource.RUSAGE_THREAD)
+            return ru.ru_utime + ru.ru_stime
+
+        w0, c0 = _total("test.cpu_spin"), _total("test.cpu_spin.cpu")
+        with trace.phase("test.cpu_spin"):
+            until = used() + 0.05
+            while used() < until:
+                sum(range(200))
+        wall, cpu = _total("test.cpu_spin") - w0, _total("test.cpu_spin.cpu") - c0
+        assert 0.04 <= cpu <= wall + 1e-3
+
+    def test_a_wait_for_the_interpreter_lock_is_wall_and_no_cpu(self):
+        """Native work with the lock released, then a little Python, as the
+        parse thread does: beside two threads that spin in Python the phase's
+        wall grows by the waits for the lock and its CPU stays what the
+        thread costs alone."""
+        data, rounds = bytes(2 << 20), 20
+
+        def work(name: str):
+            def run():
+                for _ in range(rounds):
+                    with trace.phase(name):
+                        hashlib.sha256(data).digest()  # the lock released
+                        sum(range(2000))
+            return run
+
+        def reading(name: str) -> tuple:
+            w0, c0 = _total(name), _total(name + ".cpu")
+            thread = threading.Thread(target=work(name))
+            thread.start()
+            thread.join()
+            return _total(name) - w0, _total(name + ".cpu") - c0
+
+        for attempt in range(5):  # a busy machine moves the CPU side too: the thread shares its core
+            _, alone = reading("test.cpu_alone")
+            with _spinning_threads(2):
+                wall, cpu = reading("test.cpu_beside")
+            if wall >= 2 * cpu and abs(cpu - alone) <= alone / 3:
+                break
+        assert wall >= 2 * cpu, (wall, cpu, alone)
+        assert abs(cpu - alone) <= alone / 3, (wall, cpu, alone)
+
+    def test_the_two_counts_are_one(self):
+        n0, c0, t0 = _count("test.cpu_counts"), _count("test.cpu_counts.cpu"), _total("test.cpu_counts.cpu")
+        for units in (1, 0, 5):
+            with trace.phase("test.cpu_counts") as ph:
+                ph.count = units
+                _spin(0.001)
+        assert _count("test.cpu_counts") - n0 == _count("test.cpu_counts.cpu") - c0 == 6
+        assert _total("test.cpu_counts.cpu") - t0 >= 0.002  # the phase that finished no unit is timed too
+
+    def test_a_phase_reads_its_own_threads_seconds(self):
+        with _spinning_threads(1):
+            for _ in range(5):  # a busy machine starves the spinning thread
+                c0, p0 = _total("test.cpu_own.cpu"), _total("process.cpu")
+                with trace.phase("test.cpu_own"):
+                    time.sleep(0.1)
+                own, process = _total("test.cpu_own.cpu") - c0, _total("process.cpu") - p0
+                assert own < 0.02  # the other thread's 100 ms are in the process's clock alone
+                if process >= 0.05:
+                    break
+        assert process >= 0.05 and process > 2 * own
+
+    def test_a_twin_reads_the_clock_at_both_ends_of_its_phase(self, monkeypatch):
+        """Two fresh readings a phase, however short it is and however close
+        its neighbour: none is handed on from one phase to the next."""
+        reads = []
+        real = time.thread_time
+        monkeypatch.setattr(trace.time, "thread_time", lambda: reads.append(1) or real())
+        c0 = _total("test.cpu_ends.cpu")
+        with trace.phase("test.cpu_ends"):
+            _spin(0.03)  # three ticks of the coarsest clock met (10 ms, the chip machine's)
+        assert len(reads) == 2 and _total("test.cpu_ends.cpu") - c0 > 0
+        for _ in range(3):
+            with trace.phase("test.cpu_short"):
+                pass
+        assert len(reads) == 8 and _count("test.cpu_short.cpu") >= 3
+
+    def test_a_phase_outside_the_list_has_no_twin_and_reads_no_clock_but_the_wall(self, monkeypatch):
+        """Waits and the phases around other phases: the thread's CPU clock is
+        a system call with the interpreter lock held, paid only where a metric
+        reads the answer."""
+        reads = []
+        monkeypatch.setattr(trace.time, "thread_time", lambda: reads.append(1) or 0.0)
+        n0 = _count("test.wall_alone")
+        with trace.phase("test.wall_alone"):
+            with trace.phase("reader.put_wait"):
+                pass
+        assert _count("test.wall_alone") - n0 == 1 and not reads
+        snap = timers.snapshot()
+        assert "test.wall_alone.cpu" not in snap and "reader.put_wait.cpu" not in snap
+
+    def test_nested_phases_each_count_their_own_interval(self):
+        o0, i0 = _total("test.cpu_outer.cpu"), _total("test.cpu_inner.cpu")
+        with trace.phase("test.cpu_outer"):
+            _spin(0.01)
+            with trace.phase("test.cpu_inner"):
+                _spin(0.02)
+        outer, inner = _total("test.cpu_outer.cpu") - o0, _total("test.cpu_inner.cpu") - i0
+        assert outer >= inner > 0
+
+    def test_the_twin_rides_the_telemetry_plane_and_the_export(self):
+        from parameter_server_tpu.utils.metrics import merge_telemetry, telemetry_snapshot
+        from parameter_server_tpu.utils.timeseries import render_openmetrics
+
+        with trace.phase("test.cpu_plane"):
+            _spin(0.005)
+        snap = telemetry_snapshot(roll_peaks=False)
+        one = snap["timers"]["test.cpu_plane.cpu"]
+        two = merge_telemetry([snap, snap])["timers"]
+        assert two["test.cpu_plane.cpu"] == {"total_s": 2 * one["total_s"], "count": 2 * one["count"]}
+        assert two["process.cpu"]["total_s"] == 2 * snap["timers"]["process.cpu"]["total_s"]  # a cluster's CPU seconds
+        text = render_openmetrics(snap)
+        assert "ps_timer_test_cpu_plane_cpu_seconds_total " in text and "ps_timer_test_cpu_plane_seconds_total " in text
+        assert "ps_timer_process_cpu_seconds_total " in text
+
+    def test_process_cpu_is_in_every_snapshot(self):
+        a = timers.snapshot()["process.cpu"]
+        _spin(0.02)
+        b = timers.snapshot()["process.cpu"]
+        assert set(a) == set(b) == {"total_s", "count"}
+        assert b["total_s"] >= a["total_s"] + 0.01  # never decreases, and the spin is in it
+        assert b["count"] == a["count"] + 1  # the snapshots taken: a window's difference is at least 1
+        assert set(Timer().snapshot()) == {"total_s", "count"}  # a timer's two keys, as ever
+        with trace.phase("test.cpu_keys"):
+            pass
+        assert set(timers.snapshot()["test.cpu_keys.cpu"]) == {"total_s", "count"}  # and its twin's
+
+
+class TestPhaseCpuOnlyWhileTracing:
+    """The thread's CPU clock is a system call with the interpreter lock held
+    (12 us on the chip machine): no phase pays it with both planes off."""
+
+    def test_with_tracing_off_a_listed_phase_reads_the_wall_alone(self, monkeypatch):
+        assert not trace.enabled() and "reader.parse" in trace._CPU_TWINS
+        reads = []
+        monkeypatch.setattr(trace.time, "thread_time", lambda: reads.append(1) or 0.0)
+        n0, c0 = _count("reader.parse"), _count("reader.parse.cpu")
+        with trace.phase("reader.parse"):
+            pass
+        assert not reads
+        assert (_count("reader.parse") - n0, _count("reader.parse.cpu") - c0) == (1, 0)
+
+    def test_inside_a_profiler_session_a_listed_phase_has_its_twin(self, tmp_path):
+        n0, c0 = _count("eval.score"), _count("eval.score.cpu")
+        with jax.profiler.trace(str(tmp_path)):
+            with trace.phase("eval.score"):
+                _spin(0.03)
+            with trace.phase("eval.pass"):
+                pass
+        with trace.phase("eval.score"):  # the session over: the wall alone again
+            pass
+        snap = timers.snapshot()
+        assert (_count("eval.score") - n0, _count("eval.score.cpu") - c0) == (2, 1)
+        assert snap["eval.score.cpu"]["total_s"] > 0 and "eval.pass.cpu" not in snap
 
 
 class _Stream:
@@ -492,3 +700,34 @@ class TestTrainerAndEvaluatorTimers:
         # the progress report no longer carries the static traffic estimate
         assert not hasattr(t, "est_step_traffic")
         assert all("est_collective_bytes" not in r for r in t.reporter.history)
+
+
+class TestCpuTwinsOfTheHostPath:
+    def test_every_working_phase_of_a_run_has_its_cpu_twin(self, tmp_path):
+        """The reader's two threads, the feed's stacker, the trainer's loop and
+        the evaluator's caller each leave ``<name>.cpu`` beside ``<name>`` for
+        the phases they work in while a tracing plane is on: as many units,
+        and no more seconds than the wall's (a clock tick apart)."""
+        before = timers.snapshot()
+        trace.configure(str(tmp_path / "trace"), process_name="host-path-test")  # a tracing plane on
+        try:
+            list(_reader(tmp_path, "native"))
+            with PrefetchPipeline([_Stream(6)], prepare=list, depth=8) as p:
+                while p.get() is not None:
+                    pass
+            t = _trainer()
+            files = _files(tmp_path, 8, "c")
+            t.train_files(files, report_every=100)
+            t.evaluate_files(files)
+        finally:
+            trace.configure(None)
+        after = timers.snapshot()
+        zero = {"total_s": 0.0, "count": 0}
+        snap = {k: {f: v[f] - before.get(k, zero)[f] for f in v} for k, v in after.items()}
+        for name in ("reader.parse", "reader.build", "feed.stack", "trainer.dispatch", "eval.score"):
+            wall, cpu = snap[name], snap[name + ".cpu"]
+            assert cpu["count"] == wall["count"] > 0, name
+            assert 0 <= cpu["total_s"] <= 1.01 * wall["total_s"] + 1e-3, (name, wall, cpu)
+        # the waits and the phases around other phases keep to the wall
+        for name in ("reader.parsed_wait", "reader.put_wait", "trainer.retire", "eval.read", "eval.pass", "eval.dispatch"):
+            assert snap[name]["count"] > 0 and name + ".cpu" not in snap, name

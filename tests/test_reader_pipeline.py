@@ -167,10 +167,13 @@ def test_an_error_of_either_stage_reaches_the_caller_once(tmp_path, backend, sta
 @pytest.mark.parametrize("backend", BACKENDS)
 @pytest.mark.parametrize("prefetch", [1, 4])
 def test_a_caller_that_leaves_takes_both_threads_with_it(tmp_path, backend, prefetch):
-    """After one batch of seven both stages are ahead, blocked on full
-    queues (``prefetch`` 1) or about to be: closing the iterator lets them
-    go, closes the parse behind the build, and joins them."""
-    it = iter(_reader(_files(tmp_path), backend, prefetch=prefetch))
+    """After one batch of sixteen both stages are ahead, blocked on full
+    queues (``prefetch`` 1) or about to be (sixteen, because at ``prefetch``
+    4 the two queues, the build and the caller hold ten: of seven the parse
+    thread could be through and gone before the caller looks): closing the
+    iterator lets them go, closes the parse behind the build, and joins
+    them."""
+    it = iter(_reader(_files(tmp_path, sizes=(400, 330, 277)), backend, prefetch=prefetch))
     assert next(it).num_examples == 64
     assert sorted(t.name for t in _reader_threads()) == ["ps-reader-build", "ps-reader-parse"]
     it.close()

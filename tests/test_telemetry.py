@@ -145,7 +145,7 @@ class TestTimerThreadSafety:
         assert snap["a"]["count"] == 2 and snap["b"]["count"] == 1
         assert reg.timer("a") is reg.timer("a")
         reg.reset()
-        assert reg.snapshot() == {}
+        assert set(reg.snapshot()) == {"process.cpu"}  # no named timer left: the process's clock alone
 
 
 class TestMergeProgressEdges:

@@ -21,7 +21,9 @@ this prints
     window are read here, where the ``eval`` kind takes no timer snapshot,
     and which of them paces a pass: ``reader.parsed_wait`` (the build
     thread's alone) says the parse, ``reader.put_wait`` under
-    ``reader/parse`` the build, under ``reader/build`` the caller;
+    ``reader/parse`` the build, under ``reader/build`` the caller. These
+    are wall seconds: how many of them a thread was on a CPU is on a traced
+    train run's ``[timers]`` line, ``<phase>.cpu`` beside ``<phase>``;
 (b) for the N longest idle gaps of chip 0, every such thread's phases that
     overlap the gap, by seconds of overlap, the share of the gap they cover
     (under 90%: the thread was in no span there), and the events of any kind
