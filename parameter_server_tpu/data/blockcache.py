@@ -58,7 +58,8 @@ class ColumnBlocks:
     analog). Block ``b`` owns chunks ``chunk_begin[b] .. chunk_begin[b+1]``
     of the ``(n_chunks, chunk_len)`` entry arrays and ``entries[b]`` real
     entries in them, **ascending by feature** (ties in example order): a
-    block's gradient is a sorted segment sum accumulated over its chunks.
+    feature's entries are one run of the entry axis, so a block's sums by
+    feature are running sums along it, chunk by chunk (``models.darlin``).
     Only a block's last chunk is padded, with entries of the block's last
     local feature, row 0 and value 0 (inert, and the order stays sorted),
     so the arrays hold the entries within a few percent of their own bytes

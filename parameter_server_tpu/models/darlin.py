@@ -9,7 +9,8 @@ re-expressed for TPU, through the one store:
   SlotReader column-block cache            ``data.blockcache.ColumnBlocks``:
     (parse once, per-slot binary cache)      entries by feature block, sorted
                                              by feature, in fixed-length
-                                             chunks resident in HBM
+                                             chunks resident in HBM, and where
+                                             each feature's run of them ends
   servers hold w by key range              a ``spmd.Table`` (slots ``w`` and
                                              ``active``) in the one state
                                              dict, range-sharded over "kv"
@@ -34,6 +35,14 @@ program), dispatched as ``PodTrainer`` dispatches its calls; the objective,
 the largest KKT violation and the count of non-zero weights come back as
 scalars with each call's retire, never the table.
 
+A block's entries lie sorted by feature, so its two sweeps by feature run
+along the entry axis (``ops.sparse._row_scan``'s gated passes, a chunk at a
+time, a run that crosses chunks carried over): the sums g and h are running
+sums read at each feature's last entry (``_block_grad``), the direction by
+entry is placed at each feature's first entry and copied down (``_block_xd``).
+The sweeps by example (the gathers of the residual and the curvature, the
+scatter-add of X_b d) are element by element.
+
 One departure from the source: upstream bounds each coordinate's step by a
 per-coordinate trust region; here one step scale a block comes from an
 eight-point search on the true objective (``_line_search_alpha``).
@@ -47,11 +56,13 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from parameter_server_tpu.data.batch import CSRBatch
 from parameter_server_tpu.data.blockcache import ENTRY_ARRAYS, ColumnBlocks
 from parameter_server_tpu.kv.updaters import ProxNewton
 from parameter_server_tpu.models import metrics as M
+from parameter_server_tpu.ops import sparse
 from parameter_server_tpu.parallel import spmd
 from parameter_server_tpu.parallel.ssp import DispatchWindow
 from parameter_server_tpu.utils import trace
@@ -135,7 +146,12 @@ def shard_blocks_for_mesh(
         selection back to back, a block's entries still ascending by feature
         and only its last chunk padded (the ``ColumnBlocks`` contract, shard
         by shard)
-      spans: (D, B, 2) int32 — block j's chunks [begin, end) in shard d
+      spans: (D, B, 3) int32 — block j's chunks [begin, end) in shard d,
+        and j, its row of ``ends``
+      ends: (D, B, block_size) int32 — where each feature's run of real
+        entries ends in shard d's part of block j, counted from the part's
+        first entry (``bincount(feat_local).cumsum()``: feature f's entries
+        are ``ends[f - 1] .. ends[f]``, none where the two are equal)
       block_idx: (B,) absolute block ids; counts: (B, D) real entry counts.
     On one data shard the whole set in order is the cache's own arrays,
     viewed, not copied.
@@ -149,8 +165,15 @@ def shard_blocks_for_mesh(
     )
     B = len(sel)
     counts = np.zeros((B, D), np.int64)
+    ends = np.zeros((D, B, cb.block_size), np.int32)
+
+    def run_ends(feat):
+        return np.bincount(feat, minlength=cb.block_size).cumsum()
+
     if D == 1:
         counts[:, 0] = cb.entries[sel]
+        for j, b in enumerate(sel):
+            ends[0, j] = run_ends(cb.block(int(b))[0])
         n_chunk = (cb.chunk_begin[sel + 1] - cb.chunk_begin[sel]).astype(np.int64)
         if blocks is None:
             packed = {k: np.asarray(getattr(cb, k))[None] for k in ENTRY_ARRAYS}
@@ -162,7 +185,7 @@ def shard_blocks_for_mesh(
             )
             packed = {k: np.asarray(getattr(cb, k)[at])[None] for k in ENTRY_ARRAYS}
             begin = np.cumsum(n_chunk) - n_chunk
-        spans = np.stack([begin, begin + n_chunk], axis=-1)[None].astype(np.int32)
+        spans = np.stack([begin, begin + n_chunk], axis=-1)[None]
     else:
         per_shard: list[list] = [[] for _ in range(D)]
         for j, b in enumerate(sel):
@@ -170,13 +193,14 @@ def shard_blocks_for_mesh(
             s = rows // per
             order = np.argsort(s, kind="stable")  # feature order survives
             counts[j] = np.bincount(s, minlength=D)
-            ends = np.cumsum(counts[j])
+            upto = np.cumsum(counts[j])
             for d in range(D):
-                part = order[ends[d] - counts[j, d] : ends[d]]
+                part = order[upto[d] - counts[j, d] : upto[d]]
                 per_shard[d].append((feat[part], rows[part] - d * per, vals[part]))
+                ends[d, j] = run_ends(feat[part])
         n_chunk = -(-counts // C)  # (B, D)
         begin = np.cumsum(n_chunk, axis=0) - n_chunk
-        spans = np.stack([begin, begin + n_chunk], axis=-1).transpose(1, 0, 2).astype(np.int32)
+        spans = np.stack([begin, begin + n_chunk], axis=-1).transpose(1, 0, 2)
         total = max(int(n_chunk.sum(axis=0).max()), 1)
         packed = {
             k: np.zeros((D, total * C), np.float32 if k == "values" else np.int32)
@@ -200,13 +224,169 @@ def shard_blocks_for_mesh(
             k: np.concatenate([v, np.zeros((D, want - have, C), v.dtype)], axis=1)
             for k, v in packed.items()
         }
+    row = np.broadcast_to(np.arange(B)[None, :, None], (D, B, 1))
     return {
         **packed,
-        "spans": spans,
+        "spans": np.concatenate([spans, row], axis=-1).astype(np.int32),
+        "ends": ends,
         "block_idx": sel.astype(np.int32),
         "counts": counts,
         "per_shard_examples": per,
     }
+
+
+def run_carries(cb: ColumnBlocks) -> tuple[float, int]:
+    """How far the sweeps by feature carry a run from chunk to chunk, read
+    off the cache's own chunks (the layout of one data shard): the share of
+    the chunks whose first run continues the chunk before (the same block,
+    the same feature at the seam), and the most chunks one run lies in."""
+    if not cb.chunk_begin[-1]:
+        return 0.0, 0
+    first, last = np.asarray(cb.feat_local[:, 0]), np.asarray(cb.feat_local[:, -1])
+    carries = np.concatenate([[False], first[1:] == last[:-1]])
+    carries[cb.chunk_begin[(cb.chunk_begin > 0) & (cb.chunk_begin < len(first))]] = False  # a block's first chunk
+    whole = first == last  # a chunk that is one run hands on what it took up
+    longest = run = 1
+    for k in range(1, len(first)):
+        run = (run + 1 if whole[k - 1] else 2) if carries[k] else 1
+        longest = max(longest, run)
+    return float(carries.mean()), longest
+
+
+# ---------------------------------------------------------------------------
+# The sweeps of one block's entries on one data shard. ``chunks_l`` holds the
+# shard's (n_chunks, C) entry arrays and ``ends`` (B, block_size); ``span`` is
+# the block's (first chunk, one past its last, row of ``ends``).
+# ---------------------------------------------------------------------------
+
+# Features a window: a chunk's running sums are read at its features' run ends
+# (and a direction placed at their run heads) a window of the feature axis at
+# a time, those windows alone that hold one of the chunk's features. A block
+# of F features in n chunks visits about F / _WINDOW + n windows: smaller
+# windows cost launches, larger ones element reads of features the chunk
+# does not hold.
+_WINDOW = 4096
+
+
+def _chunk(chunks_l, c):
+    return tuple(
+        lax.dynamic_index_in_dim(chunks_l[k], c, 0, keepdims=False)
+        for k in ENTRY_ARRAYS
+    )
+
+
+def _runs(chunks_l, span):
+    """(block_size + 1,) bounds of the features' runs of entries in this
+    shard's part of the block, counted from the part's first entry: feature
+    f's are ``[bounds[f], bounds[f + 1])``."""
+    ends = lax.dynamic_index_in_dim(chunks_l["ends"], span[2], 0, keepdims=False)
+    return jnp.concatenate([jnp.zeros(1, ends.dtype), ends])
+
+
+def _run_scan(x, fl, last, carry):
+    """Running sums of ``x`` ((C,) or (lanes, C)) along one chunk that
+    start again at every feature (``sparse._row_scan``: a chunk's terms
+    are added as a tree), the chunk's first run taking up ``carry``, the
+    total of the run the chunk before ended in, where it continues that
+    run (``last`` is that chunk's last feature; a feature's entries lie
+    next to each other, so it continues iff the ids are equal). Returns
+    the sums and the next chunk's (last, carry)."""
+    s = sparse._row_scan(x, fl)
+    s = s + jnp.where(fl == last, carry[..., None], 0)
+    return s, fl[-1], s[..., -1]
+
+
+def _windows(bounds, fl, base, turn, carry):
+    """``carry = turn(f0, at_head, at_tail, here, carry)`` over the windows
+    of the feature axis that hold one of the chunk's features (``fl``, the
+    chunk's ids, ascending; ``base``, its first entry's place in the
+    block): the window's features ``f0 ..``, where in the chunk each one's
+    run begins and where its last entry lies, and whether that feature has
+    an entry at all. A block that is no whole number of windows has its
+    last start early; a feature visited twice is read or placed twice, the
+    same."""
+    F = bounds.shape[0] - 1
+    W = min(_WINDOW, F)
+
+    def body(w, carry):
+        f0 = jnp.minimum(w * W, F - W)
+        b = lax.dynamic_slice_in_dim(bounds, f0, W + 1)
+        return turn(f0, b[:-1] - base, b[1:] - 1 - base, b[1:] > b[:-1], carry)
+
+    return lax.fori_loop(fl[0] // W, fl[-1] // W + 1, body, carry)
+
+
+def _block_grad(err, h_ex, chunks_l, span, want_h: bool = True):
+    """This shard's (lanes, block_size) sums by feature of one block,
+    lanes = (g, h) or (g,) alone: a block's entries lie sorted by
+    feature, so a feature's sum is a running sum along the entry axis
+    read at the feature's last entry, not a scatter-add an entry. Chunk
+    by chunk: the terms' running sums (``_run_scan``), then the features
+    whose run ends in the chunk read their totals out of them
+    (``_windows``); a feature with no entry keeps 0.
+    A key that every example holds has 10^7 terms: added one by one
+    into one float32 they stop counting near 2^24 (the cell's integer
+    columns: h read 1.7% low). Here a chunk's terms are added as a tree
+    and a run that crosses chunks adds the chunks' totals one by one
+    (the carry), so no sum is longer than a chunk or than the chunks of
+    a block; nothing in it is a sum the compiler could fold into a
+    longer one."""
+    C = chunks_l["values"].shape[-1]
+    bounds = _runs(chunks_l, span)
+    # a read stays inside what the loop sweeps: a run that a shorter span
+    # cuts keeps the sum of its swept part, as a scatter-add an entry would
+    bounds = jnp.minimum(bounds, (span[1] - span[0]) * C)
+    lanes = 2 if want_h else 1
+
+    def body(c, state):
+        g, last, carry = state
+        fl, rows, vals = _chunk(chunks_l, c)
+        terms = [vals * jnp.take(err, rows)]
+        if want_h:
+            terms.append(vals * vals * jnp.take(h_ex, rows))
+        s, last, carry = _run_scan(jnp.stack(terms), fl, last, carry)
+
+        def read(f0, at_head, at_tail, here, g):
+            ends_here = here & (at_tail >= 0) & (at_tail < C)
+            old = lax.dynamic_slice_in_dim(g, f0, here.shape[0], axis=-1)
+            # a lane at a time: for one gather of both the compiler turns the
+            # chunk's sums lanes-minor first, a tile of 128 to each entry
+            got = jnp.stack([jnp.take(lane, at_tail, mode="clip") for lane in s])
+            new = jnp.where(ends_here, got, old)
+            return sparse._entries_minor(lax.dynamic_update_slice_in_dim(g, new, f0, axis=-1))
+
+        return _windows(bounds, fl, (c - span[0]) * C, read, g), last, carry
+
+    g = sparse._entries_minor(jnp.zeros((lanes, bounds.shape[0] - 1), jnp.float32))
+    init = (g, jnp.int32(-1), jnp.zeros(lanes, jnp.float32))
+    return lax.fori_loop(span[0], span[1], body, init)[0]
+
+
+def _block_xd(d, chunks_l, span, per: int):
+    """This shard's X_b d: the block's entries scattered over its
+    examples, chunk by chunk. ``d`` by feature is the gradient's sweep
+    turned round: each feature whose run begins in the chunk has its
+    value placed at the run's first entry (``_windows``) and ``_run_scan``
+    copies it down the run (d + 0 + ... + 0: exact), the value a chunk
+    ends in carried into the next."""
+    C = chunks_l["values"].shape[-1]
+    bounds = _runs(chunks_l, span)
+
+    def body(c, state):
+        xd, last, carry = state
+        fl, rows, vals = _chunk(chunks_l, c)
+
+        def place(f0, at_head, at_tail, here, heads):
+            begins_here = here & (at_head >= 0) & (at_head < C)
+            d_w = lax.dynamic_slice_in_dim(d, f0, here.shape[0])
+            return heads.at[jnp.where(begins_here, at_head, C)].set(d_w, mode="drop")
+
+        heads = _windows(bounds, fl, (c - span[0]) * C, place, jnp.zeros(C, jnp.float32))
+        d_e, last, carry = _run_scan(heads, fl, last, carry)
+        return xd.at[rows].add(vals * d_e), last, carry
+
+    init = (jnp.zeros(per, jnp.float32), jnp.int32(-1), jnp.float32(0.0))
+    return lax.fori_loop(span[0], span[1], body, init)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -223,8 +403,9 @@ def shard_blocks_for_mesh(
 
 class DarlinFns:
     """The jitted mesh programs of the solver, each over one call's blocks
-    (``spans``/``blk``/``live`` say which chunks, which key ranges, and
-    which of the call's slots hold a block at all):
+    (``spans``/``blk``/``live`` say which chunks and which row of the
+    blocks' run ends, which key ranges, and which of the call's slots hold
+    a block at all):
 
     block_call — the blocks' proximal steps in order; returns the state,
       pred and {alphas (G,), obj, viol_max, nnz_w} after the call.
@@ -255,10 +436,11 @@ def make_darlin_fns(
     """Build the solver's jitted mesh programs (see DarlinFns).
 
     Layout: the table's slots P("kv", None); pred/labels/mask (D * per,)
-    P("data"); chunk arrays (D * n_chunks, C) P("data", None). Requires every block wholly
-    inside one kv range (contiguous equal blocks that divide the shard).
+    P("data"); chunk arrays (D * n_chunks, C) and the blocks' run ends
+    (D * B, block_size) P("data", None). Requires every block wholly inside
+    one kv range (contiguous equal blocks that divide the shard).
     """
-    from jax import lax, shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     kv = mesh.shape["kv"]
@@ -270,44 +452,6 @@ def make_darlin_fns(
         )
     per = per_shard_examples
     updater: ProxNewton = table.updater
-
-    def _chunk(chunks_l, c):
-        return tuple(
-            lax.dynamic_index_in_dim(chunks_l[k], c, 0, keepdims=False)
-            for k in ENTRY_ARRAYS
-        )
-
-    def _block_grad(err, h_ex, chunks_l, span, want_h: bool = True):
-        """This shard's (g, h) of one block: two sorted segment sums a
-        chunk, each taken from zero and then added to the block's. A key
-        that every example holds has 10^7 terms: added one by one into one
-        float32 they stop counting near 2^24 (the cell's integer columns: h
-        read 1.7% low); by chunks no sum is longer than a chunk or than the
-        chunks of a block. The barrier keeps the compiler from folding the
-        add back into one scatter onto the running sum."""
-        zero = jnp.zeros(block_size, jnp.float32)
-
-        def chunk_sum(fl, terms):
-            return lax.optimization_barrier(zero.at[fl].add(terms, indices_are_sorted=True))
-
-        def body(c, gh):
-            fl, rows, vals = _chunk(chunks_l, c)
-            g = gh[0] + chunk_sum(fl, vals * jnp.take(err, rows))
-            if not want_h:
-                return g, gh[1]
-            return g, gh[1] + chunk_sum(fl, vals * vals * jnp.take(h_ex, rows))
-
-        return lax.fori_loop(span[0], span[1], body, (zero, zero))
-
-    def _block_xd(d, chunks_l, span):
-        """This shard's X_b d: the block's entries scattered over its
-        examples, chunk by chunk."""
-
-        def body(c, xd):
-            fl, rows, vals = _chunk(chunks_l, c)
-            return xd.at[rows].add(vals * jnp.take(d, fl))
-
-        return lax.fori_loop(span[0], span[1], body, jnp.zeros(per, jnp.float32))
 
     def _rows_1d(rows):
         return {k: v[:, 0] for k, v in rows.items()}
@@ -341,7 +485,7 @@ def make_darlin_fns(
                 viol = jnp.where(on, updater.violation(rows, g).max(), 0.0)
                 d = jnp.where(on, updater.direction(rows, g, h), 0.0)
             with jax.named_scope("darlin.xd"):
-                xd = _block_xd(d, chunks_l, span)
+                xd = _block_xd(d, chunks_l, span, per)
             with jax.named_scope("darlin.linesearch"):
                 alpha = _line_search_alpha(pred_l, xd, y_l, mask_l, rows["w"], d, updater)
             with jax.named_scope("ps.push"):
@@ -369,7 +513,7 @@ def make_darlin_fns(
             state_l, n_active = carry
             span, b_idx, on = x
             begin = b_idx * block_size
-            g, _ = _block_grad(err, err, chunks_l, span, want_h=False)
+            (g,) = _block_grad(err, None, chunks_l, span, want_h=False)
             rows = _rows_1d(spmd.pull_range(table, state_l, begin, block_size, shard_size, kv))
             new = updater.refresh(rows, lax.psum(g, "data"), thr)
             new = {k: jnp.where(on, v, rows[k])[:, None] for k, v in new.items()}
@@ -393,7 +537,7 @@ def make_darlin_fns(
                 )
             with jax.named_scope("darlin.xd"):
                 w_b = jnp.where(on, updater.weights(rows), 0.0)
-                return pred_l + _block_xd(w_b, chunks_l, span), None
+                return pred_l + _block_xd(w_b, chunks_l, span, per), None
 
         pred_l, _ = lax.scan(block_step, pred_l, (spans_l, blk, live))
         return pred_l
@@ -403,7 +547,7 @@ def make_darlin_fns(
     # a shard's chunks are a plain (n_chunks, C) array on its device: the
     # shards' stacked on the first axis (a leading axis of one would have the
     # chip relay the whole set at every call)
-    chunks_s = {k: dat for k in ENTRY_ARRAYS}
+    chunks_s = {k: dat for k in (*ENTRY_ARRAYS, "ends")}
     call_s = (chunks_s, span_s, P(None), P(None))  # chunks, spans, blk, live
     scalars = {"alphas": P(), "obj": P(), "viol_max": P(), "nnz_w": P()}
 
@@ -430,7 +574,7 @@ def make_darlin_fns(
         sh = NamedSharding(mesh, dat)
         return {
             k: jax.device_put(sharded[k].reshape(-1, sharded[k].shape[-1]), sh)
-            for k in ENTRY_ARRAYS
+            for k in (*ENTRY_ARRAYS, "ends")
         }
 
     return DarlinFns(
@@ -511,6 +655,9 @@ class Darlin:
         if stream <= 0:
             sharded = shard_blocks_for_mesh(cb, D)
             self._resident = (self.fns.place_blocks(sharded), sharded["spans"])
+        carry_share, longest_run = run_carries(cb)
+        observe_scalar("darlin.carry_share", carry_share)
+        observe_scalar("darlin.longest_run_chunks", longest_run)
         self.passes_done, self.history, self.prev_obj, self.converged = 0, [], None, False
         if resume_dir:
             self._load(resume_dir)
@@ -533,6 +680,10 @@ class Darlin:
         padded to the call's fixed number of blocks with slots that hold
         none."""
         G, n = self.call_blocks, len(group)
+
+        def slots(a):  # (D, n, ...) -> (D, G, ...): the slots behind the group hold no block
+            return np.concatenate([a, np.zeros((a.shape[0], G - n, *a.shape[2:]), a.dtype)], axis=1)
+
         if self._resident is not None:
             chunks, all_spans = self._resident
             spans = all_spans[:, group]
@@ -540,8 +691,9 @@ class Darlin:
             sharded = shard_blocks_for_mesh(
                 self.cb, self.mesh.shape["data"], blocks=group, pad_pow2=True
             )
+            sharded["ends"] = slots(sharded["ends"])  # one shape a call
             chunks, spans = self.fns.place_blocks(sharded), sharded["spans"]
-        spans = np.concatenate([spans, np.zeros((spans.shape[0], G - n, 2), np.int32)], axis=1)
+        spans = slots(spans)
         blk = np.concatenate([group, np.zeros(G - n, group.dtype)]).astype(np.int32)
         return chunks, spans, blk, np.arange(G) < n
 

@@ -508,11 +508,14 @@ class TestShardBlocksPacking:
         return out
 
     def _unpack(self, packed, j, d, C):
-        lo, hi = packed["spans"][d, j]
+        lo, hi, at = packed["spans"][d, j]
         n = int(packed["counts"][j, d])
-        assert hi - lo == -(-n // C)
+        assert hi - lo == -(-n // C) and at == j
         flat = lambda k: packed[k][d, lo:hi].ravel()  # noqa: E731
         assert not flat("values")[n:].any()  # only the last chunk's tail is padding
+        # feature f's run of real entries is ends[f - 1] .. ends[f] of the part
+        runs = np.searchsorted(flat("feat_local")[:n], np.arange(packed["ends"].shape[-1]), side="right")
+        np.testing.assert_array_equal(packed["ends"][d, at], runs)
         return tuple(flat(k)[:n] for k in ("feat_local", "rows", "values")), flat("feat_local")
 
     @pytest.mark.parametrize("D", [1, 2, 4])
@@ -576,3 +579,133 @@ class TestDarlinStreaming:
         np.testing.assert_allclose(
             np.array(histories[4]), np.array(histories[0]), rtol=1e-5
         )
+
+
+# -- the sweeps by feature: running sums along the entry axis -----------------
+SWEEP_CHUNK, SWEEP_BLOCK = 16, 32
+
+
+_FEW = [3] * 5 + [4] * 2 + [9] * 7 + [20] * 1  # 0-2, 5-8, 10-19, 21-31 hold no entry
+# case -> (feature ids, block to sweep, data shards, want_h): block 0 or 1 of two
+# blocks of ``SWEEP_BLOCK`` features in chunks of ``SWEEP_CHUNK``
+SWEEP_CASES = {
+    # feature 5's run of 40 lies in chunks 0..2, of 90 in chunks 0..5
+    "a_run_crosses_two_chunk_boundaries": ([1] * 3 + [5] * 40 + [6] * 2 + [31] * 3, 0, 1, True),
+    "a_run_crosses_five_chunk_boundaries": ([1] * 3 + [5] * 90 + [7] * 3, 0, 1, True),
+    "no_entry_at_head_middle_and_tail": (_FEW, 0, 1, True),
+    "the_last_chunk_is_padded": (_FEW + [25] * 20, 0, 1, True),  # 35 entries: 13 pads
+    "an_empty_block": (_FEW, 1, 1, True),
+    "the_refresh_takes_g_alone": ([1] * 3 + [5] * 40 + [31] * 3, 0, 1, False),
+    "two_data_shards": ([1] * 3 + [5] * 90 + [6] * 2 + [31] * 3, 0, 2, True),
+}
+
+
+def _sweep_blocks(feats, seed=3):
+    """A built ``ColumnBlocks`` with one entry an example (so that X_b d lands
+    each entry's value of d in a row of its own) and seeded values."""
+    from parameter_server_tpu.data.blockcache import ColumnBlocksBuilder
+
+    rng = np.random.default_rng(seed)
+    n = len(feats)
+    builder = ColumnBlocksBuilder(num_keys=2 * SWEEP_BLOCK, n_blocks=2, chunk_len=SWEEP_CHUNK)
+    order = rng.permutation(n)  # the builder sorts by feature itself
+    builder.add(np.asarray(feats)[order], np.arange(n), rng.standard_normal(n).astype(np.float32),
+                np.zeros(n, np.float32))
+    return builder.finish()
+
+
+def _shard_args(cb, D):
+    """Per data shard: (the chunk arrays and run ends as the programs take
+    them, the spans row of each block), and the examples a shard."""
+    sharded = shard_blocks_for_mesh(cb, D)
+    parts = [
+        ({k: sharded[k][d] for k in ("feat_local", "rows", "values", "ends")}, sharded["spans"][d])
+        for d in range(D)
+    ]
+    return parts, sharded["per_shard_examples"]
+
+
+# features a window of a chunk's read: the whole block's 32 at once, and 5 (a
+# chunk's features lie in several windows, and the block's last starts early)
+WINDOWS = [4096, 5]
+
+
+@pytest.mark.parametrize("window", WINDOWS)
+@pytest.mark.parametrize("case", SWEEP_CASES)
+def test_a_blocks_sums_by_feature_equal_float64_segment_sums(case, window, monkeypatch):
+    """(g, h) of one block as the programs take them, every data shard's
+    part added up (the psum), against ``bincount`` in float64 over the
+    block's real entries."""
+    import jax
+
+    from parameter_server_tpu.models import darlin
+    from parameter_server_tpu.models.darlin import _block_grad
+
+    monkeypatch.setattr(darlin, "_WINDOW", window)
+    feats, b, D, want_h = SWEEP_CASES[case]
+    cb = _sweep_blocks(feats)
+    rng = np.random.default_rng(11)
+    parts, per = _shard_args(cb, D)
+    err = rng.standard_normal(D * per).astype(np.float32)
+    h_ex = rng.random(D * per).astype(np.float32)
+    fn = jax.jit(_block_grad, static_argnames="want_h")
+    got = sum(
+        np.asarray(fn(err[d * per : (d + 1) * per], h_ex[d * per : (d + 1) * per], chunks, spans[b],
+                      want_h=want_h), np.float64)
+        for d, (chunks, spans) in enumerate(parts)
+    )
+    feat, rows, vals = (np.asarray(a) for a in cb.block(b))
+    v = vals.astype(np.float64)
+    want = [np.bincount(feat, weights=v * err[rows], minlength=SWEEP_BLOCK)]
+    if want_h:
+        want.append(np.bincount(feat, weights=v * v * h_ex[rows], minlength=SWEEP_BLOCK))
+    assert got.shape == (len(want), SWEEP_BLOCK)
+    np.testing.assert_allclose(got, np.stack(want), rtol=1e-5, atol=1e-5)
+    absent = np.bincount(feat, minlength=SWEEP_BLOCK) == 0
+    assert not got[:, absent].any()  # no entry: exactly 0, not a neighbour's total
+
+
+@pytest.mark.parametrize("window", WINDOWS)
+@pytest.mark.parametrize("case", [c for c in SWEEP_CASES if c != "the_refresh_takes_g_alone"])
+def test_d_by_feature_is_copied_down_each_run_exactly(case, window, monkeypatch):
+    """X_b d where every entry is an example of its own and every value 1:
+    row i reads d of entry i's feature, to the bit what ``take(d, feat)``
+    gives (a run's head + 0 + ... + 0), and a row with no entry of the
+    block reads 0."""
+    import jax
+
+    from parameter_server_tpu.models import darlin
+    from parameter_server_tpu.models.darlin import _block_xd
+
+    monkeypatch.setattr(darlin, "_WINDOW", window)
+    feats, b, D, _ = SWEEP_CASES[case]
+    cb = _sweep_blocks(feats)
+    cb.values[...] = np.where(cb.values != 0, 1.0, 0.0)  # the pads stay 0
+    d_vec = np.random.default_rng(13).standard_normal(SWEEP_BLOCK).astype(np.float32)
+    parts, per = _shard_args(cb, D)
+    fn = jax.jit(_block_xd, static_argnames="per")
+    got = np.concatenate([
+        np.asarray(fn(d_vec, chunks, spans[b], per=per))
+        for chunks, spans in parts
+    ])
+    feat, rows, _ = cb.block(b)
+    want = np.zeros(D * per, np.float32)
+    want[rows] = d_vec[feat]
+    np.testing.assert_array_equal(got, want)
+
+
+def test_begin_observes_how_far_the_runs_carry():
+    """Feature 5's 90 entries lie in all 6 chunks of the block: 5 of the 6
+    chunks take up the run of the chunk before, and the longest run lies in
+    6 chunks; ``begin`` leaves both as scalars beside ``darlin.viol_max``."""
+    from parameter_server_tpu.models.darlin import run_carries
+    from parameter_server_tpu.utils.metrics import latency_histograms
+
+    cb = _sweep_blocks([1] * 3 + [5] * 90 + [7] * 3)
+    assert run_carries(cb) == (5 / 6, 6)
+    assert run_carries(_sweep_blocks([3] * 5 + [4] * 2 + [9] * 7)) == (0.0, 1)
+    latency_histograms.reset()
+    solver(make_cfg(num_keys=2 * SWEEP_BLOCK, blocks=2, iters=1)).begin(cb, shuffle_blocks=False)
+    hists = latency_histograms.snapshot()
+    assert hists["darlin.carry_share"]["sum_s"] * 1e6 == pytest.approx(5 / 6)
+    assert hists["darlin.longest_run_chunks"]["sum_s"] * 1e6 == pytest.approx(6)

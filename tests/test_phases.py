@@ -357,7 +357,9 @@ class TestPhaseCpu:
 
     def test_process_cpu_is_in_every_snapshot(self):
         a = timers.snapshot()["process.cpu"]
-        _spin(0.02)
+        until = time.process_time() + 0.02  # the process's own clock: a loaded machine hands a wall spin less
+        while time.process_time() < until:
+            sum(range(200))
         b = timers.snapshot()["process.cpu"]
         assert set(a) == set(b) == {"total_s", "count"}
         assert b["total_s"] >= a["total_s"] + 0.01  # never decreases, and the spin is in it

@@ -846,8 +846,9 @@ def darlin_compiled(topo):
         k: arg((DARLIN_CHUNKS, DARLIN_CHUNK), jnp.float32 if k == "values" else jnp.int32, P("data", None))
         for k in ("feat_local", "rows", "values")
     }
+    chunks["ends"] = arg((DARLIN_BLOCKS, DARLIN_KEYS // DARLIN_BLOCKS), jnp.int32, P("data", None))
     call = (
-        chunks, arg((1, DARLIN_CALL, 2), jnp.int32, P("data", None, None)),
+        chunks, arg((1, DARLIN_CALL, 3), jnp.int32, P("data", None, None)),
         arg((DARLIN_CALL,), jnp.int32, P(None)), arg((DARLIN_CALL,), jnp.bool_, P(None)),
     )
     out = {}
@@ -890,6 +891,42 @@ def test_darlin_block_call_carries_the_five_scopes_at_the_cells_shapes(darlin_co
     for _, name, shape, opcode, operand_shapes, _ in every:
         if opcode in ("gather", "scatter"):
             assert not any(table.search(s) for s in operand_shapes), (name, "a range is a slice, not a gather")
+
+
+def test_darlin_sums_by_feature_are_passes_along_the_entries(darlin_compiled):
+    """The mechanism engaged in both programs: neither the block call nor
+    the refresh holds a scatter of a chunk's 65,536 entries into a block's
+    ``f32[1048576]`` (the sorted scatter-adds the sums by feature were up
+    to PR 54), the running sums' passes, (lanes, <= 65,536) values a
+    chunk, are entries-minor (left alone XLA keeps the lane-padded layout of
+    the gather that made the terms), and the vectors over the examples that
+    the gradient gathers from keep their place in the fast memory space."""
+    block = DARLIN_KEYS // DARLIN_BLOCKS
+    for name, lanes in (("block_call", 2), ("refresh_call", 1)):
+        every = instructions(darlin_compiled[name][0])
+        chunk_scatters = [
+            (iname, shape) for _, iname, shape, opcode, operand_shapes, _ in every
+            if opcode == "scatter" and shape.startswith(f"f32[{block}]")
+            and any(s.startswith(f"s32[{DARLIN_CHUNK}") for s in operand_shapes)
+        ]
+        assert not chunk_scatters, (name, chunk_scatters)
+        passes = [
+            shape for _, _, shape, opcode, _, _ in every
+            if opcode in ("add", "select", "pad")
+            and (m := re.match(rf"f32\[{lanes},(\d+)\]", shape)) and DARLIN_CHUNK // 2 <= int(m.group(1)) <= DARLIN_CHUNK
+        ]
+        assert len(passes) >= 3 * 16, (name, len(passes))  # 16 gated shifted adds a chunk
+        assert all(re.match(r"f32\[\d+,\d+\]\{1,0[:}]", shape) for shape in passes), (name, passes)
+        # the gathers by example read out of the fast memory space (a buffer of
+        # a block's running sums once took the refresh's residual's place
+        # there: 17 ns an entry for 7.2, on the chip), and nothing is as long
+        # as a block's entries but the resident chunks themselves
+        sources = [
+            operand_shapes[0] for _, _, _, opcode, operand_shapes, _ in every
+            if opcode == "gather" and operand_shapes[0].startswith(f"f32[{DARLIN_N}]")
+        ]
+        assert len(sources) == lanes and all("S(1)" in shape for shape in sources), (name, sources)
+        assert darlin_compiled[name][1].temp_size_in_bytes < 64 << 20, name
 
 
 def test_darlin_programs_hold_the_entries_once(darlin_compiled):

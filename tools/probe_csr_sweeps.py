@@ -50,7 +50,48 @@ linear_grad 7.896 -> 4.803 / 4.879 / 4.864 / 4.849; wd_grad 20.006 ->
 (take, 1 lane, 8,192: 0.19 / 0.99 / 2.37 / 3.88 at 0 / 131,072 / 319,488 /
 524,288 real), 1-2 us a loop turn.
 
-    chiprun --timeout 900 -- python3 tools/probe_csr_sweeps.py [SEED] [--walk [PIECE ...]]
+``--by-feature [WINDOW ...]`` (step 0 of ISSUE 55; PERF.md section 6, PR 55)
+reads the batch solver's two sweeps by feature alone instead (no gather or
+scatter by example: the terms are given): 64 chunks of 65,536 entries sorted
+by feature into a block of 2^20 features, ``hot`` (30% of the entries one
+feature, a run of 19 chunks, the rest skewed) and ``short`` (uniform, no run
+over 17 entries), every form checked on the chip against today's. Forms, at
+1 lane (g alone, the refresh) and 2 (g and h, the step):
+  chunk_sum_today                  a sorted scatter-add a chunk and lane into a
+                                   zeroed ``f32[1048576]``, as
+                                   ``models/darlin.py`` had it up to PR 54
+  passes16 [scan_alone, read_alone]  a chunk's 16 gated passes
+                                   (``sparse._row_scan``) with the carry,
+                                   written into a buffer of the block's
+                                   running sums, and one gather of the 2^20
+                                   run ends out of it (the first form of PR
+                                   55; its two halves alone)
+  passes17_piece2 .. passes19_piece8  the same, 2 / 4 / 8 chunks a loop turn
+  passes5 (short only)             the passes the longest run needs
+  windowedW                        the program's form: no buffer, a chunk's
+                                   run ends read out of its own sums W
+                                   features at a time (``darlin._windows``)
+  take_d_today, spread_d, place_d_alone, spread_d_windowedW
+                                   d by feature: a take an entry; placed at
+                                   the run heads of a block-length buffer
+                                   and copied down; the placement alone; the
+                                   program's form, placed a window at a time
+Seed 2550000001, ms for the 64 chunks (4.19M entries; the least of three
+sets of twenty), 1 lane / 2 lanes, ``hot`` (``short`` within 0.3%):
+chunk_sum_today 37.31 / 74.33 (8.9 ns an entry and lane); passes16 13.20 /
+10.55 (scan_alone 5.81 / 6.11: 91-95 us a chunk, 2 us a launch of its 48;
+read_alone 7.53 / 4.59 with the 17-33 MB buffer in the fast memory space:
+in the cell, 218 MB in HBM, the read cost 19.6 ms a block); piece2 10.24 /
+7.47, piece4 10.67 / 8.00, piece8 10.77 / 8.35; passes5 10.03 / 7.35;
+windowed1024 14.90 / 23.62, 2048 14.98 / 23.71, 4096 15.72 / 25.14, 8192
+17.47 / 28.61, 16384 21.11 / 35.85 (a window costs about 12 us and 5.8 ns a
+feature and lane: 2^20 / W + 64 of them here, + 107 in a block of the cell,
+where 2,048 and 4,096 come to 22 ms and 1,024 to 27); take_d_today 30.28
+(7.2 ns an entry), spread_d 13.47 (place_d_alone 9.24: 8.8 ns a feature),
+spread_d_windowed1024 10.94, 2048 10.78, 4096 11.15, 8192 12.26, 16384
+14.70. About 4.5 chip-minutes.
+
+    chiprun --timeout 900 -- python3 tools/probe_csr_sweeps.py [SEED] [--walk [PIECE ...] | --by-feature [WINDOW ...]]
 """
 import json, os, sys, time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -97,15 +138,15 @@ def row_scan(x, row_ids, steps):
     return x
 
 
-def patched(op, name, value):
-    """``op`` traced with ``ops.sparse``'s global ``name`` set to ``value``."""
+def patched(op, name, value, module=sparse):
+    """``op`` traced with ``module``'s (``ops.sparse``'s) global ``name`` set to ``value``."""
     def f(*args):
-        kept = getattr(sparse, name)
-        setattr(sparse, name, value)
+        kept = getattr(module, name)
+        setattr(module, name, value)
         try:
             return op(*args)
         finally:
-            setattr(sparse, name, kept)
+            setattr(module, name, kept)
     return f
 
 
@@ -233,6 +274,158 @@ def grad_inputs(rng):
     return pulled, wide_deep.init_mlp(EMB, [1024, 512, 256], seed=SEED % 1000)
 
 
+def by_feature_forms(C=1 << 16, BS=1 << 20, N=64):
+    """Step 0 of ISSUE 55: the batch solver's sweeps by feature alone, over N
+    chunks of C entries sorted by feature into a block of BS features (no
+    gather or scatter by example: the terms are given). ``hot``: 30% of the
+    entries one feature (a run of 19 chunks, as an integer column's key),
+    the rest skewed; ``short``: uniform features, no run over a few entries."""
+    from jax import lax
+    from parameter_server_tpu.models import darlin
+    WINDOWS = tuple(int(a) for a in sys.argv[sys.argv.index("--by-feature") + 1:] if a.isdigit()) or (1024, 2048, 4096, 8192, 16384)
+    rng = np.random.default_rng(SEED)
+    L, LOG = N * C, C.bit_length() - 1  # the passes a chunk takes: 16
+
+    def data(hot_share):
+        n_hot = int(L * hot_share)
+        m = L - n_hot
+        rest = rng.integers(0, BS, m)
+        if hot_share:  # half of the rest skewed towards the low ids
+            rest = np.where(rng.random(m) < 0.5, rest, (BS * rng.random(m) ** 4).astype(np.int64))
+        feat = np.sort(np.concatenate([np.full(n_hot, BS // 3), rest])).astype(np.int32)
+        ends = np.bincount(feat, minlength=BS).cumsum().astype(np.int32)
+        runs = np.diff(np.concatenate([[0], ends]))
+        return jnp.asarray(feat.reshape(N, C)), jnp.asarray(ends), int(runs.max())
+
+    t = jnp.asarray(rng.standard_normal((2, N, C)).astype(np.float32))
+    d = jnp.asarray(rng.standard_normal(BS).astype(np.float32))
+
+    def today(lanes):
+        """``chunk_sum`` as ``models/darlin.py`` had it up to PR 54."""
+        def f(feat, t):
+            zero = jnp.zeros(BS, jnp.float32)
+
+            def body(c, gh):
+                return tuple(
+                    a + lax.optimization_barrier(zero.at[feat[c]].add(t[i, c], indices_are_sorted=True))
+                    for i, a in enumerate(gh))
+            return jnp.stack(lax.fori_loop(0, N, body, (zero,) * lanes))
+        return f
+
+    def read(buf, ends):
+        starts = jnp.concatenate([jnp.zeros(1, ends.dtype), ends[:-1]])
+        return jnp.where(ends > starts, jnp.take(buf, ends - 1, axis=-1, mode="clip"), 0)
+
+    def scan_buf(lanes, steps, piece):
+        """The running sums of every chunk in one buffer: ``piece`` chunks a loop turn."""
+        P = piece * C
+
+        def f(feat, t):
+            f2, t2 = feat.reshape(-1, P), t[:lanes].reshape(lanes, -1, P)
+
+            def body(c, st):
+                buf, last, carry = st
+                fl = f2[c]
+                s = row_scan(t2[:, c], fl, steps)
+                s = s + jnp.where(fl == last, carry[:, None], 0)
+                return lax.dynamic_update_slice_in_dim(buf, s, c * P, axis=-1), fl[-1], s[:, -1]
+            buf = sparse._entries_minor(jnp.zeros((lanes, L), jnp.float32))
+            return lax.fori_loop(0, N // piece, body, (buf, jnp.int32(-1), jnp.zeros(lanes, jnp.float32)))[0]
+        return f
+
+    def passes(lanes, steps=LOG, piece=1):
+        buf = scan_buf(lanes, steps, piece)
+        return lambda feat, t, ends: read(buf(feat, t), ends)
+
+    def place(d, ends):
+        starts = jnp.concatenate([jnp.zeros(1, ends.dtype), ends[:-1]])
+        return jnp.zeros(L, jnp.float32).at[starts].add(jnp.where(ends > starts, d, 0), indices_are_sorted=True, mode="drop")
+
+    def take_today(feat, d):
+        def body(c, out):
+            return lax.dynamic_update_slice_in_dim(out, jnp.take(d, feat[c]), c * C, axis=0)
+        return lax.fori_loop(0, N, body, jnp.zeros(L, jnp.float32))
+
+    def spread(feat, d, ends):
+        heads = place(d, ends)
+
+        def body(c, st):
+            out, last, carry = st
+            fl = feat[c]
+            s = sparse._row_scan(lax.dynamic_slice_in_dim(heads, c * C, C), fl)
+            s = s + jnp.where(fl == last, carry, 0)
+            return lax.dynamic_update_slice_in_dim(out, s, c * C, axis=0), fl[-1], s[-1]
+        return lax.fori_loop(0, N, body, (jnp.zeros(L, jnp.float32), jnp.int32(-1), jnp.float32(0.0)))[0]
+
+    def at_window(fn, window):
+        return patched(fn, "_WINDOW", window, darlin)
+
+    def windowed(lanes):
+        """The program's form (``models.darlin._block_grad`` less its gathers by
+        example): no buffer, a chunk's run ends read out of its own running
+        sums a window of the feature axis at a time."""
+        def f(feat, t, ends):
+            bounds = jnp.concatenate([jnp.zeros(1, ends.dtype), ends])
+
+            def body(c, st):
+                g, last, carry = st
+                fl = feat[c]
+                s, last, carry = darlin._run_scan(t[:lanes, c], fl, last, carry)
+
+                def read(f0, at_head, at_tail, here, g):
+                    ends_here = here & (at_tail >= 0) & (at_tail < C)
+                    old = lax.dynamic_slice_in_dim(g, f0, here.shape[0], axis=-1)
+                    got = jnp.stack([jnp.take(lane, at_tail, mode="clip") for lane in s])
+                    return sparse._entries_minor(lax.dynamic_update_slice_in_dim(g, jnp.where(ends_here, got, old), f0, axis=-1))
+                return darlin._windows(bounds, fl, c * C, read, g), last, carry
+            g = sparse._entries_minor(jnp.zeros((lanes, BS), jnp.float32))
+            return lax.fori_loop(0, N, body, (g, jnp.int32(-1), jnp.zeros(lanes, jnp.float32)))[0]
+        return f
+
+    def spread_windowed(feat, d, ends):
+        """``models.darlin._block_xd`` less its scatter-add by example."""
+        bounds = jnp.concatenate([jnp.zeros(1, ends.dtype), ends])
+
+        def body(c, st):
+            out, last, carry = st
+            fl = feat[c]
+
+            def place(f0, at_head, at_tail, here, heads):
+                begins_here = here & (at_head >= 0) & (at_head < C)
+                return heads.at[jnp.where(begins_here, at_head, C)].set(lax.dynamic_slice_in_dim(d, f0, here.shape[0]), mode="drop")
+            heads = darlin._windows(bounds, fl, c * C, place, jnp.zeros(C, jnp.float32))
+            s, last, carry = darlin._run_scan(heads, fl, last, carry)
+            return lax.dynamic_update_slice_in_dim(out, s, c * C, axis=0), last, carry
+        return lax.fori_loop(0, N, body, (jnp.zeros(L, jnp.float32), jnp.int32(-1), jnp.float32(0.0)))[0]
+
+    for kind, hot_share in (("hot", 0.3), ("short", 0.0)):
+        feat, ends, longest = data(hot_share)
+        more = {"data": kind, "chunks": N, "longest_run": longest}
+        for lanes in (1, 2):
+            want = emit(f"chunk_sum_today_{kind}", lanes, today(lanes), (feat, t), **more)
+            emit(f"passes{LOG}_{kind}", lanes, passes(lanes), (feat, t, ends), want, **more)
+            for window in WINDOWS:
+                emit(f"windowed{window}_{kind}", lanes, at_window(windowed(lanes), window), (feat, t, ends), want, **more)
+            if kind == "short":  # the passes a chunk's longest run needs, were they counted on the host
+                steps = max(int(longest - 1).bit_length(), 1)
+                emit(f"passes{steps}_{kind}", lanes, passes(lanes, steps), (feat, t, ends), want, **more)
+            else:
+                for piece in (2, 4, 8):
+                    steps = LOG + piece.bit_length() - 1
+                    emit(f"passes{steps}_piece{piece}_{kind}", lanes, passes(lanes, steps, piece), (feat, t, ends), want, **more)
+                buf = jax.jit(scan_buf(lanes, LOG, 1))(feat, t)
+                emit(f"scan_alone_{kind}", lanes, scan_buf(lanes, LOG, 1), (feat, t), **more)
+                emit(f"read_alone_{kind}", lanes, read, (buf, ends), want, **more)
+        taken = emit(f"take_d_today_{kind}", 1, take_today, (feat, d), **more)
+        emit(f"spread_d_{kind}", 1, spread, (feat, d, ends), taken, **more)
+        emit(f"place_d_alone_{kind}", 1, place, (d, ends), **more)
+        for window in WINDOWS:
+            emit(f"spread_d_windowed{window}_{kind}", 1, at_window(spread_windowed, window), (feat, d, ends), taken, **more)
+
+
+if "--by-feature" in sys.argv:
+    by_feature_forms()
+    sys.exit(0)
 b = bucket()
 row_ids = jax.jit(spmd._row_ids_of)(b)
 if "--walk" in sys.argv:
